@@ -169,11 +169,13 @@ def main(model_name="tiny", slots=4, cache_len=1024, page_size=16,
     warm_toks = sum(n for _, n in warm)
     flops_tok = dec["flops"] / max(toks_p + warm_toks, 1)
     bytes_tok = dec["hbm_bytes"] / max(toks_p + warm_toks, 1)
-    mfu = costs["mfu"] if costs["mfu"] is not None else 0.0
+    mfu = costs["mfu"]      # None off-chip: no DEVICE_PEAKS row to divide by
+    util = "mfu/roofline not measured (device has no peaks row)" \
+        if mfu is None else (f"mfu {mfu:.4f}  roofline "
+                             f"{costs['roofline_ratio']:.4f}")
     print(f"device cost (compiled decode program): "
           f"{flops_tok:10,.0f} FLOPs/tok  {bytes_tok:10,.0f} HBM B/tok  "
-          f"mfu {mfu:.4f}  roofline {costs['roofline_ratio'] or 0:.4f} "
-          f"(placeholder peaks; compiles {costs['compiles']}, "
+          f"{util} (compiles {costs['compiles']}, "
           f"recompiles {costs['recompiles']})")
     parity = all(np.array_equal(a, b) for a, b in zip(outs_d, outs_p))
     print(f"token parity dense vs paged: {parity}")
@@ -228,15 +230,15 @@ def main(model_name="tiny", slots=4, cache_len=1024, page_size=16,
         bench_track = importlib.util.module_from_spec(spec)
         spec.loader.exec_module(bench_track)
         note = (f"{model_name} model, {slots} slots, cache {cache_len},"
-                f" pg {page_size}; compiled-program pricing, "
-                f"placeholder peaks")
+                f" pg {page_size}; compiled-program pricing")
         for metric, value, unit in (
                 ("paged_decode_tokens_per_sec", toks_p / dt_p,
                  "tokens/s"),
                 ("paged_decode_flops_per_token", flops_tok, "flops"),
                 ("paged_decode_hbm_bytes_per_token", bytes_tok,
                  "bytes"),
-                ("paged_decode_mfu", mfu, "ratio"),
+                *([("paged_decode_mfu", mfu, "ratio")]
+                  if mfu is not None else []),
                 ("fused_decode_tokens_per_sec", toks_f / dt_f,
                  "tokens/s"),
                 ("fused_paged_goodput_ratio", good_f["goodput_ratio"],
@@ -374,7 +376,9 @@ if __name__ == "__main__":
     argv = sys.argv[1:]
     if "--mesh" in argv:
         # the forced host-device env must land BEFORE jax initializes
-        # (mesh_main imports jax lazily, so setting it here works)
+        # (mesh_main imports jax lazily, so setting it here works).
+        # ROADMAP C5: a CPU-mesh default behind a device-metric name;
+        # goes when the mesh column becomes a cell on the chip (A8).
         os.environ.setdefault("JAX_PLATFORMS", "cpu")
         flags = os.environ.get("XLA_FLAGS", "")
         if "xla_force_host_platform_device_count" not in flags:

@@ -131,7 +131,8 @@ def device_count():
 
 
 def set_device(device):
-    return device
+    from .device import set_device as _set_device
+    return _set_device(device)
 
 
 def get_device():

@@ -1,49 +1,15 @@
-"""JAX-version compatibility shims.
-
-The codebase targets the current JAX API; the installed runtime may be
-older (0.4.x). Anything whose home or signature moved between those
-worlds gets one canonical wrapper here. (The Pallas analogue,
-``tpu_compiler_params``, lives in ``ops/pallas/__init__.py`` next to
-its users.)
-"""
-import inspect
-
+"""Runtime capability probes: questions asked of the device, never of
+the JAX version (the repo targets the one installed JAX)."""
 import jax
 
-__all__ = ["shard_map", "axis_size", "host_memory_kind"]
+__all__ = ["host_memory_kind"]
 
 
 def host_memory_kind(device=None):
-    """Host-side memory kind for offload placement: ``pinned_host`` on
-    TPU/GPU (and newer CPU runtimes); older CPU backends only expose
-    ``unpinned_host``."""
+    """Host-side memory kind for offload placement: ``pinned_host``
+    where the device exposes it (TPU/GPU, newer CPU runtimes), else
+    ``unpinned_host``. A device that cannot list its memories raises —
+    a guessed kind would fail later, far from the cause."""
     dev = device if device is not None else jax.devices()[0]
-    try:
-        kinds = {m.kind for m in dev.addressable_memories()}
-    except Exception:
-        return "pinned_host"
+    kinds = {m.kind for m in dev.addressable_memories()}
     return "pinned_host" if "pinned_host" in kinds else "unpinned_host"
-
-
-def axis_size(axis_name):
-    """``jax.lax.axis_size`` (new API) or the 0.4.x idiom — ``psum`` of
-    a literal 1, which JAX folds to the static axis size at trace time
-    (no runtime collective)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-try:
-    _shard_map = jax.shard_map              # public since jax 0.5
-except AttributeError:                      # 0.4.x: experimental home
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-_HAS_VMA = "check_vma" in inspect.signature(_shard_map).parameters
-
-
-def shard_map(f, **kwargs):
-    """``jax.shard_map`` with the ``check_vma`` kwarg translated to the
-    0.4.x spelling (``check_rep``) when needed."""
-    if "check_vma" in kwargs and not _HAS_VMA:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    return _shard_map(f, **kwargs)

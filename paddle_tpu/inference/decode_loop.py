@@ -3,9 +3,9 @@
 The reference serves decode through ``fused_multi_transformer_op.cu``
 (/root/reference/paddle/fluid/operators/fused/fused_multi_transformer_op.cu)
 driven by a host loop: one kernel launch per generated token. On TPU the
-equivalent host loop pays a full dispatch round-trip per token (and over a
-remote-execution relay, several milliseconds), while the chip-side work of
-one decode step is sub-millisecond — decode becomes dispatch-bound.
+equivalent host loop pays a full dispatch round-trip per token, while the
+chip-side work of one decode step is sub-millisecond — decode becomes
+dispatch-bound.
 
 The TPU-native design runs the WHOLE decode loop on device as one XLA
 program: ``jax.lax.scan`` over positions with the KV caches as loop carry.
@@ -24,6 +24,7 @@ import jax
 import jax.numpy as jnp
 
 from ..core.tensor import unwrap
+from ..jit.hoist import hoisted_jit
 
 __all__ = ["scan_decode", "greedy_generate", "sample_generate",
            "beam_generate", "fsm_generate", "phrases_to_fsm",
@@ -42,7 +43,9 @@ def _pure(fn):
 # Compiled-program cache. Anchored on the step_fn (or, for bound methods,
 # its instance) via weakref so entries die with their owner; the key tuple
 # holds strong refs to every function identity the compiled program closed
-# over, so an id can never be reused for a stale hit.
+# over, so an id can never be reused for a stale hit. Programs are built
+# with hoisted_jit: whatever arrays the step closures captured (a model's
+# weights) ride as runtime arguments, not as constants of the executable.
 _JIT_CACHE = weakref.WeakKeyDictionary()
 
 
@@ -89,7 +92,7 @@ def scan_decode(step_fn, x0, caches, t0, steps, donate=True):
 
     jit_run = _cached_jit(
         step_fn, ("scan_decode", steps, donate),
-        lambda: jax.jit(run, donate_argnums=(1,) if donate else ()))
+        lambda: hoisted_jit(run, donate_argnums=(1,) if donate else ()))
     return jit_run(unwrap(x0), jax.tree_util.tree_map(unwrap, caches), t0)
 
 
@@ -144,7 +147,7 @@ def greedy_generate(embed_fn, step_fn, head_fn, caches, first_token, t0,
         step_fn,
         ("greedy_generate", embed_fn, head_fn, max_new_tokens,
          eos_token_id),
-        lambda: jax.jit(run))
+        lambda: hoisted_jit(run))
     return jit_run(unwrap(first_token),
                    jax.tree_util.tree_map(unwrap, caches), t0)
 
@@ -226,7 +229,7 @@ def sample_generate(embed_fn, step_fn, head_fn, caches, first_logits, t0,
         step_fn,
         ("sample_generate", embed_fn, head_fn, max_new_tokens,
          temperature, top_k, top_p, eos_token_id),
-        lambda: jax.jit(run))
+        lambda: hoisted_jit(run))
     return jit_run(unwrap(first_logits),
                    jax.tree_util.tree_map(unwrap, caches), t0, key)
 
@@ -314,7 +317,7 @@ def beam_generate(embed_fn, step_fn, head_fn, caches, first_logits, t0,
         step_fn,
         ("beam_generate", embed_fn, head_fn, max_new_tokens, K,
          eos_token_id),
-        lambda: jax.jit(run))
+        lambda: hoisted_jit(run))
     return jit_run(unwrap(first_logits),
                    jax.tree_util.tree_map(unwrap, caches),
                    jnp.asarray(t0, jnp.int32))
@@ -386,7 +389,7 @@ def fsm_generate(embed_fn, step_fn, head_fn, caches, first_logits, t0,
         step_fn,
         ("fsm_generate", embed_fn, head_fn, max_new_tokens, do_sample,
          temperature, top_k, top_p, eos_token_id, start_state),
-        lambda: jax.jit(run))
+        lambda: hoisted_jit(run))
     return jit_run(unwrap(first_logits),
                    jax.tree_util.tree_map(unwrap, caches),
                    jnp.asarray(t0, jnp.int32), key,

@@ -1776,9 +1776,9 @@ class RemoteReplica:
 def _host_main(factory, factory_kwargs, pipe, host, heartbeat_s,
                start_server):
     """Child-process entry point: build the server from the picklable
-    factory, serve it, report the bound port, park until shutdown."""
-    import os
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
+    factory, serve it, report the bound port, park until shutdown. The
+    child runs on whatever JAX finds in ITS environment — it is never
+    steered to the CPU behind the caller's back."""
     server = factory(**(factory_kwargs or {}))
     h = ReplicaHost(server, host=host, port=0,
                     heartbeat_s=heartbeat_s).start()
@@ -1799,7 +1799,15 @@ def spawn_replica_host(factory, factory_kwargs=None, host="127.0.0.1",
     ``RemoteReplica`` to ``address``, SIGKILL ``process`` to crash it
     for real. ``method`` is the multiprocessing start method
     (``"spawn"`` pays a fresh interpreter but never inherits jax
-    runtime state mid-flight)."""
+    runtime state mid-flight).
+
+    One process per chip: the child initialises its own JAX backend
+    from its inherited environment. A parent that has touched JAX
+    HOLDS its chip, so a child that needs the same chip fails (or
+    hangs until ``startup_timeout``) — loudly, here. Process-isolated
+    replicas need a chip each (or ``JAX_PLATFORMS=cpu`` in the
+    environment, as the tests set); in-process replicas share the
+    parent's devices."""
     import multiprocessing as mp
     ctx = mp.get_context(method)
     parent, child = ctx.Pipe()

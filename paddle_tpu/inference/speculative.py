@@ -17,7 +17,6 @@ those rows before they ever become visible.
 """
 import numpy as np
 
-import jax
 import jax.numpy as jnp
 
 from ..core.tensor import unwrap, wrap
@@ -69,8 +68,9 @@ def speculative_generate(target, draft, input_ids, max_new_tokens=32,
     _, d_caches = d_prefill(draft._prefill_embed(ids_j, None),
                             d_caches, jnp.int32(0))
 
-    verify_jit = jax.jit(
-        lambda x, caches, t: t_step(x, caches, t), donate_argnums=(1,))
+    # the verify forward IS the bundle's prefill program (step_fn over a
+    # multi-token block, caches donated, weights as runtime arguments)
+    verify_jit = t_prefill
 
     emitted = [a]
     t = T0                      # next feed position (token `a` sits here)

@@ -360,6 +360,23 @@ class _RingResultQueue:
         self._rings = []
 
 
+def _refuse_device_arrays(batch):
+    """Workers are FORKED from a parent that may hold an accelerator;
+    a forked child cannot use the parent's chip (it fails or hangs),
+    so workers must stay off JAX and hand back numpy. A batch carrying
+    a framework Tensor or a jax.Array broke that rule."""
+    import jax
+    for leaf in jax.tree_util.tree_leaves(
+            batch, is_leaf=lambda x: isinstance(x, Tensor)):
+        if isinstance(leaf, (Tensor, jax.Array)):
+            raise TypeError(
+                "DataLoader workers must stay off JAX: a worker process "
+                "is forked from a parent that may hold the accelerator "
+                "and cannot share it. Return numpy from the dataset/"
+                f"collate_fn (got {type(leaf).__name__}) or use "
+                "num_workers=0.")
+
+
 def _worker_loop(dataset, index_queue, result_queue, collate_fn, wid,
                  num_workers, worker_init_fn, use_shared_memory, seed,
                  ring_name=None, ring_slot=0):
@@ -404,6 +421,7 @@ def _worker_loop(dataset, index_queue, result_queue, collate_fn, wid,
         epoch, bidx, indices = item
         try:
             batch = collate_fn([dataset[i] for i in indices])
+            _refuse_device_arrays(batch)
             if use_shared_memory and ring_name is None:
                 batch = _tree_to_shm(batch)
             _send((epoch, bidx, True, batch))
@@ -417,7 +435,11 @@ class DataLoader:
     processes (fork) with per-worker index queues and a shared result
     queue; use_shared_memory routes numpy payloads through POSIX shared
     memory instead of pickle (reference
-    python/paddle/fluid/dataloader/dataloader_iter.py:162,370)."""
+    python/paddle/fluid/dataloader/dataloader_iter.py:162,370).
+    Workers are forked after JAX may have started, so they must stay
+    off JAX (one process per chip): datasets and collate functions run
+    in workers return numpy, and a batch holding device arrays is
+    refused with a typed error."""
 
     def __init__(self, dataset, feed_list=None, places=None,
                  return_list=True, batch_sampler=None, batch_size=1,

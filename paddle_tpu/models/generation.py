@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.tensor import unwrap, wrap
+from ..jit.hoist import hoisted_jit
 
 __all__ = ["GenerationMixin"]
 
@@ -81,6 +82,27 @@ def _apply_mesh(p, mesh, shard_dims, axis="mp"):
         return jax.device_put(w, sh)
 
     return {k: place(k, v) for k, v in p.items()}
+
+
+def _stacked_weights(model, weight_dtype, mesh, build, shard_dims):
+    """The model's stacked decode weight tree: built once per
+    ``(weight_dtype, mesh)`` and SHARED by every decode bundle of the
+    model — bundles differ in cache layout, never in weights, and the
+    server always holds a dense and a paged one. Holds one tree: asking
+    for another dtype or mesh replaces it (live bundles keep theirs)."""
+    cache = getattr(model, "_pt_stacked_weights", None)
+    if cache is None:
+        cache = model._pt_stacked_weights = {}
+    key = (weight_dtype, None if mesh is None else id(mesh))
+    if key not in cache:
+        p = build()
+        if weight_dtype == "int8":
+            p = _quantize_tree(p)
+        if mesh is not None:
+            p = _apply_mesh(p, mesh, shard_dims)
+        cache.clear()
+        cache[key] = p
+    return cache[key]
 
 
 def _mesh_caches(init_caches, mesh):
@@ -531,29 +553,29 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     cfg = model.cfg
     nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     eps = cfg.rms_eps
-    blocks = [dict(blk.raw_params()) for blk in model.model.layers]
-    p = {
-        "table": unwrap(model.model.embed_tokens.weight),
-        "norm": unwrap(model.model.norm.weight),
-        "head": unwrap(model.lm_head.weight),            # [H, V]
-        "ln1": _stacked(blocks, "input_layernorm.weight"),
-        "ln2": _stacked(blocks, "post_attention_layernorm.weight"),
-        "wq": _stacked(blocks, "self_attn.q_proj.weight"),
-        "wk": _stacked(blocks, "self_attn.k_proj.weight"),
-        "wv": _stacked(blocks, "self_attn.v_proj.weight"),
-        "wo": _stacked(blocks, "self_attn.o_proj.weight"),
-        "wg": _stacked(blocks, "mlp.gate_proj.weight"),
-        "wu": _stacked(blocks, "mlp.up_proj.weight"),
-        "wd": _stacked(blocks, "mlp.down_proj.weight"),
-    }
+
+    def stack():
+        blocks = [dict(blk.raw_params()) for blk in model.model.layers]
+        return {
+            "table": unwrap(model.model.embed_tokens.weight),
+            "norm": unwrap(model.model.norm.weight),
+            "head": unwrap(model.lm_head.weight),            # [H, V]
+            "ln1": _stacked(blocks, "input_layernorm.weight"),
+            "ln2": _stacked(blocks, "post_attention_layernorm.weight"),
+            "wq": _stacked(blocks, "self_attn.q_proj.weight"),
+            "wk": _stacked(blocks, "self_attn.k_proj.weight"),
+            "wv": _stacked(blocks, "self_attn.v_proj.weight"),
+            "wo": _stacked(blocks, "self_attn.o_proj.weight"),
+            "wg": _stacked(blocks, "mlp.gate_proj.weight"),
+            "wu": _stacked(blocks, "mlp.up_proj.weight"),
+            "wd": _stacked(blocks, "mlp.down_proj.weight"),
+        }
+
+    p = _stacked_weights(model, weight_dtype, mesh, stack, {
+        "wq": 2, "wk": 2, "wv": 2, "wg": 2, "wu": 2,   # column-parallel
+        "wo": 1, "wd": 1,                              # row-parallel
+        "head": 1})
     cos, sin = rope_mod.precompute_freqs(hd, max_cache_len, cfg.rope_theta)
-    if weight_dtype == "int8":
-        p = _quantize_tree(p)
-    if mesh is not None:
-        p = _apply_mesh(p, mesh, {
-            "wq": 2, "wk": 2, "wv": 2, "wg": 2, "wu": 2,   # column-parallel
-            "wo": 1, "wd": 1,                              # row-parallel
-            "head": 1})
     dtype = p["table"].dtype
     L = cfg.num_layers
     scale = 1.0 / np.sqrt(hd)
@@ -655,30 +677,30 @@ def _make_mixtral_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     cfg = model.cfg
     nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     eps = cfg.rms_eps
-    blocks = [dict(blk.raw_params()) for blk in model.model.layers]
-    p = {
-        "table": unwrap(model.model.embed_tokens.weight),
-        "norm": unwrap(model.model.norm.weight),
-        "head": unwrap(model.lm_head.weight),
-        "ln1": _stacked(blocks, "input_layernorm.weight"),
-        "ln2": _stacked(blocks, "post_attention_layernorm.weight"),
-        "wq": _stacked(blocks, "self_attn.q_proj.weight"),
-        "wk": _stacked(blocks, "self_attn.k_proj.weight"),
-        "wv": _stacked(blocks, "self_attn.v_proj.weight"),
-        "wo": _stacked(blocks, "self_attn.o_proj.weight"),
-        "router": _stacked(blocks, "moe.gate.gate.weight"),
-        "wg": _stacked(blocks, "moe.experts.w_gate"),
-        "wu": _stacked(blocks, "moe.experts.w_up"),
-        "wd": _stacked(blocks, "moe.experts.w_down"),
-    }
+
+    def stack():
+        blocks = [dict(blk.raw_params()) for blk in model.model.layers]
+        return {
+            "table": unwrap(model.model.embed_tokens.weight),
+            "norm": unwrap(model.model.norm.weight),
+            "head": unwrap(model.lm_head.weight),
+            "ln1": _stacked(blocks, "input_layernorm.weight"),
+            "ln2": _stacked(blocks, "post_attention_layernorm.weight"),
+            "wq": _stacked(blocks, "self_attn.q_proj.weight"),
+            "wk": _stacked(blocks, "self_attn.k_proj.weight"),
+            "wv": _stacked(blocks, "self_attn.v_proj.weight"),
+            "wo": _stacked(blocks, "self_attn.o_proj.weight"),
+            "router": _stacked(blocks, "moe.gate.gate.weight"),
+            "wg": _stacked(blocks, "moe.experts.w_gate"),
+            "wu": _stacked(blocks, "moe.experts.w_up"),
+            "wd": _stacked(blocks, "moe.experts.w_down"),
+        }
+
+    p = _stacked_weights(model, weight_dtype, mesh, stack, {
+        "wq": 2, "wk": 2, "wv": 2, "wo": 1,
+        "wg": 1, "wu": 1, "wd": 1,            # expert-parallel decode
+        "head": 1})
     cos, sin = rope_mod.precompute_freqs(hd, max_cache_len, cfg.rope_theta)
-    if weight_dtype == "int8":
-        p = _quantize_tree(p)
-    if mesh is not None:
-        p = _apply_mesh(p, mesh, {
-            "wq": 2, "wk": 2, "wv": 2, "wo": 1,
-            "wg": 1, "wu": 1, "wd": 1,        # expert-parallel decode
-            "head": 1})
     dtype = p["table"].dtype
     L = cfg.num_layers
     top_k = cfg.top_k
@@ -758,25 +780,26 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             f"max_cache_len ({max_cache_len}) exceeds the learned "
             f"position table ({cfg.max_seq_len}); positions past it "
             f"would silently clamp — shorten the cache or grow wpe")
-    blocks = [dict(blk.raw_params()) for blk in model.gpt.blocks]
-    p = {
-        "table": unwrap(model.gpt.wte.weight),           # [V, H] (tied)
-        "wpe": unwrap(model.gpt.wpe.weight),
-        "lnf_w": unwrap(model.gpt.ln_f.weight),
-        "lnf_b": unwrap(model.gpt.ln_f.bias),
-    }
-    for name in ("ln1.weight", "ln1.bias", "ln2.weight", "ln2.bias",
-                 "attn.qkv.weight", "attn.qkv.bias",
-                 "attn.proj.weight", "attn.proj.bias",
-                 "mlp.fc1.weight", "mlp.fc1.bias",
-                 "mlp.fc2.weight", "mlp.fc2.bias"):
-        p[name] = _stacked(blocks, name)
-    if weight_dtype == "int8":
-        p = _quantize_tree(p)
-    if mesh is not None:
-        p = _apply_mesh(p, mesh, {
-            "attn.qkv.weight": 2, "attn.proj.weight": 1,
-            "mlp.fc1.weight": 2, "mlp.fc2.weight": 1})
+
+    def stack():
+        blocks = [dict(blk.raw_params()) for blk in model.gpt.blocks]
+        tree = {
+            "table": unwrap(model.gpt.wte.weight),       # [V, H] (tied)
+            "wpe": unwrap(model.gpt.wpe.weight),
+            "lnf_w": unwrap(model.gpt.ln_f.weight),
+            "lnf_b": unwrap(model.gpt.ln_f.bias),
+        }
+        for name in ("ln1.weight", "ln1.bias", "ln2.weight", "ln2.bias",
+                     "attn.qkv.weight", "attn.qkv.bias",
+                     "attn.proj.weight", "attn.proj.bias",
+                     "mlp.fc1.weight", "mlp.fc1.bias",
+                     "mlp.fc2.weight", "mlp.fc2.bias"):
+            tree[name] = _stacked(blocks, name)
+        return tree
+
+    p = _stacked_weights(model, weight_dtype, mesh, stack, {
+        "attn.qkv.weight": 2, "attn.proj.weight": 1,
+        "mlp.fc1.weight": 2, "mlp.fc2.weight": 1})
     dtype = p["table"].dtype
     L = cfg.num_layers
     scale = 1.0 / np.sqrt(hd)
@@ -928,17 +951,21 @@ class GenerationMixin:
         # epilogue around it and jit the WHOLE tick as one dispatch.
         # Dense bundles stay 5-tuples for existing consumers
         # (deploy_decode, speculative).
+        # The bundle functions close over the stacked weight tree, so
+        # every program over them is built with hoisted_jit: the
+        # weights ride as runtime arguments, never as constants of the
+        # executable.
         extras = bundle[4:]
-        bundle = bundle[:4] + (jax.jit(bundle[2], donate_argnums=(1,)),)
+        bundle = bundle[:4] + (hoisted_jit(bundle[2], donate_argnums=(1,)),)
         if extras:
-            bundle = bundle + (jax.jit(extras[0], donate_argnums=(2,)),)
+            bundle = bundle + (hoisted_jit(extras[0], donate_argnums=(2,)),)
             if len(extras) > 1:
                 bundle = bundle + (extras[1],)
         cached[key] = bundle
-        # each bundle closes over a full stacked weight copy: cap the
-        # cache (LRU) so varied generate() shapes can't accumulate
-        # weight copies without bound. 4 covers the server's dense +
-        # paged pair twice over.
+        # a bundle pins its stacked weight tree (shared between the
+        # bundles of one weight_dtype/mesh, see _stacked_weights) and
+        # its compiled programs: cap the cache (LRU). 4 covers the
+        # server's dense + paged pair twice over.
         while len(cached) > 4:
             cached.pop(next(iter(cached)))
         return bundle
@@ -1076,5 +1103,7 @@ class GenerationMixin:
         return wrap(jnp.asarray(full))
 
     def reset_generate_cache(self):
-        """Drop cached decode programs (call after loading new weights)."""
+        """Drop cached decode programs and the stacked weight tree
+        they share (call after loading new weights)."""
         self._pt_decode_cache = None
+        self._pt_stacked_weights = None

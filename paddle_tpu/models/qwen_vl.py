@@ -145,6 +145,7 @@ class QwenVL(nn.Layer):
 
         from ..core.tensor import unwrap, wrap
         from ..inference.decode_loop import greedy_generate, sample_generate
+        from ..jit.hoist import hoisted_jit
         from .generation import _make_llama_decode_fns
 
         ids_np = np.asarray(unwrap(input_ids)).astype(np.int32)
@@ -177,7 +178,7 @@ class QwenVL(nn.Layer):
                                          model=self.language_model,
                                          lm_head=self.lm_head)
             fns = _make_llama_decode_fns(view, max_cache_len)
-            bundle = fns + (jax.jit(fns[2], donate_argnums=(1,)),)
+            bundle = fns + (hoisted_jit(fns[2], donate_argnums=(1,)),)
         cached[key] = bundle                   # LRU: newest at the back
         while len(cached) > 4:                 # bundles pin weight copies
             cached.pop(next(iter(cached)))

@@ -13,19 +13,8 @@ import jax
 
 @functools.lru_cache(maxsize=1)
 def on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
-
-
-def tpu_compiler_params(**kwargs):
-    """JAX-version compat shim for the Mosaic compiler-params struct:
-    newer JAX exposes ``pltpu.CompilerParams``, 0.4.x calls it
-    ``pltpu.TPUCompilerParams``. Every Pallas kernel in this package
-    builds its ``compiler_params`` through here."""
-    from jax.experimental.pallas import tpu as pltpu
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = pltpu.TPUCompilerParams
-    return cls(**kwargs)
+    """Whether the default backend is a TPU. A backend that fails to
+    initialise RAISES (and is not cached): answering False there would
+    send every kernel to its XLA reference on a machine that was meant
+    to have a chip."""
+    return jax.default_backend() == "tpu"

@@ -23,7 +23,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import on_tpu, tpu_compiler_params
+from . import on_tpu
 
 # v5e-swept defaults (benchmarks/flash_block_sweep.py): 1024/1024 is
 # 3.7x faster fwd and 4.5x fwd+bwd than 128/128; >1024 fails to compile
@@ -133,7 +133,7 @@ def _flash_fwd_pallas(q, k, v, sm_scale, causal,
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -284,7 +284,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
         out_specs=pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((bh, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -310,7 +310,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
         ],
         scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
                         pltpu.VMEM((block_k, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -455,12 +455,48 @@ def _fwl_bwd(sm_scale, causal, res, ct):
 flash_attention_with_lse.defvjp(_fwl_fwd, _fwl_bwd)
 
 
+def _mesh_partition(q):
+    """``(mesh, spec)`` for a [B, H, S, D] launch traced under a mesh
+    (``with mesh:``), else None. GSPMD refuses a Mosaic call ("Mosaic
+    kernels cannot be automatically partitioned. Please wrap the call
+    in a shard_map"), so under a mesh the Pallas path shard_maps
+    itself: batch over the data axes (``dp`` x ``sharding``) that
+    divide it, heads over ``mp``, everything else replicated. Inside a
+    shard_map already (pipeline stages, ring attention) the axes are
+    bound and the launch is per-shard as it stands."""
+    from jax.interpreters import pxla
+    from jax.sharding import PartitionSpec as P
+    mesh = pxla.thread_resources.env.physical_mesh
+    if mesh.empty or mesh.size == 1 or jax.core.nonempty_axis_env_DO_NOT_USE():
+        return None
+    sizes = dict(mesh.shape)
+    data = tuple(a for a in ("dp", "sharding") if sizes.get(a, 1) > 1)
+    while data and q.shape[0] % math.prod(sizes[a] for a in data):
+        data = data[:-1]
+    heads = "mp" if (sizes.get("mp", 1) > 1
+                     and q.shape[1] % sizes["mp"] == 0) else None
+    return mesh, P(data or None, heads, None, None)
+
+
+def _attend(q, k, v, sm_scale, causal):
+    """[B, H, S, D] attention: ``_flash``, split over the active mesh
+    when the Pallas path runs under one."""
+    part = _mesh_partition(q) if _pallas_ok(q, k) else None
+    if part is None:
+        return _flash(q, k, v, sm_scale, causal)
+    mesh, spec = part
+    return jax.shard_map(
+        lambda q_, k_, v_: _flash(q_, k_, v_, sm_scale, causal),
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
+
+
 def flash_attention(q, k, v, causal=False, sm_scale=None):
     """q,k,v: paddle layout [batch, seq, num_heads, head_dim]."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     qt, kt, vt = (jnp.swapaxes(t, 1, 2) for t in (q, k, v))
-    o = _flash(qt, kt, vt, sm_scale, causal)
+    o = _attend(qt, kt, vt, sm_scale, causal)
     return jnp.swapaxes(o, 1, 2)
 
 
@@ -468,4 +504,4 @@ def flash_attention_bhsd(q, k, v, causal=False, sm_scale=None):
     """Same kernel, [batch, heads, seq, dim] layout (no transposes)."""
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    return _flash(q, k, v, sm_scale, causal)
+    return _attend(q, k, v, sm_scale, causal)

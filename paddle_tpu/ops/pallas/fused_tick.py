@@ -53,15 +53,37 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-from . import on_tpu, tpu_compiler_params
+from . import on_tpu
 from .paged_attention import NEG_INF
 from .ragged_prefill import _QUERY_TILE
 
-__all__ = ["fused_tick_attention", "build_schedule", "available"]
+__all__ = ["fused_tick_attention", "build_schedule", "available",
+           "refuse_on_tpu"]
 
 
 def available() -> bool:
     return on_tpu()
+
+
+def refuse_on_tpu():
+    """The Mosaic build of this kernel compiles for a v5e and then
+    HALTS THE CORE when it runs (PR 21, ``chip_smoke.py``): standalone,
+    at GPT-2 345M geometry (8 slots, 16 heads x 64, page 16, 8-row query
+    tiles, f32 and bf16), with a ladder-padded and with an exact
+    schedule alike, and the process that launched it loses the chip. So
+    on a real TPU the kernel — and ``serving_mode="fused"`` on top of it
+    — refuses instead of either crashing the chip or quietly handing
+    over to ``_ref_fused_tick``. The interpreter (``interpret=True``)
+    and the XLA composition off-TPU are unaffected."""
+    if jax.default_backend() == "tpu":
+        raise NotImplementedError(
+            "the fused-tick Pallas kernel halts a TPU v5e core at run "
+            "time (libtpu 0.0.34: 'Core halted unexpectedly ... "
+            "perhaps due to an on-device check-failure', "
+            "TensorCoreSequencer) although Mosaic compiles it; until "
+            "its Mosaic bring-up lands (ROADMAP A1) it runs only under "
+            "interpret=True or as the XLA composition off-TPU — use "
+            "serving_mode='split' on the chip")
 
 
 # ------------------------------------------------------------- schedule
@@ -269,7 +291,7 @@ def _fused_tick_pallas(q, k_pages, v_pages, block_tables, t0, sched_slot,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(flat_bt, t0.astype(jnp.int32), sched_slot.astype(jnp.int32),
@@ -360,6 +382,8 @@ def fused_tick_attention(q, k_pages, v_pages, block_tables, t0, last,
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    if not interpret:
+        refuse_on_tpu()
     if available() or interpret:
         # tile wide chunks down to the ragged kernel's VMEM-bounded
         # row count; each tile is a shifted-offset launch against the

@@ -33,7 +33,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import on_tpu, tpu_compiler_params
+from . import on_tpu
 
 NEG_INF = -1e30
 
@@ -163,7 +163,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(flat_bt, lengths.astype(jnp.int32), q, k_pages, v_pages)
@@ -202,12 +202,11 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths,
     one replicated launch."""
     from jax.sharding import PartitionSpec as P
 
-    from ..._compat import shard_map
     if kv_head_shards(mesh, k_pages.shape[2], q.shape[1], axis) <= 1:
         return None
     fn = functools.partial(_paged_attention_pallas, sm_scale=sm_scale,
                            interpret=interpret)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, axis, None), P(None, None, axis, None),
                   P(None, None, axis, None), P(None, None), P(None)),

@@ -16,8 +16,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from .flash_attention import on_tpu
-from . import tpu_compiler_params
+from . import on_tpu
 
 DEFAULT_BLOCK_M = 256
 DEFAULT_BLOCK_N = 256
@@ -45,14 +44,16 @@ def _qmm_kernel(x_ref, w_ref, sx_ref, sw_ref, o_ref, acc_scr, *, nk):
     def _done():
         # fused dequant epilogue: per-tensor x scale, per-channel w scale
         o_ref[...] = (acc_scr[...].astype(jnp.float32)
-                      * sx_ref[0] * sw_ref[...][None, :]).astype(o_ref.dtype)
+                      * sx_ref[0, 0] * sw_ref[...]).astype(o_ref.dtype)
 
 
 def quantized_matmul(x, w, scale_x, scale_w, block_m=DEFAULT_BLOCK_M,
                      block_n=DEFAULT_BLOCK_N, block_k=DEFAULT_BLOCK_K,
                      interpret=False, out_dtype=jnp.float32):
     """x: int8 [M, K]; w: int8 [K, N]; scale_x scalar; scale_w scalar or
-    [N]. Returns dequantized [M, N] in ``out_dtype``."""
+    [N]. Returns dequantized [M, N] in ``out_dtype``. Pallas kernel on
+    TPU (or under ``interpret=True``) for block-divisible shapes; the
+    XLA int32-accumulate composition elsewhere."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -61,13 +62,15 @@ def quantized_matmul(x, w, scale_x, scale_w, block_m=DEFAULT_BLOCK_M,
     bm = min(block_m, m)
     bn = min(block_n, n)
     bk = min(block_k, k)
-    sw = jnp.broadcast_to(jnp.asarray(scale_w, jnp.float32), (n,))
-    sx = jnp.asarray(scale_x, jnp.float32).reshape(1)
-    if m % bm or n % bn or k % bk:
-        # ragged shapes: plain XLA path (still int32 MXU accumulate)
+    # scales ride as 2-D operands: Mosaic refuses a 1-D (bn,) block
+    # (its T(256) layout does not match XLA's T(1024) for 1-D f32)
+    sw = jnp.broadcast_to(jnp.asarray(scale_w, jnp.float32), (n,))[None]
+    sx = jnp.asarray(scale_x, jnp.float32).reshape(1, 1)
+    if not (available() or interpret) or m % bm or n % bn or k % bk:
+        # off-TPU and ragged shapes: plain XLA path (int32 accumulate)
         acc = jax.lax.dot_general(x, w, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.int32)
-        return (acc.astype(jnp.float32) * sx * sw[None, :]).astype(out_dtype)
+        return (acc.astype(jnp.float32) * sx * sw).astype(out_dtype)
 
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(
@@ -76,13 +79,13 @@ def quantized_matmul(x, w, scale_x, scale_w, block_m=DEFAULT_BLOCK_M,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((1,), lambda i, j, kk: (0,)),
-            pl.BlockSpec((bn,), lambda i, j, kk: (j,)),
+            pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
+            pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x, w, sx, sw)

@@ -37,7 +37,7 @@ import math
 import jax
 import jax.numpy as jnp
 
-from . import on_tpu, tpu_compiler_params
+from . import on_tpu
 from .paged_attention import NEG_INF
 
 __all__ = ["ragged_prefill_attention", "available"]
@@ -188,7 +188,7 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, last,
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, hd), q.dtype),
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(flat_bt, t0.astype(jnp.int32), last.astype(jnp.int32),
@@ -209,13 +209,12 @@ def _ragged_prefill_sharded(q, k_pages, v_pages, block_tables, t0, last,
     axis; the caller then runs one replicated launch."""
     from jax.sharding import PartitionSpec as P
 
-    from ..._compat import shard_map
     from .paged_attention import kv_head_shards
     if kv_head_shards(mesh, k_pages.shape[2], q.shape[2], axis) <= 1:
         return None
     fn = functools.partial(_ragged_prefill_pallas, sm_scale=sm_scale,
                            interpret=interpret)
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, None, axis, None), P(None, None, axis, None),
                   P(None, None, axis, None), P(None, None), P(None),

@@ -19,7 +19,6 @@ import math
 
 import jax
 import jax.numpy as jnp
-from ..._compat import axis_size as _axis_size
 
 NEG_INF = -1e30
 
@@ -57,7 +56,7 @@ def ring_attention(q, k, v, axis_name="sp", causal=True, sm_scale=None):
 
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     my = jax.lax.axis_index(axis_name)
     b, h, sq, d = q.shape
     # GQA: permute the RAW kv shards (ICI bytes stay at the kv-head
@@ -110,7 +109,7 @@ def ulysses_attention(q, k, v, axis_name="sp", causal=True, sm_scale=None,
     """DeepSpeed-Ulysses alternative: all_to_all heads<->sequence so each
     rank holds ALL tokens for H/n heads, runs full (flash) attention
     locally, then all_to_alls back. Needs heads % axis_size == 0."""
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if q.shape[1] % n != 0:
         raise ValueError(
             f"ulysses_attention: local heads {q.shape[1]} not divisible "

@@ -11,7 +11,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import on_tpu, tpu_compiler_params
+from . import on_tpu
 
 
 def available() -> bool:
@@ -120,7 +120,7 @@ def _pallas_bwd(x, w, g, eps, block_rows=256, interpret=False):
             jax.ShapeDtypeStruct((1, d), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, d), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(x2, w.reshape(1, d), g2)

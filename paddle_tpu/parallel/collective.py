@@ -21,7 +21,6 @@ import numpy as np
 
 from ..core.tensor import Tensor, dispatch, unwrap, wrap
 from .mesh import get_mesh
-from .._compat import axis_size as _axis_size
 
 __all__ = ["ReduceOp", "all_reduce", "all_gather", "all_gather_object",
            "reduce_scatter", "broadcast", "reduce", "scatter", "alltoall",
@@ -382,7 +381,7 @@ def alltoall_single(out_tensor, in_tensor, in_split_sizes=None,
         # ragged case needs per-process programs and maps to the
         # object/host APIs instead.
         sizes = np.asarray(in_split_sizes, np.int64)
-        world = _axis_size(ax)
+        world = jax.lax.axis_size(ax)
         if sizes.shape != (world, world):
             raise ValueError(f"size matrix must be [{world}, {world}], "
                              f"got {sizes.shape}")
@@ -455,7 +454,7 @@ def partial_allgather(tensor, nranks=None, rank_id=None, group=None):
     ax = axis_or_none(group)
     if ax is None:
         return tensor
-    world = _axis_size(ax)
+    world = jax.lax.axis_size(ax)
     nranks = nranks or world
     if nranks != world:
         raise ValueError(f"partial_allgather nranks={nranks} != group "
@@ -484,7 +483,7 @@ def partial_ppermute(tensor, perm, nranks=None, index=None, group=None):
     ax = axis_or_none(group)
     if ax is None:
         return tensor
-    nranks = nranks or _axis_size(ax)
+    nranks = nranks or jax.lax.axis_size(ax)
 
     def fn(v):
         if v.shape[0] % nranks != 0:

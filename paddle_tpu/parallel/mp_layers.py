@@ -26,7 +26,6 @@ from ..nn.layer import Layer
 from . import mp_ops
 from .collective import in_shard_map
 from .mesh import P, get_mesh
-from .._compat import axis_size as _axis_size
 
 __all__ = ["VocabParallelEmbedding", "ColumnParallelLinear",
            "RowParallelLinear", "ParallelCrossEntropy"]
@@ -62,7 +61,7 @@ class VocabParallelEmbedding(Layer):
         if in_shard_map():
             # explicit: local rows hold [start, end); mask + psum
             def fn(idx, w):
-                n = _axis_size("mp")
+                n = jax.lax.axis_size("mp")
                 rank = jax.lax.axis_index("mp")
                 rows = w.shape[0]
                 start = rank * rows

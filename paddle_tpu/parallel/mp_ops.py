@@ -18,7 +18,6 @@ import jax
 import jax.numpy as jnp
 
 from .collective import axis_or_none
-from .._compat import axis_size as _axis_size
 
 __all__ = ["c_identity", "mp_allreduce", "c_split", "c_concat",
            "c_softmax_with_cross_entropy"]
@@ -77,7 +76,7 @@ def c_split(x, group=None, axis=-1):
     ax = axis_or_none(group or "mp")
     if ax is None:
         return x
-    n = _axis_size(ax)
+    n = jax.lax.axis_size(ax)
     idx = jax.lax.axis_index(ax)
     size = x.shape[axis] // n
     return jax.lax.dynamic_slice_in_dim(x, idx * size, size, axis=axis)

@@ -37,7 +37,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from .mesh import HybridMesh, P
-from .._compat import shard_map as _shard_map
 from .pp_schedules import (Schedule, build_schedule, FwdSchedule,
                            build_forward_schedule)
 
@@ -574,7 +573,7 @@ def build_pp_forward_step(block_fn, embed_fn, head_fn,
 
     in_specs = (blocks_spec, embed_spec, head_spec, bspec, bspec)
 
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         sharded_body, mesh=mesh.mesh, in_specs=in_specs,
         out_specs=out_spec, check_vma=False)
 
@@ -823,7 +822,7 @@ def build_1f1b_train_step(block_fn, embed_fn, head_loss_fn,
     in_specs = (blocks_spec, embed_spec, head_spec, bspec, bspec, P())
     out_specs = (P(), blocks_spec, embed_spec, head_spec)
 
-    smapped = _shard_map(
+    smapped = jax.shard_map(
         sharded_body, mesh=mesh.mesh, in_specs=in_specs,
         out_specs=out_specs, check_vma=False)
 

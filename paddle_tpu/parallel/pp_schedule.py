@@ -26,7 +26,6 @@ import numpy as np
 from jax.sharding import NamedSharding
 
 from .mesh import HybridMesh, P
-from .._compat import shard_map as _shard_map
 
 __all__ = ["stack_stage_params", "spmd_pipeline_forward",
            "pipeline_train_step"]
@@ -140,7 +139,7 @@ def pipeline_train_step(pipe, embed_fn, head_loss_fn, optimizer,
         B = x.shape[0]
         mb = B // num_micro
         x_micro = x.reshape((num_micro, mb) + x.shape[1:])
-        outs = _shard_map(
+        outs = jax.shard_map(
             body, mesh=mesh.mesh,
             in_specs=in_specs_body,
             out_specs=P(None, "dp"),

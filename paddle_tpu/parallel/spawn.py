@@ -36,7 +36,16 @@ def spawn(func, args=(), nprocs=1, join=True, daemon=False, **options):
     """paddle.distributed.spawn parity: run ``func`` in ``nprocs``
     processes with the launcher's env protocol (PADDLE_TRAINER_ID /
     PADDLE_TRAINERS_NUM). Returns the process list (a MultiprocessContext
-    stand-in when join=False)."""
+    stand-in when join=False).
+
+    One process per chip: every worker is a fresh interpreter that
+    initialises its OWN JAX backend from the parent's environment plus
+    ``env=`` (that is where a worker is told what it runs on —
+    ``JAX_PLATFORMS``, a per-rank device mask). A parent that has
+    already touched JAX on a TPU holds that chip, and a worker that
+    needs it fails or hangs: keep the parent off JAX, as
+    ``parallel/launch/main.py`` does, or hand each worker its own
+    devices through ``env=``."""
     ctx = mp.get_context("spawn")
     base_env = {k: str(v) for k, v in options.get("env", {}).items()}
     procs = []

@@ -174,8 +174,7 @@ class Int8InferLinear(Layer):
             shape = xv.shape
             x2 = xv.reshape(-1, shape[-1])
             qx, sx = qm.quantize_tensor(x2)
-            out = qm.quantized_matmul(
-                qx, qw, sx, sw, interpret=not qm.available())
+            out = qm.quantized_matmul(qx, qw, sx, sw)
             return out.reshape(shape[:-1] + (out.shape[-1],)).astype(
                 xv.dtype)
 

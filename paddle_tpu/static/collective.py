@@ -19,7 +19,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-from .._compat import shard_map as _shard_map
 from ..core.tensor import dispatch
 
 
@@ -191,7 +190,7 @@ def run_program_sharded(program, mesh, feed, fetch_list, in_specs,
     m = mesh.mesh if hasattr(mesh, "mesh") else mesh
     specs = tuple(in_specs.get(n, P()) for n in feed_names) + \
         tuple(P() for _ in scope_names)
-    out = _shard_map(body, mesh=m, in_specs=specs,
+    out = jax.shard_map(body, mesh=m, in_specs=specs,
                         out_specs=tuple(out_specs.get(n, P())
                                         for n in fetch_names),
                         check_vma=check_vma)(

@@ -48,12 +48,12 @@ layer turns the serving stack's host->device dispatch profile (PR 9's
 - **MFU / roofline**: per charged tick, achieved FLOPs/s over
   ``peak_flops`` is published as the ``serving_mfu`` gauge (and
   ``roofline_ratio`` — the max of the FLOPs and HBM-bandwidth
-  utilizations — rides ``snapshot()``). Peaks are injectable; the
-  defaults are CPU-safe placeholders (1 TFLOP/s, 100 GB/s) so the
-  gauge is well-defined on any backend — inject real chip numbers in
-  production. ``serving_mfu`` merges across a fleet by MEAN on
-  ``/fleet`` (``exposition.merge_snapshots``), like ``*_ratio``
-  gauges.
+  utilizations — rides ``snapshot()``). Peaks come from
+  ``DEVICE_PEAKS`` (one table keyed by ``device_kind``, with its
+  source) or are injected; a device with no row — the CPU — has no
+  peak, so no utilisation is computed and no gauge is published
+  there. ``serving_mfu`` merges across a fleet by MEAN on ``/fleet``
+  (``exposition.merge_snapshots``), like ``*_ratio`` gauges.
 
 Cost contract (mirrors ``FlightRecorder``/``GoodputLedger``):
 ``charge``/``charge_bytes``/``add_phase`` are plain dict bumps under
@@ -86,7 +86,7 @@ import threading
 from .clock import MonotonicClock
 
 __all__ = ["CostCatalog", "COMPILE_BUCKETS", "PHASE_BUCKETS",
-           "TICK_PHASES"]
+           "TICK_PHASES", "DEVICE_PEAKS", "device_peaks"]
 
 # compiles span ~10 ms (tiny CPU programs) to minutes (big TPU fusions)
 COMPILE_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
@@ -97,11 +97,30 @@ PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
 TICK_PHASES = ("admission", "prefill_launch", "decode_launch",
                "fused_launch", "token_callbacks", "bookkeeping")
 
-# CPU-safe placeholder peaks: any positive number keeps the MFU gauge
-# well-defined without hardware introspection; inject the real chip
-# numbers (e.g. v5e: 197e12 bf16 FLOP/s, 819e9 B/s HBM) in production
-DEFAULT_PEAK_FLOPS = 1e12
-DEFAULT_PEAK_HBM = 1e11
+# The one peaks table: ``device_kind`` (as ``jax.devices()[0]`` reports
+# it) -> (bf16 FLOP/s, HBM bytes/s) of one chip. Every utilisation in
+# the repo divides by a row of this table; a kind with no row has no
+# utilisation. Source: Google Cloud documentation, "TPU v5e" (197
+# TFLOP/s bf16, 819 GB/s HBM).
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+}
+
+
+def device_peaks(device_kind=None):
+    """``(peak bf16 FLOP/s, peak HBM bytes/s)`` for ``device_kind``
+    (default: the first JAX device's). An unknown kind raises — a
+    measurement against a guessed peak is not a measurement."""
+    if device_kind is None:
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise LookupError(
+            f"no peak FLOP/s / HBM bandwidth on record for device kind "
+            f"{device_kind!r}; add a sourced row to "
+            f"telemetry.costs.DEVICE_PEAKS") from None
 
 
 def _signature(args):
@@ -146,12 +165,17 @@ class _PricedProgram:
         self._catalog.charge(self)
         return out
 
+    @property
+    def executable(self):
+        """What a dispatch runs: the compiled stage (``as_text``,
+        ``memory_analysis``…), or the raw callable if pricing failed."""
+        return self._fn
+
 
 class CostCatalog:
     """Compiled-program cost catalog + compile watch + tick phases.
 
-    >>> cat = CostCatalog(registry=tele.registry,
-    ...                   peak_flops=197e12, peak_hbm_bytes_per_s=819e9)
+    >>> cat = CostCatalog(registry=tele.registry)
     >>> srv = ContinuousBatchingServer(model, ..., costs=cat)
     >>> srv.run()
     >>> cat.snapshot()["ops"]["decode"]["flops"]
@@ -163,14 +187,26 @@ class CostCatalog:
                  warm_after_ticks=2):
         self.enabled = bool(enabled)
         self.clock = clock if clock is not None else MonotonicClock()
-        self.peak_flops = float(peak_flops if peak_flops is not None
-                                else DEFAULT_PEAK_FLOPS)
-        self.peak_hbm_bytes_per_s = float(
-            peak_hbm_bytes_per_s if peak_hbm_bytes_per_s is not None
-            else DEFAULT_PEAK_HBM)
-        if self.peak_flops <= 0 or self.peak_hbm_bytes_per_s <= 0:
-            raise ValueError("peak_flops / peak_hbm_bytes_per_s must "
-                             "be > 0")
+        if peak_flops is None or peak_hbm_bytes_per_s is None:
+            import jax
+            row = DEVICE_PEAKS.get(jax.devices()[0].device_kind,
+                                   (None, None))
+            if peak_flops is None:
+                peak_flops = row[0]
+            if peak_hbm_bytes_per_s is None:
+                peak_hbm_bytes_per_s = row[1]
+        # None = this device has no row: nothing to divide by, so no
+        # MFU / roofline is computed and no gauge is registered
+        self.peak_flops = None if peak_flops is None else float(peak_flops)
+        self.peak_hbm_bytes_per_s = (
+            None if peak_hbm_bytes_per_s is None
+            else float(peak_hbm_bytes_per_s))
+        for peak in (self.peak_flops, self.peak_hbm_bytes_per_s):
+            if peak is not None and peak <= 0:
+                raise ValueError("peak_flops / peak_hbm_bytes_per_s "
+                                 "must be > 0")
+        self._has_peaks = (self.peak_flops is not None
+                           and self.peak_hbm_bytes_per_s is not None)
         self._warm_after = int(warm_after_ticks)
         self._lock = threading.Lock()
         self._programs = {}       # (op, sig) -> _PricedProgram
@@ -224,10 +260,12 @@ class CostCatalog:
                 "bookkeeping) — the host-bound-vs-device-bound "
                 "verdict", labelnames=("phase",),
                 buckets=PHASE_BUCKETS)
-            self._g_mfu = registry.gauge(
-                "serving_mfu",
-                "Achieved FLOP/s over peak_flops for the last charged "
-                "tick (merged by MEAN on /fleet, like *_ratio gauges)")
+            if self._has_peaks:
+                self._g_mfu = registry.gauge(
+                    "serving_mfu",
+                    "Achieved FLOP/s over peak_flops for the last "
+                    "charged tick (merged by MEAN on /fleet, like "
+                    "*_ratio gauges)")
 
     # --------------------------------------------------------- pricing
     def program(self, op, fn, args):
@@ -359,7 +397,7 @@ class CostCatalog:
         tick_flops = sum(c[0] for c in tick.values())
         tick_bytes = sum(c[1] for c in tick.values())
         mfu = roofline = None
-        if elapsed > 0:
+        if elapsed > 0 and self._has_peaks:
             mfu = (tick_flops / elapsed) / self.peak_flops
             roofline = max(mfu, (tick_bytes / elapsed)
                            / self.peak_hbm_bytes_per_s)
@@ -430,7 +468,8 @@ class CostCatalog:
     def mfu(self):
         """The last charged tick's model-FLOPs utilization (achieved
         FLOP/s over ``peak_flops``), or None before any charged tick
-        — rides remote heartbeat digests for routing-side views."""
+        and on a device without a peaks row — rides remote heartbeat
+        digests for routing-side views."""
         return self._last_mfu
 
     def totals(self):
@@ -443,6 +482,13 @@ class CostCatalog:
     @property
     def ticks(self):
         return self._ticks
+
+    def programs(self):
+        """Every cataloged program, ``[(op, priced program), ...]`` by
+        op — for callers that inspect what was actually compiled."""
+        return sorted(((op, prog) for (op, _), prog
+                       in list(self._programs.items())),
+                      key=lambda t: t[0])
 
     def compiles(self):
         """Cumulative compile counts by op."""

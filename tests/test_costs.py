@@ -85,6 +85,13 @@ class _CountingLock:
         return False
 
 
+def _cat_with_peaks(**kw):
+    """The CPU has no row in DEVICE_PEAKS; tests that read a
+    utilisation inject peaks, as a deployment on an unlisted chip
+    would."""
+    return CostCatalog(peak_flops=1e12, peak_hbm_bytes_per_s=1e11, **kw)
+
+
 def _paged_server(**kw):
     kw.setdefault("max_slots", 2)
     kw.setdefault("max_cache_len", 32)
@@ -260,6 +267,30 @@ class TestCostCatalogUnit:
         with pytest.raises(ValueError):
             CostCatalog(peak_hbm_bytes_per_s=-1)
 
+    def test_device_without_peaks_row_publishes_no_utilisation(self):
+        """The CPU has no row in DEVICE_PEAKS: the catalog still prices
+        and charges, but computes no MFU and registers no gauge — a
+        utilisation against a made-up peak is not published."""
+        from paddle_tpu.telemetry.costs import DEVICE_PEAKS, device_peaks
+        assert DEVICE_PEAKS["TPU v5 lite"] == (197e12, 819e9)
+        with pytest.raises(LookupError, match="cpu"):
+            device_peaks()
+        with pytest.raises(LookupError):
+            device_peaks("TPU v99")
+        reg = MetricRegistry()
+        cat = CostCatalog(registry=reg, clock=FakeClock())
+        assert cat.peak_flops is None and cat.peak_hbm_bytes_per_s is None
+        fn = jax.jit(lambda x: (x @ x).sum())
+        x = jnp.ones((8, 8))
+        prog = cat.program("decode", fn, (x,))
+        prog(x)
+        cat.add_phase("decode_launch", 0.5)
+        cat.flush_tick()
+        assert cat.totals()["decode"]["flops"] > 0
+        assert cat.mfu() is None
+        assert cat.snapshot()["roofline_ratio"] is None
+        assert reg.get("serving_mfu") is None
+
 
 # --------------------------------------------------------------------------
 # Disabled catalog: structurally zero cost (flight-recorder contract)
@@ -303,7 +334,7 @@ class TestDisabledCatalog:
 class TestServerCosting:
     def test_steady_state_publishes_nonzero_costs_and_mfu(self):
         tele = ServerTelemetry()
-        cat = CostCatalog(registry=tele.registry)
+        cat = _cat_with_peaks(registry=tele.registry)
         srv = _paged_server(telemetry=tele, costs=cat)
         rng = np.random.default_rng(3)
         rids = []
@@ -451,7 +482,7 @@ class TestServerCosting:
 
     def test_heartbeat_digest_carries_utilization(self):
         from paddle_tpu.inference.remote import ReplicaHost
-        srv = _paged_server(costs=True, ledger=True)
+        srv = _paged_server(costs=_cat_with_peaks(), ledger=True)
         rid = srv.submit(_prompt(1, 2, 3), max_new_tokens=4)
         srv.run()
         del rid
@@ -481,7 +512,7 @@ class TestUtilizationOverWire:
     def test_remote_replica_reads_util_from_digest(self):
         from paddle_tpu.inference.remote import (RemoteReplica,
                                                  ReplicaHost)
-        srv = _paged_server(costs=True, ledger=True)
+        srv = _paged_server(costs=_cat_with_peaks(), ledger=True)
         host = ReplicaHost(srv, heartbeat_s=0.01).start()
         rep = RemoteReplica(host.address)
         try:
