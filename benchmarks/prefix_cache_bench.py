@@ -90,7 +90,8 @@ def _drain(model, prompts, args, auto, prefill_mode,
         cache_backend="paged", page_size=args.page_size,
         num_pages=args.num_pages, auto_prefix_cache=auto,
         prefill_mode=prefill_mode, serving_mode=serving_mode,
-        prefill_tokens_per_tick=args.budget)
+        prefill_tokens_per_tick=args.budget,
+        telemetry=True)     # the phase boundary feeds prefill_wall_s
     for p in prompts[:args.slots]:                  # warm the compiles
         srv.submit(p, max_new_tokens=2)
     srv.run()

@@ -13,8 +13,8 @@ Host/device split: the device does batched prefill + batched decode
 steps; the host only assigns slots, harvests finished rows, and swaps
 new prompts in — O(requests), not O(tokens), host work.
 """
+import contextlib
 import threading
-import time as _time_mod
 
 import numpy as np
 
@@ -31,6 +31,7 @@ from ..reliability import (CallbackError, CircuitOpenError, DEAD,
                            RequestCancelled, ServeSupervisor, ServerClosed,
                            faults)
 from ..telemetry.clock import MonotonicClock
+from ..telemetry.serving import TickBoundary
 
 __all__ = ["ContinuousBatchingServer", "PreemptionPolicy", "PoolBalance"]
 
@@ -769,7 +770,11 @@ class ContinuousBatchingServer:
         self.costs = costs
         self._costs = costs if (costs is not None
                                 and costs.enabled) else None
-        self._phase_timer = None    # per-tick, set by _step_locked
+        # the tick's one phase boundary (telemetry.TickBoundary): set
+        # by _step_locked, closed by _fire_callbacks; None whenever
+        # telemetry and costs are both off
+        self._boundary = None
+        self._tick_seq = 0          # ticks opened, the spans' tick=<n>
         self._decode_prog = None    # priced decode program (static sig)
         self._kv_row_nbytes = None  # lazy: bytes per K+V token row
         # journey recorder for STANDALONE servers (closes the PR-9
@@ -1007,7 +1012,7 @@ class ContinuousBatchingServer:
                                  "calling submit() per row")
             ids = ids[0]
         T = ids.shape[0]
-        with self._lock:
+        with self._submit_lock() as lock_wait:
             if not self._accepting:
                 raise ServerClosed(
                     f"server is {self._health.state}; not accepting "
@@ -1107,10 +1112,26 @@ class ContinuousBatchingServer:
                                         int(seed), on_token, deadline,
                                         int(priority), journey))
             if self._tele is not None:
-                self._tele.on_submit(rid, T, len(self._queue))
+                self._tele.on_submit(rid, T, len(self._queue), lock_wait)
             if journey is not None:
                 journey.event("queued", rid=rid, prompt_tokens=int(T))
         return rid
+
+    @contextlib.contextmanager
+    def _submit_lock(self):
+        """The server's lock for ``submit()``. With telemetry on it
+        yields how long the acquisition waited (a tick holds the lock
+        while it runs), under a ``serve.submit`` annotation on the
+        caller's thread; with telemetry off it is the bare lock."""
+        tele = self._tele
+        if tele is None:
+            with self._lock:
+                yield None
+            return
+        with jax.profiler.TraceAnnotation("serve.submit"):
+            t = tele.clock.now()
+            with self._lock:
+                yield tele.clock.now() - t
 
     def cancel(self, rid):
         """Drop a request: un-queue it, or free its slot mid-decode (the
@@ -1958,6 +1979,9 @@ class ContinuousBatchingServer:
         budget = self._prefill_budget - self._prefill_used
         if not self._prefill_fifo or budget <= 0:
             return
+        b = self._boundary
+        if b is not None:
+            b.mark("prefill_pack")
         plan = []                        # (slot, start, take)
         used = 0
         for slot in self._prefill_fifo:
@@ -1984,11 +2008,6 @@ class ContinuousBatchingServer:
                 out_idx[slot] = take - 1
                 done.append(slot)
         self._sync_block_table()
-        tele = self._tele
-        t_started = tele.prefill_started() if tele is not None else None
-        if self._phase_timer is not None:
-            self._phase_timer.mark("admission")
-        wall0 = _time_mod.perf_counter()
         toks_d, t0_d, out_d = (jnp.asarray(toks), jnp.asarray(t0),
                                jnp.asarray(out_idx))
         prefill_fn = self._ragged_fn
@@ -1999,6 +2018,12 @@ class ContinuousBatchingServer:
             prefill_fn = self._cost_program(
                 self._cost_op("prefill"), self._ragged_fn,
                 (toks_d, t0_d, self._caches, out_d))
+        if b is not None:
+            # the chip's from here to the first value read back (in
+            # _activate, which marks "activate")
+            t_launch = b.mark(
+                "prefill_wait", width=C, rows=len(plan),
+                rids=[self._slots[slot].rid for slot, _, _ in plan])
         logits, self._caches = prefill_fn(toks_d, t0_d, self._caches,
                                           out_d)
         self._count_dispatches(1, op="prefill")
@@ -2029,11 +2054,11 @@ class ContinuousBatchingServer:
                                  take=take)
         for slot in done:
             self._activate(slot, logits[slot:slot + 1])
-        self.stats["prefill_wall_s"] += _time_mod.perf_counter() - wall0
-        if self._phase_timer is not None:
-            self._phase_timer.mark("prefill_launch")
-        if tele is not None:
-            tele.on_prefill_batch(t_started, used)
+        if b is not None:
+            wall = b.mark("admit") - t_launch
+            self.stats["prefill_wall_s"] += wall
+            if self._tele is not None:
+                self._tele.on_prefill_batch(wall, width=C)
 
     def _activate(self, slot, logits):
         """A slot's prompt is fully written: draw its first token from
@@ -2053,6 +2078,11 @@ class ContinuousBatchingServer:
                 axis=-1)[0])
         else:
             first = int(jnp.argmax(logits, -1)[0])
+        b = self._boundary
+        if b is not None and b.phase == "prefill_wait":
+            # the launch's first value is back on the host: from here
+            # the chip is idle (later draws are tiny programs)
+            b.mark("activate")
         self._pending_key[slot] = key
         self._pending_tok[slot] = first
         self._pending_t[slot] = st.prompt_len
@@ -2228,10 +2258,9 @@ class ContinuousBatchingServer:
                                       pre_pages)
             self._count_headroom(slot, T)
         tele = self._tele
-        t_started = tele.prefill_started() if tele is not None else None
-        if self._phase_timer is not None:
-            self._phase_timer.mark("admission")
-        wall0 = _time_mod.perf_counter()
+        b = self._boundary
+        if b is not None:
+            t_launch = b.mark("prefill_launch")
 
         def _ledger_prefill(n_seg):
             # dense-path prefill rows: n_seg real rows (replay when a
@@ -2355,11 +2384,11 @@ class ContinuousBatchingServer:
         st.stream(self._deferred_cbs)
         self._slots[slot] = st
         self.stats["admissions"] += 1
-        self.stats["prefill_wall_s"] += _time_mod.perf_counter() - wall0
-        if self._phase_timer is not None:
-            self._phase_timer.mark("prefill_launch")
+        if b is not None:
+            wall = b.mark("admit") - t_launch
+            self.stats["prefill_wall_s"] += wall
         if tele is not None:
-            tele.on_prefill_batch(t_started, T - n_pre)
+            tele.on_prefill_batch(wall)
             tele.on_first_token(rid, T - n_pre, n_pre)
 
     # ------------------------------------- optimistic growth / preemption
@@ -2510,7 +2539,7 @@ class ContinuousBatchingServer:
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
             return nxt, caches, t + 1, keys
 
-        def block(tok, caches, t, keys):
+        def decode_tick(tok, caches, t, keys):
             def body(carry, _):
                 carry = one(*carry)
                 return carry, carry[0]
@@ -2518,7 +2547,8 @@ class ContinuousBatchingServer:
                 body, (tok, caches, t, keys), None, length=n)
             return tok, caches, t, keys, jnp.transpose(toks, (1, 0))
 
-        return hoisted_jit(block, donate_argnums=(1,))
+        # the trace's ``jit_decode_tick``
+        return hoisted_jit(decode_tick, donate_argnums=(1,))
 
     def _build_fused_step(self):
         """One jitted program running a WHOLE serving tick: the model
@@ -2546,7 +2576,7 @@ class ContinuousBatchingServer:
         if cached is not None:
             return cached
 
-        def fused_step(tokens, t0, last, dec, emit, fresh, seeds,
+        def fused_tick(tokens, t0, last, dec, emit, fresh, seeds,
                        out_idx, keys, bt_live, ss, sp, caches):
             logits, caches = fused_fn(tokens, t0, last, dec, caches,
                                       out_idx, bt_live, ss, sp)
@@ -2574,7 +2604,7 @@ class ContinuousBatchingServer:
                 keys_out = keys
             return nxt, keys_out, caches
 
-        prog = hoisted_jit(fused_step, donate_argnums=(12,))
+        prog = hoisted_jit(fused_tick, donate_argnums=(12,))
         _FUSED_STEP_CACHE[key] = prog
         while len(_FUSED_STEP_CACHE) > _FUSED_STEP_CACHE_MAX:
             _FUSED_STEP_CACHE.pop(next(iter(_FUSED_STEP_CACHE)))
@@ -2614,8 +2644,10 @@ class ContinuousBatchingServer:
         self._prefill_used = 0
         self._expire_locked()
         self._admit(run_prefill=False)     # reserve; chunks ride the launch
-        if self._phase_timer is not None:
-            self._phase_timer.mark("admission")
+        b = self._boundary
+        if b is not None:
+            # everything up to the launch's tokens back on the host
+            t_launch = b.mark("fused_launch")
         # harvest BEFORE packing: a slot whose budget is spent (or that
         # emitted eos at activation) must not decode further
         self._harvest()
@@ -2700,10 +2732,6 @@ class ContinuousBatchingServer:
             self._faults.check(faults.DECODE_TICK)
         tele = self._tele
         n_active = len(dec_slots)
-        t_tick = tele.tick_started() if tele is not None else None
-        t_pre = tele.prefill_started() if (tele is not None and plan) \
-            else None
-        wall0 = _time_mod.perf_counter()
         if self._fused_jit is None:
             self._fused_jit = self._build_fused_step()
         args = (jnp.asarray(tokens), jnp.asarray(t0), jnp.asarray(last),
@@ -2732,8 +2760,8 @@ class ContinuousBatchingServer:
             self._count_dispatches(1, op="fused")
         else:
             self._tick_dispatch("fused")
-        if self._phase_timer is not None:
-            self._phase_timer.mark("fused_launch")
+        if b is not None:
+            wall = b.mark("bookkeeping") - t_launch
         led = self._led
         for slot, start, take in plan:
             st = self._slots[slot]
@@ -2772,15 +2800,14 @@ class ContinuousBatchingServer:
             # page DMAs like the split mode's full-width cut they
             # replace; bounded at ~25% of live entries)
             led.add("skipped_page_dma", (len(ss) - n_live) * pg)
-        if plan:
-            self.stats["prefill_wall_s"] += \
-                _time_mod.perf_counter() - wall0
+        if plan and b is not None:
+            self.stats["prefill_wall_s"] += wall
         if tele is not None:
-            tele.on_tick(t_tick, n_active, decoded)
-            if t_pre is not None:
+            tele.on_tick(wall, n_active, decoded)
+            if plan:
                 # the launch wall covers decode rows too — documented:
                 # fused prefill seconds are launch seconds
-                tele.on_prefill_batch(t_pre, used)
+                tele.on_prefill_batch(wall)
         self._harvest()
         # end-of-tick admissions reserve only: their chunks ride the
         # NEXT tick's launch (the token budget is per tick)
@@ -2811,8 +2838,7 @@ class ContinuousBatchingServer:
         step()/run() caller or the supervised serve loop, which fails
         exactly the offending requests."""
         cbs, self._deferred_cbs = self._deferred_cbs, []
-        ct = self._costs
-        t_cb = ct.clock.now() if (ct is not None and cbs) else None
+        b, self._boundary = self._boundary, None
         errors = []
         for cb, rid, toks in cbs:
             try:
@@ -2821,11 +2847,13 @@ class ContinuousBatchingServer:
                 cb(rid, toks)
             except Exception as e:
                 errors.append((rid, e))
-        if t_cb is not None:
-            # fires OUTSIDE the lock after the tick flushed, so this
-            # phase folds into the NEXT tick's breakdown (a one-tick
-            # skew, documented in telemetry.costs)
-            ct.add_phase("token_callbacks", ct.clock.now() - t_cb)
+        if b is not None:
+            # the tick's last phase, "callbacks" (opened by
+            # _step_locked), ends here, OUTSIDE the lock and after the
+            # tick flushed: the cost catalog folds it into the NEXT
+            # tick's breakdown (a one-tick skew, documented in
+            # telemetry.costs)
+            b.close()
         if errors:
             raise CallbackError(errors, what="on_token callback")
 
@@ -2837,16 +2865,27 @@ class ContinuousBatchingServer:
         wants to see)."""
         self._tick_disp = {}
         ct = self._costs
-        if ct is not None:
-            self._phase_timer = ct.phase_timer()
+        b = None
+        if ct is not None or self._tele is not None:
+            self._tick_seq += 1
+            b = self._boundary = TickBoundary(
+                ct, self._tele, "admission" if self._fused else "expire",
+                tick=self._tick_seq)
         try:
-            return self._step_inner()
+            n = self._step_inner()
+        except BaseException:
+            if b is not None:            # a raising tick fires no callbacks
+                b.close()
+                self._boundary = None
+            raise
+        else:
+            if b is not None:
+                # whatever phase the tick ended in (an early return
+                # stays in the phase it left from) closes here;
+                # "callbacks" runs until _fire_callbacks closes it
+                b.mark("callbacks")
+            return n
         finally:
-            if self._phase_timer is not None:
-                # trailing work since the last mark (token-emit loop,
-                # end-of-tick harvest/admit, or an early return's
-                # remainder) is bookkeeping
-                self._phase_timer.close("bookkeeping")
             prof = self._tick_disp
             if prof:
                 total = sum(prof.values())
@@ -2872,28 +2911,30 @@ class ContinuousBatchingServer:
                 # phases, publish FLOPs/bytes/MFU, advance the compile
                 # watch's warmup
                 ct.flush_tick()
-                self._phase_timer = None
 
     def _step_inner(self):
         if self._fused:
             # serving_mode="fused": the whole tick is one program
             return self._step_fused()
         self._prefill_used = 0       # per-tick prefill token budget
+        # the tick opened in "expire"; each mark below opens the phase
+        # it names (TICK_PHASES says which leave the chip idle)
+        b = self._boundary
         self._expire_locked()
+        if b is not None:
+            b.mark("admit")
+        # a prefill launch marks itself out from inside (prefill_pack,
+        # prefill_wait, activate) and comes back in "admit"
         self._admit()
-        if self._phase_timer is not None:
-            # scheduling work minus the prefill launches (those mark
-            # themselves out as "prefill_launch" from inside)
-            self._phase_timer.mark("admission")
         if not self._active.any():
             if self._tele is not None:     # keep the gauge live when a
                 self._tele.set_active_slots(0)   # drained tick skips decode
             return 0
         # harvest BEFORE stepping: a slot whose budget is spent (or that
         # emitted eos at admission) must not decode further
+        if b is not None:
+            b.mark("harvest")
         self._harvest()
-        if self._phase_timer is not None:
-            self._phase_timer.mark("bookkeeping")
         if not self._active.any():
             if self._tele is not None:
                 self._tele.set_active_slots(0)
@@ -2908,6 +2949,8 @@ class ContinuousBatchingServer:
             # steps of finished/inactive rows) are redirected to the
             # null page and need no coverage in either mode.
             if self._optimistic:
+                if b is not None:
+                    b.mark("grow")
                 self._grow_locked()
                 if not self._active.any():
                     # extreme pressure: growth parked every decoding
@@ -2916,6 +2959,9 @@ class ContinuousBatchingServer:
                     if self._tele is not None:
                         self._tele.set_active_slots(0)
                     return 0
+        if b is not None:
+            b.mark("state_push")
+        if self._kv is not None:
             self._sync_block_table()
         # ragged mode: activations batched their tok/t/key updates —
         # push them (and the parked write positions of slots still
@@ -2931,7 +2977,6 @@ class ContinuousBatchingServer:
             self._faults.check(faults.DECODE_TICK)
         tele = self._tele
         n_active = int(self._active.sum())
-        t_tick = tele.tick_started() if tele is not None else None
         decode_fn = self._decode_jit
         if self._costs is not None:
             # the catalog's AOT executable is the SAME HLO the jit
@@ -2945,15 +2990,16 @@ class ContinuousBatchingServer:
                     self._cost_op("decode"), self._decode_jit,
                     (self._tok, self._caches, self._t, self._keys))
             decode_fn = self._decode_prog
+        if b is not None:
+            # the chip's: dispatch to the tokens back on the host
+            t_launch = b.mark("decode_wait")
         (self._tok, self._caches, self._t, self._keys,
          toks) = decode_fn(self._tok, self._caches, self._t,
                            self._keys)
         self._tick_dispatch("decode")
         toks = np.asarray(toks)                    # [slots, tick_block]
-        if self._phase_timer is not None:
-            # covers grow/state-flush/block-table sync, the decode
-            # compile (watched separately), dispatch, and device sync
-            self._phase_timer.mark("decode_launch")
+        if b is not None:
+            wall = b.mark("emit") - t_launch
         decoded = wasted = 0
         led = self._led
         if led is not None:
@@ -2988,7 +3034,7 @@ class ContinuousBatchingServer:
         if tele is not None:
             # np.asarray above synced the dispatch, so the tick time
             # covers host dispatch + device work
-            tele.on_tick(t_tick, n_active, decoded)
+            tele.on_tick(wall, n_active, decoded)
             if wasted:
                 tele.add_wasted_block_tokens(wasted)
             if self._kv is not None:
@@ -2996,10 +3042,14 @@ class ContinuousBatchingServer:
                 # all-null block table row straight to the null page
                 tele.add_null_writes(
                     (self.max_slots - n_active) * toks.shape[1])
+        if b is not None:
+            b.mark("harvest")
         self._harvest()
         # end-of-tick admissions reserve only (ragged: their prefill
         # chunks run at the NEXT tick's single batched launch — the
         # token budget is per tick); the dense path prefills inline
+        if b is not None:
+            b.mark("admit")
         self._admit(run_prefill=False)
         n = int(self._active.sum())
         if tele is not None:
@@ -3431,7 +3481,15 @@ class ContinuousBatchingServer:
                             # not stay degraded (and alerting) forever
                             sup.success()
                             self._recover_health()
+                        # nothing to do: with telemetry on the wait is
+                        # a phase like any other (no tick number, and
+                        # nothing for the cost catalog, whose phases
+                        # split a tick)
+                        wait = None if self._tele is None else \
+                            TickBoundary(None, self._tele, "idle_wait")
                         _time.sleep(idle_sleep)
+                        if wait is not None:
+                            wait.close()
                         continue
                     if not sup.allow():          # breaker cooldown
                         with self._lock:

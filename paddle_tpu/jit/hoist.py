@@ -73,6 +73,12 @@ class _Program:
             out = jax.core.eval_jaxpr(jaxpr, consts, *flat)
             return jax.tree_util.tree_unflatten(out_tree, out)
 
+        # the program is called what the function it wraps is called
+        # (``jit_<name>`` in a profiler trace and in the HLO), on the
+        # jit path and through ``lower().compile()`` alike. A constant
+        # name: an id or a width in it would miss the persistent
+        # compile cache on every start
+        run.__name__ = run.__qualname__ = getattr(fn, "__name__", "run")
         self.consts = closed.consts
         self.jitted = jax.jit(
             run, donate_argnums=tuple(i + 1 for i in donate_argnums),
@@ -133,5 +139,6 @@ def hoisted_jit(fn, donate_argnums=()):
     captured from its closure passed as a runtime argument instead of
     baked into the program. Positional arguments only; supports
     ``.lower(*args).compile()`` like a jitted function (the compiled
-    stage is called with ``fn``'s own arguments)."""
+    stage is called with ``fn``'s own arguments). The program carries
+    ``fn``'s name: give ``fn`` one fit for a trace."""
     return _HoistedJit(fn, donate_argnums)

@@ -492,14 +492,15 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
     static per (S, C): the server pads C up a power-of-two ladder so
     compiles stay O(log max_cache_len), not O(distinct prompt lengths).
     """
-    def ragged_prefill(tokens, t0, caches, out_idx):
+    def prefill_tick(tokens, t0, caches, out_idx):
         S = tokens.shape[0]
         x = embed_tokens(tokens, t0)
         out, caches = step_fn(x, caches, t0)
         rows = out[jnp.arange(S), out_idx][:, None]        # [S, 1, H]
         return head_fn(rows)[:, -1], caches
 
-    return ragged_prefill
+    # hoisted_jit names the program after it: ``jit_prefill_tick``
+    return prefill_tick
 
 
 def _make_fused_tick_fn(fused_step, head_fn, embed_tokens):
@@ -954,9 +955,16 @@ class GenerationMixin:
         # The bundle functions close over the stacked weight tree, so
         # every program over them is built with hoisted_jit: the
         # weights ride as runtime arguments, never as constants of the
-        # executable.
+        # executable. A program is called what its function is called:
+        # ``jit_decode_step`` here, ``jit_prefill_tick`` for the ragged
+        # entry point.
         extras = bundle[4:]
-        bundle = bundle[:4] + (hoisted_jit(bundle[2], donate_argnums=(1,)),)
+        step_fn = bundle[2]
+
+        def decode_step(x, caches, t):
+            return step_fn(x, caches, t)
+
+        bundle = bundle[:4] + (hoisted_jit(decode_step, donate_argnums=(1,)),)
         if extras:
             bundle = bundle + (hoisted_jit(extras[0], donate_argnums=(2,)),)
             if len(extras) > 1:

@@ -114,6 +114,7 @@ def _flash_fwd_pallas(q, k, v, sm_scale, causal,
         block_k=block_k, num_kv_blocks=nk)
     o, lse = pl.pallas_call(
         kernel,
+        name="flash_fwd",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
@@ -278,6 +279,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
     dq = pl.pallas_call(
         functools.partial(_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_kv_blocks=nk),
+        name="flash_bwd_dq",
         grid=(bh, nq, nk),
         in_specs=[qkv_spec_q, kv_spec_q, kv_spec_q, qkv_spec_q,
                   row_spec_q, row_spec_q],
@@ -297,6 +299,7 @@ def _flash_bwd_pallas(q, k, v, o, lse, do, sm_scale, causal,
     dk, dv = pl.pallas_call(
         functools.partial(_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
                           block_q=block_q, block_k=block_k, num_q_blocks=nq),
+        name="flash_bwd_dkv",
         grid=(bh, nk, nq),
         in_specs=[qkv_spec_k, kv_spec_k, kv_spec_k, qkv_spec_k,
                   row_spec_k, row_spec_k],

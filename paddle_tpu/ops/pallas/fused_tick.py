@@ -289,6 +289,7 @@ def _fused_tick_pallas(q, k_pages, v_pages, block_tables, t0, sched_slot,
     )
     return pl.pallas_call(
         kernel,
+        name="fused_tick",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -94,6 +94,7 @@ def _gemm_epilogue_pallas(x, w, bias, activation, interpret=False):
 
     return pl.pallas_call(
         kernel,
+        name="gemm_epilogue",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),

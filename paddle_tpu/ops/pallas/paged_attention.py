@@ -161,6 +161,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
     )
     return pl.pallas_call(
         kernel,
+        name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

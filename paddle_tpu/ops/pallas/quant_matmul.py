@@ -75,6 +75,7 @@ def quantized_matmul(x, w, scale_x, scale_w, block_m=DEFAULT_BLOCK_M,
     grid = (m // bm, n // bn, k // bk)
     return pl.pallas_call(
         functools.partial(_qmm_kernel, nk=grid[2]),
+        name="quant_matmul",
         grid=grid,
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),

@@ -186,6 +186,7 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, last,
     )
     return pl.pallas_call(
         kernel,
+        name="ragged_prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, C, nh, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

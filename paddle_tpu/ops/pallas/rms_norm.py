@@ -50,6 +50,7 @@ def _pallas_fwd(x, w, eps, block_rows=256):
     br = _pick_block_rows(rows, block_rows)
     out = pl.pallas_call(
         functools.partial(_kernel, eps=eps),
+        name="rms_norm_fwd",
         out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
         grid=(rows // br,),
         in_specs=[
@@ -105,6 +106,7 @@ def _pallas_bwd(x, w, g, eps, block_rows=256, interpret=False):
     nblocks = rows // br
     dx, dw = pl.pallas_call(
         functools.partial(_bwd_kernel, eps=eps, nblocks=nblocks),
+        name="rms_norm_bwd",
         grid=(nblocks,),
         in_specs=[
             pl.BlockSpec((br, d), lambda i: (i, 0)),

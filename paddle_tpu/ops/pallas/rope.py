@@ -101,6 +101,7 @@ def _rope_call(xt, c, s, bs, d, d2, grid, interpret):
 
     return pl.pallas_call(
         kernel,
+        name="rope",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bs, d), lambda i, j: (i, j, 0)),

@@ -10,8 +10,10 @@ observability rather than one-off profiling sessions):
   exposition. A disabled registry hands out no-op instruments — zero
   locks and zero clock reads on the hot path.
 - ``Tracer`` / ``Span`` (tracing.py): host-side trace spans on an
-  injectable clock, Chrome-trace JSON export, optional mirroring into
-  ``profiler.RecordEvent`` so spans land inside jax device traces.
+  injectable clock, Chrome-trace JSON export; a span closed by the
+  thread that opened it is mirrored into
+  ``jax.profiler.TraceAnnotation``, so it lands inside a jax device
+  trace on the profiler's clock.
 - ``MetricsServer`` (exposition.py): ``/metrics`` (Prometheus text) +
   ``/stats`` (JSON) scrape endpoint, plus ``/debug/journey/<rid>`` and
   ``/debug/postmortem`` when the owner wires them.
@@ -34,7 +36,10 @@ observability rather than one-off profiling sessions):
   merged into one Perfetto trace with cross-replica flow events.
 - ``ServerTelemetry`` (serving.py): the continuous-batching server's
   SLO instrumentation — TTFT/TPOT/queue-wait, tick occupancy, page-pool
-  gauges, prefix-cache counters, per-request lifecycle spans.
+  gauges, prefix-cache counters, per-request lifecycle spans; and
+  ``TickBoundary``, the serve loop's one phase boundary (one clock
+  read feeds the phase histogram, the ``serve.<phase>`` spans and the
+  cost catalog's tick split).
 - ``TelemetryCallback`` (training.py): hapi bridge for step time,
   loss, tokens/s.
 - ``MonotonicClock`` / ``FakeClock`` (clock.py): every time read is

@@ -34,17 +34,17 @@ layer turns the serving stack's host->device dispatch profile (PR 9's
   trips alarms for an op still climbing its own ladder nor holds the
   decode program's shape-leak watch hostage. ``warmed`` (the global
   view) is true once every compiled op has warmed.
-- **Tick-phase attribution**: the server splits each tick's wall into
-  phases (admission / prefill_launch / decode_launch / fused_launch
-  / token_callbacks
-  / bookkeeping) through ``phase_timer()``; phases publish as
-  ``serving_tick_phase_seconds{phase}`` and ride the recorder's
-  per-tick events — the host-bound-vs-device-bound verdict the
-  megakernel work will be judged against. (``token_callbacks`` is
-  measured outside the server lock after the tick flushes, so it
-  folds into the NEXT CHARGED tick's breakdown — carried across idle
-  polls, a one-tick skew; only a drain's final tail of callbacks has
-  no later tick to land in.)
+- **Tick-phase attribution**: the server's one phase boundary
+  (``telemetry.serving.TickBoundary``) splits each tick's wall into
+  ``TICK_PHASES`` and feeds them in through ``add_phase``; they ride
+  ``last_tick_phases`` and the recorder's per-tick events — the
+  host-bound-vs-device-bound verdict (the ``*_wait`` phases are the
+  chip's). The ``serving_tick_phase_seconds{phase}`` histogram is the
+  server telemetry's, fed by the same reads. (``callbacks`` runs
+  outside the server lock after the tick flushes, so it folds into the
+  NEXT CHARGED tick's breakdown — carried across idle polls, a
+  one-tick skew; only a drain's final tail of callbacks has no later
+  tick to land in.)
 - **MFU / roofline**: per charged tick, achieved FLOPs/s over
   ``peak_flops`` is published as the ``serving_mfu`` gauge (and
   ``roofline_ratio`` — the max of the FLOPs and HBM-bandwidth
@@ -91,11 +91,20 @@ __all__ = ["CostCatalog", "COMPILE_BUCKETS", "PHASE_BUCKETS",
 # compiles span ~10 ms (tiny CPU programs) to minutes (big TPU fusions)
 COMPILE_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
                    5.0, 10.0, 30.0, 60.0)
-# per-tick phase slices live at the serving-tick scale
+# a phase of a tick runs from tens of microseconds to a prefill launch
 PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                  0.025, 0.05, 0.1, 0.25, 0.5, 1.0)
-TICK_PHASES = ("admission", "prefill_launch", "decode_launch",
-               "fused_launch", "token_callbacks", "bookkeeping")
+# The split tick's phases separate the host from the chip: the chip
+# works through prefill_wait and decode_wait (dispatch to the value
+# read back) and is idle through the rest, but for the three tiny
+# programs of state_push. The fused tick keeps its three (admission,
+# fused_launch, bookkeeping); the dense admission path its
+# prefill_launch.
+TICK_PHASES = ("expire", "admit", "prefill_pack", "prefill_wait",
+               "activate", "grow", "state_push", "decode_wait", "emit",
+               "harvest", "callbacks",
+               "admission", "prefill_launch", "fused_launch",
+               "bookkeeping")
 
 # The one peaks table: ``device_kind`` (as ``jax.devices()[0]`` reports
 # it) -> (bf16 FLOP/s, HBM bytes/s) of one chip. Every utilisation in
@@ -228,11 +237,10 @@ class CostCatalog:
         self._last_mfu = None
         self._last_roofline = None
         self._c_flops = self._c_bytes = self._c_compiles = None
-        self._h_compile = self._h_phase = self._g_mfu = None
+        self._h_compile = self._g_mfu = None
         self._flops_children = {}
         self._bytes_children = {}
         self._compile_children = {}
-        self._phase_children = {}
         if (self.enabled and registry is not None
                 and getattr(registry, "enabled", False)):
             self._c_flops = registry.counter(
@@ -253,13 +261,6 @@ class CostCatalog:
                 "serving_compile_seconds",
                 "Wall seconds per trace/lower/compile of one serving "
                 "program", buckets=COMPILE_BUCKETS)
-            self._h_phase = registry.histogram(
-                "serving_tick_phase_seconds",
-                "One tick's wall split by phase (admission / "
-                "prefill_launch / decode_launch / token_callbacks / "
-                "bookkeeping) — the host-bound-vs-device-bound "
-                "verdict", labelnames=("phase",),
-                buckets=PHASE_BUCKETS)
             if self._has_peaks:
                 self._g_mfu = registry.gauge(
                     "serving_mfu",
@@ -351,14 +352,10 @@ class CostCatalog:
         cell[2] += n
 
     # ---------------------------------------------------------- phases
-    def phase_timer(self):
-        """A per-tick phase splitter on the catalog's clock:
-        ``mark(phase)`` attributes the wall since the previous mark TO
-        ``phase`` (accumulating), ``close(phase)`` sweeps any trailing
-        remainder. One instance per tick, server-lock single-writer."""
-        return _PhaseTimer(self)
-
     def add_phase(self, phase, seconds):
+        """``seconds`` of the current tick spent in ``phase`` (the
+        server's ``TickBoundary`` calls this with its own reads;
+        phases may repeat and accumulate)."""
         if seconds > 0:
             self._phases[phase] = self._phases.get(phase, 0.0) + seconds
 
@@ -378,20 +375,19 @@ class CostCatalog:
         exists to give, but never arms or trips another op's watch).
         Returns the tick's ``{op: (flops, bytes, dispatches)}``, or
         None when nothing was charged — an idle serve-loop poll,
-        whose phase scraps are DISCARDED (publishing microsecond
-        "ticks" at the poll rate would drown the phase histogram in
-        idle noise)."""
+        whose phase scraps are DISCARDED (microsecond "ticks" at the
+        poll rate are no tick's split)."""
         tick, self._tick = self._tick, {}
         phases, self._phases = self._phases, {}
         if not tick:
             # idle serve-loop poll: its admission/bookkeeping scraps
-            # are discarded, but pending token_callbacks time (the one
+            # are discarded, but pending callbacks time (the one
             # phase generated OUTSIDE a tick) is carried forward so a
             # request-sparse loop doesn't systematically drop it — it
             # folds into the next CHARGED tick
-            cb = phases.get("token_callbacks")
+            cb = phases.get("callbacks")
             if cb:
-                self._phases["token_callbacks"] = cb
+                self._phases["callbacks"] = cb
             return None
         elapsed = sum(phases.values())
         tick_flops = sum(c[0] for c in tick.values())
@@ -440,12 +436,6 @@ class CostCatalog:
                         child = self._bytes_children[op] = \
                             self._c_bytes.labels(op=op)
                     child.inc(cell[1])
-            for phase, s in phases.items():
-                child = self._phase_children.get(phase)
-                if child is None:
-                    child = self._phase_children[phase] = \
-                        self._h_phase.labels(phase=phase)
-                child.observe(s)
             if mfu is not None:
                 self._g_mfu.set(mfu)
         return tick or None
@@ -520,25 +510,3 @@ class CostCatalog:
                 "peak_hbm_bytes_per_s": self.peak_hbm_bytes_per_s,
                 "last_tick_phases": dict(self._last_phases),
             }
-
-
-class _PhaseTimer:
-    """Splits one tick's wall into named phases. ``mark(phase)``
-    charges the time since the last mark (or construction) to
-    ``phase``; phases may repeat (accumulate). ``close(phase)`` sweeps
-    whatever trails the final mark so the phases sum to the tick wall
-    even on early-return ticks."""
-
-    __slots__ = ("_catalog", "_clock", "_t")
-
-    def __init__(self, catalog):
-        self._catalog = catalog
-        self._clock = catalog.clock
-        self._t = self._clock.now()
-
-    def mark(self, phase):
-        t = self._clock.now()
-        self._catalog.add_phase(phase, t - self._t)
-        self._t = t
-
-    close = mark

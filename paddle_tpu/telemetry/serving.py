@@ -5,6 +5,10 @@ One ``ServerTelemetry`` object owns every signal an SLO-aware scheduler
 
 Request lifecycle (spans ``request.queued`` -> ``request.prefill``
 -> ``request.decode`` per rid, plus histograms):
+- ``serving_submit_lock_wait_seconds``  ``submit()``'s wait for the
+                                  server's lock, which a tick holds
+                                  (also ``lock_wait_s`` on the
+                                  request's ``request.queued`` span)
 - ``serving_queue_wait_seconds``  submit -> admission pop
 - ``serving_ttft_seconds``        submit -> first token available
                                   (admission prefill emits it)
@@ -12,13 +16,22 @@ Request lifecycle (spans ``request.queued`` -> ``request.prefill``
 - ``serving_e2e_seconds``         submit -> finish
 - ``serving_requests_total{state=submitted|finished|canceled|failed}``
 
-Per-tick engine signals:
-- ``serving_tick_seconds``        one batched decode dispatch (host
-                                  wall, includes device sync)
+Per-tick engine signals. Every time below comes from the reads of
+``TickBoundary``, the serve loop's one phase boundary:
+- ``serving_tick_phase_seconds{phase}``  the serve loop's wall, one
+                                  observation per phase interval; each
+                                  interval is also a ``serve.<phase>``
+                                  span with the tick's number, mirrored
+                                  into the profiler's trace
+- ``serving_tick_seconds``        one batched decode dispatch, to its
+                                  tokens back on the host
 - ``serving_tick_occupancy``      active slots entering the tick
 - ``serving_active_slots`` / ``serving_queue_depth`` gauges
 - ``serving_prefill_seconds``     one prefill batch (a ragged packed
-                                  launch, or one dense admission)
+                                  launch to its last activation, or one
+                                  dense admission)
+- ``serving_prefill_launches_total{width}``  ragged launches by chunk
+                                  width
 - ``server_prefill_dispatches_total``  host dispatches on the
   admission/prefill path — the ragged prefill path's counter-asserted
   win is this dropping per admission vs the dense baseline
@@ -59,17 +72,77 @@ state needs no extra synchronization. Host-side only — never call any
 of this from jit-traced code.
 """
 from .clock import MonotonicClock
+from .costs import PHASE_BUCKETS
 from .metrics import DEFAULT_BUCKETS, MetricRegistry
 from .tracing import Tracer
 
-__all__ = ["ServerTelemetry", "RouterTelemetry", "TPOT_BUCKETS",
-           "TICK_BUCKETS", "OCCUPANCY_BUCKETS"]
+__all__ = ["ServerTelemetry", "RouterTelemetry", "TickBoundary",
+           "TPOT_BUCKETS", "TICK_BUCKETS", "OCCUPANCY_BUCKETS"]
 
 # per-token / per-tick scales are finer than request-level latencies
 TPOT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                 0.25, 0.5, 1.0)
 TICK_BUCKETS = TPOT_BUCKETS
 OCCUPANCY_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
+
+
+class TickBoundary:
+    """The serve loop's ONE phase boundary. ``mark(phase)`` reads the
+    clock once; everything from that read to the next belongs to
+    ``phase``. The read that closes a phase feeds every consumer that
+    is on: the cost catalog's ``add_phase`` (the tick's split in
+    ``last_tick_phases`` and the recorder's tick events), and, with
+    telemetry, ``serving_tick_phase_seconds{phase}`` and a
+    ``serve.<phase>`` span carrying the tick's number, which the
+    tracer mirrors into the profiler's trace. Opened and closed by the
+    thread that drives the tick. The server builds none when both
+    consumers are off."""
+
+    __slots__ = ("_costs", "_tele", "_clock", "_tick", "_t", "_span",
+                 "phase")
+
+    def __init__(self, costs, tele, phase, tick=None):
+        self._costs = costs
+        self._tele = tele
+        self._clock = tele.clock if tele is not None else costs.clock
+        self._tick = tick
+        self._span = None
+        self.phase = None
+        if tele is not None and tick is not None:
+            tele.tick = tick
+        self.mark(phase)
+
+    def mark(self, phase, **args):
+        """Close the running phase and open ``phase`` at one read of
+        the clock, which is returned. ``args`` go on the new span."""
+        t = self._clock.now()
+        self._close(t)
+        self.phase = phase
+        self._t = t
+        if self._tele is not None:
+            if self._tick is not None:
+                args["tick"] = self._tick
+            self._span = self._tele.tracer.span("serve." + phase, at=t,
+                                                **args)
+        return t
+
+    def close(self):
+        """Close the running phase; returns the read."""
+        t = self._clock.now()
+        self._close(t)
+        self.phase = None
+        return t
+
+    def _close(self, t):
+        phase = self.phase
+        if phase is None:
+            return
+        seconds = t - self._t
+        if self._costs is not None:
+            self._costs.add_phase(phase, seconds)
+        if self._tele is not None:
+            self._tele.on_phase(phase, seconds)
+            self._span.end(at=t)
 
 
 class _ReqState:
@@ -110,6 +183,7 @@ class ServerTelemetry:
             else Tracer(clock=self.clock, enabled=self.registry.enabled)
         self.enabled = self.registry.enabled
         self._req = {}
+        self.tick = None     # the running tick's number (TickBoundary)
         r = self.registry
         req = r.counter("serving_requests_total",
                         "Requests by lifecycle outcome",
@@ -122,6 +196,10 @@ class ServerTelemetry:
                                 "Requests waiting for a slot")
         self._g_active = r.gauge("serving_active_slots",
                                  "Slots decoding after the last tick")
+        self._h_lock_wait = r.histogram(
+            "serving_submit_lock_wait_seconds",
+            "submit()'s wait for the server's lock, which a tick holds",
+            buckets=TICK_BUCKETS)
         self._h_wait = r.histogram("serving_queue_wait_seconds",
                                    "submit() to admission pop",
                                    buckets=DEFAULT_BUCKETS)
@@ -137,6 +215,13 @@ class ServerTelemetry:
         self._h_tick = r.histogram("serving_tick_seconds",
                                    "One batched decode dispatch",
                                    buckets=TICK_BUCKETS)
+        self._h_phase = r.histogram(
+            "serving_tick_phase_seconds",
+            "The serve loop's wall by phase, one observation per phase "
+            "interval: the *_wait phases are the chip's, idle_wait is "
+            "nobody's, the rest is host work that leaves the chip idle",
+            labelnames=("phase",), buckets=PHASE_BUCKETS)
+        self._phase_children = {}
         self._h_occ = r.histogram("serving_tick_occupancy",
                                   "Active slots entering a tick",
                                   buckets=OCCUPANCY_BUCKETS)
@@ -234,6 +319,10 @@ class ServerTelemetry:
             "serving_prefill_seconds",
             "One prefill batch: a ragged packed launch, or one "
             "admission's dense prefill", buckets=TICK_BUCKETS)
+        self._c_launches = r.counter(
+            "serving_prefill_launches_total",
+            "Ragged prefill launches by chunk width",
+            labelnames=("width",))
         # dispatches-per-decode-tick: THE success metric for the fused
         # decode megakernel (ROADMAP item 4) — today a tick costs one
         # decode program plus state pushes / block-table syncs /
@@ -296,15 +385,22 @@ class ServerTelemetry:
             "3 dead (alert on >= 2)")
 
     # -------------------------------------------------------- lifecycle
-    def on_submit(self, rid, prompt_tokens, queue_depth):
+    def on_submit(self, rid, prompt_tokens, queue_depth,
+                  lock_wait_s=None):
+        """``lock_wait_s``: how long ``submit()`` waited for the
+        server's lock (two reads of this clock around the
+        acquisition)."""
         if not self.enabled:
             return
         t = self.clock.now()
         self._c_submitted.inc()
         self._g_queue.set(queue_depth)
-        self._req[rid] = _ReqState(
-            t, self.tracer.begin_span("request.queued", rid=rid,
-                                      prompt_tokens=prompt_tokens))
+        span = self.tracer.begin_span("request.queued", rid=rid,
+                                      prompt_tokens=prompt_tokens)
+        if lock_wait_s is not None:
+            self._h_lock_wait.observe(lock_wait_s)
+            span.set(lock_wait_s=lock_wait_s)
+        self._req[rid] = _ReqState(t, span)
 
     def on_admit(self, rid, queue_depth):
         """Request popped from the queue; admission prefill starts
@@ -369,8 +465,11 @@ class ServerTelemetry:
             st.t_first = t
         st.preempted = False     # the replay caught up; spans normalize
         if st.prefill_span is not None:
+            # the tick whose launch served it: the same number its
+            # serve.prefill_wait span carries
             st.prefill_span.end(prefill_tokens=prefill_tokens,
-                                prefix_hit_tokens=prefix_hit_tokens)
+                                prefix_hit_tokens=prefix_hit_tokens,
+                                tick=self.tick)
             st.prefill_span = None
         if prefill_tokens:
             self._c_tok_prefill.inc(prefill_tokens)
@@ -421,16 +520,21 @@ class ServerTelemetry:
                             error=type(exc).__name__)
 
     # ------------------------------------------------------ engine ticks
-    def tick_started(self):
-        """Timestamp handle for on_tick (one clock read)."""
-        if not self.enabled:
-            return None
-        return self.clock.now()
+    def on_phase(self, phase, seconds):
+        """One interval of the serve loop spent in ``phase``
+        (``TickBoundary``'s reads)."""
+        child = self._phase_children.get(phase)
+        if child is None:
+            child = self._phase_children[phase] = \
+                self._h_phase.labels(phase=phase)
+        child.observe(seconds)
 
-    def on_tick(self, t_started, active_slots, decode_tokens):
+    def on_tick(self, seconds, active_slots, decode_tokens):
+        """One decode dispatch took ``seconds`` (the boundary's reads
+        around it)."""
         if not self.enabled:
             return
-        self._h_tick.observe(self.clock.now() - t_started)
+        self._h_tick.observe(seconds)
         self._h_occ.observe(active_slots)
         self._g_active.set(active_slots)
         if decode_tokens:
@@ -566,20 +670,16 @@ class ServerTelemetry:
                     self._c_disp.labels(op=op)
             child.inc(n)
 
-    def prefill_started(self):
-        """Timestamp handle for on_prefill_batch (one clock read)."""
-        if not self.enabled:
-            return None
-        return self.clock.now()
-
-    def on_prefill_batch(self, t_started, tokens):
-        """One prefill batch finished: a ragged packed launch covering
-        ``tokens`` prompt rows across its slots, or one admission's
-        dense prefill. (Token counters are driven by on_first_token;
-        this only times the batch.)"""
+    def on_prefill_batch(self, seconds, width=None):
+        """One prefill batch took ``seconds`` (the boundary's reads
+        around it): a ragged packed launch of chunk width ``width``,
+        or one admission's dense prefill. (Token counters are driven by
+        on_first_token; this only times and counts the batch.)"""
         if not self.enabled:
             return
-        self._h_prefill.observe(self.clock.now() - t_started)
+        self._h_prefill.observe(seconds)
+        if width is not None:
+            self._c_launches.labels(width=width).inc()
 
     # ------------------------------------------------------- reliability
     def on_shed(self, policy):

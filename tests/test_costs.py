@@ -52,6 +52,7 @@ from paddle_tpu.telemetry import (CostCatalog, FakeClock, FlightRecorder,
                                   MetricRegistry, ServerTelemetry,
                                   merge_snapshots)
 from paddle_tpu.telemetry.costs import TICK_PHASES
+from paddle_tpu.telemetry.serving import TickBoundary
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -239,18 +240,19 @@ class TestCostCatalogUnit:
         y = jnp.ones((8, 2), jnp.float32)
         prog = cat.program("decode", fn, (x, y))     # 128 flops
         prog(x, y)
-        tp = cat.phase_timer()
+        tb = TickBoundary(cat, None, "decode_wait")  # the catalog alone
         fc.advance(0.5)
-        tp.mark("decode_launch")
+        tb.close()
         cat.flush_tick()
         # (128 flops / 0.5 s) / 1000 peak = 0.256
         assert cat.mfu() == pytest.approx(prog.flops / 0.5 / 1000.0)
         assert reg.get("serving_mfu").value == pytest.approx(cat.mfu())
         snap = cat.snapshot()
         assert snap["roofline_ratio"] >= snap["mfu"]
-        assert snap["last_tick_phases"] == {"decode_launch": 0.5}
-        ph = reg.get("serving_tick_phase_seconds")
-        assert ph.labels(phase="decode_launch").count == 1
+        assert snap["last_tick_phases"] == {"decode_wait": 0.5}
+        # the phase histogram is the server telemetry's: a catalog
+        # publishes none, whatever registry it was given
+        assert reg.get("serving_tick_phase_seconds") is None
 
     def test_charge_bytes_is_flops_free(self):
         cat = CostCatalog()
@@ -284,12 +286,97 @@ class TestCostCatalogUnit:
         x = jnp.ones((8, 8))
         prog = cat.program("decode", fn, (x,))
         prog(x)
-        cat.add_phase("decode_launch", 0.5)
+        cat.add_phase("decode_wait", 0.5)
         cat.flush_tick()
         assert cat.totals()["decode"]["flops"] > 0
         assert cat.mfu() is None
         assert cat.snapshot()["roofline_ratio"] is None
         assert reg.get("serving_mfu") is None
+
+
+# --------------------------------------------------------------------------
+# Program names: one constant name on the jit path and through the catalog
+# --------------------------------------------------------------------------
+class TestProgramNames:
+    def test_hoisted_program_is_named_after_its_function_everywhere(self):
+        """``hoisted_jit(f)`` is the module ``jit_<f's name>``: through
+        ``.lower()``, through its compiled stage, and through the
+        catalog's ahead-of-time executable, the same constant string
+        (an id or a width in it would miss the persistent compile cache
+        on every start)."""
+        from paddle_tpu.jit.hoist import hoisted_jit
+        w = jnp.ones((8, 8), jnp.float32)
+
+        def decode_tick(x):
+            return x @ w
+
+        h = hoisted_jit(decode_tick)
+        names = set()
+        for width in (2, 4):
+            x = jnp.ones((width, 8), jnp.float32)
+            lowered = h.lower(x)
+            assert "module @jit_decode_tick " in lowered.as_text()
+            hlo = lowered.compile().as_text()
+            names.add(hlo.split()[1].rstrip(","))
+            prog = CostCatalog().program("decode", h, (x,))
+            assert prog.compiled_now
+            assert prog.executable.as_text().split()[1].rstrip(",") \
+                == "jit_decode_tick"
+            np.testing.assert_allclose(np.asarray(prog(x)),
+                                       np.asarray(h(x)))
+        assert names == {"jit_decode_tick"}
+
+    @pytest.fixture(scope="class")
+    def served_programs(self):
+        """{op: module name} of what a paged server over the real
+        llama_tiny bundle compiled through the catalog (the stub model
+        brings program functions of its own)."""
+        import paddle_tpu as pt
+        from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+        pt.seed(3)
+        model = LlamaForCausalLM(llama_tiny())
+        model.eval()
+        cat = CostCatalog()
+        srv = ContinuousBatchingServer(
+            model, max_slots=2, max_cache_len=32, cache_backend="paged",
+            page_size=8, costs=cat)
+        srv.submit(_prompt(1, 2, 3), max_new_tokens=3)
+        srv.run()
+        return {op: p.executable.as_text().split()[1].rstrip(",")
+                for op, p in cat.programs()}
+
+    @pytest.mark.parametrize("op,module", [
+        ("decode", "jit_decode_tick"), ("prefill", "jit_prefill_tick")])
+    def test_serving_programs_carry_their_tick_names(
+            self, served_programs, op, module):
+        """The split tick's two programs as the cost catalog compiled
+        them, which is what the server dispatches: the names the
+        benchmark's ``decode_tick_ms`` and ``prefill_tick_ms`` look
+        up in the device trace."""
+        assert served_programs[op] == module
+        assert "jit_run" not in served_programs.values()
+
+    def test_fused_tick_program_name(self):
+        srv = _paged_server(costs=CostCatalog(), serving_mode="fused")
+        srv.submit(_prompt(1, 2, 3), max_new_tokens=3)
+        srv.run()
+        (text,) = {p.executable.as_text().split()[1].rstrip(",")
+                   for _, p in srv.costs.programs()}
+        assert text == "jit_fused_tick"
+
+    def test_dense_prefill_program_is_decode_step(self):
+        """``_decode_bundle``'s jitted step (the dense prefill program,
+        generation.py) is ``jit_decode_step``."""
+        import paddle_tpu as pt
+        from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+        pt.seed(3)
+        model = LlamaForCausalLM(llama_tiny())
+        model.eval()
+        bundle = model._decode_bundle(16)
+        init_caches, prefill_jit = bundle[0], bundle[4]
+        x = jnp.zeros((1, 4, model.cfg.hidden_size), jnp.float32)
+        text = prefill_jit.lower(x, init_caches(1), jnp.int32(0)).as_text()
+        assert "module @jit_decode_step " in text
 
 
 # --------------------------------------------------------------------------
@@ -306,11 +393,13 @@ class TestDisabledCatalog:
         fn = jax.jit(lambda a: a + 1)
         assert cat.program("decode", fn, (jnp.ones((2,)),)) is fn
         srv = _paged_server(costs=cat)
-        assert srv._costs is None and srv._phase_timer is None
+        assert srv._costs is None
         rid = srv.submit(_prompt(1, 2, 3), max_new_tokens=4)
         out = srv.run()
         np.testing.assert_array_equal(out[rid],
                                       stub_tokens([1, 2, 3], 4))
+        # telemetry=None, costs off: no tick ever built a boundary
+        assert srv._boundary is None and srv._tick_seq == 0
         assert fc.reads == 0 and lock.acquisitions == 0
         assert cat._tick == {} and cat._phases == {}
         assert srv.device_costs() is None
@@ -444,8 +533,8 @@ class TestServerCosting:
         assert phases and set(phases) <= set(TICK_PHASES)
         assert all(v >= 0 for v in phases.values())
         h = tele.registry.get("serving_tick_phase_seconds")
-        assert h.labels(phase="decode_launch").count > 0
-        assert h.labels(phase="admission").count > 0
+        assert h.labels(phase="decode_wait").count > 0
+        assert h.labels(phase="admit").count > 0
         ticks = rec.events(kind="tick")
         assert ticks and "phases" in ticks[-1]
         assert set(ticks[-1]["phases"]) <= set(TICK_PHASES)

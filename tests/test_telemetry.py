@@ -29,6 +29,29 @@ def _scripted_telemetry():
                            tracer=Tracer(clock=fc)), fc, reg
 
 
+def _record_annotations(monkeypatch):
+    """Stand a recorder in for ``jax.profiler.TraceAnnotation`` (a
+    tracer looks it up when it is built): returns the list it appends
+    ("enter", name, kwargs) and ("exit", name) to."""
+    import jax
+    seen = []
+
+    class Recorder:
+        def __init__(self, name, **kwargs):
+            self.name, self.kwargs = name, kwargs
+
+        def __enter__(self):
+            seen.append(("enter", self.name, self.kwargs))
+            return self
+
+        def __exit__(self, *exc):
+            seen.append(("exit", self.name))
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    return seen
+
+
 def _hist(reg, name, labels=None):
     m = reg.get(name)
     child = m.labels(**labels) if labels else m
@@ -128,8 +151,8 @@ class TestDisabledRegistry:
         tele.on_submit(0, 8, 1)
         tele.on_admit(0, 0)
         tele.on_first_token(0, 8, 0)
-        assert tele.tick_started() is None
-        tele.on_tick(None, 1, 1)
+        tele.on_tick(0.5, 1, 1)
+        tele.on_prefill_batch(0.5, width=8)
         tele.on_finish(0, 4)
         tele.set_pool(1, 2, 3)
         tele.add_null_writes(5)
@@ -164,16 +187,16 @@ class TestTracing:
         assert ev["ts"] == 0.0 and ev["dur"] == pytest.approx(5e5)
         assert ev["args"] == {"tokens": 128, "chunks": 2}
 
-    def test_cross_scope_span_and_decorator(self, tmp_path):
+    def test_cross_scope_span_and_with_span(self, tmp_path):
         fc = FakeClock()
         tr = Tracer(clock=fc)
         sp = tr.begin_span("queued", rid=1)      # ends on another path
         fc.advance(2.0)
 
-        @tr.trace("work")
         def work():
-            fc.advance(1.0)
-            return 42
+            with tr.span("work"):
+                fc.advance(1.0)
+                return 42
 
         assert work() == 42
         sp.end()
@@ -194,13 +217,396 @@ class TestTracing:
                 pass
         assert len(tr.events()) == 2 and tr.dropped == 2
 
-    def test_record_event_interop(self):
-        """annotate=True mirrors spans into profiler.RecordEvent (jax
-        TraceAnnotation) without breaking span collection."""
-        tr = Tracer(clock=FakeClock(), annotate=True)
-        with tr.span("annotated"):
-            pass
-        assert tr.events()[0]["name"] == "annotated"
+    def test_same_thread_spans_reach_the_profiler_request_spans_never(
+            self, monkeypatch):
+        """A span its opening thread closes (``with``, or the tick's
+        phase boundary) is mirrored into a
+        ``jax.profiler.TraceAnnotation``; a ``begin_span`` (the
+        ``request.*`` spans, which may end on another thread) never
+        is. Collection is the same for both."""
+        seen = _record_annotations(monkeypatch)
+        tr = Tracer(clock=FakeClock())
+        with tr.span("serve.decode_wait", tick=3, rids=[4, 5]):
+            assert seen == [("enter", "serve.decode_wait",
+                             {"tick": 3, "rids": "4 5"})]
+        tr.begin_span("request.queued", rid=4).end()
+        assert seen == [("enter", "serve.decode_wait",
+                         {"tick": 3, "rids": "4 5"}),
+                        ("exit", "serve.decode_wait")]
+        names = [e["name"] for e in tr.events()]
+        assert names == ["serve.decode_wait", "request.queued"]
+        assert tr.events()[0]["args"] == {"tick": 3, "rids": [4, 5]}
+
+
+# ------------------------------------------------- the tick's boundary
+
+class _SteppingClock(FakeClock):
+    """A fake clock on which every read takes a millisecond, so that no
+    two reads agree and every phase has a length."""
+
+    __slots__ = ()
+
+    def now(self):
+        t = super().now()
+        self.advance(0.001)
+        return t
+
+
+# which side of the split a phase of the split tick falls on: the chip
+# works through these and is idle (the host's doing) through the rest
+_CHIP_PHASES = {"prefill_wait", "decode_wait"}
+_HOST_PHASES = {"expire", "admit", "prefill_pack", "activate", "grow",
+                "state_push", "emit", "harvest", "callbacks"}
+
+
+def _stub_server(**kw):
+    from _serving_stub import StubModel
+    from paddle_tpu.inference.continuous_batching import \
+        ContinuousBatchingServer
+    kw.setdefault("max_slots", 2)
+    kw.setdefault("max_cache_len", 32)
+    kw.setdefault("cache_backend", "paged")
+    kw.setdefault("page_size", 4)
+    return ContinuousBatchingServer(StubModel(), **kw)
+
+
+def _serve_spans(tele, tick=None):
+    return [e for e in tele.tracer.events()
+            if e["name"].startswith("serve.")
+            and (tick is None or e["args"].get("tick") == tick)]
+
+
+class TestTickBoundary:
+    @pytest.mark.parametrize("consumers", ["catalog", "telemetry", "both"])
+    def test_one_mark_is_one_read_whichever_consumers_are_on(
+            self, consumers):
+        from paddle_tpu.telemetry import CostCatalog
+        from paddle_tpu.telemetry.serving import TickBoundary
+        fc = FakeClock()
+        cat = CostCatalog(clock=fc) if consumers != "telemetry" else None
+        tele = ServerTelemetry(clock=fc) if consumers != "catalog" \
+            else None
+        tb = TickBoundary(cat, tele, "expire", tick=7)
+        assert fc.reads == 1                       # opening reads once
+        for n, phase in enumerate(("admit", "decode_wait", "emit"), 2):
+            fc.advance(0.25)
+            tb.mark(phase)
+            assert fc.reads == n
+        fc.advance(0.25)
+        tb.close()
+        assert fc.reads == 5
+        want = {"expire": 0.25, "admit": 0.25, "decode_wait": 0.25,
+                "emit": 0.25}
+        if cat is not None:
+            assert cat.pending_phases() == want
+        if tele is not None:
+            h = tele.registry.get("serving_tick_phase_seconds")
+            assert {k[0]: v["sum"] for k, v in h.samples().items()} \
+                == want
+            spans = _serve_spans(tele, tick=7)
+            assert [e["name"] for e in spans] == [
+                "serve.expire", "serve.admit", "serve.decode_wait",
+                "serve.emit"]
+            # contiguous: each ends where the next begins
+            for a, b in zip(spans, spans[1:]):
+                assert a["ts"] + a["dur"] == pytest.approx(b["ts"])
+
+    def test_scripted_tick_phases_sum_to_its_wall_and_split_host_from_chip(
+            self):
+        """One tick that admits, prefills, activates and decodes: its
+        serve.* spans tile the tick's wall with no hole, the programs
+        are dispatched inside the *_wait phases and the host's work
+        falls in the phases that leave the chip idle."""
+        clock = _SteppingClock()
+        tele = ServerTelemetry(clock=clock)
+        srv = _stub_server(telemetry=tele)
+        at = {}
+
+        def spy(name, attr):
+            inner = getattr(srv, attr)
+
+            def wrapped(*a, **kw):
+                at.setdefault(name, []).append(srv._boundary.phase)
+                return inner(*a, **kw)
+            setattr(srv, attr, wrapped)
+
+        for name in ("_expire_locked", "_admit_ragged", "_ragged_fn",
+                     "_flush_slot_state", "_harvest"):
+            spy(name, name)
+        srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=4)
+        srv._decode_jit = srv._build_decode_step()
+        spy("decode", "_decode_jit")
+        srv.step()
+
+        assert at == {"_expire_locked": ["expire"],
+                      "_admit_ragged": ["admit", "admit"],
+                      "_ragged_fn": ["prefill_wait"],
+                      "_harvest": ["harvest", "harvest"],
+                      "_flush_slot_state": ["state_push"],
+                      "decode": ["decode_wait"]}
+        spans = _serve_spans(tele, tick=1)
+        names = [e["name"][len("serve."):] for e in spans]
+        assert names == ["expire", "admit", "prefill_pack",
+                         "prefill_wait", "activate", "admit", "harvest",
+                         "state_push", "decode_wait", "emit", "harvest",
+                         "admit", "callbacks"]
+        assert set(names) <= _CHIP_PHASES | _HOST_PHASES
+        wall = spans[-1]["ts"] + spans[-1]["dur"] - spans[0]["ts"]
+        assert sum(e["dur"] for e in spans) == pytest.approx(wall)
+        assert all(e["dur"] > 0 for e in spans)
+        h = tele.registry.get("serving_tick_phase_seconds")
+        by_phase = {k[0]: v["sum"] for k, v in h.samples().items()}
+        assert sum(by_phase.values()) * 1e6 == pytest.approx(wall)
+        # the launch's span says what it served, and the request's own
+        # prefill span carries the same tick
+        launch = spans[names.index("prefill_wait")]["args"]
+        assert launch == {"tick": 1, "width": 4, "rows": 1, "rids": [0]}
+        (prefill,) = [e for e in tele.tracer.events()
+                      if e["name"] == "request.prefill"]
+        assert prefill["args"]["tick"] == 1 and prefill["args"]["rid"] == 0
+        assert tele.registry.get("serving_prefill_launches_total") \
+            .labels(width=4).value == 1.0
+        # the tick and prefill-batch histograms and the stat are fed
+        # from the boundary's reads: the spans' own lengths
+        dur = {n: e["dur"] / 1e6 for n, e in zip(names, spans)}
+        assert _hist(tele.registry, "serving_tick_seconds") == \
+            (1, pytest.approx(dur["decode_wait"]))
+        batch = dur["prefill_wait"] + dur["activate"]
+        assert _hist(tele.registry, "serving_prefill_seconds") == \
+            (1, pytest.approx(batch))
+        assert srv.stats["prefill_wall_s"] == pytest.approx(batch)
+
+    def test_serve_spans_are_mirrored_request_spans_are_not(
+            self, monkeypatch):
+        seen = _record_annotations(monkeypatch)
+        tele = ServerTelemetry(clock=FakeClock())
+        srv = _stub_server(telemetry=tele)
+        srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=3)
+        srv.run()
+        entered = [name for kind, name, *_ in seen if kind == "enter"]
+        exited = [name for kind, name, *_ in seen if kind == "exit"]
+        assert entered and sorted(entered) == sorted(exited)
+        assert all(n.startswith("serve.") for n in entered)
+        assert {"serve.submit", "serve.prefill_wait",
+                "serve.decode_wait", "serve.callbacks"} <= set(entered)
+        collected = {e["name"] for e in tele.tracer.events()}
+        assert {"request.queued", "request.prefill",
+                "request.decode"} <= collected
+
+    def test_off_means_no_clock_read_and_no_annotation(self, monkeypatch):
+        """telemetry=None, costs=None: a tick builds no boundary, reads
+        no clock and builds no TraceAnnotation, and submit() takes the
+        bare lock."""
+        seen = _record_annotations(monkeypatch)
+        fc = FakeClock()
+        srv = _stub_server(clock=fc)
+        rid = srv.submit(np.asarray([1, 2, 3], np.int32),
+                         max_new_tokens=4)
+        assert len(srv.run()[rid]) == 4
+        assert fc.reads == 0 and seen == []
+        assert srv._boundary is None and srv._tick_seq == 0
+        assert srv.stats["prefill_wall_s"] == 0.0
+
+    def test_untraced_benchmark_setting_reads_only_at_the_boundary(
+            self, monkeypatch):
+        """telemetry=None with the catalog on (the benchmark's untraced
+        runs): once the programs are compiled, every clock read of a
+        wave is one of the boundary's, and it feeds the catalog alone:
+        no span, no TraceAnnotation."""
+        from paddle_tpu.telemetry import CostCatalog
+        from paddle_tpu.telemetry.serving import TickBoundary
+        seen = _record_annotations(monkeypatch)
+        fc = FakeClock()
+        cat = CostCatalog(clock=fc)
+        srv = _stub_server(costs=cat)
+        prompt = np.asarray([1, 2, 3], np.int32)
+        srv.submit(prompt, max_new_tokens=4)
+        srv.run()                          # compiles (the watch reads)
+        marks = []
+        for attr in ("mark", "close"):
+            inner = getattr(TickBoundary, attr)
+
+            def counted(self, *a, _inner=inner, **kw):
+                marks.append(1)
+                return _inner(self, *a, **kw)
+            monkeypatch.setattr(TickBoundary, attr, counted)
+        before = fc.reads
+        srv.submit(prompt, max_new_tokens=4)
+        srv.run()
+        assert marks and fc.reads - before == len(marks)
+        assert seen == []
+        assert set(cat.snapshot()["last_tick_phases"]) <= \
+            _CHIP_PHASES | _HOST_PHASES
+
+    def test_submit_lock_wait_is_observed_and_rides_the_queued_span(self):
+        clock = _SteppingClock()
+        tele = ServerTelemetry(clock=clock)
+        srv = _stub_server(telemetry=tele)
+        for _ in range(3):
+            srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=2)
+        # two reads around the acquisition, a millisecond apart here
+        n, total = _hist(tele.registry, "serving_submit_lock_wait_seconds")
+        assert n == 3 and total == pytest.approx(0.003)
+        srv.run()
+        queued = [e for e in tele.tracer.events()
+                  if e["name"] == "request.queued"]
+        assert len(queued) == 3
+        assert all(e["args"]["lock_wait_s"] == pytest.approx(0.001)
+                   for e in queued)
+
+    def test_idle_serve_loop_waits_in_idle_wait(self):
+        """With nothing to do the serve thread's sleep is a phase of
+        its own, with no tick number, and is nobody's fault: readers
+        leave it out."""
+        import time
+        tele = ServerTelemetry()
+        srv = _stub_server(telemetry=tele)
+        srv.start(idle_sleep=0.001)
+        try:
+            deadline = time.monotonic() + 10.0
+            h = tele.registry.get("serving_tick_phase_seconds")
+            while h.labels(phase="idle_wait").count < 3 \
+                    and time.monotonic() < deadline:
+                time.sleep(0.005)
+        finally:
+            srv.stop()
+        assert h.labels(phase="idle_wait").count >= 3
+        idle = [e for e in tele.tracer.events()
+                if e["name"] == "serve.idle_wait"]
+        assert idle and all("tick" not in e.get("args", {}) for e in idle)
+
+
+# ---------------------------------------------------------- kernel names
+
+def _kernel_cases():
+    """(name the trace must show, a function that traces the kernel's
+    wrapper at a tiny shape)."""
+    import jax
+    import jax.numpy as jnp
+    f32, bf16, i8, i32 = jnp.float32, jnp.bfloat16, jnp.int8, jnp.int32
+
+    def sds(shape, dtype=f32):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    def paged():
+        from paddle_tpu.ops.pallas.paged_attention import \
+            _paged_attention_pallas
+        return jax.make_jaxpr(
+            lambda q, k, v, bt, ln: _paged_attention_pallas(
+                q, k, v, bt, ln, 0.125))(
+            sds((2, 4, 64)), sds((8, 16, 4, 64)), sds((8, 16, 4, 64)),
+            sds((2, 4), i32), sds((2,), i32))
+
+    def ragged():
+        from paddle_tpu.ops.pallas.ragged_prefill import \
+            _ragged_prefill_pallas
+        return jax.make_jaxpr(
+            lambda q, k, v, bt, t0, last: _ragged_prefill_pallas(
+                q, k, v, bt, t0, last, 0.125))(
+            sds((2, 8, 4, 64)), sds((8, 16, 4, 64)), sds((8, 16, 4, 64)),
+            sds((2, 4), i32), sds((2,), i32), sds((2,), i32))
+
+    def fused():
+        from paddle_tpu.ops.pallas.fused_tick import _fused_tick_pallas
+        return jax.make_jaxpr(
+            lambda q, k, v, bt, t0, ss, sp: _fused_tick_pallas(
+                q, k, v, bt, t0, ss, sp, 0.125))(
+            sds((2, 8, 4, 64)), sds((8, 16, 4, 64)), sds((8, 16, 4, 64)),
+            sds((2, 4), i32), sds((2,), i32), sds((8,), i32),
+            sds((8,), i32))
+
+    def flash_fwd():
+        from paddle_tpu.ops.pallas.flash_attention import _flash_fwd_pallas
+        return jax.make_jaxpr(
+            lambda q, k, v: _flash_fwd_pallas(q, k, v, 0.125, True))(
+            sds((2, 128, 64), bf16), sds((2, 128, 64), bf16),
+            sds((2, 128, 64), bf16))
+
+    def flash_bwd():
+        from paddle_tpu.ops.pallas.flash_attention import _flash_bwd_pallas
+        x = sds((2, 128, 64), bf16)
+        return jax.make_jaxpr(
+            lambda q, k, v, o, lse, do: _flash_bwd_pallas(
+                q, k, v, o, lse, do, 0.125, True))(
+            x, x, x, x, sds((2, 128)), x)
+
+    def quant():
+        from paddle_tpu.ops.pallas.quant_matmul import quantized_matmul
+        return jax.make_jaxpr(
+            lambda x, w: quantized_matmul(x, w, 0.5, 0.5, interpret=True))(
+            sds((128, 128), i8), sds((128, 128), i8))
+
+    def gemm():
+        from paddle_tpu.ops.pallas.gemm_epilogue import \
+            _gemm_epilogue_pallas
+        return jax.make_jaxpr(
+            lambda x, w, b: _gemm_epilogue_pallas(x, w, b, "gelu"))(
+            sds((128, 128)), sds((128, 128)), sds((128,)))
+
+    def rms_fwd():
+        from paddle_tpu.ops.pallas.rms_norm import _pallas_fwd
+        return jax.make_jaxpr(lambda x, w: _pallas_fwd(x, w, 1e-6))(
+            sds((16, 128)), sds((128,)))
+
+    def rms_bwd():
+        from paddle_tpu.ops.pallas.rms_norm import _pallas_bwd
+        return jax.make_jaxpr(lambda x, w, g: _pallas_bwd(x, w, g, 1e-6))(
+            sds((16, 128)), sds((128,)), sds((16, 128)))
+
+    def rope():
+        from paddle_tpu.ops.pallas.rope import apply_rotary_pallas
+        return jax.make_jaxpr(apply_rotary_pallas)(
+            sds((1, 16, 2, 64)), sds((16, 32)), sds((16, 32)))
+
+    return [("paged_attention_decode", paged),
+            ("ragged_prefill_attention", ragged),
+            ("fused_tick", fused), ("flash_fwd", flash_fwd),
+            ("flash_bwd_dq", flash_bwd), ("flash_bwd_dkv", flash_bwd),
+            ("quant_matmul", quant), ("gemm_epilogue", gemm),
+            ("rms_norm_fwd", rms_fwd), ("rms_norm_bwd", rms_bwd),
+            ("rope", rope)]
+
+
+def _pallas_call_names(jaxpr):
+    """The ``name`` of every pallas_call in a jaxpr, sub-jaxprs
+    included."""
+    import jax
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+            continue
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            names += _pallas_call_names(sub)
+    return names
+
+
+class TestKernelNames:
+    @pytest.mark.parametrize("name,trace", _kernel_cases(),
+                             ids=[c[0] for c in _kernel_cases()])
+    def test_kernel_wrapper_holds_a_pallas_call_under_its_constant_name(
+            self, name, trace):
+        assert name in _pallas_call_names(trace().jaxpr)
+
+    def test_every_pallas_call_site_passes_a_name(self):
+        """No kernel reaches a trace as ``%closed_call.N``: each
+        ``pl.pallas_call(`` under ops/pallas is followed by a constant
+        ``name=``, and no two kernels share one."""
+        import os
+        import re
+        root = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "paddle_tpu", "ops", "pallas")
+        names = []
+        for fn in sorted(os.listdir(root)):
+            if not fn.endswith(".py"):
+                continue
+            with open(os.path.join(root, fn)) as f:
+                src = f.read()
+            calls = len(re.findall(r"pl\.pallas_call\(", src))
+            named = re.findall(r'^\s+name="([a-z_]+)",$', src, re.M)
+            assert calls == len(named), fn
+            names += named
+        assert sorted(names) == sorted(n for n, _ in _kernel_cases())
 
 
 # ------------------------------------------------------------ exposition
@@ -578,8 +984,7 @@ class TestDisabledOverheadStructural:
         fc = FakeClock()
         tele = ServerTelemetry(registry=reg, clock=fc)
         for _ in range(100):
-            t = tele.tick_started()
-            tele.on_tick(t, 4, 4)
+            tele.on_tick(0.01, 4, 4)
         assert fc.reads == 0
 
 
