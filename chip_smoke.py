@@ -279,6 +279,21 @@ class KernelCase:
         import jax.numpy as jnp
         return jnp.asarray(self.rng.standard_normal(shape), self.dtype)
 
+    def same_at_layer(self, name, call, got, q, k, v, rest):
+        """The serving loop's call of a paged kernel: the WHOLE pool,
+        lane-dense, read through a layer index. The one-layer pools
+        k/v sit at layer 1 of two (layer 0 holds other values) and the
+        output must equal ``got``, the one-layer call's, bit for bit."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.models.generation import pool_lanes
+        kk, vv = (pool_lanes(jnp.stack([-a, a])) for a in (k, v))
+        at1 = self.run(lambda q, k, v, *r: call(q, k, v, *r, layer=1),
+                       (q, kk, vv) + rest)
+        if not bool((at1 == got).all()):
+            self.bad.append(f"{name}[{self.name}]: the pool read at a "
+                            f"layer index differs from that layer alone")
+
 
 def kernel_paged(c):
     """Decode attention: ragged lengths incl. 1, a page boundary, one past
@@ -291,8 +306,11 @@ def kernel_paged(c):
                     np.int32)
     k, v, bt = c.pool(lens)
     q, lens_d = c.normal(c.S, c.nh, c.hd), jnp.asarray(lens)
-    got = c.run(lambda q, k, v, bt, ln: pa.paged_attention(
-        q, k, v, bt, ln, c.scale), (q, k, v, bt, lens_d))
+    def call(q, k, v, bt, ln, layer=None):
+        return pa.paged_attention(q, k, v, bt, ln, c.scale, layer=layer)
+
+    got = c.run(call, (q, k, v, bt, lens_d))
+    c.same_at_layer("paged_attention", call, got, q, k, v, (bt, lens_d))
     want = c.ref(lambda q, k, v, bt, ln: pa._ref_paged_attention(
         q, k, v, bt, ln, c.scale), q, k, v, bt, lens_d)
     c.close("paged_attention", got, want)
@@ -317,9 +335,13 @@ def kernel_ragged(c):
     k, v, bt = c.pool(np.maximum(last + 1, 0))
     q = c.normal(c.S, C, c.nh, c.hd)
     t0_d, last_d = jnp.asarray(t0), jnp.asarray(last)
-    got = c.run(lambda q, k, v, bt, t0, last: rp.ragged_prefill_attention(
-        q, k, v, bt, t0, last=last, sm_scale=c.scale),
-        (q, k, v, bt, t0_d, last_d))
+    def call(q, k, v, bt, t0, last, layer=None):
+        return rp.ragged_prefill_attention(q, k, v, bt, t0, last=last,
+                                           sm_scale=c.scale, layer=layer)
+
+    got = c.run(call, (q, k, v, bt, t0_d, last_d))
+    c.same_at_layer(f"ragged_prefill C={C}", call, got, q, k, v,
+                    (bt, t0_d, last_d))
     want = c.ref(lambda q, k, v, bt, t0: rp._ref_ragged_prefill(
         q, k, v, bt, t0, c.scale), q, k, v, bt, t0_d)
     live = _live_rows(C, take)             # rows past a take are padding
@@ -522,9 +544,12 @@ def check_tokens(name, ref_logits, prompt, emitted):
           f"the f32 reference's maximum (margin {LOGIT_MARGIN_STD})")
 
 
-def inspect_programs(cat, weight_bytes, expect, rehearse):
+def inspect_programs(cat, weight_bytes, pool_bytes, expect, rehearse):
     """Every serving program the catalog compiled: generated code under
-    CODE_SHARE_MAX of the weights, and the Mosaic calls it should hold."""
+    CODE_SHARE_MAX of the weights, temporaries under half the page pool
+    (a tick that copies, slices or relays out the pool shows up as a
+    pool-sized temp: tests/test_tick_programs_v5e.py holds the same
+    here, without the chip), and the Mosaic calls it should hold."""
     seen = {}
     for op, prog in cat.programs():
         exe = prog.executable
@@ -543,6 +568,10 @@ def inspect_programs(cat, weight_bytes, expect, rehearse):
               f"{op}: generated code {code} B is over "
               f"{CODE_SHARE_MAX:.0%} of the weights ({weight_bytes} B): "
               f"the weights are constants of the executable")
+        check(mem.temp_size_in_bytes <= pool_bytes / 2,
+              f"{op}: temp {mem.temp_size_in_bytes} B is over half the "
+              f"page pool ({pool_bytes} B): the program holds a copy of "
+              f"the pool instead of updating it in place")
     for op, at_least in expect.items():
         base = op.split("_mp")[0]
         got = [c for o, cs in seen.items() if o.split("_mp")[0] == base
@@ -558,7 +587,6 @@ def inspect_programs(cat, weight_bytes, expect, rehearse):
 def serve_once(P, model, mode, mesh, rehearse, watch, ref_logits,
                also_resident):
     from paddle_tpu.inference import ContinuousBatchingServer
-    from paddle_tpu.ops.pallas.ragged_prefill import _QUERY_TILE
     from paddle_tpu.telemetry import CostCatalog
 
     cat = CostCatalog()
@@ -634,11 +662,10 @@ def serve_once(P, model, mode, mesh, rehearse, watch, ref_logits,
     for (tag, i), (p, out) in results.items():
         if tag != "warm-tail":
             check_tokens(f"{mode}/{tag}#{i}", ref_logits, p, out)
-    chunk = 1 << (max(len(p) for p in wave) - 1).bit_length()
-    tiles = -(-chunk // _QUERY_TILE)
-    expect = ({"decode": 1, "prefill": tiles} if mode == "split"
+    # the prefill program loops over its query tiles: one kernel call
+    expect = ({"decode": 1, "prefill": 1} if mode == "split"
               else {"fused": 1})
-    inspect_programs(cat, w_bytes, expect, rehearse)
+    inspect_programs(cat, w_bytes, pool_bytes, expect, rehearse)
     resident = dict({"stacked weights": w_bytes, "pool": pool_bytes},
                     **also_resident)
     mem_line(f"serve[{mode}]", "; known residents: " + " + ".join(

@@ -473,9 +473,14 @@ class ContinuousBatchingServer:
             # fallback: kv heads not divisible by the mp axis) — the
             # host-side bookkeeping's ONLY mesh knowledge, feeding the
             # per-shard balance views and the cost-op namespacing
-            from ..models.generation import paged_pool_shards
-            self._pool_shards = paged_pool_shards(
-                mesh, int(self._caches["pool"]["k"].shape[3]))
+            from ..models.generation import (paged_kv_heads,
+                                             paged_pool_shards)
+            # the pool stores a token's kv heads merged into one
+            # lane-dense axis (generation.paged_pool_shape): the head
+            # count comes from the model's config, and the page movers
+            # below view rows per head at the host boundary
+            self._kv_heads = paged_kv_heads(model.cfg)
+            self._pool_shards = paged_pool_shards(mesh, self._kv_heads)
             # host KV tier (kv_tier.HostTier): eviction SPILLS cold
             # prefix pages to checksummed host buffers instead of
             # dropping them, and admissions hitting a spilled run
@@ -1261,14 +1266,14 @@ class ContinuousBatchingServer:
         page_size) into the pool at ``pages`` (position order)."""
         if not pages:
             return
+        from ..models.generation import pool_lanes
         pg = self._kv.page_size
         n = len(pages) * pg
         ids = jnp.asarray(np.asarray(pages, np.int32))
 
-        def seg(c):            # [L, 1, T', h, hd] -> [L, npg, pg, h, hd]
-            s = c[:, 0, start:start + n]
-            return s.reshape(s.shape[0], len(pages), pg, s.shape[2],
-                             s.shape[3])
+        def seg(c):            # [L, 1, T', h, hd] -> [L, npg, pg, h*hd]
+            s = pool_lanes(c[:, 0, start:start + n])
+            return s.reshape(s.shape[0], len(pages), pg, s.shape[2])
 
         pool = jax.tree_util.tree_map(
             lambda p_, c: p_.at[:, ids].set(seg(c).astype(p_.dtype)),
@@ -1287,14 +1292,16 @@ class ContinuousBatchingServer:
         zero extra dispatches (BENCHNOTES Round 7 measured this
         gather→dense→scatter round-trip exceeding the saved FLOPs on
         small models)."""
+        from ..models.generation import pool_heads
         pg = self._kv.page_size
         n = len(pages) * pg
         idx = jnp.asarray(np.asarray(pages, np.int32))
         base = self._init_caches(1)
 
-        def take(pool, dense):         # [L, P, pg, h, hd] -> dense rows
+        def take(pool, dense):   # [L, P, pg, h*hd] -> dense rows
             s = pool[:, idx]
-            s = s.reshape(s.shape[0], 1, n, s.shape[3], s.shape[4])
+            s = pool_heads(s.reshape(s.shape[0], 1, n, s.shape[3]),
+                           self._kv_heads)
             return dense.at[:, :, :n].set(s.astype(dense.dtype))
 
         pool = self._caches["pool"]
@@ -1306,30 +1313,59 @@ class ContinuousBatchingServer:
                 "v": take(pool["v"], base["v"])}
 
     def _spill_payload(self, page):
-        """One pool page's K and V rows as host numpy arrays — the
-        demotion gather ``PrefixCache.evict`` routes through the host
-        tier. On a sharded pool the gather goes PER SHARD: each
+        """One pool page's K and V rows as host numpy arrays
+        ``[L, pg, kvh, hd]`` — the demotion gather ``PrefixCache.evict``
+        routes through the host tier, and the wire format of migration
+        and handoff. On a sharded pool the gather goes PER SHARD: each
         device ships only its kv-head slice (``addressable_shards``,
-        ordered by kv-head offset) and the slices concatenate on the
-        head dim — never a full-pool replication bounce (the PR-14
-        gap). Runs inside an allocator reclaim under the server lock,
-        off the tick path."""
+        ordered by their offset on the pool's merged head axis) and
+        the slices concatenate on that axis — never a full-pool
+        replication bounce (the PR-14 gap). Runs inside an allocator
+        reclaim under the server lock, off the tick path."""
+        from ..models.generation import pool_heads
         page = int(page)
         out = []
         for name in ("k", "v"):
             leaf = self._caches["pool"][name]
+            rows = None
             if self._pool_shards > 1:
                 try:
                     shards = sorted(leaf.addressable_shards,
                                     key=lambda s: s.index[3].start or 0)
-                    out.append(np.concatenate(
+                    rows = np.concatenate(
                         [np.asarray(s.data[:, page]) for s in shards],
-                        axis=2))
-                    continue
+                        axis=2)
                 except Exception:
                     pass       # runtime hid the buffers: global gather
-            out.append(np.asarray(jax.device_get(leaf[:, page])))
+            if rows is None:
+                rows = np.asarray(jax.device_get(leaf[:, page]))
+            out.append(pool_heads(rows, self._kv_heads))
         return out
+
+    def _write_pages(self, pages, payloads):
+        """Scatter page payloads (``_spill_payload``'s format: a K and
+        a V array ``[L, pg, kvh, hd]`` a page) into pool pages
+        ``pages`` — one batched ``.at[:, idx].set`` per k/v leaf. On a
+        sharded pool the host rows are laid out against the pool's own
+        sharding first (``jax.device_put`` with the leaf's sharding —
+        each device receives only its kv-head slice): the mirror of
+        the spill's per-shard gather."""
+        from ..models.generation import pool_lanes
+        idx = jnp.asarray(np.asarray(pages, np.int32))
+        pool = dict(self._caches["pool"])
+        for j, name in enumerate(("k", "v")):
+            leaf = pool[name]
+            # [L, n, pg, kvh*hd]: page payloads stacked on a new pages
+            # axis, matching leaf[:, idx]
+            val = pool_lanes(np.stack([p[j] for p in payloads], axis=1))
+            val = val.astype(leaf.dtype)
+            if self._pool_shards > 1:
+                try:
+                    val = jax.device_put(val, leaf.sharding)
+                except Exception:
+                    pass
+            pool[name] = leaf.at[:, idx].set(jnp.asarray(val))
+        self._caches = dict(self._caches, pool=pool)
 
     def _restore_match(self, m):
         """Restore a tree match's host-resident suffix into freshly
@@ -1344,11 +1380,8 @@ class ContinuousBatchingServer:
         corrupt node (and its all-host subtree) for good, and an
         OutOfPages trims to the hot prefix.
 
-        On a sharded pool the scatter goes PER SHARD: the host
-        payload is laid out against the pool's own sharding
-        (``jax.device_put`` with the leaf's sharding — each device
-        receives only its kv-head slice) before one batched
-        ``.at[].set`` — the restore mirror of the spill gather."""
+        The scatter is ``_write_pages`` (per shard on a sharded
+        pool) — the restore mirror of the spill gather."""
         from .prefix_cache import PrefixMatch
         nodes = m.nodes
         hot = m.hot_len()
@@ -1388,22 +1421,7 @@ class ContinuousBatchingServer:
             finally:           # serve the hot prefix only
                 self._prefix.protect(())
             if fresh is not None:
-                idx = jnp.asarray(np.asarray(fresh, np.int32))
-                pool = dict(self._caches["pool"])
-                for j, name in enumerate(("k", "v")):
-                    leaf = pool[name]
-                    # [L, n, pg, kvh, hd]: page payloads stacked on a
-                    # new pages axis, matching leaf[:, idx]
-                    val = np.stack([p[j] for p in payloads], axis=1)
-                    val = val.astype(leaf.dtype)
-                    if self._pool_shards > 1:
-                        try:
-                            val = jax.device_put(
-                                val, leaf.sharding)
-                        except Exception:
-                            pass
-                    pool[name] = leaf.at[:, idx].set(jnp.asarray(val))
-                self._caches = dict(self._caches, pool=pool)
+                self._write_pages(fresh, payloads)
                 for nd, page in zip(restoring, fresh):
                     self._prefix.promote(nd, page)
                 if self._costs is not None:
@@ -4048,29 +4066,6 @@ class ContinuousBatchingServer:
                 f"send decoding or prefilling slots only)")
         return phase, emitted, prompt_len, budget, written
 
-    def _scatter_pages_locked(self, own, base, payloads):
-        """Scatter received page payloads into this pool's pages
-        ``own[base : base + len(payloads)]`` — one batched
-        ``.at[:, idx].set`` per k/v leaf, laid out per shard on a mesh
-        (the ``_restore_match`` mirror of the source's per-shard
-        gather). Caller holds the lock and handles rollback."""
-        idx = jnp.asarray(np.asarray(
-            own[base:base + len(payloads)], np.int32))
-        pool = dict(self._caches["pool"])
-        for j, name in enumerate(("k", "v")):
-            leaf = pool[name]
-            # [L, n, pg, kvh, hd]: page payloads stacked on a new
-            # pages axis, matching leaf[:, idx]
-            val = np.stack([p[j] for p in payloads], axis=1)
-            val = val.astype(leaf.dtype)
-            if self._pool_shards > 1:
-                try:
-                    val = jax.device_put(val, leaf.sharding)
-                except Exception:
-                    pass
-            pool[name] = leaf.at[:, idx].set(jnp.asarray(val))
-        self._caches = dict(self._caches, pool=pool)
-
     def _restore_slot_locked(self, slot, state, phase, emitted,
                              prompt_len, budget, written,
                              on_token, journey):
@@ -4209,7 +4204,7 @@ class ContinuousBatchingServer:
             own = self._kv.admit_slot(slot, max(written, extent))
             if payloads:
                 try:
-                    self._scatter_pages_locked(own, 0, payloads)
+                    self._write_pages(own[:len(payloads)], payloads)
                 except Exception:
                     self._kv.free_slot(slot)
                     raise
@@ -4329,7 +4324,8 @@ class ContinuousBatchingServer:
                     f"staged pages [{base}, {base + len(payloads)}) "
                     f"fall outside the slot's {len(own)}-page extent")
             if payloads:
-                self._scatter_pages_locked(own, base, payloads)
+                self._write_pages(own[base:base + len(payloads)],
+                                  payloads)
                 if self._costs is not None:
                     self._charge_transfer(
                         "page_migrate",
@@ -4397,7 +4393,8 @@ class ContinuousBatchingServer:
                         f"closing page {base + i} failed its "
                         f"end-to-end sha256 check")
             if payloads:
-                self._scatter_pages_locked(own, base, list(payloads))
+                self._write_pages(own[base:base + len(payloads)],
+                                  payloads)
                 if self._costs is not None:
                     self._charge_transfer(
                         "page_migrate",

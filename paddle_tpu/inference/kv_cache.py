@@ -3,8 +3,10 @@
 The dense decode backend allocates ``[max_slots, ..., max_cache_len]``
 KV buffers, so cache HBM scales with the CONFIGURED cache length. The
 paged backend (cf. "Ragged Paged Attention", PAPERS.md) stores K/V in a
-fixed global pool ``[num_pages, page_size, kv_heads, head_dim]`` per
-layer and gives each slot an ordered block table of page ids — HBM and
+fixed global pool of ``num_pages`` pages of ``page_size`` tokens per
+layer (its storage shape has one owner:
+``models/generation.paged_pool_shape``) and gives each slot an ordered
+block table of page ids — HBM and
 decode bandwidth then scale with ACTUAL tokens, and a pool sized to the
 real working set serves slot counts x cache lengths that a dense layout
 could not.

@@ -131,28 +131,26 @@ def paged_pool_shards(mesh, num_kv_heads, axis="mp"):
     return size if size > 1 and num_kv_heads % size == 0 else 1
 
 
-def _mesh_paged_caches(init_caches, mesh, axis="mp"):
+def _mesh_paged_caches(init_caches, mesh, kv_heads, axis="mp"):
     """Mesh placement for a fresh PAGED cache tree: the global K/V page
-    pools shard on the kv-head dimension (axis 3 of
-    ``[layers, num_pages, page_size, kvh, hd]``) over the mesh's
-    ``axis`` — per-device pool bytes shrink by 1/mp at fixed page
-    capacity, the capacity unlock of ROADMAP item 1 — while the block
-    table stays REPLICATED: page ids are global, so the host-side
-    allocator, grow/preempt/donate, and the prefix radix tree never
-    learn the mesh exists. A kv-head count the axis size doesn't divide
-    falls back to a replicated pool (``paged_pool_shards`` reports 1),
-    exactly like ``_apply_mesh`` does for weights."""
+    pools shard their merged ``kv_heads * head_dim`` axis (the minor
+    one, see ``paged_pool_shape``) over the mesh's ``axis`` — contiguous
+    blocks of whole kv heads, so per-device pool bytes shrink by 1/mp
+    at fixed page capacity, the capacity unlock of ROADMAP item 1 —
+    while the block table stays REPLICATED: page ids are global, so the
+    host-side allocator, grow/preempt/donate, and the prefix radix tree
+    never learn the mesh exists. A ``kv_heads`` count (the model
+    config's) the axis size doesn't divide falls back to a replicated
+    pool (``paged_pool_shards`` reports 1), exactly like ``_apply_mesh``
+    does for weights."""
     from jax.sharding import NamedSharding
     from jax.sharding import PartitionSpec as P
     rep = NamedSharding(mesh, P())
+    sh = (NamedSharding(mesh, P(None, None, None, axis))
+          if paged_pool_shards(mesh, kv_heads, axis) > 1 else rep)
 
     def init(batch):
         tree = init_caches(batch)
-        kvh = tree["pool"]["k"].shape[3]
-        if paged_pool_shards(mesh, kvh, axis) > 1:
-            sh = NamedSharding(mesh, P(None, None, None, axis, None))
-        else:
-            sh = rep
         return dict(tree,
                     pool={n: jax.device_put(a, sh)
                           for n, a in tree["pool"].items()},
@@ -294,75 +292,97 @@ def _check_paged_config(max_cache_len, page_size, num_pages, cache_dtype,
             f"({max_cache_len}) for dense/paged token parity")
 
 
+def paged_pool_shape(layers, num_pages, page_size, kv_heads, head_dim):
+    """THE storage shape of a paged K or V pool — spelled here and
+    nowhere else: ``[layers, num_pages, page_size, kv_heads *
+    head_dim]``, a token's kv heads merged into one LANE-DENSE minor
+    axis. A ``head_dim`` of 64 is half a TPU lane tile: stored as its
+    own minor axis the compiler's default layout puts another
+    dimension (the pages) on the lanes instead of padding every row to
+    128, and since a Mosaic call takes its operands row-major, every
+    layer's pool was relaid out before each kernel call and back after
+    it. Merged, the minor axis is ``kv_heads * head_dim`` wide, the
+    default layout IS row-major, and kernel and XLA read the same
+    bytes. ``pool_heads`` / ``pool_lanes`` are the views to and from
+    per-head rows (reshapes of the minor axis: head ``g`` is lanes
+    ``[g * head_dim, (g + 1) * head_dim)``, so a mesh shard of the
+    merged axis is a contiguous block of whole heads)."""
+    return (int(layers), int(num_pages), int(page_size),
+            int(kv_heads) * int(head_dim))
+
+
+def pool_heads(a, kv_heads):
+    """View pool-stored rows ``[..., kv_heads * head_dim]`` as
+    ``[..., kv_heads, head_dim]`` (what payloads, dense caches and the
+    attention math see)."""
+    return a.reshape(a.shape[:-1] + (kv_heads, a.shape[-1] // kv_heads))
+
+
+def pool_lanes(a):
+    """Inverse of ``pool_heads``: rows ``[..., kv_heads, head_dim]`` as
+    the pool stores them, ``[..., kv_heads * head_dim]``."""
+    return a.reshape(a.shape[:-2] + (a.shape[-2] * a.shape[-1],))
+
+
+def paged_kv_heads(cfg):
+    """kv heads a model config's paged pool stores per token (GQA
+    models cache them unrepeated; GPT has no separate count)."""
+    return int(getattr(cfg, "num_kv_heads", None) or cfg.num_heads)
+
+
 def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
                    kvh, hd, dtype):
-    """Paged decode cache tree: one global K/V page pool per layer plus
-    the per-slot block table (a RUNTIME argument of the decode program —
-    page churn never recompiles)."""
-    shape = (layers, num_pages, page_size, kvh, hd)
+    """Paged decode cache tree: one global K/V page pool over all
+    layers (``paged_pool_shape``) plus the per-slot block table (a
+    RUNTIME argument of the decode program — page churn never
+    recompiles)."""
+    shape = paged_pool_shape(layers, num_pages, page_size, kvh, hd)
     return {"pool": {"k": jnp.zeros(shape, dtype),
                      "v": jnp.zeros(shape, dtype)},
             "bt": jnp.zeros((batch, pages_per_slot), jnp.int32)}
 
 
-def _page_write(pool, kv, bt, t):
-    """pool [P, pg, h, hd] <- kv [B, 1, h, hd] at per-slot positions
-    ``t`` ([B] or scalar). A position past the block-table width is
-    redirected to the null page (page 0), so the wasted decode steps of
-    finished/inactive slots can never corrupt a live slot's pages (the
-    dense analogue relies on out-of-bounds writes being dropped). The
-    redirected payload is ZEROED: past the cache length, rope gathers
-    beyond its table and returns jnp's NaN fill — and since every
-    slot's unused block-table entries point at the null page, a stored
-    NaN would poison every row's attention through 0-weight * NaN."""
-    pg = pool.shape[1]
-    b = kv.shape[0]
-    maxp = bt.shape[1]
-    if jnp.ndim(t) == 0:
-        t = jnp.full((b,), t, jnp.int32)
-    pidx = t // pg
-    oob = pidx >= maxp
-    page = jnp.where(oob, jnp.int32(0),
-                     bt[jnp.arange(b), jnp.minimum(pidx, maxp - 1)])
-    vals = kv[:, 0].astype(pool.dtype)
-    vals = jnp.where(oob[:, None, None], jnp.zeros_like(vals), vals)
-    return pool.at[page, t % pg].set(vals)
-
-
-def _paged_attend(q, k_pool, v_pool, bt, t, scale, mesh=None):
+def _paged_attend(q, pool, layer, bt, t, scale, mesh=None):
     """Decode-step attention through the block table: q [B, 1, nh, hd],
-    pools [P, pg, kvh, hd], valid lengths t+1 (cache already written
-    through t). Pallas ragged kernel on TPU (per-kv-head-shard launches
-    under ``mesh`` — XLA cannot partition a custom call, so the kernel
-    path shard_maps itself), bit-exact dense-mirroring gather
-    composition elsewhere (GSPMD partitions it from the pool's input
-    sharding). Returns [B, 1, nh, hd]."""
+    ``pool`` the whole K/V pools ``{"k", "v"}`` [L, P, pg, kvh*hd] read
+    at ``layer``, valid lengths t+1 (cache already written through t).
+    Pallas ragged kernel on TPU (per-kv-head-shard launches under
+    ``mesh`` — XLA cannot partition a custom call, so the kernel path
+    shard_maps itself), bit-exact dense-mirroring gather composition
+    elsewhere (GSPMD partitions it from the pool's input sharding).
+    Returns [B, 1, nh, hd]."""
     from ..ops.pallas.paged_attention import paged_attention
     b = q.shape[0]
     if jnp.ndim(t) == 0:
         t = jnp.full((b,), t, jnp.int32)
-    return paged_attention(q[:, 0], k_pool, v_pool, bt, t + 1, scale,
-                           mesh=mesh)[:, None]
+    return paged_attention(q[:, 0], pool["k"], pool["v"], bt, t + 1, scale,
+                           mesh=mesh, layer=layer)[:, None]
 
 
-def _page_write_seq(pool, kv, bt, t, last=None):
-    """Ragged-prefill page write: pool [P, pg, h, hd] <- kv
-    [B, s, h, hd] at per-slot position runs [t_b, t_b + s). The
-    multi-token analogue of ``_page_write`` with the same null-page
-    discipline: any position past the block-table width is redirected
-    to page 0 with a ZEROED payload (padded chunk rows of idle slots
-    carry rope's out-of-range NaN fill — a stored NaN in the null page
-    would poison every slot's attention through 0-weight reads).
-    Positions inside the table but past a slot's allocation land in its
-    NULL_PAGE tail entries — finite garbage the length masks hide,
-    exactly like a wasted decode step.
+def _page_write(pool, layer, kv, bt, t, last=None):
+    """Page write: pool [L, P, pg, h*hd] <- kv [B, s, h, hd] into layer
+    ``layer`` at per-slot position runs [t_b, t_b + s) — a decode row
+    (s = 1) or a ragged-prefill chunk. The write is a scatter of
+    ``B * s`` rows into the CARRIED pool, so inside the layer loop it
+    updates the donated buffer in place: nothing pool-sized is sliced
+    out, copied or written back. Null-page discipline: any position
+    past the block-table width is redirected to page 0 with a ZEROED
+    payload, so the wasted decode steps of finished/inactive slots can
+    never corrupt a live slot's pages (the dense analogue relies on
+    out-of-bounds writes being dropped), and padded chunk rows of idle
+    slots — which carry rope's / the position table's out-of-range NaN
+    fill — never store a NaN that would poison every slot's attention
+    through 0-weight reads (every slot's unused block-table entries
+    point at the null page). Positions inside the table but past a
+    slot's allocation land in its NULL_PAGE tail entries — finite
+    garbage the length masks hide.
 
     ``last`` ([B] int32, optional): each slot's last VALID position —
     rows past it are null-redirected zeroed too. The fused tick passes
     it so a decode slot's C-row group writes exactly its one token
     (the C-1 pad rows never touch the slot's real pages) and an idle
     slot (``last = -1``) writes nothing at all."""
-    pg = pool.shape[1]
+    pg = pool.shape[2]
     b, s = kv.shape[0], kv.shape[1]
     maxp = bt.shape[1]
     if jnp.ndim(t) == 0:
@@ -375,34 +395,36 @@ def _page_write_seq(pool, kv, bt, t, last=None):
     page = jnp.where(
         oob, jnp.int32(0),
         jnp.take_along_axis(bt, jnp.minimum(pidx, maxp - 1), axis=1))
-    vals = kv.astype(pool.dtype)
-    vals = jnp.where(oob[..., None, None], jnp.zeros_like(vals), vals)
+    vals = pool_lanes(kv.astype(pool.dtype))             # [B, s, h*hd]
+    vals = jnp.where(oob[..., None], jnp.zeros_like(vals), vals)
     n = b * s
-    return pool.at[page.reshape(n), (P % pg).reshape(n)].set(
-        vals.reshape((n,) + vals.shape[2:]))
+    return pool.at[layer, page.reshape(n), (P % pg).reshape(n)].set(
+        vals.reshape(n, vals.shape[-1]))
 
 
-def _paged_prefill_attend(q, k_pool, v_pool, bt, t, scale, mesh=None):
+def _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=None):
     """Ragged packed-prefill attention through the block table: q
-    [B, s, nh, hd] chunk rows starting at per-slot offsets ``t``, pools
-    [P, pg, kvh, hd]; row j of slot b attends to positions <= t_b + j
-    (cache already written through the chunk). Pallas kernel on TPU,
-    bit-exact dense-mirroring gather composition elsewhere. A slot
-    carrying the scheduler's idle sentinel (``t`` past the block-table
-    extent) is handed ``last = -1`` so the kernel skips its every page
-    instead of sweeping NaN garbage; live slots scan at most one chunk
-    width past their real frontier (the chunk's own padding rows)."""
+    [B, s, nh, hd] chunk rows starting at per-slot offsets ``t``,
+    ``pool`` the whole K/V pools read at ``layer``; row j of slot b
+    attends to positions <= t_b + j (cache already written through the
+    chunk). Pallas kernel on TPU, bit-exact dense-mirroring gather
+    composition elsewhere. A slot carrying the scheduler's idle
+    sentinel (``t`` past the block-table extent) is handed ``last =
+    -1`` so the kernel skips its every page instead of sweeping NaN
+    garbage; live slots scan at most one chunk width past their real
+    frontier (the chunk's own padding rows)."""
     from ..ops.pallas.ragged_prefill import ragged_prefill_attention
     b, s = q.shape[0], q.shape[1]
     if jnp.ndim(t) == 0:
         t = jnp.full((b,), t, jnp.int32)
-    limit = bt.shape[1] * k_pool.shape[1]          # tokens a table spans
+    limit = bt.shape[1] * pool["k"].shape[2]       # tokens a table spans
     last = jnp.where(t >= limit, jnp.int32(-1), t + s - 1)
-    return ragged_prefill_attention(q, k_pool, v_pool, bt, t, last=last,
-                                    sm_scale=scale, mesh=mesh)
+    return ragged_prefill_attention(q, pool["k"], pool["v"], bt, t,
+                                    last=last, sm_scale=scale, mesh=mesh,
+                                    layer=layer)
 
 
-def _fused_attend(q, k_pool, v_pool, bt, t, last, dec, ss, sp, scale):
+def _fused_attend(q, pool, layer, bt, t, last, dec, ss, sp, scale):
     """Fused mixed prefill/decode tick attention through the LIVE
     block-table slice: q [B, C, nh, hd] packed row groups (a prefill
     chunk, a single decode row, or idle garbage per slot) at per-slot
@@ -410,30 +432,86 @@ def _fused_attend(q, k_pool, v_pool, bt, t, last, dec, ss, sp, scale):
     (ops/pallas/fused_tick.py). Decode slots (``dec``) route through
     an s=1-shaped fallback einsum so fused serving stays bit-identical
     to the unfused decode program; idle slots (``last < 0``) read as
-    zeros."""
+    zeros. The fused kernel takes ONE layer's pools per head
+    (``[P, pg, kvh, hd]``): it is handed the ``pool[layer]`` slice,
+    viewed per head — a copy of one layer a call, which nothing on a
+    TPU pays (the kernel refuses there, ROADMAP A1)."""
     from ..ops.pallas.fused_tick import fused_tick_attention
-    return fused_tick_attention(q, k_pool, v_pool, bt, t, last, dec,
-                                ss, sp, sm_scale=scale)
+    kvh = pool["k"].shape[-1] // q.shape[-1]
+    return fused_tick_attention(
+        q, pool_heads(pool["k"][layer], kvh),
+        pool_heads(pool["v"][layer], kvh), bt, t, last, dec, ss, sp,
+        sm_scale=scale)
+
+
+def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, fused=None,
+                   mesh=None):
+    """One layer's paged K/V write and attention over the CARRIED
+    pools ``{"k", "v"}`` [L, P, pg, kvh*hd]: the new rows k/v
+    [B, s, kvh, hd] land in layer ``layer``'s pages through the block
+    table, then q [B, s, nh, hd] attends through it. s == 1 is a
+    decode step (ragged paged-attention kernel); s > 1 a RAGGED
+    PREFILL chunk at per-slot offsets ``t`` — which is what lets the
+    server prefill several admissions as one launch with no
+    dense-cache detour. ``fused`` (a ``(last, dec, ss, sp)`` tuple)
+    switches to the FUSED TICK: ``bt`` is then the live block-table
+    slice, rows past ``last`` null-redirect zeroed on write, and
+    attention runs the fused kernel whose DMA schedule ``(ss, sp)``
+    covers only live pages — prefill chunks and s=1 decode rows
+    (``dec``) of one serving tick in a single launch. Returns
+    ``(att [B, s, nh, hd], pool)``."""
+    last = fused[0] if fused is not None else None
+    pool = {"k": _page_write(pool["k"], layer, k, bt, t, last=last),
+            "v": _page_write(pool["v"], layer, v, bt, t, last=last)}
+    if fused is not None:
+        last, dec, ss, sp = fused
+        att = _fused_attend(q, pool, layer, bt, t, last, dec, ss, sp, scale)
+    elif q.shape[1] > 1:
+        att = _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=mesh)
+    else:
+        att = _paged_attend(q, pool, layer, bt, t, scale, mesh=mesh)
+    return att, pool
+
+
+def _run_layers(layer_fn, x, blk_tree, caches, paged):
+    """THE layer loop of every decode bundle. ``layer_fn(xx, blk, lc,
+    l) -> (xx, lc)`` runs one layer over its slice ``blk`` of the
+    stacked weights.
+
+    Dense: a scan with the per-layer caches as ``xs``/``ys`` (``lc`` is
+    layer ``l``'s cache dict). Paged: the hidden state AND the whole
+    K/V pools are the loop's CARRY (``lc`` is the pool dict, written
+    and read at layer index ``l``); the stacked weights are scanned as
+    before. The pool must be carried, not scanned: as ``xs``/``ys`` a
+    donated pool can never be its own result, so the compiled tick
+    copied the pool whole, sliced each layer out and wrote it back
+    into a new one — pool-sized HBM traffic five times a tick and a
+    second copy of the pool in temp. Carried, with the layer's rows
+    scattered in at ``[l, page, offset]`` and the kernels indexing the
+    layer themselves, the donated argument's buffer IS the result's."""
+    layers = jnp.arange(jax.tree_util.tree_leaves(blk_tree)[0].shape[0],
+                        dtype=jnp.int32)
+    if not paged:
+        return jax.lax.scan(lambda xx, xs: layer_fn(xx, *xs), x,
+                            (blk_tree, caches, layers))
+
+    def body(carry, xs):
+        return layer_fn(carry[0], xs[0], carry[1], xs[1]), None
+
+    (x, pool), _ = jax.lax.scan(body, (x, caches["pool"]),
+                                (blk_tree, layers))
+    return x, dict(caches, pool=pool)
 
 
 def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
-                   fused=None, mesh=None):
-    """Shared llama-family attention sublayer for the decode scan:
+                   fused=None, mesh=None, layer=None):
+    """Shared llama-family attention sublayer for the decode loop:
     pre-RMSNorm, rope at absolute positions, GQA cache write + masked
     cached attention, output projection + residual. ``lc`` is this
     layer's cache dict (fp or int8 codec) — or, when ``bt`` (a per-slot
-    block table) is given, this layer's K/V page pools, written and
-    attended through the table (paged backend). Paged with s == 1 is a
-    decode step (ragged paged-attention kernel); s > 1 is a RAGGED
-    PREFILL chunk — K/V written straight into pool pages at per-slot
-    offsets ``t`` and attended causally through the block table, which
-    is what lets the server prefill several admissions as one launch
-    with no dense-cache detour. ``fused`` (a ``(last, dec, ss, sp)``
-    tuple) switches the paged s > 1 path to the FUSED TICK: ``bt`` is
-    then the live block-table slice, rows past ``last`` null-redirect
-    zeroed on write, and attention runs the fused kernel whose DMA
-    schedule ``(ss, sp)`` covers only live pages — prefill chunks and
-    s=1 decode rows (``dec``) of one serving tick in a single launch.
+    block table) is given, the WHOLE K/V page pools, written and
+    attended at ``layer`` through the table (paged backend:
+    ``_paged_kv_step``, which also explains ``fused``).
     Returns (xx, lc, h2) with h2 = the post-attention norm for the FFN."""
     b, s, nh, kvh, hd, scale = dims
     cos, sin = tables
@@ -444,21 +522,9 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
     v = _mm(h, blk["wv"]).reshape(b, s, kvh, hd)
     q = rope_mod._apply_rotary_jnp(q, cos, sin, position_ids=pos)
     k = rope_mod._apply_rotary_jnp(k, cos, sin, position_ids=pos)
-    if bt is not None and fused is not None:
-        last, dec, ss, sp = fused
-        lc = {"k": _page_write_seq(lc["k"], k, bt, t, last=last),
-              "v": _page_write_seq(lc["v"], v, bt, t, last=last)}
-        att = _fused_attend(q, lc["k"], lc["v"], bt, t, last, dec,
-                            ss, sp, scale)
-    elif bt is not None and s > 1:
-        lc = {"k": _page_write_seq(lc["k"], k, bt, t),
-              "v": _page_write_seq(lc["v"], v, bt, t)}
-        att = _paged_prefill_attend(q, lc["k"], lc["v"], bt, t, scale,
-                                    mesh=mesh)
-    elif bt is not None:
-        lc = {"k": _page_write(lc["k"], k, bt, t),
-              "v": _page_write(lc["v"], v, bt, t)}
-        att = _paged_attend(q, lc["k"], lc["v"], bt, t, scale, mesh=mesh)
+    if bt is not None:
+        att, lc = _paged_kv_step(lc, layer, q, k, v, bt, t, scale,
+                                 fused=fused, mesh=mesh)
     else:
         lc = _kv_write(lc, "k", k, t)
         lc = _kv_write(lc, "v", v, t)
@@ -594,40 +660,35 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                         cache_dtype)
 
     if mesh is not None:
-        init_caches = (_mesh_paged_caches if paged
-                       else _mesh_caches)(init_caches, mesh)
+        init_caches = (_mesh_paged_caches(init_caches, mesh, kvh) if paged
+                       else _mesh_caches(init_caches, mesh))
 
     def embed_fn(tok, t):
         return p["table"][tok][:, None, :]
 
-    def _run_layers(x, caches, t, bt, fused=None):
+    def _forward(x, caches, t, bt, fused=None):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
         pos = _positions(t, b, s)                         # [B, s]
 
-        def layer(xx, xs):
-            blk, lc = xs
+        def layer(xx, blk, lc, l):
             xx, lc, h2 = _rope_gqa_attn(
                 blk, xx, lc, t, pos, (b, s, nh, kvh, hd, scale),
-                (cos, sin), eps, bt=bt, fused=fused, mesh=mesh)
+                (cos, sin), eps, bt=bt, fused=fused, mesh=mesh, layer=l)
             xx = xx + _mm(jax.nn.silu(_mm(h2, blk["wg"]))
                           * _mm(h2, blk["wu"]), blk["wd"])
             return xx, lc
 
         blk_tree = {k_: v_ for k_, v_ in p.items()
                     if k_ not in ("table", "norm", "head")}
-        if paged:
-            x, pool = jax.lax.scan(layer, x, (blk_tree, caches["pool"]))
-            return x, dict(caches, pool=pool)
-        x, new_caches = jax.lax.scan(layer, x, (blk_tree, caches))
-        return x, new_caches
+        return _run_layers(layer, x, blk_tree, caches, paged)
 
     def step_fn(x, caches, t):
-        return _run_layers(x, caches, t, caches["bt"] if paged else None)
+        return _forward(x, caches, t, caches["bt"] if paged else None)
 
     def fused_step(x, caches, t, last, dec, bt_live, ss, sp):
-        return _run_layers(x, caches, t, bt_live,
-                           fused=(last, dec, ss, sp))
+        return _forward(x, caches, t, bt_live,
+                        fused=(last, dec, ss, sp))
 
     def head_fn(out):
         return (_rms(unwrap(out), p["norm"], eps) @ p["head"]
@@ -720,40 +781,35 @@ def _make_mixtral_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                         cache_dtype)
 
     if mesh is not None:
-        init_caches = (_mesh_paged_caches if paged
-                       else _mesh_caches)(init_caches, mesh)
+        init_caches = (_mesh_paged_caches(init_caches, mesh, kvh) if paged
+                       else _mesh_caches(init_caches, mesh))
 
     def embed_fn(tok, t):
         return p["table"][tok][:, None, :]
 
-    def _run_layers(x, caches, t, bt, fused=None):
+    def _forward(x, caches, t, bt, fused=None):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
         pos = _positions(t, b, s)
 
-        def layer(xx, xs):
-            blk, lc = xs
+        def layer(xx, blk, lc, l):
             xx, lc, h2 = _rope_gqa_attn(
                 blk, xx, lc, t, pos, (b, s, nh, kvh, hd, scale),
-                (cos, sin), eps, bt=bt, fused=fused, mesh=mesh)
+                (cos, sin), eps, bt=bt, fused=fused, mesh=mesh, layer=l)
             xx = xx + _moe_topk_ffn(h2, blk["router"], blk["wg"],
                                     blk["wu"], blk["wd"], top_k)
             return xx, lc
 
         blk_tree = {k_: v_ for k_, v_ in p.items()
                     if k_ not in ("table", "norm", "head")}
-        if paged:
-            x, pool = jax.lax.scan(layer, x, (blk_tree, caches["pool"]))
-            return x, dict(caches, pool=pool)
-        x, new_caches = jax.lax.scan(layer, x, (blk_tree, caches))
-        return x, new_caches
+        return _run_layers(layer, x, blk_tree, caches, paged)
 
     def step_fn(x, caches, t):
-        return _run_layers(x, caches, t, caches["bt"] if paged else None)
+        return _forward(x, caches, t, caches["bt"] if paged else None)
 
     def fused_step(x, caches, t, last, dec, bt_live, ss, sp):
-        return _run_layers(x, caches, t, bt_live,
-                           fused=(last, dec, ss, sp))
+        return _forward(x, caches, t, bt_live,
+                        fused=(last, dec, ss, sp))
 
     def head_fn(out):
         return (_rms(unwrap(out), p["norm"], eps) @ p["head"]
@@ -818,8 +874,8 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                         cache_dtype)
 
     if mesh is not None:
-        init_caches = (_mesh_paged_caches if paged
-                       else _mesh_caches)(init_caches, mesh)
+        init_caches = (_mesh_paged_caches(init_caches, mesh, nh) if paged
+                       else _mesh_caches(init_caches, mesh))
 
     def embed_fn(tok, t):
         pos_emb = p["wpe"][t]                # scalar t: [H]; [B] t: [B,H]
@@ -827,32 +883,18 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             pos_emb = pos_emb[None]
         return (p["table"][tok] + pos_emb)[:, None, :]
 
-    def _run_layers(x, caches, t, bt, fused=None):
+    def _forward(x, caches, t, bt, fused=None):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
 
-        def layer(xx, xs):
-            blk, lc = xs
+        def layer(xx, blk, lc, l):
             h = _ln(xx, blk["ln1.weight"], blk["ln1.bias"], eps)
             qkv = (_mm(h, blk["attn.qkv.weight"]) + blk["attn.qkv.bias"]
                    ).reshape(b, s, 3, nh, hd)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
-            if paged and fused is not None:  # fused serving tick
-                last, dec, ss, sp = fused
-                lc = {"k": _page_write_seq(lc["k"], k, bt, t, last=last),
-                      "v": _page_write_seq(lc["v"], v, bt, t, last=last)}
-                att = _fused_attend(q, lc["k"], lc["v"], bt, t, last,
-                                    dec, ss, sp, scale)
-            elif paged and s > 1:            # ragged prefill chunk
-                lc = {"k": _page_write_seq(lc["k"], k, bt, t),
-                      "v": _page_write_seq(lc["v"], v, bt, t)}
-                att = _paged_prefill_attend(q, lc["k"], lc["v"], bt, t,
-                                            scale, mesh=mesh)
-            elif paged:
-                lc = {"k": _page_write(lc["k"], k, bt, t),
-                      "v": _page_write(lc["v"], v, bt, t)}
-                att = _paged_attend(q, lc["k"], lc["v"], bt, t, scale,
-                                    mesh=mesh)
+            if paged:
+                att, lc = _paged_kv_step(lc, l, q, k, v, bt, t, scale,
+                                         fused=fused, mesh=mesh)
             else:
                 lc = _kv_write(lc, "k", k, t)
                 lc = _kv_write(lc, "v", v, t)
@@ -870,18 +912,14 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
 
         blk_tree = {k_: v_ for k_, v_ in p.items()
                     if k_ not in ("table", "wpe", "lnf_w", "lnf_b")}
-        if paged:
-            x, pool = jax.lax.scan(layer, x, (blk_tree, caches["pool"]))
-            return x, dict(caches, pool=pool)
-        x, new_caches = jax.lax.scan(layer, x, (blk_tree, caches))
-        return x, new_caches
+        return _run_layers(layer, x, blk_tree, caches, paged)
 
     def step_fn(x, caches, t):
-        return _run_layers(x, caches, t, caches["bt"] if paged else None)
+        return _forward(x, caches, t, caches["bt"] if paged else None)
 
     def fused_step(x, caches, t, last, dec, bt_live, ss, sp):
-        return _run_layers(x, caches, t, bt_live,
-                           fused=(last, dec, ss, sp))
+        return _forward(x, caches, t, bt_live,
+                        fused=(last, dec, ss, sp))
 
     def head_fn(out):
         h = _ln(unwrap(out), p["lnf_w"], p["lnf_b"], eps)

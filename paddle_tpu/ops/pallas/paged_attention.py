@@ -3,12 +3,16 @@
 Serving-side analogue of "Ragged Paged Attention" (PAPERS.md): instead of
 one dense per-slot KV buffer ``[slots, max_cache_len, heads, dim]`` —
 whose HBM footprint and decode read bandwidth scale with the CONFIGURED
-cache length — K/V live in a global page pool
-``[num_pages, page_size, kv_heads, head_dim]`` and each decode slot owns
-an ordered list of page ids (its block table). Decode attention gathers
-pages through the block table, masks by the slot's ACTUAL length, and
-early-exits pages wholly beyond it, so both memory and bandwidth scale
-with real tokens.
+cache length — K/V live in a global page pool and each decode slot owns
+an ordered list of page ids (its block table). The pool holds every
+layer, LANE-DENSE: ``[layers, num_pages, page_size, kv_heads *
+head_dim]`` (``models/generation.paged_pool_shape``), and the kernel
+reads it through a LAYER INDEX that rides the scalar prefetch beside the
+block table — so the layer loop hands over the whole pool as it stands
+and nothing is sliced out or relaid out around the call. Decode
+attention gathers pages through the block table, masks by the slot's
+ACTUAL length, and early-exits pages wholly beyond it, so both memory
+and bandwidth scale with real tokens.
 
 Kernel shape: one query token per slot (decode step). Grid is
 ``(slots, pages_per_slot)`` with the page axis innermost ("arbitrary"),
@@ -47,13 +51,15 @@ def available() -> bool:
 # ----------------------------------------------------------------- kernel
 
 
-def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                       m_scr, l_scr, acc_scr, *, page_size, pages_per_slot,
-                       kv_heads, rep, sm_scale):
+def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                       o_ref, m_scr, l_scr, acc_scr, *, page_size,
+                       pages_per_slot, kv_heads, rep, sm_scale):
     """Grid (slots, pages_per_slot); one query row per slot.
 
     q_ref  [1, nh, hd]       this slot's query token
-    k_ref  [1, page_size, kvh, hd]   the page block_tables[s, p] points at
+    k_ref  [1, 1, page_size, kvh*hd]  the page block_tables[s, p] points
+                             at, in layer layer_ref[0]; kv head g is
+                             lanes [g*hd, (g+1)*hd)
     len_ref[s]               valid KV tokens for slot s (ragged lengths)
     Scratch m/l/acc carry the online softmax across the page axis.
     """
@@ -75,9 +81,9 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(p * page_size < length)
     def _compute():
         q = q_ref[0].astype(jnp.float32)            # [nh, hd]
-        k = k_ref[0].astype(jnp.float32)            # [pg, kvh, hd]
-        v = v_ref[0].astype(jnp.float32)
-        nh = q.shape[0]
+        k = k_ref[0, 0].astype(jnp.float32)         # [pg, kvh*hd]
+        v = v_ref[0, 0].astype(jnp.float32)
+        nh, hd = q.shape
         m_prev = m_scr[:]                           # [nh, 128]
         l_prev = l_scr[:]
 
@@ -90,7 +96,7 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         logits = []
         for g in range(kv_heads):
             qg = q[g * rep:(g + 1) * rep]           # [rep, hd]
-            kg = k[:, g]                            # [pg, hd]
+            kg = k[:, g * hd:(g + 1) * hd]          # [pg, hd]
             logits.append(jax.lax.dot_general(
                 qg, kg, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32))
@@ -108,7 +114,7 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         pv = []
         for g in range(kv_heads):
             pv.append(jax.lax.dot_general(
-                pexp[g * rep:(g + 1) * rep], v[:, g],
+                pexp[g * rep:(g + 1) * rep], v[:, g * hd:(g + 1) * hd],
                 (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32))         # [rep, hd]
         acc_scr[:] = acc_scr[:] * corr + jnp.concatenate(pv, axis=0)
@@ -121,16 +127,33 @@ def _paged_attn_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[0] = (acc_scr[:] / l).astype(o_ref.dtype)
 
 
+def as_layered(k_pages, v_pages, layer):
+    """The pools as the kernels take them — every layer, lane-dense,
+    ``[L, P, pg, kvh*hd]`` — and the layer index as an int32 [1]. With
+    ``layer`` None the operands are ONE layer's pools per head,
+    ``[P, pg, kvh, hd]``: the one-layer case of the same code (a
+    reshape of the minor axes, layer 0)."""
+    if layer is None:
+        P, pg, kvh, hd = k_pages.shape
+        k_pages = k_pages.reshape(1, P, pg, kvh * hd)
+        v_pages = v_pages.reshape(1, P, pg, kvh * hd)
+        layer = 0
+    return k_pages, v_pages, jnp.asarray(layer, jnp.int32).reshape(1)
+
+
 def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
-                            sm_scale, interpret=False):
-    """q [S, nh, hd]; pages [P, pg, kvh, hd]; block_tables [S, maxp] int32
-    (unused tail entries must hold any VALID page id, e.g. 0); lengths
-    [S] int32. Returns [S, nh, hd]."""
+                            sm_scale, interpret=False, layer=None):
+    """q [S, nh, hd]; pages [L, P, pg, kvh*hd] read at ``layer``, or one
+    layer's [P, pg, kvh, hd] (``as_layered``); block_tables [S, maxp]
+    int32 (unused tail entries must hold any VALID page id, e.g. 0);
+    lengths [S] int32. Returns [S, nh, hd]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
     S, nh, hd = q.shape
-    P, pg, kvh, _ = k_pages.shape
+    _, P, pg, width = k_pages.shape
+    kvh = width // hd
     maxp = block_tables.shape[1]
     rep = nh // kvh
     if nh % kvh:
@@ -142,17 +165,19 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
         _paged_attn_kernel, page_size=pg, pages_per_slot=maxp,
         kv_heads=kvh, rep=rep, sm_scale=sm_scale)
 
+    def page(s, p, bt, ln, l):
+        return (l[0], bt[s * maxp + p], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
+        num_scalar_prefetch=3,
         grid=(S, maxp),
         in_specs=[
-            pl.BlockSpec((1, nh, hd), lambda s, p, bt, ln: (s, 0, 0)),
-            pl.BlockSpec((1, pg, kvh, hd),
-                         lambda s, p, bt, ln: (bt[s * maxp + p], 0, 0, 0)),
-            pl.BlockSpec((1, pg, kvh, hd),
-                         lambda s, p, bt, ln: (bt[s * maxp + p], 0, 0, 0)),
+            pl.BlockSpec((1, nh, hd), lambda s, p, bt, ln, l: (s, 0, 0)),
+            pl.BlockSpec((1, 1, pg, width), page),
+            pl.BlockSpec((1, 1, pg, width), page),
         ],
-        out_specs=pl.BlockSpec((1, nh, hd), lambda s, p, bt, ln: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, nh, hd),
+                               lambda s, p, bt, ln, l: (s, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((nh, 128), jnp.float32),
             pltpu.VMEM((nh, 128), jnp.float32),
@@ -167,7 +192,7 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(flat_bt, lengths.astype(jnp.int32), q, k_pages, v_pages)
+    )(flat_bt, lengths.astype(jnp.int32), layer, q, k_pages, v_pages)
 
 
 # ------------------------------------------------- mesh-sharded kernel path
@@ -190,48 +215,55 @@ def kv_head_shards(mesh, num_kv_heads, num_heads=None, axis="mp"):
 
 
 def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths,
-                             sm_scale, mesh, axis, interpret):
+                             layer, sm_scale, mesh, axis, interpret):
     """Per-shard Pallas launches over the mesh's ``axis``: the page
-    pools arrive sharded on their kv-head dim, q splits into the
-    matching query-head groups (a GQA group never straddles a shard —
-    consecutive head blocks keep each kv head with its own rep query
-    heads), the block table and lengths ride replicated, and the
-    out_spec's head-axis concatenation IS the attention all-gather
-    GSPMD would insert on the fallback path. XLA cannot partition a
-    custom call, so the kernel path must shard_map itself; returns None
-    when the head counts don't divide the axis — the caller then runs
-    one replicated launch."""
+    pools arrive sharded on their merged kv-head axis (contiguous
+    blocks of whole heads), q splits into the matching query-head
+    groups (a GQA group never straddles a shard — consecutive head
+    blocks keep each kv head with its own rep query heads), the block
+    table, lengths and layer index ride replicated, and the out_spec's
+    head-axis concatenation IS the attention all-gather GSPMD would
+    insert on the fallback path. XLA cannot partition a custom call,
+    so the kernel path must shard_map itself; returns None when the
+    head counts don't divide the axis — the caller then runs one
+    replicated launch."""
     from jax.sharding import PartitionSpec as P
 
-    if kv_head_shards(mesh, k_pages.shape[2], q.shape[1], axis) <= 1:
+    kvh = k_pages.shape[-1] // q.shape[-1]
+    if kv_head_shards(mesh, kvh, q.shape[1], axis) <= 1:
         return None
-    fn = functools.partial(_paged_attention_pallas, sm_scale=sm_scale,
-                           interpret=interpret)
+    def fn(q, k_pages, v_pages, block_tables, lengths, layer):
+        return _paged_attention_pallas(q, k_pages, v_pages, block_tables,
+                                       lengths, sm_scale, interpret, layer)
+
+    pool = P(None, None, None, axis)
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(P(None, axis, None), P(None, None, axis, None),
-                  P(None, None, axis, None), P(None, None), P(None)),
+        in_specs=(P(None, axis, None), pool, pool, P(None, None), P(None),
+                  P(None)),
         out_specs=P(None, axis, None), check_vma=False,
-    )(q, k_pages, v_pages, block_tables, lengths)
+    )(q, k_pages, v_pages, block_tables, lengths, layer)
 
 
 # ------------------------------------------------------ XLA reference path
 
 
 def _ref_paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                         sm_scale):
+                         sm_scale, layer=None):
     """Gather-through-block-table reference. Mirrors the dense decode
     attention (`generation._cached_attend` at s=1) op-for-op so the paged
     server emits bit-identical tokens to the dense backend on every
     platform: valid positions carry the exact cached values, positions at
     or beyond ``lengths`` are masked to -1e30 before the same f32 softmax
     (contributing exactly 0.0), and the einsum specs match."""
+    k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
     S, nh, hd = q.shape
-    P, pg, kvh, _ = k_pages.shape
+    _, P, pg, width = k_pages.shape
+    kvh = width // hd
     maxp = block_tables.shape[1]
     T = maxp * pg
-    k = k_pages[block_tables].reshape(S, T, kvh, hd)
-    v = v_pages[block_tables].reshape(S, T, kvh, hd)
+    k = k_pages[layer[0], block_tables].reshape(S, T, kvh, hd)
+    v = v_pages[layer[0], block_tables].reshape(S, T, kvh, hd)
     rep = nh // kvh
     if rep > 1:
         k = jnp.repeat(k, rep, axis=2)
@@ -249,18 +281,24 @@ def _ref_paged_attention(q, k_pages, v_pages, block_tables, lengths,
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                    sm_scale=None, interpret=False, mesh=None):
+                    sm_scale=None, interpret=False, mesh=None, layer=None):
     """Ragged paged-attention decode step.
 
     q            [slots, num_heads, head_dim]   one query token per slot
-    k_pages      [num_pages, page_size, kv_heads, head_dim]  global pool
+    k_pages      the global pool: with ``layer`` given, every layer
+                 lane-dense ``[layers, num_pages, page_size, kv_heads *
+                 head_dim]``; without, one layer per head
+                 ``[num_pages, page_size, kv_heads, head_dim]``
     v_pages      same shape as ``k_pages``
+    layer        int32 scalar (may be traced): the layer of the pool
+                 this call reads — the serving layer loop passes its
+                 loop index and the WHOLE carried pool
     block_tables [slots, pages_per_slot] int32  page ids, in position
                  order; entries past a slot's allocation must hold a
                  valid id (the manager fills them with 0)
     lengths      [slots] int32  valid KV tokens per slot (ragged)
     mesh         optional ``jax.sharding.Mesh`` whose ``mp`` axis the
-                 page pools are sharded over on their kv-head dim
+                 page pools are sharded over on their kv-head axis
                  (sharded paged serving): the Pallas path then runs one
                  launch PER SHARD via shard_map — each shard reads only
                  its resident pool slice, block tables replicated —
@@ -275,15 +313,16 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
     """
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
+    k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
     if available() or interpret:
         if mesh is not None:
             out = _paged_attention_sharded(
-                q, k_pages, v_pages, block_tables, lengths, sm_scale,
-                mesh, "mp", interpret)
+                q, k_pages, v_pages, block_tables, lengths, layer,
+                sm_scale, mesh, "mp", interpret)
             if out is not None:
                 return out
         return _paged_attention_pallas(q, k_pages, v_pages, block_tables,
                                        lengths, sm_scale,
-                                       interpret=interpret)
+                                       interpret=interpret, layer=layer)
     return _ref_paged_attention(q, k_pages, v_pages, block_tables,
-                                lengths, sm_scale)
+                                lengths, sm_scale, layer=layer)

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from . import on_tpu
-from .paged_attention import NEG_INF
+from .paged_attention import NEG_INF, as_layered, kv_head_shards
 
 __all__ = ["ragged_prefill_attention", "available"]
 
@@ -54,13 +54,16 @@ def available() -> bool:
 # ----------------------------------------------------------------- kernel
 
 
-def _ragged_prefill_kernel(bt_ref, t0_ref, last_ref, q_ref, k_ref, v_ref,
-                           o_ref, m_scr, l_scr, acc_scr, *, page_size,
-                           pages_per_slot, chunk, kv_heads, rep, sm_scale):
+def _ragged_prefill_kernel(bt_ref, t0_ref, last_ref, layer_ref, q_ref,
+                           k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
+                           page_size, pages_per_slot, chunk, kv_heads, rep,
+                           sm_scale):
     """Grid (slots, pages_per_slot); ``chunk`` query rows per slot.
 
     q_ref  [1, chunk, nh, hd]       this slot's packed prompt chunk
-    k_ref  [1, page_size, kvh, hd]  the page block_tables[s, p] points at
+    k_ref  [1, 1, page_size, kvh*hd]  the page block_tables[s, p] points
+                at, in layer layer_ref[0]; kv head g is lanes
+                [g*hd, (g+1)*hd)
     t0_ref[s]   absolute position of the chunk's first row (prefix offset)
     last_ref[s] last position the chunk writes (t0 + take - 1); -1 for a
                 slot with no prefill work this launch (all compute skipped)
@@ -87,8 +90,9 @@ def _ragged_prefill_kernel(bt_ref, t0_ref, last_ref, q_ref, k_ref, v_ref,
     @pl.when(p * page_size <= last)
     def _compute():
         q = q_ref[0].astype(jnp.float32)            # [chunk, nh, hd]
-        k = k_ref[0].astype(jnp.float32)            # [pg, kvh, hd]
-        v = v_ref[0].astype(jnp.float32)
+        k = k_ref[0, 0].astype(jnp.float32)         # [pg, kvh*hd]
+        v = v_ref[0, 0].astype(jnp.float32)
+        hd = q.shape[-1]
         m_prev = m_scr[:]                           # [chunk*nh, 128]
         l_prev = l_scr[:]
 
@@ -96,7 +100,7 @@ def _ragged_prefill_kernel(bt_ref, t0_ref, last_ref, q_ref, k_ref, v_ref,
         logits = []
         for g in range(kv_heads):
             qg = q[:, g * rep:(g + 1) * rep].reshape(chunk * rep, -1)
-            kg = k[:, g]                            # [pg, hd]
+            kg = k[:, g * hd:(g + 1) * hd]          # [pg, hd]
             logits.append(jax.lax.dot_general(
                 qg, kg, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)
@@ -126,7 +130,7 @@ def _ragged_prefill_kernel(bt_ref, t0_ref, last_ref, q_ref, k_ref, v_ref,
         for g in range(kv_heads):
             pv.append(jax.lax.dot_general(
                 pe[:, g * rep:(g + 1) * rep].reshape(chunk * rep, -1),
-                v[:, g], (((1,), (0,)), ((), ())),
+                v[:, g * hd:(g + 1) * hd], (((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)
                 .reshape(chunk, rep, -1))
         pv = jnp.concatenate(pv, axis=1).reshape(chunk * nh, -1)
@@ -142,16 +146,19 @@ def _ragged_prefill_kernel(bt_ref, t0_ref, last_ref, q_ref, k_ref, v_ref,
 
 
 def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, last,
-                           sm_scale, interpret=False):
-    """q [S, C, nh, hd]; pages [P, pg, kvh, hd]; block_tables [S, maxp]
-    int32 (unused tail entries must hold any VALID page id, e.g. 0);
-    t0/last [S] int32 (last = t0 + take - 1, or -1 to skip the slot).
-    Returns [S, C, nh, hd]."""
+                           sm_scale, interpret=False, layer=None):
+    """q [S, C, nh, hd]; pages [L, P, pg, kvh*hd] read at ``layer``, or
+    one layer's [P, pg, kvh, hd] (``as_layered``); block_tables
+    [S, maxp] int32 (unused tail entries must hold any VALID page id,
+    e.g. 0); t0/last [S] int32 (last = t0 + take - 1, or -1 to skip the
+    slot). Returns [S, C, nh, hd]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
+    k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
     S, C, nh, hd = q.shape
-    P, pg, kvh, _ = k_pages.shape
+    _, P, pg, width = k_pages.shape
+    kvh = width // hd
     maxp = block_tables.shape[1]
     rep = nh // kvh
     if nh % kvh:
@@ -163,21 +170,21 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, last,
         _ragged_prefill_kernel, page_size=pg, pages_per_slot=maxp,
         chunk=C, kv_heads=kvh, rep=rep, sm_scale=sm_scale)
 
+    def rows(s, p, bt, t0_, ls, l):
+        return (s, 0, 0, 0)
+
+    def page(s, p, bt, t0_, ls, l):
+        return (l[0], bt[s * maxp + p], 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(S, maxp),
         in_specs=[
-            pl.BlockSpec((1, C, nh, hd),
-                         lambda s, p, bt, t0_, ls: (s, 0, 0, 0)),
-            pl.BlockSpec((1, pg, kvh, hd),
-                         lambda s, p, bt, t0_, ls:
-                         (bt[s * maxp + p], 0, 0, 0)),
-            pl.BlockSpec((1, pg, kvh, hd),
-                         lambda s, p, bt, t0_, ls:
-                         (bt[s * maxp + p], 0, 0, 0)),
+            pl.BlockSpec((1, C, nh, hd), rows),
+            pl.BlockSpec((1, 1, pg, width), page),
+            pl.BlockSpec((1, 1, pg, width), page),
         ],
-        out_specs=pl.BlockSpec((1, C, nh, hd),
-                               lambda s, p, bt, t0_, ls: (s, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, C, nh, hd), rows),
         scratch_shapes=[
             pltpu.VMEM((C * nh, 128), jnp.float32),
             pltpu.VMEM((C * nh, 128), jnp.float32),
@@ -192,7 +199,7 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, last,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(flat_bt, t0.astype(jnp.int32), last.astype(jnp.int32),
+    )(flat_bt, t0.astype(jnp.int32), last.astype(jnp.int32), layer,
       q, k_pages, v_pages)
 
 
@@ -200,34 +207,38 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, last,
 
 
 def _ragged_prefill_sharded(q, k_pages, v_pages, block_tables, t0, last,
-                            sm_scale, mesh, axis, interpret):
+                            layer, sm_scale, mesh, axis, interpret):
     """Per-shard Pallas launches over the mesh's ``axis`` (sharded
-    paged serving): pools sharded on kv heads, q split into the
-    matching query-head groups (head axis 2 of [S, C, nh, hd]), block
-    table / t0 / last replicated, output restitched on the head axis —
-    the same split ``paged_attention._paged_attention_sharded`` makes
-    for decode. Returns None when the head counts don't divide the
-    axis; the caller then runs one replicated launch."""
+    paged serving): pools sharded on their merged kv-head axis, q split
+    into the matching query-head groups (head axis 2 of
+    [S, C, nh, hd]), block table / t0 / last / layer replicated, output
+    restitched on the head axis — the same split
+    ``paged_attention._paged_attention_sharded`` makes for decode.
+    Returns None when the head counts don't divide the axis; the caller
+    then runs one replicated launch."""
     from jax.sharding import PartitionSpec as P
 
-    from .paged_attention import kv_head_shards
-    if kv_head_shards(mesh, k_pages.shape[2], q.shape[2], axis) <= 1:
+    kvh = k_pages.shape[-1] // q.shape[-1]
+    if kv_head_shards(mesh, kvh, q.shape[2], axis) <= 1:
         return None
-    fn = functools.partial(_ragged_prefill_pallas, sm_scale=sm_scale,
-                           interpret=interpret)
+    def fn(q, k_pages, v_pages, block_tables, t0, last, layer):
+        return _ragged_prefill_pallas(q, k_pages, v_pages, block_tables,
+                                      t0, last, sm_scale, interpret, layer)
+
+    pool = P(None, None, None, axis)
     return jax.shard_map(
         fn, mesh=mesh,
-        in_specs=(P(None, None, axis, None), P(None, None, axis, None),
-                  P(None, None, axis, None), P(None, None), P(None),
-                  P(None)),
+        in_specs=(P(None, None, axis, None), pool, pool, P(None, None),
+                  P(None), P(None), P(None)),
         out_specs=P(None, None, axis, None), check_vma=False,
-    )(q, k_pages, v_pages, block_tables, t0, last)
+    )(q, k_pages, v_pages, block_tables, t0, last, layer)
 
 
 # ------------------------------------------------------ XLA reference path
 
 
-def _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0, sm_scale):
+def _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0, sm_scale,
+                        layer=None):
     """Gather-through-block-table reference. Mirrors the dense prefill
     attention (``generation._cached_attend``) op-for-op so the ragged
     prefill path emits BIT-IDENTICAL cache rows and logits to the dense
@@ -235,12 +246,14 @@ def _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0, sm_scale):
     cached values, positions beyond a row's causal frontier are masked
     to -1e30 before the same f32 softmax (contributing exactly 0.0),
     and the einsum specs match."""
+    k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
     S, C, nh, hd = q.shape
-    P, pg, kvh, _ = k_pages.shape
+    _, P, pg, width = k_pages.shape
+    kvh = width // hd
     maxp = block_tables.shape[1]
     T = maxp * pg
-    k = k_pages[block_tables].reshape(S, T, kvh, hd)
-    v = v_pages[block_tables].reshape(S, T, kvh, hd)
+    k = k_pages[layer[0], block_tables].reshape(S, T, kvh, hd)
+    v = v_pages[layer[0], block_tables].reshape(S, T, kvh, hd)
     rep = nh // kvh
     if rep > 1:
         k = jnp.repeat(k, rep, axis=2)
@@ -259,7 +272,7 @@ def _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0, sm_scale):
 
 def ragged_prefill_attention(q, k_pages, v_pages, block_tables, t0,
                              last=None, sm_scale=None, interpret=False,
-                             mesh=None):
+                             mesh=None, layer=None):
     """Ragged packed-prefill attention over paged KV.
 
     q            [slots, chunk, num_heads, head_dim]  packed prompt
@@ -267,8 +280,13 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_tables, t0,
                  segments are padded on the right; their garbage rows
                  are causally self-contained and discarded by the
                  caller)
-    k_pages      [num_pages, page_size, kv_heads, head_dim]  global pool
+    k_pages      the global pool: with ``layer`` given, every layer
+                 lane-dense ``[layers, num_pages, page_size, kv_heads *
+                 head_dim]``; without, one layer per head
+                 ``[num_pages, page_size, kv_heads, head_dim]``
     v_pages      same shape as ``k_pages``
+    layer        int32 scalar (may be traced): the layer of the pool
+                 this call reads (see ``paged_attention``)
     block_tables [slots, pages_per_slot] int32  page ids in position
                  order; entries past a slot's allocation must hold a
                  valid id (the manager fills them with 0)
@@ -291,17 +309,18 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_tables, t0,
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     if last is None:
         last = t0 + q.shape[1] - 1
+    k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
 
     def _launch(qt, t0t, lastt):
         if mesh is not None:
             out = _ragged_prefill_sharded(qt, k_pages, v_pages,
-                                          block_tables, t0t, lastt,
+                                          block_tables, t0t, lastt, layer,
                                           sm_scale, mesh, "mp", interpret)
             if out is not None:
                 return out
         return _ragged_prefill_pallas(qt, k_pages, v_pages, block_tables,
                                       t0t, lastt, sm_scale,
-                                      interpret=interpret)
+                                      interpret=interpret, layer=layer)
 
     if available() or interpret:
         # the kernel's VMEM scratch is (rows * nh)-tall: tile the query
@@ -311,18 +330,29 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_tables, t0,
         # at serve time). Row r of tile starting at r0 sits at absolute
         # position t0 + r0 + r, so each tile is just a ragged launch
         # with a shifted prefix offset; the idle sentinel (last = -1)
-        # survives the min().
-        C = q.shape[1]
-        if C <= _QUERY_TILE:
+        # survives the min(). The tiles are a LOOP over one launch, not
+        # C/8 launches spelled out: the kernel is traced and lowered
+        # once a program whatever its width (unrolled, a C=512 program
+        # traced 64 kernels: 24 s of a 100 s server start on the chip's
+        # host, PERF.md section 6, PR 26).
+        C, tile = q.shape[1], _QUERY_TILE
+        if C <= tile:
             return _launch(q, t0, last)
-        outs = []
-        for r0 in range(0, C, _QUERY_TILE):
-            qt = q[:, r0:r0 + _QUERY_TILE]
-            lastt = jnp.minimum(last, t0 + r0 + qt.shape[1] - 1)
-            outs.append(_launch(qt, t0 + r0, lastt))
-        return jnp.concatenate(outs, axis=1)
+        pad = -C % tile                 # never on the server's pow2 ladder
+        qp = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else q
+
+        def one_tile(i, out):
+            r0 = i * tile
+            qt = jax.lax.dynamic_slice_in_dim(qp, r0, tile, axis=1)
+            lastt = jnp.minimum(last, t0 + r0 + tile - 1)
+            return jax.lax.dynamic_update_slice_in_dim(
+                out, _launch(qt, t0 + r0, lastt), r0, axis=1)
+
+        out = jax.lax.fori_loop(0, (C + pad) // tile, one_tile,
+                                jnp.zeros_like(qp))
+        return out[:, :C] if pad else out
     out = _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0,
-                              sm_scale)
+                              sm_scale, layer=layer)
     # platform-consistent skip semantics: the kernel's idle slots
     # (last < 0) finalize to zeros through the empty-accumulator guard;
     # zero the same rows here so fallback output matches bit-for-bit

@@ -19,10 +19,14 @@ with the same write-token-values semantics, so the ragged scheduler's
 chunk packing, null-redirects and prefix-offset resumes are exercised
 against the oracle too.
 """
+import types
+
 import numpy as np
 
 import jax
 import jax.numpy as jnp
+
+from paddle_tpu.models.generation import paged_pool_shape, pool_lanes
 
 V = 16
 
@@ -42,6 +46,7 @@ def stub_tokens(prompt, n):
 class StubModel:
     L, H, HD = 1, 1, 2           # layers / kv heads / head dim
     V = V
+    cfg = types.SimpleNamespace(num_heads=H)   # what the server reads
 
     def _decode_bundle(self, max_cache_len, weight_dtype=None, mesh=None,
                        cache_dtype=None, cache_backend="dense",
@@ -54,7 +59,7 @@ class StubModel:
             maxp = C // pg
 
             def init_caches(batch):
-                shape = (L, int(num_pages), pg, h, hd)
+                shape = paged_pool_shape(L, num_pages, pg, h, hd)
                 return {"pool": {"k": jnp.zeros(shape, jnp.float32),
                                  "v": jnp.zeros(shape, jnp.float32)},
                         "bt": jnp.zeros((batch, maxp), jnp.int32)}
@@ -96,8 +101,8 @@ class StubModel:
                         bt, jnp.minimum(pidx, maxp - 1), axis=1))
                 vals = jnp.where(oob, 0.0, tokens.astype(jnp.float32))
                 n = S * Cc
-                flat = jnp.broadcast_to(
-                    vals.reshape(n)[:, None, None], (n, h, hd))
+                flat = pool_lanes(jnp.broadcast_to(
+                    vals.reshape(n)[:, None, None], (n, h, hd)))
                 fp, fo = page.reshape(n), (pos % pg).reshape(n)
                 pool = {"k": pool["k"].at[:, fp, fo].set(flat[None]),
                         "v": pool["v"].at[:, fp, fo].set(flat[None])}
@@ -132,8 +137,8 @@ class StubModel:
                         bt_live, jnp.minimum(pidx, W - 1), axis=1))
                 vals = jnp.where(oob, 0.0, tokens.astype(jnp.float32))
                 n = S * Cc
-                flat = jnp.broadcast_to(
-                    vals.reshape(n)[:, None, None], (n, h, hd))
+                flat = pool_lanes(jnp.broadcast_to(
+                    vals.reshape(n)[:, None, None], (n, h, hd)))
                 fp, fo = page.reshape(n), (pos % pg).reshape(n)
                 pool = {"k": pool["k"].at[:, fp, fo].set(flat[None]),
                         "v": pool["v"].at[:, fp, fo].set(flat[None])}

@@ -30,6 +30,7 @@ from _serving_stub import StubModel, stub_tokens
 from paddle_tpu.inference.continuous_batching import ContinuousBatchingServer
 from paddle_tpu.inference.kv_cache import PagedKVCache
 from paddle_tpu.inference.kv_tier import HostTier
+from paddle_tpu.models.generation import pool_heads
 from paddle_tpu.inference.prefix_cache import PrefixCache
 from paddle_tpu.reliability import (CallbackError, CircuitBreaker,
                                     FaultInjector, InjectedFault,
@@ -267,7 +268,8 @@ class TestHostTierServer:
         # proof the payload round-tripped bit-exact, not just the ids
         m = srv._prefix.lookup(ext, 8)
         assert m is not None and m.hot_len() == len(m.nodes) == 2
-        pool_k = np.asarray(srv._caches["pool"]["k"])
+        pool_k = pool_heads(np.asarray(srv._caches["pool"]["k"]),
+                            StubModel.H)
         for i, nd in enumerate(m.nodes):
             np.testing.assert_array_equal(
                 pool_k[0, nd.page, :, 0, 0],

@@ -30,6 +30,7 @@ import jax.numpy as jnp
 import paddle_tpu as pt
 from _serving_stub import StubModel, stub_tokens
 from paddle_tpu.inference.continuous_batching import ContinuousBatchingServer
+from paddle_tpu.models.generation import pool_heads
 from paddle_tpu.ops.pallas import ragged_prefill as rp
 
 
@@ -200,7 +201,8 @@ class TestRaggedPrefillBundle:
                                       np.asarray(lg_a))
         np.testing.assert_array_equal(np.asarray(logits[1:2]),
                                       np.asarray(lg_b))
-        pool_k = np.asarray(caches["pool"]["k"])
+        pool_k = pool_heads(np.asarray(caches["pool"]["k"]),
+                            m.cfg.num_kv_heads)
         ka = pool_k[:, [1, 2]].reshape(pool_k.shape[0], 16,
                                        *pool_k.shape[3:])[:, :12]
         np.testing.assert_array_equal(ka, np.asarray(cd_a["k"])[:, 0, :12])
@@ -222,7 +224,8 @@ class TestRaggedPrefillBundle:
             caches2, jnp.asarray(np.array([3, 0], np.int32)))
         np.testing.assert_array_equal(np.asarray(lg2[0:1]),
                                       np.asarray(lg_a))
-        pool_k2 = np.asarray(caches2["pool"]["k"])
+        pool_k2 = pool_heads(np.asarray(caches2["pool"]["k"]),
+                             m.cfg.num_kv_heads)
         ka2 = pool_k2[:, [4, 5]].reshape(pool_k2.shape[0], 16,
                                          *pool_k2.shape[3:])[:, :12]
         np.testing.assert_array_equal(ka2,

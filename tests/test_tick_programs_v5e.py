@@ -1,0 +1,242 @@
+"""The serving tick programs, compiled for a DESCRIBED TPU v5e (no chip).
+
+The sandbox's libtpu compiles for a chip that is described and not
+attached, so these tests see what the chip's compiler does to the paged
+tick programs at the benchmark cell's real geometry (GPT-2 medium, 32
+slots, page 16, 2,049 pages: a 3.0 GiB pool) without running anything:
+layouts, aliasing and bytes of temporaries — never a time.
+
+What they hold (ISSUE 26): inside a tick the page pool is never copied,
+sliced out or relaid out. The pool is stored lane-dense
+(``[layers, pages, page_size, kv_heads * head_dim]``), carried through
+the layer loop and read by the kernels through a layer index, so the
+donated argument's buffer IS the result's:
+
+- ``temp_size_in_bytes`` under a quarter of the pool's bytes (the parent
+  held a second copy of the pool: 3.8 GiB),
+- ``alias_size_in_bytes`` at least the pool's,
+- the pool row-major wherever the optimised HLO names its shape,
+- no ``copy``, ``dynamic-slice`` or ``dynamic-update-slice`` whose
+  result has the pool's whole or per-layer shape.
+
+Everything is built from shapes: the stacked weight tree is a tree of
+``ShapeDtypeStruct`` handed to the bundle builder inside the traced
+function (no 0.7 GB of weights, no 3 GiB of zeros on the CPU). The
+topology is described in a module-scoped fixture that skips — never at
+import (one process holds libtpu at a time; see the
+``on-chip-measurement`` guide, section 2) — and the persistent compile
+cache is off around the compiles (an entry written for a described chip
+cannot be read back without one).
+"""
+import re
+import types
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.models import generation
+from paddle_tpu.models.gpt import GPTConfig, GPTForCausalLM, gpt2_tiny
+
+# the cell's server (perfbench/traffic/chat-steady.json)
+SLOTS, PAGE, CACHE_LEN = 32, 16, 1024
+MEDIUM = dict(cfg=GPTConfig(vocab_size=50304, hidden_size=1024,
+                            num_layers=24, num_heads=16, max_seq_len=1024),
+              slots=SLOTS, num_pages=SLOTS * CACHE_LEN // PAGE + 1)
+# GPT-2 XL as PR 24 ran it on one chip: 25 heads x 64 = 1,600 lanes,
+# which is no multiple of 128
+XL = dict(cfg=GPTConfig(vocab_size=50304, hidden_size=1600, num_layers=48,
+                        num_heads=25, max_seq_len=1024),
+          slots=8, num_pages=8 * CACHE_LEN // PAGE + 1)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def as_on_chip():
+    """The kernels ask ``on_tpu()`` whether to run Mosaic or their XLA
+    reference: answer yes for this module's compiles (and forget the
+    answer afterwards), with the persistent compile cache off and the
+    chip's own matmul precision (conftest sets "highest" for CPU
+    parity; nothing sets it on the chip)."""
+    from jax.experimental.compilation_cache import compilation_cache
+    from paddle_tpu.ops.pallas import on_tpu
+    cache_was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    on_tpu.cache_clear()
+    with mock.patch.object(jax, "default_backend", return_value="tpu"):
+        assert on_tpu()
+    try:
+        with jax.default_matmul_precision("default"):
+            yield
+    finally:
+        on_tpu.cache_clear()
+        jax.config.update("jax_enable_compilation_cache", cache_was)
+        compilation_cache.reset_cache()
+
+
+def _weight_shapes(cfg, dtype=jnp.bfloat16):
+    """Shapes of ``_make_gpt_decode_fns``'s stacked weight tree."""
+    L, H, F, V = (cfg.num_layers, cfg.hidden_size, cfg.intermediate_size,
+                  cfg.vocab_size)
+    shapes = {
+        "table": (V, H), "wpe": (cfg.max_seq_len, H),
+        "lnf_w": (H,), "lnf_b": (H,),
+        "ln1.weight": (L, H), "ln1.bias": (L, H),
+        "ln2.weight": (L, H), "ln2.bias": (L, H),
+        "attn.qkv.weight": (L, H, 3 * H), "attn.qkv.bias": (L, 3 * H),
+        "attn.proj.weight": (L, H, H), "attn.proj.bias": (L, H),
+        "mlp.fc1.weight": (L, H, F), "mlp.fc1.bias": (L, F),
+        "mlp.fc2.weight": (L, F, H), "mlp.fc2.bias": (L, H),
+    }
+    return {k: jax.ShapeDtypeStruct(s, dtype) for k, s in shapes.items()}
+
+
+def _paged_bundle(cfg, weights, num_pages):
+    """The paged GPT decode bundle over ``weights`` (arrays or tracers):
+    the bundle builder finds its stacked tree already made, so nothing
+    is materialised."""
+    model = types.SimpleNamespace(
+        cfg=cfg, _pt_stacked_weights={(None, None): weights})
+    return generation._make_gpt_decode_fns(
+        model, CACHE_LEN, cache_backend="paged", page_size=PAGE,
+        num_pages=num_pages)
+
+
+def _decode_tick(cfg, num_pages):
+    """The server's own ``decode_tick`` (greedy, one step a tick) over
+    a bundle built from the traced weights."""
+    from paddle_tpu.inference.continuous_batching import (
+        ContinuousBatchingServer)
+
+    def decode_tick(weights, tok, caches, t, keys):
+        b = _paged_bundle(cfg, weights, num_pages)
+        srv = types.SimpleNamespace(
+            _embed_fn=b[1], _step_fn=b[2], _head_fn=b[3], do_sample=False,
+            _temperature=1.0, _top_k=0, _top_p=1.0, tick_block=1)
+        tick = ContinuousBatchingServer._build_decode_step(srv)
+        return tick._fn(tok, caches, t, keys)
+
+    return decode_tick
+
+
+def _prefill_tick(cfg, num_pages):
+    def prefill_tick(weights, tokens, t0, caches, out_idx):
+        return _paged_bundle(cfg, weights, num_pages)[4](
+            tokens, t0, caches, out_idx)
+
+    return prefill_tick
+
+
+def _compile(fn, donate, one_chip, *specs):
+    specs = jax.tree_util.tree_map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one_chip),
+        specs)
+    return (jax.jit(fn, donate_argnums=donate).trace(*specs)
+            .lower(lowering_platforms=("tpu",)).compile())
+
+
+def _cache_shapes(cfg, slots, num_pages):
+    init = _paged_bundle(cfg, _weight_shapes(cfg), num_pages)[0]
+    return jax.eval_shape(lambda: init(slots))
+
+
+_INSTR = re.compile(r"=\s*(\w+)\[([\d,]*)\](?:\{([\d,]*)[^}]*\})?\s+"
+                    r"([\w\-]+)\(")
+
+
+def _assert_pool_stays(exe, caches):
+    """Every way the compiled program moves the pool, in one message."""
+    pool = caches["pool"]["k"]
+    pool_bytes = 2 * int(np.prod(pool.shape)) * pool.dtype.itemsize
+    mem = exe.memory_analysis()
+    gib = 2.0 ** 30
+    bad = []
+    if mem.temp_size_in_bytes >= pool_bytes / 4:
+        bad.append(f"temp {mem.temp_size_in_bytes / gib:.2f} GiB against "
+                   f"a pool of {pool_bytes / gib:.2f} GiB: the program "
+                   f"holds a copy of it")
+    if mem.alias_size_in_bytes < pool_bytes:
+        bad.append(f"alias {mem.alias_size_in_bytes / gib:.2f} GiB: the "
+                   f"donated pool ({pool_bytes / gib:.2f} GiB) is not the "
+                   f"result's buffer")
+    whole = ",".join(map(str, pool.shape))
+    layer = ",".join(map(str, pool.shape[1:]))
+    row_major = ",".join(str(i) for i in reversed(range(pool.ndim)))
+    seen = 0
+    for line in exe.as_text().splitlines():
+        m = _INSTR.search(line)
+        if m is None:
+            continue
+        _, dims, layout, opcode = m.groups()
+        if dims == whole:
+            seen += 1
+            if layout is not None and layout != row_major:
+                bad.append(f"pool laid out {{{layout}}}: "
+                           f"{line.strip()[:160]}")
+        if (opcode in ("copy", "dynamic-slice", "dynamic-update-slice")
+                and dims in (whole, "1," + layer, layer)):
+            bad.append(f"{opcode} of the pool: {line.strip()[:160]}")
+    assert seen, "the optimised HLO never names the pool's shape"
+    assert not bad, "\n".join(bad[:12])
+
+
+def _decode_specs(cfg, slots, num_pages):
+    caches = _cache_shapes(cfg, slots, num_pages)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    return caches, (_weight_shapes(cfg), i32(slots), caches, i32(slots),
+                    jax.ShapeDtypeStruct((slots, 2), jnp.uint32))
+
+
+@pytest.mark.parametrize("geometry", [MEDIUM, XL], ids=["medium", "xl"])
+def test_decode_tick_leaves_the_pool_in_place(geometry, one_chip,
+                                              as_on_chip):
+    cfg, slots, num_pages = (geometry[k] for k in
+                             ("cfg", "slots", "num_pages"))
+    caches, specs = _decode_specs(cfg, slots, num_pages)
+    exe = _compile(_decode_tick(cfg, num_pages), (2,), one_chip, *specs)
+    assert "paged_attention_decode" in exe.as_text()
+    _assert_pool_stays(exe, caches)
+
+
+def test_prefill_tick_leaves_the_pool_in_place(one_chip, as_on_chip):
+    """One ragged-prefill width of the cell's ladder (C = 64, its most
+    frequent: 13 of 46 launches in PR 25's window)."""
+    cfg, slots, num_pages = (MEDIUM[k] for k in
+                             ("cfg", "slots", "num_pages"))
+    caches = _cache_shapes(cfg, slots, num_pages)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    exe = _compile(_prefill_tick(cfg, num_pages), (3,), one_chip,
+                   _weight_shapes(cfg), i32(slots, 64), i32(slots), caches,
+                   i32(slots))
+    assert "ragged_prefill_attention" in exe.as_text()
+    _assert_pool_stays(exe, caches)
+
+
+def test_weight_shapes_are_the_bundles_own():
+    """The shapes spelled above are those a real (tiny) model stacks:
+    the compiles would otherwise judge a program nobody runs."""
+    cfg = gpt2_tiny()
+    model = GPTForCausalLM(cfg)
+    model._decode_bundle(32)
+    (tree,) = model._pt_stacked_weights.values()
+    want = _weight_shapes(cfg)
+    assert {k: tuple(v.shape) for k, v in tree.items()} == \
+        {k: v.shape for k, v in want.items()}
