@@ -481,6 +481,12 @@ class ContinuousBatchingServer:
             # below view rows per head at the host boundary
             self._kv_heads = paged_kv_heads(model.cfg)
             self._pool_shards = paged_pool_shards(mesh, self._kv_heads)
+            # heads each pool leaf stores a token, off the dense
+            # bundle's cache tree ([L, B, T, heads, dim] a leaf), which
+            # has the same leaves unmerged
+            dense = jax.eval_shape(lambda: self._init_caches(1))
+            self._leaf_heads = {name: int(dense[name].shape[3])
+                                for name in self._caches["pool"]}
             # host KV tier (kv_tier.HostTier): eviction SPILLS cold
             # prefix pages to checksummed host buffers instead of
             # dropping them, and admissions hitting a spilled run
@@ -716,7 +722,26 @@ class ContinuousBatchingServer:
                       # partial page batches shipped as the source
                       # (migrate_out(partial=True)) / staged batches
                       # landed as the target (migrate_in_pages)
-                      "handoff_pages_out": 0, "handoff_pages_in": 0}
+                      "handoff_pages_out": 0, "handoff_pages_in": 0,
+                      # what a routed-expert / key-selecting model's
+                      # launches did (zero for models with neither):
+                      # rows through the expert FFN (a launch computes
+                      # slots x width of them) and those of a live
+                      # token; distinct experts the live rows of a
+                      # DECODE tick chose, summed over layers and
+                      # ticks; keys in the context of live decode rows
+                      # (a tick's last row a slot, a layer at a time)
+                      # and keys the selection kept of them, which the
+                      # device counts where it makes the mask
+                      "decode_ticks": 0, "moe_rows": 0,
+                      "moe_live_rows": 0, "moe_experts_touched": 0,
+                      "attn_keys_context": 0, "attn_keys_selected": 0}
+        cfg = getattr(model, "cfg", None)
+        self._moe_k = int(getattr(cfg, "top_k", 0) or 0) \
+            if getattr(cfg, "num_experts", 0) else 0
+        indexer = getattr(cfg, "indexer", None)
+        self._select_k = int(indexer[2]) if indexer else 0
+        self._n_layers = int(getattr(cfg, "num_layers", 0) or 0)
         # telemetry (paddle_tpu.telemetry.ServerTelemetry): True builds
         # a default-enabled one; None (default) keeps the hot path at
         # a single attribute check — no locks, no clock reads
@@ -1275,10 +1300,9 @@ class ContinuousBatchingServer:
             s = pool_lanes(c[:, 0, start:start + n])
             return s.reshape(s.shape[0], len(pages), pg, s.shape[2])
 
-        pool = jax.tree_util.tree_map(
-            lambda p_, c: p_.at[:, ids].set(seg(c).astype(p_.dtype)),
-            self._caches["pool"],
-            {"k": caches1["k"], "v": caches1["v"]})
+        pool = {name: leaf.at[:, ids].set(
+                    seg(caches1[name]).astype(leaf.dtype))
+                for name, leaf in self._caches["pool"].items()}
         self._caches = dict(self._caches, pool=pool)
 
     def _seed_from_pages(self, pages):
@@ -1298,10 +1322,10 @@ class ContinuousBatchingServer:
         idx = jnp.asarray(np.asarray(pages, np.int32))
         base = self._init_caches(1)
 
-        def take(pool, dense):   # [L, P, pg, h*hd] -> dense rows
+        def take(name, pool, dense):   # [L, P, pg, h*hd] -> dense rows
             s = pool[:, idx]
             s = pool_heads(s.reshape(s.shape[0], 1, n, s.shape[3]),
-                           self._kv_heads)
+                           self._leaf_heads[name])
             return dense.at[:, :, :n].set(s.astype(dense.dtype))
 
         pool = self._caches["pool"]
@@ -1309,12 +1333,14 @@ class ContinuousBatchingServer:
             # pool flatten must not run on the costs=None path
             self._charge_transfer("page_gather",
                                   2 * n * self._row_nbytes())
-        return {"k": take(pool["k"], base["k"]),
-                "v": take(pool["v"], base["v"])}
+        return {name: take(name, leaf, base[name])
+                for name, leaf in pool.items()}
 
     def _spill_payload(self, page):
-        """One pool page's K and V rows as host numpy arrays
-        ``[L, pg, kvh, hd]`` — the demotion gather ``PrefixCache.evict``
+        """One pool page's rows as host numpy arrays, one a pool leaf
+        by leaf NAME in sorted order (``k`` and ``v`` ``[L, pg, kvh,
+        hd]``; between them ``ki``, a key-selecting model's indexer
+        keys ``[L, pg, 1, dim]``) — the demotion gather ``PrefixCache.evict``
         routes through the host tier, and the wire format of migration
         and handoff. On a sharded pool the gather goes PER SHARD: each
         device ships only its kv-head slice (``addressable_shards``,
@@ -1325,8 +1351,9 @@ class ContinuousBatchingServer:
         from ..models.generation import pool_heads
         page = int(page)
         out = []
-        for name in ("k", "v"):
-            leaf = self._caches["pool"][name]
+        pool = self._caches["pool"]
+        for name in sorted(pool):    # THE payload order: leaf names sorted
+            leaf = pool[name]
             rows = None
             if self._pool_shards > 1:
                 try:
@@ -1339,13 +1366,13 @@ class ContinuousBatchingServer:
                     pass       # runtime hid the buffers: global gather
             if rows is None:
                 rows = np.asarray(jax.device_get(leaf[:, page]))
-            out.append(pool_heads(rows, self._kv_heads))
+            out.append(pool_heads(rows, self._leaf_heads[name]))
         return out
 
     def _write_pages(self, pages, payloads):
-        """Scatter page payloads (``_spill_payload``'s format: a K and
-        a V array ``[L, pg, kvh, hd]`` a page) into pool pages
-        ``pages`` — one batched ``.at[:, idx].set`` per k/v leaf. On a
+        """Scatter page payloads (``_spill_payload``'s format: one
+        array ``[L, pg, heads, hd]`` a pool leaf a page) into pool pages
+        ``pages`` — one batched ``.at[:, idx].set`` per leaf. On a
         sharded pool the host rows are laid out against the pool's own
         sharding first (``jax.device_put`` with the leaf's sharding —
         each device receives only its kv-head slice): the mirror of
@@ -1353,7 +1380,7 @@ class ContinuousBatchingServer:
         from ..models.generation import pool_lanes
         idx = jnp.asarray(np.asarray(pages, np.int32))
         pool = dict(self._caches["pool"])
-        for j, name in enumerate(("k", "v")):
+        for j, name in enumerate(sorted(pool)):   # _spill_payload's order
             leaf = pool[name]
             # [L, n, pg, kvh*hd]: page payloads stacked on a new pages
             # axis, matching leaf[:, idx]
@@ -1456,7 +1483,7 @@ class ContinuousBatchingServer:
                                   2 * self._kv.block_table.nbytes)
 
     def _shard_pool_bytes(self):
-        """K+V pool bytes actually RESIDENT on one shard's device —
+        """Pool bytes (every leaf) actually RESIDENT on one shard's device —
         measured off the live arrays (an addressable shard's buffer),
         not derived, so a placement bug (pool silently replicated when
         it should shard) shows up as 1x instead of 1/mp. Falls back to
@@ -1469,12 +1496,12 @@ class ContinuousBatchingServer:
         memo = getattr(self, "_shard_bytes_memo", None)
         if memo is not None:
             return memo
-        pool = self._caches["pool"]
+        leaves = list(self._caches["pool"].values())
         try:
-            memo = int(pool["k"].addressable_shards[0].data.nbytes
-                       + pool["v"].addressable_shards[0].data.nbytes)
+            memo = int(sum(leaf.addressable_shards[0].data.nbytes
+                           for leaf in leaves))
         except Exception:
-            memo = int((pool["k"].nbytes + pool["v"].nbytes)
+            memo = int(sum(leaf.nbytes for leaf in leaves)
                        // max(1, self._pool_shards))
         self._shard_bytes_memo = memo
         return memo
@@ -2045,6 +2072,8 @@ class ContinuousBatchingServer:
         logits, self._caches = prefill_fn(toks_d, t0_d, self._caches,
                                           out_d)
         self._count_dispatches(1, op="prefill")
+        if self._moe_k:
+            self._count_routed(None, used, S * C)
         led = self._led
         for slot, start, take in plan:
             st = self._slots[slot]
@@ -2563,7 +2592,21 @@ class ContinuousBatchingServer:
                 return carry, carry[0]
             (tok, caches, t, keys), toks = jax.lax.scan(
                 body, (tok, caches, t, keys), None, length=n)
-            return tok, caches, t, keys, jnp.transpose(toks, (1, 0))
+            toks = jnp.transpose(toks, (1, 0))
+            # what each slot's last row did rides the read-back the
+            # tick makes anyway, beside its tokens: the experts it chose
+            # ([L, S, k] -> [S, L * k]), then the keys its attention
+            # kept, summed over layers ([L, S] -> [S, 1])
+            aux = caches if isinstance(caches, dict) else {}
+            if "route" in aux:
+                route = aux["route"]
+                toks = jnp.concatenate(
+                    [toks, jnp.transpose(route, (1, 0, 2)).reshape(
+                        route.shape[1], -1)], axis=1)
+            if "kept" in aux:
+                toks = jnp.concatenate(
+                    [toks, jnp.sum(aux["kept"], axis=0)[:, None]], axis=1)
+            return tok, caches, t, keys, toks
 
         # the trace's ``jit_decode_tick``
         return hoisted_jit(decode_tick, donate_argnums=(1,))
@@ -3016,9 +3059,16 @@ class ContinuousBatchingServer:
                            self._keys)
         self._tick_dispatch("decode")
         toks = np.asarray(toks)                    # [slots, tick_block]
+        aux, toks = toks[:, self.tick_block:], toks[:, :self.tick_block]
+        route, kept = (aux[:, :-1], aux[:, -1]) if self._select_k \
+            else (aux, None)
         if b is not None:
             wall = b.mark("emit") - t_launch
-        decoded = wasted = 0
+        self.stats["decode_ticks"] += 1
+        if self._moe_k:
+            self._count_routed(route, n_active * toks.shape[1],
+                               self.max_slots * toks.shape[1])
+        decoded = wasted = keys_ctx = keys_sel = 0
         led = self._led
         if led is not None:
             # rows of slots holding no live decode work still ride the
@@ -3034,6 +3084,13 @@ class ContinuousBatchingServer:
             if led is not None and self._kv is not None:
                 led.add("skipped_page_dma", self._skipped_dma(
                     st.prompt_len + len(st.emitted)))
+            if kept is not None:
+                # the block's last row attended from position base +
+                # block - 2: base + block - 1 keys in its context, and
+                # in every layer; what it KEPT is the device's count
+                keys_ctx += (st.prompt_len + len(st.emitted)
+                             + toks.shape[1] - 1) * self._n_layers
+                keys_sel += int(kept[slot])
             for j in range(toks.shape[1]):
                 st.emitted.append(int(toks[slot, j]))
                 if led is not None:
@@ -3049,6 +3106,11 @@ class ContinuousBatchingServer:
                     break              # later block tokens are waste
             decoded += min(j + 1, toks.shape[1])
             st.stream(self._deferred_cbs)
+        if keys_ctx:
+            self.stats["attn_keys_context"] += keys_ctx
+            self.stats["attn_keys_selected"] += keys_sel
+            if tele is not None:
+                tele.on_selected_keys(keys_ctx, keys_sel)
         if tele is not None:
             # np.asarray above synced the dispatch, so the tick time
             # covers host dispatch + device work
@@ -3073,6 +3135,24 @@ class ContinuousBatchingServer:
         if tele is not None:
             tele.set_active_slots(n)
         return n
+
+    def _count_routed(self, route, live_rows, rows):
+        """Expert-FFN accounting of one launch: ``rows`` computed,
+        ``live_rows`` of them a live token's. ``route`` (decode ticks:
+        ``[slots, layers * k]`` expert ids off the token read-back, or
+        None) gives the distinct experts the live slots' rows chose,
+        a layer at a time."""
+        touched = 0
+        if route is not None and route.size:
+            k = self._moe_k
+            live = route[self._active].reshape(-1, route.shape[1] // k, k)
+            touched = sum(int(np.unique(live[:, l]).size)
+                          for l in range(live.shape[1])) if live.size else 0
+        self.stats["moe_rows"] += rows
+        self.stats["moe_live_rows"] += live_rows
+        self.stats["moe_experts_touched"] += touched
+        if self._tele is not None:
+            self._tele.on_moe_rows(rows, live_rows, touched)
 
     def _busy_locked(self):
         """Work pending: queued requests, decoding slots, slots still

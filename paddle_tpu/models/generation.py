@@ -151,10 +151,10 @@ def _mesh_paged_caches(init_caches, mesh, kv_heads, axis="mp"):
 
     def init(batch):
         tree = init_caches(batch)
-        return dict(tree,
+        return dict({n: jax.device_put(a, rep) for n, a in tree.items()
+                     if n != "pool"},
                     pool={n: jax.device_put(a, sh)
-                          for n, a in tree["pool"].items()},
-                    bt=jax.device_put(tree["bt"], rep))
+                          for n, a in tree["pool"].items()})
 
     return init
 
@@ -167,16 +167,6 @@ def _mm(x, w):
         qw, s = w
         return (x @ qw.astype(x.dtype)) * s.astype(x.dtype)
     return x @ w
-
-
-def _emm(spec, x, w):
-    """einsum analogue of _mm for stacked expert weights."""
-    if isinstance(w, tuple):
-        qw, s = w
-        out = jnp.einsum(spec, x, qw.astype(x.dtype))
-        # out [..., E, s, F]; scale [E, 1, F] broadcasts over the token dim
-        return out * s.astype(x.dtype)
-    return jnp.einsum(spec, x, w)
 
 
 def _rms(x, w, eps):
@@ -252,8 +242,13 @@ def _kv_read(lc, name, dtype):
     return c
 
 
-def _init_kv(shape, dtype, cache_dtype):
+def _init_kv(shape, dtype, cache_dtype, index_dim=None):
+    """Dense per-layer caches ``[L, B, T, heads, head_dim]``; with
+    ``index_dim`` a third leaf ``ki`` ``[L, B, T, 1, index_dim]`` for
+    the key-selection indexer's keys (never quantised)."""
     lc = {}
+    if index_dim:
+        lc["ki"] = jnp.zeros(shape[:3] + (1, int(index_dim)), dtype)
     if cache_dtype == "int8":
         lc["k"] = jnp.zeros(shape, jnp.int8)
         lc["v"] = jnp.zeros(shape, jnp.int8)
@@ -331,15 +326,35 @@ def paged_kv_heads(cfg):
 
 
 def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
-                   kvh, hd, dtype):
-    """Paged decode cache tree: one global K/V page pool over all
-    layers (``paged_pool_shape``) plus the per-slot block table (a
-    RUNTIME argument of the decode program — page churn never
-    recompiles)."""
-    shape = paged_pool_shape(layers, num_pages, page_size, kvh, hd)
-    return {"pool": {"k": jnp.zeros(shape, dtype),
-                     "v": jnp.zeros(shape, dtype)},
+                   kvh, hd, dtype, extra=None, route_k=None, kept=False):
+    """Paged decode cache tree: one global page pool over all layers
+    (``paged_pool_shape``) plus the per-slot block table (a RUNTIME
+    argument of the decode program — page churn never recompiles).
+    The pool's leaves are ``k`` and ``v`` and whatever ``extra`` names
+    (``{leaf: (heads, head_dim)}``: the key-selection indexer's keys
+    ``ki`` ride here). EVERY leaf is addressed by the SAME block table
+    and page ids: a page holds all of a token run's state, so
+    allocation, prefix sharing, preemption, spill and migration move
+    the leaves together (the server walks the pool's leaves and never
+    spells their names). ``route_k`` adds ``route`` ``[L, batch,
+    route_k]``: the experts each slot's LAST decode row chose, which
+    the decode tick packs into the read-back it already makes; ``kept``
+    adds ``kept`` ``[L, batch]``, the keys the selection kept for that
+    row, which rides the same read-back."""
+    def leaf(heads, dim):
+        return jnp.zeros(paged_pool_shape(layers, num_pages, page_size,
+                                          heads, dim), dtype)
+
+    pool = {"k": leaf(kvh, hd), "v": leaf(kvh, hd)}
+    for name, (heads, dim) in (extra or {}).items():
+        pool[name] = leaf(heads, dim)
+    tree = {"pool": pool,
             "bt": jnp.zeros((batch, pages_per_slot), jnp.int32)}
+    if route_k:
+        tree["route"] = jnp.zeros((layers, batch, int(route_k)), jnp.int32)
+    if kept:
+        tree["kept"] = jnp.zeros((layers, batch), jnp.int32)
+    return tree
 
 
 def _paged_attend(q, pool, layer, bt, t, scale, mesh=None):
@@ -445,42 +460,67 @@ def _fused_attend(q, pool, layer, bt, t, last, dec, ss, sp, scale):
 
 
 def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, fused=None,
-                   mesh=None):
-    """One layer's paged K/V write and attention over the CARRIED
-    pools ``{"k", "v"}`` [L, P, pg, kvh*hd]: the new rows k/v
-    [B, s, kvh, hd] land in layer ``layer``'s pages through the block
-    table, then q [B, s, nh, hd] attends through it. s == 1 is a
-    decode step (ragged paged-attention kernel); s > 1 a RAGGED
-    PREFILL chunk at per-slot offsets ``t`` — which is what lets the
-    server prefill several admissions as one launch with no
-    dense-cache detour. ``fused`` (a ``(last, dec, ss, sp)`` tuple)
-    switches to the FUSED TICK: ``bt`` is then the live block-table
-    slice, rows past ``last`` null-redirect zeroed on write, and
-    attention runs the fused kernel whose DMA schedule ``(ss, sp)``
-    covers only live pages — prefill chunks and s=1 decode rows
-    (``dec``) of one serving tick in a single launch. Returns
-    ``(att [B, s, nh, hd], pool)``."""
+                   mesh=None, select=None):
+    """One layer's page write and attention over the CARRIED pools
+    [L, P, pg, lanes]: the new rows k/v [B, s, kvh, hd] land in layer
+    ``layer``'s pages through the block table, then q [B, s, nh, hd]
+    attends through it. s == 1 is a decode step (ragged paged-attention
+    kernel); s > 1 a RAGGED PREFILL chunk at per-slot offsets ``t`` —
+    which is what lets the server prefill several admissions as one
+    launch with no dense-cache detour. ``fused`` (a ``(last, dec, ss,
+    sp)`` tuple) switches to the FUSED TICK: ``bt`` is then the live
+    block-table slice, rows past ``last`` null-redirect zeroed on
+    write, and attention runs the fused kernel whose DMA schedule
+    ``(ss, sp)`` covers only live pages — prefill chunks and s=1 decode
+    rows (``dec``) of one serving tick in a single launch.
+
+    ``select`` (``(qi, wi, ki, topk)``: indexer queries [B, s, J, D],
+    head weights [B, s, J], the rows' indexer keys [B, s, 1, D]) is
+    learned key selection: ``ki`` is written into the pool's third
+    leaf beside k and v, and attention runs over the ``topk`` keys the
+    indexer ranks highest (``ops/key_selection.py``, one composition
+    for decode rows and prefill chunks). Returns ``(att [B, s, nh,
+    hd], pool, kept)``: ``kept`` [B, s] is the number of keys the
+    selection let each row attend (None without ``select``)."""
     last = fused[0] if fused is not None else None
-    pool = {"k": _page_write(pool["k"], layer, k, bt, t, last=last),
-            "v": _page_write(pool["v"], layer, v, bt, t, last=last)}
-    if fused is not None:
+    rows = {"k": k, "v": v}
+    if select is not None:
+        rows["ki"] = select[2]
+    pool = {n: _page_write(pool[n], layer, rows[n], bt, t, last=last)
+            for n in pool}
+    kept = None
+    if select is not None:
+        if fused is not None:
+            raise NotImplementedError(
+                "the fused tick has no key selection (ROADMAP A1: the "
+                "fused kernel is refused on a TPU anyway): serve a "
+                "model with an indexer through serving_mode='split'")
+        from ..ops.key_selection import sparse_paged_attention
+        b = q.shape[0]
+        if jnp.ndim(t) == 0:
+            t = jnp.full((b,), t, jnp.int32)
+        att, kept = sparse_paged_attention(q, select[0], select[1], pool,
+                                           layer, bt, t, select[3], scale)
+    elif fused is not None:
         last, dec, ss, sp = fused
         att = _fused_attend(q, pool, layer, bt, t, last, dec, ss, sp, scale)
     elif q.shape[1] > 1:
         att = _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=mesh)
     else:
         att = _paged_attend(q, pool, layer, bt, t, scale, mesh=mesh)
-    return att, pool
+    return att, pool, kept
 
 
 def _run_layers(layer_fn, x, blk_tree, caches, paged):
     """THE layer loop of every decode bundle. ``layer_fn(xx, blk, lc,
-    l) -> (xx, lc)`` runs one layer over its slice ``blk`` of the
-    stacked weights.
+    l) -> (xx, lc, aux)`` runs one layer over its slice ``blk`` of the
+    stacked weights; ``aux`` (None, or a small dict such as the experts
+    a row chose and the keys it kept) comes back stacked over layers.
+    Returns ``(x, caches, aux)``.
 
     Dense: a scan with the per-layer caches as ``xs``/``ys`` (``lc`` is
     layer ``l``'s cache dict). Paged: the hidden state AND the whole
-    K/V pools are the loop's CARRY (``lc`` is the pool dict, written
+    page pools are the loop's CARRY (``lc`` is the pool dict, written
     and read at layer index ``l``); the stacked weights are scanned as
     before. The pool must be carried, not scanned: as ``xs``/``ys`` a
     donated pool can never be its own result, so the compiled tick
@@ -492,27 +532,41 @@ def _run_layers(layer_fn, x, blk_tree, caches, paged):
     layers = jnp.arange(jax.tree_util.tree_leaves(blk_tree)[0].shape[0],
                         dtype=jnp.int32)
     if not paged:
-        return jax.lax.scan(lambda xx, xs: layer_fn(xx, *xs), x,
-                            (blk_tree, caches, layers))
+        def dense(xx, xs):
+            xx, lc, aux = layer_fn(xx, *xs)
+            return xx, (lc, aux)
+
+        x, (caches, aux) = jax.lax.scan(dense, x, (blk_tree, caches,
+                                                   layers))
+        return x, caches, aux
 
     def body(carry, xs):
-        return layer_fn(carry[0], xs[0], carry[1], xs[1]), None
+        xx, pool, aux = layer_fn(carry[0], xs[0], carry[1], xs[1])
+        return (xx, pool), aux
 
-    (x, pool), _ = jax.lax.scan(body, (x, caches["pool"]),
-                                (blk_tree, layers))
-    return x, dict(caches, pool=pool)
+    (x, pool), aux = jax.lax.scan(body, (x, caches["pool"]),
+                                  (blk_tree, layers))
+    return x, dict(caches, pool=pool), aux
 
 
 def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
-                   fused=None, mesh=None, layer=None):
+                   fused=None, mesh=None, layer=None, qk_norm=False,
+                   indexer=None):
     """Shared llama-family attention sublayer for the decode loop:
     pre-RMSNorm, rope at absolute positions, GQA cache write + masked
     cached attention, output projection + residual. ``lc`` is this
     layer's cache dict (fp or int8 codec) — or, when ``bt`` (a per-slot
-    block table) is given, the WHOLE K/V page pools, written and
-    attended at ``layer`` through the table (paged backend:
-    ``_paged_kv_step``, which also explains ``fused``).
-    Returns (xx, lc, h2) with h2 = the post-attention norm for the FFN."""
+    block table) is given, the WHOLE page pools, written and attended
+    at ``layer`` through the table (paged backend: ``_paged_kv_step``,
+    which also explains ``fused``). ``qk_norm``: RMSNorm over each
+    head's dims with the learned gains ``blk["qn"]``/``blk["kn"]``,
+    before the rope (Qwen3's). ``indexer`` (``(heads, dim, topk, (cos,
+    sin))``): learned key selection — indexer queries ``blk["iq"]``,
+    one shared key ``blk["ik"]`` (cached beside k and v) and head
+    weights ``blk["iw"]`` pick the ``topk`` keys attention runs over.
+    Returns (xx, lc, h2, kept) with h2 = the post-attention norm for the
+    FFN and kept [B, s] the keys the selection let each row attend (None
+    without an indexer)."""
     b, s, nh, kvh, hd, scale = dims
     cos, sin = tables
     from ..ops.pallas import rope as rope_mod
@@ -520,23 +574,44 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
     q = _mm(h, blk["wq"]).reshape(b, s, nh, hd)
     k = _mm(h, blk["wk"]).reshape(b, s, kvh, hd)
     v = _mm(h, blk["wv"]).reshape(b, s, kvh, hd)
+    if qk_norm:
+        q = _rms(q, blk["qn"], eps)
+        k = _rms(k, blk["kn"], eps)
     q = rope_mod._apply_rotary_jnp(q, cos, sin, position_ids=pos)
     k = rope_mod._apply_rotary_jnp(k, cos, sin, position_ids=pos)
+    select = kept = None
+    if indexer is not None:
+        heads, dim, topk, (icos, isin) = indexer
+        qi = rope_mod._apply_rotary_jnp(
+            _mm(h, blk["iq"]).reshape(b, s, heads, dim), icos, isin,
+            position_ids=pos)
+        ki = rope_mod._apply_rotary_jnp(
+            _mm(h, blk["ik"]).reshape(b, s, 1, dim), icos, isin,
+            position_ids=pos)
+        select = (qi, _mm(h, blk["iw"]), ki, topk)
     if bt is not None:
-        att, lc = _paged_kv_step(lc, layer, q, k, v, bt, t, scale,
-                                 fused=fused, mesh=mesh)
+        att, lc, kept = _paged_kv_step(lc, layer, q, k, v, bt, t, scale,
+                                       fused=fused, mesh=mesh,
+                                       select=select)
     else:
         lc = _kv_write(lc, "k", k, t)
         lc = _kv_write(lc, "v", v, t)
         kc = _kv_read(lc, "k", q.dtype)
         vc = _kv_read(lc, "v", q.dtype)
-        rep = nh // kvh
-        kk = jnp.repeat(kc, rep, axis=2) if rep > 1 else kc
-        vv = jnp.repeat(vc, rep, axis=2) if rep > 1 else vc
-        att = _cached_attend(q, kk, vv, t, s, scale)
+        if select is not None:
+            from ..ops.key_selection import sparse_dense_attention
+            lc = _kv_write(lc, "ki", select[2], t)
+            att, kept = sparse_dense_attention(
+                q, select[0], select[1], kc, vc, lc["ki"], t, select[3],
+                scale)
+        else:
+            rep = nh // kvh
+            kk = jnp.repeat(kc, rep, axis=2) if rep > 1 else kc
+            vv = jnp.repeat(vc, rep, axis=2) if rep > 1 else vc
+            att = _cached_attend(q, kk, vv, t, s, scale)
     xx = xx + _mm(att.reshape(b, s, nh * hd), blk["wo"])
     h2 = _rms(xx, blk["ln2"], eps)
-    return xx, lc, h2
+    return xx, lc, h2, kept
 
 
 def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
@@ -607,42 +682,85 @@ def _make_fused_tick_fn(fused_step, head_fn, embed_tokens):
     return fused_tick
 
 
+# bundle leaf -> the per-block parameter a LlamaBlock / MixtralBlock holds
+_LLAMA_ATTN = {"ln1": "input_layernorm.weight",
+               "ln2": "post_attention_layernorm.weight",
+               "wq": "self_attn.q_proj.weight",
+               "wk": "self_attn.k_proj.weight",
+               "wv": "self_attn.v_proj.weight",
+               "wo": "self_attn.o_proj.weight"}
+_LLAMA_FFN = {"wg": "mlp.gate_proj.weight", "wu": "mlp.up_proj.weight",
+              "wd": "mlp.down_proj.weight"}
+_MIXTRAL_FFN = {"router": "moe.gate.gate.weight",
+                "wg": "moe.experts.w_gate", "wu": "moe.experts.w_up",
+                "wd": "moe.experts.w_down"}
+_EXPERT_LEAVES = ("wg", "wu", "wd")
+
+
+def _llama_family_weights(model, moe):
+    """The llama-family bundle's weight tree, every block leaf stacked
+    over layers. A model that already keeps its blocks stacked hands
+    over its OWN arrays (``decode_weights()``: no second copy of the
+    weights exists); a model of per-layer blocks is stacked here."""
+    own = getattr(model, "decode_weights", None)
+    if own is not None:
+        return own()
+    blocks = [dict(blk.raw_params()) for blk in model.model.layers]
+    tree = {"table": unwrap(model.model.embed_tokens.weight),
+            "norm": unwrap(model.model.norm.weight),
+            "head": unwrap(model.lm_head.weight)}             # [H, V]
+    names = dict(_LLAMA_ATTN, **(_MIXTRAL_FFN if moe else _LLAMA_FFN))
+    tree.update({leaf: _stacked(blocks, name)
+                 for leaf, name in names.items()})
+    return tree
+
+
 def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                 cache_dtype=None, cache_backend="dense", page_size=None,
                 num_pages=None):
-    """(init_caches, embed_fn, step_fn, head_fn) for LlamaForCausalLM —
-    GQA-aware (kv heads cached unrepeated), rope applied at absolute
-    positions. ``cache_backend="paged"`` swaps the dense per-slot cache
-    for a global page pool + per-slot block tables (decode steps only;
-    prefill runs on a dense batch-1 bundle and is scattered into
-    pages)."""
+    """(init_caches, embed_fn, step_fn, head_fn[, ragged, fused]) for
+    the llama family: pre-RMSNorm blocks, rope at absolute positions,
+    GQA (kv heads cached unrepeated), SwiGLU. What a block has BESIDES
+    that is read from ``model.cfg`` — one builder, no copy per model:
+
+    - ``num_experts`` / ``top_k`` (/ ``norm_topk_prob``): the FFN is a
+      routed expert FFN (``ops/routed_ffn.py``: exact top-k for any k,
+      no capacity, only the chosen experts computed) — Mixtral, Keye;
+    - ``qk_norm``: per-head RMSNorm of q and k before the rope;
+    - ``indexer`` (``(heads, dim, topk)``): learned key selection with
+      an indexer-key cache beside K and V (``ops/key_selection.py``).
+
+    ``cache_backend="paged"`` swaps the dense per-slot cache for a
+    global page pool + per-slot block tables."""
     from ..ops.pallas import rope as rope_mod
+    from ..ops.routed_ffn import route_topk, routed_ffn
     cfg = model.cfg
     nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     eps = cfg.rms_eps
+    moe = bool(getattr(cfg, "num_experts", 0))
+    top_k = int(getattr(cfg, "top_k", 0)) if moe else 0
+    norm_topk = bool(getattr(cfg, "norm_topk_prob", True))
+    qk_norm = bool(getattr(cfg, "qk_norm", False))
+    indexer = getattr(cfg, "indexer", None)
+    if indexer is not None and mesh is not None:
+        raise NotImplementedError(
+            "key selection is not wired for a mesh (ROADMAP A8, the "
+            "mesh column): its indexer-key pool has one head, which no "
+            "kv-head sharding divides")
 
-    def stack():
-        blocks = [dict(blk.raw_params()) for blk in model.model.layers]
-        return {
-            "table": unwrap(model.model.embed_tokens.weight),
-            "norm": unwrap(model.model.norm.weight),
-            "head": unwrap(model.lm_head.weight),            # [H, V]
-            "ln1": _stacked(blocks, "input_layernorm.weight"),
-            "ln2": _stacked(blocks, "post_attention_layernorm.weight"),
-            "wq": _stacked(blocks, "self_attn.q_proj.weight"),
-            "wk": _stacked(blocks, "self_attn.k_proj.weight"),
-            "wv": _stacked(blocks, "self_attn.v_proj.weight"),
-            "wo": _stacked(blocks, "self_attn.o_proj.weight"),
-            "wg": _stacked(blocks, "mlp.gate_proj.weight"),
-            "wu": _stacked(blocks, "mlp.up_proj.weight"),
-            "wd": _stacked(blocks, "mlp.down_proj.weight"),
-        }
-
-    p = _stacked_weights(model, weight_dtype, mesh, stack, {
-        "wq": 2, "wk": 2, "wv": 2, "wg": 2, "wu": 2,   # column-parallel
-        "wo": 1, "wd": 1,                              # row-parallel
-        "head": 1})
+    ffn_dims = ({"wg": 1, "wu": 1, "wd": 1} if moe     # expert-parallel
+                else {"wg": 2, "wu": 2, "wd": 1})
+    p = _stacked_weights(
+        model, weight_dtype, mesh,
+        lambda: _llama_family_weights(model, moe),
+        dict({"wq": 2, "wk": 2, "wv": 2,               # column-parallel
+              "wo": 1, "head": 1}, **ffn_dims))        # row-parallel
     cos, sin = rope_mod.precompute_freqs(hd, max_cache_len, cfg.rope_theta)
+    if indexer is not None:
+        heads, dim, topk = indexer
+        indexer = (int(heads), int(dim), int(topk),
+                   rope_mod.precompute_freqs(int(dim), max_cache_len,
+                                             cfg.rope_theta))
     dtype = p["table"].dtype
     L = cfg.num_layers
     scale = 1.0 / np.sqrt(hd)
@@ -653,11 +771,14 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
 
     def init_caches(batch):
         if paged:
-            return _init_paged_kv(batch, L, num_pages, page_size,
-                                  max_cache_len // page_size, kvh, hd,
-                                  dtype)
+            return _init_paged_kv(
+                batch, L, num_pages, page_size, max_cache_len // page_size,
+                kvh, hd, dtype,
+                extra={"ki": (1, indexer[1])} if indexer else None,
+                route_k=top_k, kept=indexer is not None)
         return _init_kv((L, batch, max_cache_len, kvh, hd), dtype,
-                        cache_dtype)
+                        cache_dtype,
+                        index_dim=indexer[1] if indexer else None)
 
     if mesh is not None:
         init_caches = (_mesh_paged_caches(init_caches, mesh, kvh) if paged
@@ -666,22 +787,43 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     def embed_fn(tok, t):
         return p["table"][tok][:, None, :]
 
+    # the expert stacks are NOT scanned with the block: a scan would
+    # slice a layer's 128 experts out whole; the routed FFN slices one
+    # expert a tile at (layer, expert)
+    skip = ("table", "norm", "head") + (_EXPERT_LEAVES if moe else ())
+    blk_tree = {k_: v_ for k_, v_ in p.items() if k_ not in skip}
+
     def _forward(x, caches, t, bt, fused=None):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
-        pos = _positions(t, b, s)                         # [B, s]
+        # an idle slot's offset is parked past the table: its rows are
+        # garbage nobody reads, rotated at the last real position
+        pos = jnp.minimum(_positions(t, b, s), max_cache_len - 1)
 
         def layer(xx, blk, lc, l):
-            xx, lc, h2 = _rope_gqa_attn(
+            xx, lc, h2, kept = _rope_gqa_attn(
                 blk, xx, lc, t, pos, (b, s, nh, kvh, hd, scale),
-                (cos, sin), eps, bt=bt, fused=fused, mesh=mesh, layer=l)
-            xx = xx + _mm(jax.nn.silu(_mm(h2, blk["wg"]))
-                          * _mm(h2, blk["wu"]), blk["wd"])
-            return xx, lc
+                (cos, sin), eps, bt=bt, fused=fused, mesh=mesh, layer=l,
+                qk_norm=qk_norm, indexer=indexer)
+            # what each slot's LAST row did, for the decode tick's
+            # read-back: the keys it attended, the experts it chose
+            aux = {} if kept is None else {"kept": kept[:, -1]}
+            if not moe:
+                return xx + _mm(jax.nn.silu(_mm(h2, blk["wg"]))
+                                * _mm(h2, blk["wu"]), blk["wd"]), lc, aux
+            with jax.named_scope("moe_ffn"):
+                rows = h2.reshape(b * s, h2.shape[-1])
+                idx, gate = route_topk(rows, blk["router"], top_k,
+                                       normalize=norm_topk)
+                y = routed_ffn(rows, idx, gate, p["wg"], p["wu"], p["wd"],
+                               layer=l)
+            aux["route"] = idx.reshape(b, s, top_k)[:, -1]
+            return xx + y.reshape(xx.shape), lc, aux
 
-        blk_tree = {k_: v_ for k_, v_ in p.items()
-                    if k_ not in ("table", "norm", "head")}
-        return _run_layers(layer, x, blk_tree, caches, paged)
+        x, caches, aux = _run_layers(layer, x, blk_tree, caches, paged)
+        if paged and s == 1:
+            caches = dict(caches, **aux)
+        return x, caches
 
     def step_fn(x, caches, t):
         return _forward(x, caches, t, caches["bt"] if paged else None)
@@ -697,127 +839,8 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     if paged:
         embed_tokens = lambda tokens, t0: p["table"][tokens]
         ragged = _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens)
-        fused = _make_fused_tick_fn(fused_step, head_fn, embed_tokens)
-        return init_caches, embed_fn, step_fn, head_fn, ragged, fused
-    return init_caches, embed_fn, step_fn, head_fn
-
-
-def _moe_topk_ffn(h, router_w, wg, wu, wd, top_k):
-    """Dropless dense-expert MoE FFN for decode: every expert runs (E/k
-    FLOP overhead — the measured right choice at decode batch sizes, cf.
-    benchmarks/moe_dispatch_bench.py) and tokens combine their top-k
-    normalized gate weights. Matches the training GShard combine
-    (parallel/moe/gate.py _top2_dense_dispatch) whenever capacity drops
-    nothing — decode batches are far below capacity."""
-    E = router_w.shape[-1]
-    logits = h @ router_w                                  # [b, s, E]
-    probs = jax.nn.softmax(logits.astype(jnp.float32), -1)
-    g1 = probs.max(-1)
-    i1 = probs.argmax(-1)
-    if top_k >= 2:
-        probs2 = probs * (1.0 - jax.nn.one_hot(i1, E, dtype=probs.dtype))
-        g2 = probs2.max(-1)
-        i2 = probs2.argmax(-1)
-        denom = g1 + g2 + 1e-9
-        w = (jax.nn.one_hot(i1, E, dtype=probs.dtype)
-             * (g1 / denom)[..., None]
-             + jax.nn.one_hot(i2, E, dtype=probs.dtype)
-             * (g2 / denom)[..., None])
-    else:
-        w = jax.nn.one_hot(i1, E, dtype=probs.dtype) * g1[..., None]
-    g = _emm("bsh,ehf->besf", h, wg)
-    u = _emm("bsh,ehf->besf", h, wu)
-    o = _emm("besf,efh->besh", jax.nn.silu(g) * u, wd)
-    return jnp.einsum("bse,besh->bsh", w.astype(o.dtype), o)
-
-
-def _make_mixtral_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
-                  cache_dtype=None, cache_backend="dense", page_size=None,
-                  num_pages=None):
-    """Llama-style attention + routed-expert FFN (MixtralForCausalLM)."""
-    from ..ops.pallas import rope as rope_mod
-    cfg = model.cfg
-    nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
-    eps = cfg.rms_eps
-
-    def stack():
-        blocks = [dict(blk.raw_params()) for blk in model.model.layers]
-        return {
-            "table": unwrap(model.model.embed_tokens.weight),
-            "norm": unwrap(model.model.norm.weight),
-            "head": unwrap(model.lm_head.weight),
-            "ln1": _stacked(blocks, "input_layernorm.weight"),
-            "ln2": _stacked(blocks, "post_attention_layernorm.weight"),
-            "wq": _stacked(blocks, "self_attn.q_proj.weight"),
-            "wk": _stacked(blocks, "self_attn.k_proj.weight"),
-            "wv": _stacked(blocks, "self_attn.v_proj.weight"),
-            "wo": _stacked(blocks, "self_attn.o_proj.weight"),
-            "router": _stacked(blocks, "moe.gate.gate.weight"),
-            "wg": _stacked(blocks, "moe.experts.w_gate"),
-            "wu": _stacked(blocks, "moe.experts.w_up"),
-            "wd": _stacked(blocks, "moe.experts.w_down"),
-        }
-
-    p = _stacked_weights(model, weight_dtype, mesh, stack, {
-        "wq": 2, "wk": 2, "wv": 2, "wo": 1,
-        "wg": 1, "wu": 1, "wd": 1,            # expert-parallel decode
-        "head": 1})
-    cos, sin = rope_mod.precompute_freqs(hd, max_cache_len, cfg.rope_theta)
-    dtype = p["table"].dtype
-    L = cfg.num_layers
-    top_k = cfg.top_k
-    scale = 1.0 / np.sqrt(hd)
-    paged = cache_backend == "paged"
-    if paged:
-        _check_paged_config(max_cache_len, page_size, num_pages,
-                            cache_dtype, mesh)
-
-    def init_caches(batch):
-        if paged:
-            return _init_paged_kv(batch, L, num_pages, page_size,
-                                  max_cache_len // page_size, kvh, hd,
-                                  dtype)
-        return _init_kv((L, batch, max_cache_len, kvh, hd), dtype,
-                        cache_dtype)
-
-    if mesh is not None:
-        init_caches = (_mesh_paged_caches(init_caches, mesh, kvh) if paged
-                       else _mesh_caches(init_caches, mesh))
-
-    def embed_fn(tok, t):
-        return p["table"][tok][:, None, :]
-
-    def _forward(x, caches, t, bt, fused=None):
-        x = unwrap(x)
-        b, s = x.shape[0], x.shape[1]
-        pos = _positions(t, b, s)
-
-        def layer(xx, blk, lc, l):
-            xx, lc, h2 = _rope_gqa_attn(
-                blk, xx, lc, t, pos, (b, s, nh, kvh, hd, scale),
-                (cos, sin), eps, bt=bt, fused=fused, mesh=mesh, layer=l)
-            xx = xx + _moe_topk_ffn(h2, blk["router"], blk["wg"],
-                                    blk["wu"], blk["wd"], top_k)
-            return xx, lc
-
-        blk_tree = {k_: v_ for k_, v_ in p.items()
-                    if k_ not in ("table", "norm", "head")}
-        return _run_layers(layer, x, blk_tree, caches, paged)
-
-    def step_fn(x, caches, t):
-        return _forward(x, caches, t, caches["bt"] if paged else None)
-
-    def fused_step(x, caches, t, last, dec, bt_live, ss, sp):
-        return _forward(x, caches, t, bt_live,
-                        fused=(last, dec, ss, sp))
-
-    def head_fn(out):
-        return (_rms(unwrap(out), p["norm"], eps) @ p["head"]
-                ).astype(jnp.float32)
-
-    if paged:
-        embed_tokens = lambda tokens, t0: p["table"][tokens]
-        ragged = _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens)
+        if indexer is not None:      # no fused entry: the server says so
+            return init_caches, embed_fn, step_fn, head_fn, ragged
         fused = _make_fused_tick_fn(fused_step, head_fn, embed_tokens)
         return init_caches, embed_fn, step_fn, head_fn, ragged, fused
     return init_caches, embed_fn, step_fn, head_fn
@@ -893,8 +916,8 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                    ).reshape(b, s, 3, nh, hd)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             if paged:
-                att, lc = _paged_kv_step(lc, l, q, k, v, bt, t, scale,
-                                         fused=fused, mesh=mesh)
+                att, lc, _ = _paged_kv_step(lc, l, q, k, v, bt, t, scale,
+                                            fused=fused, mesh=mesh)
             else:
                 lc = _kv_write(lc, "k", k, t)
                 lc = _kv_write(lc, "v", v, t)
@@ -908,11 +931,11 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             ff = jax.nn.gelu(_mm(h2, blk["mlp.fc1.weight"])
                              + blk["mlp.fc1.bias"], approximate=True)
             xx = xx + _mm(ff, blk["mlp.fc2.weight"]) + blk["mlp.fc2.bias"]
-            return xx, lc
+            return xx, lc, None
 
         blk_tree = {k_: v_ for k_, v_ in p.items()
                     if k_ not in ("table", "wpe", "lnf_w", "lnf_b")}
-        return _run_layers(layer, x, blk_tree, caches, paged)
+        return _run_layers(layer, x, blk_tree, caches, paged)[:2]
 
     def step_fn(x, caches, t):
         return _forward(x, caches, t, caches["bt"] if paged else None)
@@ -957,27 +980,20 @@ class GenerationMixin:
         if key in cached:
             cached[key] = cached.pop(key)      # LRU: move to back
             return cached[key]
-        from .gpt import GPTForCausalLM
-        from .llama import LlamaForCausalLM
-        from .mixtral import MixtralForCausalLM
         kw = dict(cache_backend=cache_backend, page_size=page_size,
                   num_pages=num_pages)
-        if isinstance(self, MixtralForCausalLM):
-            bundle = _make_mixtral_decode_fns(self, max_cache_len,
-                                              weight_dtype, mesh,
-                                              cache_dtype, **kw)
-        elif isinstance(self, LlamaForCausalLM):
-            bundle = _make_llama_decode_fns(self, max_cache_len,
-                                            weight_dtype, mesh,
-                                            cache_dtype, **kw)
-        elif isinstance(self, GPTForCausalLM):
-            bundle = _make_gpt_decode_fns(self, max_cache_len,
-                                          weight_dtype, mesh,
-                                          cache_dtype, **kw)
-        else:
+        # a model names its block family (``decode_family``); what its
+        # blocks have beyond the family's plain form the builder reads
+        # from the config
+        build = {"llama": _make_llama_decode_fns,
+                 "gpt": _make_gpt_decode_fns}.get(
+                     getattr(self, "decode_family", None))
+        if build is None:
             # no-roadmap: model-family dispatch, not a scope cut
             raise NotImplementedError(
                 f"generate() not wired for {type(self).__name__}")
+        bundle = build(self, max_cache_len, weight_dtype, mesh,
+                       cache_dtype, **kw)
         # one prefill program per (bundle, prompt-shape): jit here, not
         # inside generate(), so repeated calls reuse the compile. Paged
         # bundles carry a SIXTH element — the jitted ragged-prefill
@@ -1020,8 +1036,7 @@ class GenerationMixin:
         """[B, T] ids -> [B, T, H] input embeddings for a multi-token
         step starting at position ``t0`` (prefill: 0; speculative
         verify: the current decode offset)."""
-        from .gpt import GPTForCausalLM
-        if isinstance(self, GPTForCausalLM):
+        if self.decode_family == "gpt":
             table = unwrap(self.gpt.wte.weight)
             wpe = unwrap(self.gpt.wpe.weight)
             return table[ids] + wpe[t0 + jnp.arange(ids.shape[1])][None]

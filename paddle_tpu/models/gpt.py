@@ -125,6 +125,8 @@ class GPTModel(nn.Layer):
 
 
 class GPTForCausalLM(nn.Layer, GenerationMixin):
+    decode_family = "gpt"    # generation.py picks the bundle builder
+
     def __init__(self, cfg: GPTConfig):
         super().__init__()
         self.cfg = cfg

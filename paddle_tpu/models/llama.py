@@ -159,6 +159,8 @@ class LlamaModel(nn.Layer):
 
 
 class LlamaForCausalLM(nn.Layer, GenerationMixin):
+    decode_family = "llama"    # generation.py picks the bundle builder
+
     def __init__(self, cfg: LlamaConfig):
         super().__init__()
         self.cfg = cfg

@@ -76,6 +76,8 @@ class MixtralModel(nn.Layer):
 
 
 class MixtralForCausalLM(nn.Layer, GenerationMixin):
+    decode_family = "llama"    # generation.py picks the bundle builder
+
     def __init__(self, cfg: MixtralConfig):
         super().__init__()
         self.cfg = cfg

@@ -1,0 +1,106 @@
+"""Routed expert FFN for the serving path: any ``top_k``, no capacity,
+no dropped token, and only the experts a row chose are computed.
+
+The (row, expert) pairs are sorted by expert and laid out so that every
+expert's group starts on a tile boundary; a loop with a DYNAMIC trip
+count then runs one SwiGLU tile an iteration against that expert's
+weights, sliced out of the stacked ``[layers, experts, ...]`` arrays by
+``(layer, expert)``. What a launch reads of the expert weights is
+therefore what its rows touched: a decode tick of 8 rows x top-8 reads
+at most 64 experts a layer, a prefill launch of thousands of rows reads
+each expert once a tile. Plain XLA (sort, gather, while, dot): no
+kernel, nothing to fall back from.
+"""
+import jax
+import jax.numpy as jnp
+
+__all__ = ["route_topk", "routed_ffn"]
+
+
+def route_topk(h, router_w, top_k, normalize=True):
+    """``h`` [N, H] -> (experts [N, k] int32, gates [N, k] float32).
+    Router logits accumulate in float32 and the softmax is float32; the
+    top-k is EXACT (``jax.lax.top_k``: ties to the lower expert id).
+    ``normalize`` divides the kept gates by their sum (Mixtral's and
+    Qwen-MoE's ``norm_topk_prob``)."""
+    logits = jnp.dot(h, router_w, preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate, idx = jax.lax.top_k(probs, top_k)
+    if normalize:
+        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+    return idx.astype(jnp.int32), gate
+
+
+def _tile_rows(pairs, experts):
+    """Rows a tile: the power of two nearest the mean group size, within
+    [8, 256]. Small launches (decode) keep the padding rows few, wide
+    ones (prefill) amortise an expert's 9 MB of weights over 256 rows."""
+    mean = max(1, pairs // max(1, experts))
+    return int(min(256, max(8, 1 << (mean - 1).bit_length())))
+
+
+def _expert(w, layer, e):
+    """Expert ``e`` of layer ``layer`` out of a stacked weight
+    ``[L, E, a, b]`` (or ``[E, a, b]`` with ``layer`` None); an
+    ``(int8, scale)`` pair yields the pair of its slices."""
+    if isinstance(w, tuple):
+        return tuple(_expert(a, layer, e) for a in w)
+    if layer is None:
+        return jax.lax.dynamic_index_in_dim(w, e, 0, keepdims=False)
+    return jax.lax.dynamic_slice(
+        w, (layer, e, 0, 0), (1, 1) + w.shape[2:])[0, 0]
+
+
+def _mm(x, w):
+    if isinstance(w, tuple):
+        return (x @ w[0].astype(x.dtype)) * w[1].astype(x.dtype)
+    return x @ w
+
+
+def routed_ffn(h, idx, gate, wg, wu, wd, layer=None, tile=None):
+    """``sum_j gate[n, j] * SwiGLU_{idx[n, j]}(h[n])`` for rows ``h``
+    [N, H]. ``wg``/``wu`` are ``[L, E, H, F]`` and ``wd`` ``[L, E, F,
+    H]`` indexed at ``layer`` (or ``[E, ...]`` with ``layer`` None);
+    int8 pairs work too. Rows are independent: a NaN row (an idle
+    slot's) stays in its own output row."""
+    n, hidden = h.shape
+    k = idx.shape[1]
+    main = wg[0] if isinstance(wg, tuple) else wg
+    experts = main.shape[-3]
+    m = n * k
+    t = int(tile or _tile_rows(m, experts))
+    n_tiles = -(-m // t) + experts           # every group padded to t
+    flat = idx.reshape(m)
+    order = jnp.argsort(flat, stable=True)   # pair ids, by expert
+    e_sorted = flat[order]
+    counts = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
+    padded = -(-counts // t) * t
+    p_end = jnp.cumsum(padded)
+    p_start = p_end - padded
+    start = jnp.cumsum(counts) - counts
+    # where sorted pair j sits in the tile-aligned layout
+    dst = p_start[e_sorted] + (jnp.arange(m, dtype=jnp.int32)
+                               - start[e_sorted])
+    # the layout's rows, gathered: slot -> source row (n = a zero row)
+    src = jnp.full((n_tiles * t,), n, jnp.int32).at[dst].set(
+        (order // k).astype(jnp.int32))
+    rows = jnp.concatenate([h, jnp.zeros((1, hidden), h.dtype)])[src]
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(p_end, jnp.arange(n_tiles, dtype=jnp.int32) * t,
+                         side="right"), experts - 1).astype(jnp.int32)
+    used = p_end[-1] // t
+
+    def body(i, out):
+        e = tile_expert[i]
+        x = jax.lax.dynamic_slice(rows, (i * t, 0), (t, hidden))
+        y = _mm(jax.nn.silu(_mm(x, _expert(wg, layer, e)))
+                * _mm(x, _expert(wu, layer, e)), _expert(wd, layer, e))
+        return jax.lax.dynamic_update_slice(out, y.astype(out.dtype),
+                                            (i * t, 0))
+
+    out = jax.lax.fori_loop(0, used, body, jnp.zeros_like(rows))
+    # back to (row, choice) order, then the gated sum in float32
+    where = jnp.zeros((m,), jnp.int32).at[order].set(dst)
+    y = out[where].reshape(n, k, hidden)
+    return jnp.sum(y.astype(jnp.float32) * gate[..., None], axis=1
+                   ).astype(h.dtype)

@@ -340,6 +340,25 @@ class ServerTelemetry:
             "(decode / prefill / state_push / block_table / "
             "page_gather / page_scatter)", labelnames=("op",))
         self._disp_children = {}
+        # what a routed-expert / key-selecting model's launches did
+        # (the server counts them; models with neither leave these 0)
+        moe = r.counter(
+            "serving_moe_rows_total",
+            "Rows sent through the expert FFN: every row a launch "
+            "computes (slots x width), and those of a live token",
+            labelnames=("kind",))
+        self._c_moe_rows = moe.labels(kind="launched")
+        self._c_moe_live = moe.labels(kind="live")
+        self._c_moe_touched = r.counter(
+            "serving_moe_experts_touched_total",
+            "Distinct experts chosen by the live rows of a decode "
+            "tick, summed over layers and ticks")
+        keys = r.counter(
+            "serving_attn_keys_total",
+            "Keys of live decode rows: in their context, and kept by "
+            "the learned selection", labelnames=("kind",))
+        self._c_keys_context = keys.labels(kind="context")
+        self._c_keys_selected = keys.labels(kind="selected")
         # reliability signals (paddle_tpu.reliability): admission
         # control, supervised-loop retries, breaker, health
         shed = r.counter("server_shed_total",
@@ -539,6 +558,22 @@ class ServerTelemetry:
         self._g_active.set(active_slots)
         if decode_tokens:
             self._c_tok_decode.inc(decode_tokens)
+
+    def on_moe_rows(self, rows, live, touched=0):
+        """One launch's expert-FFN rows: all of them, the live ones,
+        and (decode ticks) the distinct experts the live ones chose."""
+        if not self.enabled:
+            return
+        self._c_moe_rows.inc(rows)
+        self._c_moe_live.inc(live)
+        if touched:
+            self._c_moe_touched.inc(touched)
+
+    def on_selected_keys(self, context, selected):
+        """A decode tick's live rows: keys in context, keys kept."""
+        if self.enabled:
+            self._c_keys_context.inc(context)
+            self._c_keys_selected.inc(selected)
 
     def set_queue_depth(self, n):
         if self.enabled:

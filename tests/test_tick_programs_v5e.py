@@ -240,3 +240,109 @@ def test_weight_shapes_are_the_bundles_own():
     want = _weight_shapes(cfg)
     assert {k: tuple(v.shape) for k, v in tree.items()} == \
         {k: v.shape for k, v in want.items()}
+
+
+# ----------------------------------------------------------------------
+# Keye-VL-2.0-30B-A3B's language model at the geometry of its cell
+# (perfbench/traffic/longctx-steady.json): 6 layers of 128 experts, 8
+# slots, page 16, 16,384 positions, 8,193 pages. The llama-family
+# builder's routed FFN and key selection are XLA compositions, so what is
+# held here is that the chip's compiler takes both tick programs at the
+# real size, that they fit beside the 8.75 GB of weights, and that the K
+# and V pools stay where they are (the 64-lane indexer pool is relaid
+# out, PERF.md section 7).
+KEYE = dict(slots=8, page=16, cache_len=16384, num_pages=8193)
+
+
+def _keye_cfg():
+    from paddle_tpu.models.keye_vl import KeyeVL2Config
+    return KeyeVL2Config(num_hidden_layers=6)
+
+
+def _keye_weight_shapes(cfg):
+    from paddle_tpu.models import keye_vl
+    raw = {n: jax.ShapeDtypeStruct(s, jnp.bfloat16)
+           for n, s in keye_vl.param_shapes(cfg).items()}
+    tree = {"table": raw["model.embed_tokens.weight"],
+            "norm": raw["model.norm.weight"], "head": raw["lm_head.weight"]}
+    tree.update({leaf: raw["model.layers." + name]
+                 for leaf, name in keye_vl._BUNDLE_LEAVES.items()})
+    return tree
+
+
+def _keye_bundle(cfg, weights):
+    model = types.SimpleNamespace(
+        cfg=cfg, _pt_stacked_weights={(None, None): weights})
+    return generation._make_llama_decode_fns(
+        model, KEYE["cache_len"], cache_backend="paged",
+        page_size=KEYE["page"], num_pages=KEYE["num_pages"])
+
+
+def _assert_keye_fits(exe, caches):
+    pool = caches["pool"]
+    assert set(pool) == {"k", "v", "ki"}
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize
+                     for a in pool.values())
+    mem = exe.memory_analysis()
+    assert mem.alias_size_in_bytes >= pool_bytes
+    # weights 8.75 GB + pool 1.71 GB + temp inside 16 GB, with room
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 13.5e9
+    whole = ",".join(map(str, pool["k"].shape))
+    layer = ",".join(map(str, pool["k"].shape[1:]))
+    bad = []
+    for line in exe.as_text().splitlines():
+        m = _INSTR.search(line)
+        if m and m.group(4) in ("copy", "dynamic-slice",
+                                "dynamic-update-slice") \
+                and m.group(2) in (whole, "1," + layer, layer):
+            bad.append(line.strip()[:160])
+    assert not bad, "\n".join(bad[:8])
+    return mem
+
+
+def test_keye_decode_tick_compiles_and_fits(one_chip, as_on_chip):
+    from paddle_tpu.inference.continuous_batching import (
+        ContinuousBatchingServer)
+    cfg = _keye_cfg()
+    shapes = _keye_weight_shapes(cfg)
+    caches = jax.eval_shape(
+        lambda: _keye_bundle(cfg, shapes)[0](KEYE["slots"]))
+    assert caches["route"].shape == (6, 8, 8)
+    assert caches["kept"].shape == (6, 8)
+
+    def decode_tick(weights, tok, caches, t, keys):
+        b = _keye_bundle(cfg, weights)
+        srv = types.SimpleNamespace(
+            _embed_fn=b[1], _step_fn=b[2], _head_fn=b[3], do_sample=False,
+            _temperature=1.0, _top_k=0, _top_p=1.0, tick_block=1)
+        return ContinuousBatchingServer._build_decode_step(srv)._fn(
+            tok, caches, t, keys)
+
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    exe = _compile(decode_tick, (2,), one_chip, shapes, i32(8), caches,
+                   i32(8), jax.ShapeDtypeStruct((8, 2), jnp.uint32))
+    mem = _assert_keye_fits(exe, caches)
+    assert mem.temp_size_in_bytes < 0.5e9
+    # no Pallas kernel on this path, and the tokens' read-back carries
+    # the experts chosen and the keys kept: [slots, 1 + layers * top_k + 1]
+    assert "tpu_custom_call" not in exe.as_text()
+    assert exe.output_shardings is not None
+    out = jax.eval_shape(decode_tick, shapes, i32(8), caches, i32(8),
+                         jax.ShapeDtypeStruct((8, 2), jnp.uint32))
+    assert out[4].shape == (8, 1 + 6 * 8 + 1)
+
+
+def test_keye_prefill_tick_compiles_and_fits(one_chip, as_on_chip):
+    """One width of the ladder (128: a row tile of the selection)."""
+    cfg = _keye_cfg()
+    shapes = _keye_weight_shapes(cfg)
+    caches = jax.eval_shape(
+        lambda: _keye_bundle(cfg, shapes)[0](KEYE["slots"]))
+
+    def prefill_tick(weights, tokens, t0, caches, out_idx):
+        return _keye_bundle(cfg, weights)[4](tokens, t0, caches, out_idx)
+
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    exe = _compile(prefill_tick, (3,), one_chip, shapes, i32(8, 128),
+                   i32(8), caches, i32(8))
+    _assert_keye_fits(exe, caches)
