@@ -698,7 +698,9 @@ class ContinuousBatchingServer:
         self._pending_t = {}
         self._pending_key = {}
         self._tok = jnp.zeros((self.max_slots,), jnp.int32)
-        self._t = jnp.zeros((self.max_slots,), jnp.int32)
+        # every slot starts parked on the idle sentinel (_park_slot)
+        self._t = jnp.full((self.max_slots,), self.max_cache_len,
+                           jnp.int32)
         self._active = np.zeros((self.max_slots,), bool)   # host-side
         self._slots = [None] * self.max_slots
         self._queue = []          # (rid, ids_np, max_new_tokens)
@@ -723,17 +725,22 @@ class ContinuousBatchingServer:
                       # (migrate_out(partial=True)) / staged batches
                       # landed as the target (migrate_in_pages)
                       "handoff_pages_out": 0, "handoff_pages_in": 0,
-                      # what a routed-expert / key-selecting model's
+                      # rows the decode ticks carried (slots x block a
+                      # tick) and those of a slot that was decoding:
+                      # the rest rode parked on the idle sentinel.
+                      # What a routed-expert / key-selecting model's
                       # launches did (zero for models with neither):
-                      # rows through the expert FFN (a launch computes
-                      # slots x width of them) and those of a live
-                      # token; distinct experts the live rows of a
+                      # rows the expert FFN computed (those of the
+                      # slots that rode the launch live: a parked
+                      # slot's join no expert's group) and those of a
+                      # live token; distinct experts the live rows of a
                       # DECODE tick chose, summed over layers and
                       # ticks; keys in the context of live decode rows
                       # (a tick's last row a slot, a layer at a time)
                       # and keys the selection kept of them, which the
                       # device counts where it makes the mask
-                      "decode_ticks": 0, "moe_rows": 0,
+                      "decode_ticks": 0, "decode_rows": 0,
+                      "decode_live_rows": 0, "moe_rows": 0,
                       "moe_live_rows": 0, "moe_experts_touched": 0,
                       "attn_keys_context": 0, "attn_keys_selected": 0}
         cfg = getattr(model, "cfg", None)
@@ -1226,6 +1233,24 @@ class ContinuousBatchingServer:
                 return True
         return False
 
+    def _park_slot(self, slot):
+        """Take ``slot`` out of the decoding set — THE owner of the idle
+        sentinel. A slot that holds no decoding request (never used,
+        finished, cancelled, expired, preempted, rolled back, paused
+        for a migration, staged, or still prefilling) carries ``t =
+        max_cache_len`` on the device: the table's span, which is what
+        the tick programs compare against. Its decode rows then write
+        to the null page, attend over nothing, select no key and join
+        no expert's group (``models/generation.py``), so the tick pays
+        for the slots that decode. The write rides ``_pending_t``, the
+        state push the next decode dispatch makes; activation
+        (``_activate``) and resume overwrite it with the real position.
+        Fused mode keeps no slot state on the device: its launch
+        carries the sentinel as an argument."""
+        self._active[slot] = False
+        if not self._fused:
+            self._pending_t[slot] = self.max_cache_len
+
     def _release_slot(self, slot, cold=False):
         """Tear down a slot's host + page state (no result recording).
         Paged backend with auto prefix caching: the request's full
@@ -1239,7 +1264,7 @@ class ContinuousBatchingServer:
         (preemption teardown) donates at the cold end of the LRU so
         the grow that displaced this slot reclaims its pages first."""
         st = self._slots[slot]
-        self._active[slot] = False
+        self._park_slot(slot)
         self._slots[slot] = None
         if slot in self._prefill_fifo:
             self._prefill_fifo.remove(slot)
@@ -1817,7 +1842,7 @@ class ContinuousBatchingServer:
                 # NOT fail
                 if self._kv is not None and self._kv.slot_pages(slot):
                     self._kv.free_slot(slot)
-                self._active[slot] = False
+                self._park_slot(slot)
                 self._slots[slot] = None
                 self._defer_admission_locked(src, req)
                 if self._tele is not None:
@@ -1831,7 +1856,7 @@ class ContinuousBatchingServer:
             except Exception as e:
                 if self._kv is not None and self._kv.slot_pages(slot):
                     self._kv.free_slot(slot)     # roll back a part-admit
-                self._active[slot] = False
+                self._park_slot(slot)
                 self._slots[slot] = None
                 self._failures[rid] = e
                 if self._tele is not None:
@@ -1886,7 +1911,7 @@ class ContinuousBatchingServer:
             except Exception as e:
                 if self._kv.slot_pages(slot):
                     self._kv.free_slot(slot)     # roll back a part-admit
-                self._active[slot] = False
+                self._park_slot(slot)
                 self._slots[slot] = None
                 if slot in self._prefill_fifo:
                     self._prefill_fifo.remove(slot)
@@ -1965,14 +1990,10 @@ class ContinuousBatchingServer:
         self._bind_request(st, req, slot)
         self._slots[slot] = st
         self._prefill_fifo.append(slot)
-        if not self._fused:
-            # park the slot's decode write position past the block
-            # table: until activation, its wasted decode-step writes
-            # null-redirect (zeroed) instead of corrupting the pages
-            # being prefilled. (Fused mode has no device-resident slot
-            # state to park — mid-prefill slots ride the launch as
-            # real prefill rows, idle ones are kernel-skipped.)
-            self._pending_t[slot] = self.max_cache_len
+        # parked until activation: its decode rows must not write into
+        # the pages being prefilled. (Fused mode: mid-prefill slots ride
+        # the launch as real prefill rows, idle ones are kernel-skipped.)
+        self._park_slot(slot)
 
     def _bind_request(self, st, req, slot):
         """Carry the request's scheduling state onto its slot. A
@@ -2073,7 +2094,9 @@ class ContinuousBatchingServer:
                                           out_d)
         self._count_dispatches(1, op="prefill")
         if self._moe_k:
-            self._count_routed(None, used, S * C)
+            # the experts compute the rows of the slots in the plan;
+            # every other slot rides this launch on the sentinel
+            self._count_routed(None, used, len(plan) * C)
         led = self._led
         for slot, start, take in plan:
             st = self._slots[slot]
@@ -2412,6 +2435,7 @@ class ContinuousBatchingServer:
                     2 * sum(leaf.nbytes for leaf
                             in jax.tree_util.tree_leaves(caches1)))
         self._tok = self._tok.at[slot].set(first)
+        self._pending_t.pop(slot, None)     # the park of its last tenant
         self._t = self._t.at[slot].set(T)
         self._count_dispatches(3, op="state_push")    # tok/t/key pushes
         if self._costs is not None:
@@ -2555,13 +2579,16 @@ class ContinuousBatchingServer:
         Larger blocks amortize dispatch at the price of admission
         latency and ≤n-1 wasted steps on slots that finish mid-block —
         wasted rows write out of bounds (dropped) or above the frontier
-        (masked), never corrupting live slots."""
+        (masked), never corrupting live slots. A slot parked on the
+        idle sentinel (``_park_slot``) stays on it through every step
+        of every block."""
         embed_p, step_p, head_p = (self._embed_fn, self._step_fn,
                                    self._head_fn)
         do_sample = self.do_sample
         temperature, top_k, top_p = (self._temperature, self._top_k,
                                      self._top_p)
         n = self.tick_block
+        span = self.max_cache_len
 
         def one(tok, caches, t, keys):
             x = embed_p(tok, t)
@@ -2584,7 +2611,10 @@ class ContinuousBatchingServer:
                 keys, nxt = jax.vmap(samp)(keys, logits)
             else:
                 nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-            return nxt, caches, t + 1, keys
+            # a parked slot stays parked, step after step and tick after
+            # tick: its t rests ON the sentinel instead of counting on
+            # from it (a server left up for months would wrap an int32)
+            return nxt, caches, jnp.minimum(t + 1, span), keys
 
         def decode_tick(tok, caches, t, keys):
             def body(carry, _):
@@ -3025,9 +3055,10 @@ class ContinuousBatchingServer:
         if self._kv is not None:
             self._sync_block_table()
         # ragged mode: activations batched their tok/t/key updates —
-        # push them (and the parked write positions of slots still
-        # prefilling: their wasted decode writes must null-redirect,
-        # not land in the pages being filled) before the decode program
+        # push them, and the sentinel of every slot parked since the
+        # last dispatch (_park_slot: released, rolled back, paused or
+        # still prefilling, whose decode writes must null-redirect, not
+        # land in the pages being filled), before the decode program
         self._flush_slot_state()
         if self._decode_jit is None:
             self._decode_jit = self._build_decode_step()
@@ -3064,19 +3095,24 @@ class ContinuousBatchingServer:
             else (aux, None)
         if b is not None:
             wall = b.mark("emit") - t_launch
+        # rows of slots holding no decoding request still ride the
+        # program, parked on the idle sentinel (_park_slot): the paged
+        # backend sends their writes to the null page, the dense one
+        # drops them out of bounds, and attention, key selection and
+        # the expert FFN skip them
+        rows, live_rows = (self.max_slots * toks.shape[1],
+                           n_active * toks.shape[1])
         self.stats["decode_ticks"] += 1
+        self.stats["decode_rows"] += rows
+        self.stats["decode_live_rows"] += live_rows
+        if tele is not None:
+            tele.on_decode_rows(rows, live_rows)
         if self._moe_k:
-            self._count_routed(route, n_active * toks.shape[1],
-                               self.max_slots * toks.shape[1])
+            self._count_routed(route, live_rows, live_rows)
         decoded = wasted = keys_ctx = keys_sel = 0
         led = self._led
         if led is not None:
-            # rows of slots holding no live decode work still ride the
-            # program: empty slots and mid-prefill slots (parked past
-            # the table so their writes null-redirect; the dense
-            # backend drops them out of bounds — same waste class)
-            led.add("null_redirect",
-                    (self.max_slots - n_active) * toks.shape[1])
+            led.add("null_redirect", rows - live_rows)
         for slot in range(self.max_slots):
             if not self._active[slot]:
                 continue
@@ -3118,10 +3154,9 @@ class ContinuousBatchingServer:
             if wasted:
                 tele.add_wasted_block_tokens(wasted)
             if self._kv is not None:
-                # inactive rows still step; their writes go through an
-                # all-null block table row straight to the null page
-                tele.add_null_writes(
-                    (self.max_slots - n_active) * toks.shape[1])
+                # parked rows still step; each writes one zeroed row to
+                # the null page (their t is past the table)
+                tele.add_null_writes(rows - live_rows)
         if b is not None:
             b.mark("harvest")
         self._harvest()
@@ -3137,7 +3172,8 @@ class ContinuousBatchingServer:
         return n
 
     def _count_routed(self, route, live_rows, rows):
-        """Expert-FFN accounting of one launch: ``rows`` computed,
+        """Expert-FFN accounting of one launch: ``rows`` computed (the
+        rows of the slots that rode it live, chunk padding included),
         ``live_rows`` of them a live token's. ``route`` (decode ticks:
         ``[slots, layers * k]`` expert ids off the token read-back, or
         None) gives the distinct experts the live slots' rows chose,
@@ -3945,12 +3981,10 @@ class ContinuousBatchingServer:
             # re-queues the fifo for a prefill slot), so nothing the
             # device scribbles while paused is ever read
             prior = st.phase
-            self._active[slot] = False
+            self._park_slot(slot)
             st.phase = "migrating"
             if prior == "prefill" and slot in self._prefill_fifo:
                 self._prefill_fifo.remove(slot)
-            if not self._fused:
-                self._pending_t[slot] = self.max_cache_len
             self._migrating[rid] = (slot, t0, prior)
             if self._rec is not None:
                 self._rec.record("migrate_out", rid=rid,
@@ -4070,10 +4104,9 @@ class ContinuousBatchingServer:
                 st.phase = "prefill"
                 if slot not in self._prefill_fifo:
                     self._prefill_fifo.append(slot)
-                if not self._fused:
-                    self._pending_t[slot] = self.max_cache_len
-                # _active stays False until activation, like any
-                # admitted mid-prefill slot
+                # stays parked until activation, like any admitted
+                # mid-prefill slot
+                self._park_slot(slot)
             else:
                 st.phase = "decode"
                 if not self._fused:
@@ -4179,10 +4212,8 @@ class ContinuousBatchingServer:
             st.phase = "prefill"
             st.fill_pos = st.filled = written
             self._prefill_fifo.append(slot)
-            if not self._fused:
-                # park the write cursor on the null page until
-                # activation, like any admitted mid-prefill slot
-                self._pending_t[slot] = self.max_cache_len
+            # parked until activation, like any admitted mid-prefill slot
+            self._park_slot(slot)
         else:
             # prime the decode chain exactly where the source paused
             # it: pending input = last emitted token, write position =
@@ -4364,8 +4395,7 @@ class ContinuousBatchingServer:
             st.fill_pos = st.filled = 0
             st.seed = int(state.get("seed", 0))
             self._slots[slot] = st
-            if not self._fused:
-                self._pending_t[slot] = self.max_cache_len
+            self._park_slot(slot)
             handle = self._next_xfer
             self._next_xfer += 1
             self._staging[handle] = {"slot": slot, "own": list(own),
@@ -4508,7 +4538,7 @@ class ContinuousBatchingServer:
             st = self._slots[slot]
             if st is not None and st.rid == ent["rid"]:
                 self._slots[slot] = None
-                self._active[slot] = False
+                self._park_slot(slot)
                 pages = self._kv.detach_slot(slot)
                 if pages:
                     self._kv.release(pages)

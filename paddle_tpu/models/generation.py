@@ -361,6 +361,13 @@ def _paged_attend(q, pool, layer, bt, t, scale, mesh=None):
     """Decode-step attention through the block table: q [B, 1, nh, hd],
     ``pool`` the whole K/V pools ``{"k", "v"}`` [L, P, pg, kvh*hd] read
     at ``layer``, valid lengths t+1 (cache already written through t).
+    A slot carrying the scheduler's idle sentinel (``t`` past the
+    block-table extent: it holds no decoding request) is handed length
+    0, as ``_paged_prefill_attend`` hands it ``last = -1``: the kernel
+    skips its every page and writes zeros, the fallback masks every
+    position (a finite mean of the null page nobody reads). Handed
+    ``t + 1`` it would be the kernel's dearest row: a sweep over its
+    whole table of null pages.
     Pallas ragged kernel on TPU (per-kv-head-shard launches under
     ``mesh`` — XLA cannot partition a custom call, so the kernel path
     shard_maps itself), bit-exact dense-mirroring gather composition
@@ -370,8 +377,10 @@ def _paged_attend(q, pool, layer, bt, t, scale, mesh=None):
     b = q.shape[0]
     if jnp.ndim(t) == 0:
         t = jnp.full((b,), t, jnp.int32)
-    return paged_attention(q[:, 0], pool["k"], pool["v"], bt, t + 1, scale,
-                           mesh=mesh, layer=layer)[:, None]
+    limit = bt.shape[1] * pool["k"].shape[2]       # tokens a table spans
+    lengths = jnp.where(t < limit, t + 1, jnp.int32(0))
+    return paged_attention(q[:, 0], pool["k"], pool["v"], bt, lengths,
+                           scale, mesh=mesh, layer=layer)[:, None]
 
 
 def _page_write(pool, layer, kv, bt, t, last=None):
@@ -382,15 +391,19 @@ def _page_write(pool, layer, kv, bt, t, last=None):
     updates the donated buffer in place: nothing pool-sized is sliced
     out, copied or written back. Null-page discipline: any position
     past the block-table width is redirected to page 0 with a ZEROED
-    payload, so the wasted decode steps of finished/inactive slots can
-    never corrupt a live slot's pages (the dense analogue relies on
-    out-of-bounds writes being dropped), and padded chunk rows of idle
-    slots — which carry rope's / the position table's out-of-range NaN
-    fill — never store a NaN that would poison every slot's attention
-    through 0-weight reads (every slot's unused block-table entries
-    point at the null page). Positions inside the table but past a
-    slot's allocation land in its NULL_PAGE tail entries — finite
-    garbage the length masks hide.
+    payload. That is where EVERY slot without a decoding request
+    writes: the server parks its offset on the idle sentinel
+    (``max_cache_len``, the table's span) whether it was never used,
+    finished, was cancelled or preempted, or is mid-prefill, so its
+    decode rows can never corrupt a live slot's pages (the dense
+    analogue relies on out-of-bounds writes being dropped), and its
+    padded chunk rows — which carry rope's / the position table's
+    out-of-range NaN fill — never store a NaN that would poison every
+    slot's attention through 0-weight reads (every slot's unused
+    block-table entries point at the null page). The wasted block steps
+    of a LIVE slot past its budget land inside the table but past its
+    allocation, in its NULL_PAGE tail entries — finite garbage the
+    length masks hide.
 
     ``last`` ([B] int32, optional): each slot's last VALID position —
     rows past it are null-redirected zeroed too. The fused tick passes
@@ -799,6 +812,11 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         # an idle slot's offset is parked past the table: its rows are
         # garbage nobody reads, rotated at the last real position
         pos = jnp.minimum(_positions(t, b, s), max_cache_len - 1)
+        # ... and they are sent to no expert: every row of a slot parked
+        # on the sentinel is dead to the routed FFN. The padding rows
+        # INSIDE a live slot's chunk stay live (the program is told no
+        # row count a slot)
+        live = jnp.repeat(jnp.broadcast_to(t < max_cache_len, (b,)), s)
 
         def layer(xx, blk, lc, l):
             xx, lc, h2, kept = _rope_gqa_attn(
@@ -816,7 +834,7 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                 idx, gate = route_topk(rows, blk["router"], top_k,
                                        normalize=norm_topk)
                 y = routed_ffn(rows, idx, gate, p["wg"], p["wu"], p["wd"],
-                               layer=l)
+                               layer=l, live=live)
             aux["route"] = idx.reshape(b, s, top_k)[:, -1]
             return xx + y.reshape(xx.shape), lc, aux
 

@@ -14,8 +14,11 @@ row), equal scores go to the LOWER position (15 more passes over the
 positions), positions past the row's own rank last. What comes out is a
 mask over the context, applied to dense attention scores; the rows a
 slot's block table spans are gathered once a slot and layer. A slot
-with nothing to do (its offset parked past the table) is skipped at run
-time by a ``cond``.
+with nothing to do is skipped at run time by a ``cond`` on its offset:
+the server parks EVERY slot that holds no request for this launch past
+the table (never used, finished, cancelled, preempted, mid-prefill in a
+decode tick, decoding in a prefill launch), so the gathers and the
+search are paid for the slots that have rows.
 
 Nothing here materialises ``[rows, indexer heads, context]``: rows go
 in tiles of ``ROW_TILE`` and the sum over indexer heads is a loop that
@@ -159,8 +162,10 @@ def sparse_paged_attention(q, qi, wi, pool, layer, bt, t, topk, scale):
     nh, hd], ``qi`` [B, s, J, D], ``wi`` [B, s, J], ``pool`` the page
     pools ``{"k", "v", "ki"}`` [L, P, pg, lanes] read at ``layer``,
     ``bt`` [B, pages], ``t`` [B] the rows' first positions. A slot
-    whose ``t`` lies past its table is idle: zeros, at no cost.
-    Returns ``(out [B, s, nh, hd], kept [B, s])``."""
+    whose ``t`` lies past its table is idle (the scheduler's sentinel,
+    which every slot without a request for this launch carries): zeros
+    and ``kept`` 0, and neither its pages gathered nor its keys
+    searched. Returns ``(out [B, s, nh, hd], kept [B, s])``."""
     b, s, nh, hd = q.shape
     pg = pool["k"].shape[2]
     span = bt.shape[1] * pg

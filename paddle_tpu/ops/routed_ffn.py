@@ -57,12 +57,48 @@ def _mm(x, w):
     return x @ w
 
 
-def routed_ffn(h, idx, gate, wg, wu, wd, layer=None, tile=None):
+def _layout(flat, experts, t, n_tiles):
+    """Tile-aligned layout of the (row, expert) pairs ``flat`` [m]
+    (expert ids; ``experts`` marks a dead pair): every expert's group
+    starts on a multiple of ``t``. Returns ``(order, dst, tile_expert,
+    used)``: the pair ids sorted by expert, where sorted pair j sits in
+    the layout, the expert of every tile, and the number of tiles that
+    hold a live pair. The dead pairs sort last and make no group: they
+    sit one after another behind the last group, on rows no tile that
+    runs reaches (the groups' padding is under ``experts * t`` rows and
+    the layout has ``experts * t`` to spare), and read back the zero
+    the output was made of."""
+    m = flat.shape[0]
+    order = jnp.argsort(flat, stable=True)   # pair ids, by expert
+    e_sorted = flat[order]
+    counts = jnp.zeros((experts + 1,), jnp.int32).at[flat].add(1)
+    counts = counts.at[experts].set(0)       # the dead: counted nowhere
+    padded = -(-counts // t) * t
+    p_end = jnp.cumsum(padded)
+    p_start = p_end - padded
+    start = jnp.cumsum(counts) - counts
+    dst = p_start[e_sorted] + (jnp.arange(m, dtype=jnp.int32)
+                               - start[e_sorted])
+    tile_expert = jnp.minimum(
+        jnp.searchsorted(p_end, jnp.arange(n_tiles, dtype=jnp.int32) * t,
+                         side="right"), experts - 1).astype(jnp.int32)
+    return order, dst, tile_expert, p_end[-1] // t
+
+
+def routed_ffn(h, idx, gate, wg, wu, wd, layer=None, tile=None, live=None):
     """``sum_j gate[n, j] * SwiGLU_{idx[n, j]}(h[n])`` for rows ``h``
     [N, H]. ``wg``/``wu`` are ``[L, E, H, F]`` and ``wd`` ``[L, E, F,
     H]`` indexed at ``layer`` (or ``[E, ...]`` with ``layer`` None);
-    int8 pairs work too. Rows are independent: a NaN row (an idle
-    slot's) stays in its own output row."""
+    int8 pairs work too. Rows are independent: a NaN row stays in its
+    own output row.
+
+    ``live`` ([N] bool, default every row): a dead row (an idle slot's
+    garbage) joins no expert's group. Its pairs sort behind every live
+    pair and add to no count, so the loop's trip count covers the live
+    rows' tiles only and no expert is read for a dead row's sake; its
+    output row is zero whatever it held. A live row meets the same
+    weights in the same arithmetic as without the mask. Shapes stay
+    static: the tile and the layout's length follow ``N * k``."""
     n, hidden = h.shape
     k = idx.shape[1]
     main = wg[0] if isinstance(wg, tuple) else wg
@@ -71,24 +107,15 @@ def routed_ffn(h, idx, gate, wg, wu, wd, layer=None, tile=None):
     t = int(tile or _tile_rows(m, experts))
     n_tiles = -(-m // t) + experts           # every group padded to t
     flat = idx.reshape(m)
-    order = jnp.argsort(flat, stable=True)   # pair ids, by expert
-    e_sorted = flat[order]
-    counts = jnp.zeros((experts,), jnp.int32).at[flat].add(1)
-    padded = -(-counts // t) * t
-    p_end = jnp.cumsum(padded)
-    p_start = p_end - padded
-    start = jnp.cumsum(counts) - counts
-    # where sorted pair j sits in the tile-aligned layout
-    dst = p_start[e_sorted] + (jnp.arange(m, dtype=jnp.int32)
-                               - start[e_sorted])
+    if live is not None:
+        # dead pairs: expert id ``experts``, which sorts last
+        flat = jnp.where(jnp.repeat(live, k), flat, experts)
+        gate = jnp.where(live[:, None], gate, 0.0)
+    order, dst, tile_expert, used = _layout(flat, experts, t, n_tiles)
     # the layout's rows, gathered: slot -> source row (n = a zero row)
     src = jnp.full((n_tiles * t,), n, jnp.int32).at[dst].set(
         (order // k).astype(jnp.int32))
     rows = jnp.concatenate([h, jnp.zeros((1, hidden), h.dtype)])[src]
-    tile_expert = jnp.minimum(
-        jnp.searchsorted(p_end, jnp.arange(n_tiles, dtype=jnp.int32) * t,
-                         side="right"), experts - 1).astype(jnp.int32)
-    used = p_end[-1] // t
 
     def body(i, out):
         e = tile_expert[i]

@@ -340,13 +340,23 @@ class ServerTelemetry:
             "(decode / prefill / state_push / block_table / "
             "page_gather / page_scatter)", labelnames=("op",))
         self._disp_children = {}
+        # how full the decode ticks ran: the rows they carried (slots
+        # x block a tick) and those of a slot that was decoding; the
+        # rest rode parked on the idle sentinel
+        rows = r.counter(
+            "serving_decode_rows_total",
+            "Rows of the decode ticks: every row a tick carries (slots "
+            "x block), and those of a decoding slot",
+            labelnames=("kind",))
+        self._c_rows = rows.labels(kind="launched")
+        self._c_rows_live = rows.labels(kind="live")
         # what a routed-expert / key-selecting model's launches did
         # (the server counts them; models with neither leave these 0)
         moe = r.counter(
             "serving_moe_rows_total",
-            "Rows sent through the expert FFN: every row a launch "
-            "computes (slots x width), and those of a live token",
-            labelnames=("kind",))
+            "Rows the expert FFN computed: those of the slots that rode "
+            "a launch live (a parked slot's join no expert's group), "
+            "and those of a live token", labelnames=("kind",))
         self._c_moe_rows = moe.labels(kind="launched")
         self._c_moe_live = moe.labels(kind="live")
         self._c_moe_touched = r.counter(
@@ -559,9 +569,17 @@ class ServerTelemetry:
         if decode_tokens:
             self._c_tok_decode.inc(decode_tokens)
 
+    def on_decode_rows(self, rows, live):
+        """One decode tick's rows: all it carried, and those of a
+        decoding slot."""
+        if self.enabled:
+            self._c_rows.inc(rows)
+            self._c_rows_live.inc(live)
+
     def on_moe_rows(self, rows, live, touched=0):
-        """One launch's expert-FFN rows: all of them, the live ones,
-        and (decode ticks) the distinct experts the live ones chose."""
+        """One launch's expert-FFN rows: those computed (the live
+        slots'), a live token's, and (decode ticks) the distinct
+        experts the live ones chose."""
         if not self.enabled:
             return
         self._c_moe_rows.inc(rows)

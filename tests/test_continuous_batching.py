@@ -341,3 +341,132 @@ class TestContinuousBatching:
         for rid, prompt in zip(rids, prompts):
             np.testing.assert_array_equal(outs[rid],
                                           _solo(model, prompt, 5))
+
+
+# ------------------------------------------------------- the idle sentinel
+CACHE = 64
+
+
+def _stub_server(backend, tick_block, **kw):
+    from _serving_stub import StubModel
+    if backend == "paged":
+        kw.setdefault("num_pages", 33)
+        kw.update(cache_backend="paged", page_size=8)
+    kw.setdefault("max_slots", 4)
+    return ContinuousBatchingServer(StubModel(), max_cache_len=CACHE,
+                                    tick_block=tick_block, **kw)
+
+
+def _watch_dispatches(srv):
+    """Record, at every decode dispatch, the ``t`` the program is handed
+    and the host's active set."""
+    seen = []
+    real = srv._decode_jit = srv._build_decode_step()
+
+    def spy(tok, caches, t, keys):
+        seen.append((np.asarray(t).copy(), srv._active.copy()))
+        return real(tok, caches, t, keys)
+
+    srv._decode_jit = spy
+    return seen
+
+
+def _assert_parked(seen, tick_block, slots=4):
+    """Every slot outside the active set rode parked, every slot in it
+    at a real position; and the counters say the same."""
+    assert seen
+    for t, active in seen:
+        assert (t[~active] >= CACHE).all(), (t, active)
+        assert (t[active] < CACHE).all(), (t, active)
+    return (len(seen) * slots * tick_block,
+            sum(int(a.sum()) for _, a in seen) * tick_block)
+
+
+@pytest.mark.parametrize("tick_block", [1, 4])
+@pytest.mark.parametrize("backend", ["dense", "paged"])
+class TestIdleSentinel:
+    """A slot that holds no decoding request reads ``t >=
+    max_cache_len`` on the device at every decode dispatch, whatever
+    took it out of the active set; the tick programs read liveness off
+    that (tests/test_paged_attention.py, tests/test_keye_vl.py)."""
+
+    def test_never_used_finished_and_cancelled_slots(self, backend,
+                                                     tick_block):
+        from _serving_stub import stub_tokens
+        srv = _stub_server(backend, tick_block)
+        seen = _watch_dispatches(srv)
+        # before anything was admitted every slot is parked
+        assert (np.asarray(srv._t) == CACHE).all()
+        prompts = [np.arange(3 + i, dtype=np.int32) % 16 for i in range(3)]
+        short = srv.submit(prompts[0], max_new_tokens=3)
+        gone = srv.submit(prompts[1], max_new_tokens=30)
+        long = srv.submit(prompts[2], max_new_tokens=20)
+        for _ in range(3):
+            srv.step()
+        assert srv.cancel(gone)            # mid-flight: slot 1 frees
+        outs = srv.run()
+        np.testing.assert_array_equal(outs[short],
+                                      stub_tokens(prompts[0], 3))
+        np.testing.assert_array_equal(outs[long],
+                                      stub_tokens(prompts[2], 20))
+        rows, live = _assert_parked(seen, tick_block)
+        # slot 3 was never used; the finished and the cancelled slot
+        # were seen parked while the long request still decoded
+        assert all(t[3] >= CACHE for t, _ in seen)
+        tail_t, tail_active = seen[-1]
+        assert tail_active.sum() == 1 and (tail_t >= CACHE).sum() == 3
+        s = srv.stats
+        assert s["decode_ticks"] == len(seen)
+        assert (s["decode_rows"], s["decode_live_rows"]) == (rows, live)
+        assert 0 < live < rows
+        # and once drained, every slot rests ON the sentinel (the last
+        # park rides the state push of the next decode dispatch)
+        srv._flush_slot_state()
+        assert (np.asarray(srv._t) == CACHE).all()
+
+    def test_parked_slot_stays_parked_and_refills_exactly(self, backend,
+                                                          tick_block):
+        """One slot, three tenants in turn: each decodes the tokens a
+        fresh server gives it, and between them (and across many blocks
+        of another slot's decoding) the parked ``t`` never moves."""
+        from _serving_stub import stub_tokens
+        srv = _stub_server(backend, tick_block, max_slots=2)
+        seen = _watch_dispatches(srv)
+        anchor = np.arange(5, dtype=np.int32)
+        ra = srv.submit(anchor, max_new_tokens=40)   # keeps slot 0 busy
+        tenants = [np.arange(4 + i, dtype=np.int32)[::-1] % 16
+                   for i in range(3)]
+        for p in tenants:
+            rid = srv.submit(p, max_new_tokens=5)
+            while rid not in srv._results:
+                srv.step()
+                if not srv._active[1]:
+                    # parked exactly on the sentinel, not past it
+                    srv._flush_slot_state()
+                    assert int(np.asarray(srv._t)[1]) == CACHE
+            np.testing.assert_array_equal(srv._results[rid],
+                                          stub_tokens(p, 5))
+        outs = srv.run()
+        np.testing.assert_array_equal(outs[ra], stub_tokens(anchor, 40))
+        _assert_parked(seen, tick_block, slots=2)
+
+
+@pytest.mark.parametrize("tick_block", [1, 4])
+def test_preempted_slot_is_parked(tick_block):
+    """Optimistic admission over a pool too small: victims are torn
+    down mid-decode, park on the sentinel, and replay bit-exactly."""
+    from _serving_stub import stub_tokens
+    srv = _stub_server("paged", tick_block, num_pages=9,
+                       admission="optimistic")
+    seen = _watch_dispatches(srv)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 16, (int(k),)).astype(np.int32)
+               for k in rng.integers(4, 12, (8,))]
+    rids = [srv.submit(p, max_new_tokens=28) for p in prompts]
+    outs = srv.run()
+    assert srv.stats["preemptions"] > 0
+    for rid, p in zip(rids, prompts):
+        np.testing.assert_array_equal(outs[rid], stub_tokens(p, 28))
+    rows, live = _assert_parked(seen, tick_block)
+    assert (srv.stats["decode_rows"], srv.stats["decode_live_rows"]) \
+        == (rows, live)

@@ -15,7 +15,8 @@ reference ``perfbench/reference_keye_vl2.py``:
 (d) the negative control: the reference with the selection left out
     differs from the reference by far more than (b)'s tolerance;
 (e) the router's top-k for k in {1, 2, 8} against a sort, and the routed
-    FFN against every chosen expert applied one by one.
+    FFN against every chosen expert applied one by one; rows masked dead
+    join no expert's group and leave the live rows bit for bit.
 
 TOLERANCE of (a) and (b): 2e-4 absolute on logits whose spread (std) is
 about 0.17. Both sides are float32 under ``highest`` matmul precision
@@ -40,7 +41,7 @@ from paddle_tpu.inference.kv_tier import HostTier
 from paddle_tpu.models.keye_vl import (KeyeVL2Config, KeyeVL2ForCausalLM,
                                        keye_vl2_tiny)
 from paddle_tpu.ops.key_selection import topk_mask
-from paddle_tpu.ops.routed_ffn import route_topk, routed_ffn
+from paddle_tpu.ops.routed_ffn import _layout, route_topk, routed_ffn
 from perfbench import reference_keye_vl2 as ref
 from perfbench.families import keye_vl2 as family
 
@@ -285,6 +286,25 @@ def test_counters_in_stats_and_registry(model):
         == s["attn_keys_selected"]
 
 
+def test_one_live_slot_of_four_pays_for_its_own_rows(model, cold):
+    """One request on a server of four slots: the three parked slots'
+    rows join no expert's group and select no key. ``moe_rows`` counts
+    what the experts computed (the prompt's one chunk of 16 with its 4
+    rows of padding, then a row a decode tick), ``decode_rows`` the 4
+    rows every tick carried, and the keys kept are the live row's own."""
+    srv = _server(model, max_slots=4, num_pages=33, auto_prefix_cache=False)
+    prompt = _ids(12, seed=61)
+    rid = srv.submit(prompt, max_new_tokens=5)
+    out = np.asarray(srv.run()[rid])
+    np.testing.assert_array_equal(out, cold(prompt, 5))
+    s = srv.stats
+    assert s["decode_ticks"] == 4
+    assert (s["decode_rows"], s["decode_live_rows"]) == (16, 4)
+    assert (s["moe_rows"], s["moe_live_rows"]) == (16 + 4, 12 + 4)
+    assert s["attn_keys_context"] == 2 * sum(12 + j for j in range(1, 5))
+    assert s["attn_keys_selected"] == 2 * 4 * TOPK
+
+
 def test_kept_keys_are_counted_where_the_mask_is_made(model, monkeypatch):
     """A program that left the selection out would read 100: the count
     is the mask's own sum, not arithmetic on lengths."""
@@ -368,6 +388,48 @@ def test_routed_ffn_against_each_expert_applied(k):
             want[row] += float(gate[row, j]) * np.asarray(
                 (jax.nn.silu(a) * b) @ wd[2, e])
     np.testing.assert_allclose(np.asarray(got), want, atol=1e-5)
+
+
+@pytest.mark.parametrize("k", [1, 2, 8])
+def test_routed_ffn_dead_rows_join_no_group(k):
+    """With a liveness mask the live rows equal the unmasked call bit
+    for bit, a dead row (NaN, as an idle slot's are) comes out zero, and
+    the tiles that run are those of a call on the live rows alone."""
+    rng = np.random.default_rng(20 + k)
+    n, hid, experts, width, tile = 21, 16, 12, 10, 4
+    h = rng.normal(size=(n, hid)).astype(np.float32)
+    live = rng.random(n) < 0.4
+    live[:2] = True, False
+    router = jnp.asarray(rng.normal(size=(hid, experts)), jnp.float32)
+    wg, wu = (jnp.asarray(rng.normal(size=(experts, hid, width)) * 0.3,
+                          jnp.float32) for _ in range(2))
+    wd = jnp.asarray(rng.normal(size=(experts, width, hid)) * 0.3,
+                     jnp.float32)
+    idx, gate = route_topk(jnp.asarray(h), router, k)
+    plain = np.asarray(jax.jit(
+        lambda hh: routed_ffn(hh, idx, gate, wg, wu, wd, tile=tile))(
+            jnp.asarray(h)))
+    h[~live] = np.nan
+    dead_idx, dead_gate = route_topk(jnp.asarray(h), router, k)
+    got = np.asarray(jax.jit(
+        lambda hh, m: routed_ffn(hh, dead_idx, dead_gate, wg, wu, wd,
+                                 tile=tile, live=m))(jnp.asarray(h),
+                                                     jnp.asarray(live)))
+    np.testing.assert_array_equal(got[live], plain[live])
+    assert (got[~live] == 0).all()
+    # the layout: the masked call's tiles against the live rows' own
+    m = n * k
+    n_tiles = -(-m // tile) + experts
+    masked = jnp.where(jnp.repeat(jnp.asarray(live), k),
+                       idx.reshape(m), experts)
+    alone = idx[np.where(live)[0]].reshape(-1)
+    *_, te_masked, used_masked = _layout(masked, experts, tile, n_tiles)
+    *_, te_alone, used_alone = _layout(
+        alone, experts, tile, -(-alone.shape[0] // tile) + experts)
+    *_, used_all = _layout(idx.reshape(m), experts, tile, n_tiles)
+    assert int(used_masked) == int(used_alone) < int(used_all)
+    np.testing.assert_array_equal(np.asarray(te_masked)[:int(used_masked)],
+                                  np.asarray(te_alone)[:int(used_alone)])
 
 
 @pytest.mark.parametrize("k", [1, 8, 64])

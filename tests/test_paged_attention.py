@@ -113,6 +113,67 @@ class TestPagedAttentionKernel:
         np.testing.assert_array_equal(np.asarray(got),
                                       np.asarray(want[:, 0]))
 
+    @pytest.mark.parametrize("path", ["interpret", "fallback"])
+    def test_idle_rows_cost_the_live_rows_nothing(self, path):
+        """A slot at length 0 (the idle sentinel, as ``_paged_attend``
+        hands it over) attends over nothing: the kernel skips its every
+        page and writes zeros, the fallback masks every position and
+        stays finite. The live rows come out bit for bit as from a call
+        that holds no idle row."""
+        nh, kvh, hd, P, pg, maxp = 4, 2, 32, 12, 8, 4
+        q = _rand(5, nh, hd, seed=11)
+        kp = _rand(P, pg, kvh, hd, seed=12)
+        vp = _rand(P, pg, kvh, hd, seed=13)
+        # the null page holds whatever parked rows wrote: make it loud
+        kp, vp = kp.at[0].set(1e3), vp.at[0].set(-1e3)
+        rng = np.random.RandomState(14)
+        bt = np.stack([rng.choice(np.arange(1, P), maxp, replace=False)
+                       for _ in range(5)]).astype(np.int32)
+        idle = np.array([True, False, True, False, True])
+        bt[idle] = 0                       # an idle slot's table: null
+        lengths = np.where(idle, 0, [0, 13, 0, maxp * pg, 0]).astype(
+            np.int32)
+
+        def run(rows):
+            args = (q[rows], kp, vp, jnp.asarray(bt[rows]),
+                    jnp.asarray(lengths[rows]), 1.0 / np.sqrt(hd))
+            if path == "interpret":
+                return np.asarray(pa._paged_attention_pallas(
+                    *args, interpret=True))
+            return np.asarray(pa._ref_paged_attention(*args))
+
+        out = run(np.arange(5))
+        np.testing.assert_array_equal(out[~idle], run(np.where(~idle)[0]))
+        if path == "interpret":
+            assert (out[idle] == 0).all()
+        else:
+            assert np.isfinite(out[idle]).all()
+
+    def test_paged_attend_hands_parked_slots_length_zero(self, monkeypatch):
+        """``generation._paged_attend``: a slot whose ``t`` sits at or
+        past the table's span gets length 0, every other ``t + 1``."""
+        from paddle_tpu.models import generation
+        L, P, pg, kvh, hd, maxp = 2, 9, 8, 2, 16, 4
+        pool = {"k": _rand(L, P, pg, kvh * hd, seed=15),
+                "v": _rand(L, P, pg, kvh * hd, seed=16)}
+        bt = jnp.asarray(np.array([[1, 2, 3, 4], [0] * 4, [5, 6, 7, 8],
+                                   [0] * 4], np.int32))
+        t = jnp.asarray(np.array([9, maxp * pg, 30, maxp * pg + 7],
+                                 np.int32))
+        q = _rand(4, 1, 4, hd, seed=17)
+        seen = {}
+        real = pa.paged_attention
+
+        def spy(q, k, v, bt, lengths, *a, **kw):
+            seen["lengths"] = np.asarray(lengths)
+            return real(q, k, v, bt, lengths, *a, **kw)
+
+        monkeypatch.setattr(pa, "paged_attention", spy)
+        out = generation._paged_attend(q, pool, 1, bt, t, 0.25)
+        np.testing.assert_array_equal(seen["lengths"], [10, 0, 31, 0])
+        assert out.shape == (4, 1, 4, hd) and np.isfinite(
+            np.asarray(out)).all()
+
 
 @pytest.mark.slow
 class TestPagedAttentionOnChip:

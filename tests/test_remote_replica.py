@@ -272,7 +272,12 @@ class TestRouterOverRemote:
                                telemetry=True)
         fleet["routers"].append(router)
         router.start(poll_interval=0.02)
-        rids = [(router.submit(_prompt(2, i + 1), max_new_tokens=4), i)
+        # budgets long enough that host0 still holds work when it is
+        # severed: four requests of 4 tokens could all finish inside
+        # the 20 ms (a loaded box oversleeps), and then nothing is left
+        # to evacuate
+        new = 48
+        rids = [(router.submit(_prompt(2, i + 1), max_new_tokens=new), i)
                 for i in range(8)]
         time.sleep(0.02)
         host0.sever()
@@ -281,7 +286,7 @@ class TestRouterOverRemote:
             outs[rid] = (router.wait(rid, timeout=30), _prompt(2, i + 1))
         full = partial = 0
         for rid, (got, p) in outs.items():
-            exp = stub_tokens(p, 4)
+            exp = stub_tokens(p, new)
             if np.array_equal(got, exp):
                 full += 1
             else:

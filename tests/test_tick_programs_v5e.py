@@ -130,7 +130,8 @@ def _decode_tick(cfg, num_pages):
         b = _paged_bundle(cfg, weights, num_pages)
         srv = types.SimpleNamespace(
             _embed_fn=b[1], _step_fn=b[2], _head_fn=b[3], do_sample=False,
-            _temperature=1.0, _top_k=0, _top_p=1.0, tick_block=1)
+            _temperature=1.0, _top_k=0, _top_p=1.0, tick_block=1,
+            max_cache_len=CACHE_LEN)
         tick = ContinuousBatchingServer._build_decode_step(srv)
         return tick._fn(tok, caches, t, keys)
 
@@ -314,7 +315,8 @@ def test_keye_decode_tick_compiles_and_fits(one_chip, as_on_chip):
         b = _keye_bundle(cfg, weights)
         srv = types.SimpleNamespace(
             _embed_fn=b[1], _step_fn=b[2], _head_fn=b[3], do_sample=False,
-            _temperature=1.0, _top_k=0, _top_p=1.0, tick_block=1)
+            _temperature=1.0, _top_k=0, _top_p=1.0, tick_block=1,
+            max_cache_len=KEYE["cache_len"])
         return ContinuousBatchingServer._build_decode_step(srv)._fn(
             tok, caches, t, keys)
 
