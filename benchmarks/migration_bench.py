@@ -116,8 +116,7 @@ def _run_mode(args, mode):
                     # home rather than skewing the replay accounting
                     src.migrate_abort(rid)
                     print(f"  note: request {i} still mid-prefill at "
-                          f"the drain point; skipped "
-                          f"(disagg_bench prices the prefill handoff)")
+                          f"the drain point; skipped")
                     continue
                 carried[i] = tgt.migrate_in(state, payloads,
                                             on_token=sink(i))

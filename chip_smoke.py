@@ -10,8 +10,7 @@ full width of GPT-2 345M (24 layers, hidden 1024, 16 heads x 64, vocab
   composition under ``jax.default_matmul_precision("highest")``.
 - ``serve``: ``ContinuousBatchingServer(cache_backend="paged")`` (ragged
   prefill + split tick) answering mixed-length requests, checked on logits
-  against a plain f32 forward; then the same requests under
-  ``serving_mode="fused"``.
+  against a plain f32 forward.
 - ``train``: ``jit.train_step_fn(model, ce, AdamW)`` at B=8, S=1024.
 - ``mesh4`` (only where JAX reports >= 4 devices): the serve phase over an
   ``mp=4`` mesh plus ``parallel.parallel_train_step``.
@@ -353,38 +352,6 @@ def kernel_ragged(c):
                      "as zeros")
 
 
-def kernel_fused(c):
-    """Fused tick: prefill chunks, decode rows and an idle slot in one
-    launch over the live-page schedule."""
-    import jax.numpy as jnp
-
-    from paddle_tpu.ops.pallas import fused_tick as ft
-    from paddle_tpu.ops.pallas.ragged_prefill import _QUERY_TILE
-    pg, T = c.pg, c.T
-    C = 2 * _QUERY_TILE
-    t0 = np.array([0, 3 * pg + 2, T, pg, 5 * pg - 1, 0, 40, pg - 1][:c.S],
-                  np.int32)
-    take = np.array([C, 1, 0, C - 3, 1, pg // 2 + 1, 1, C][:c.S], np.int32)
-    dec = np.array([0, 1, 0, 0, 1, 0, 1, 0][:c.S], np.int32)
-    last = np.where(take > 0, t0 + take - 1, -1).astype(np.int32)
-    k, v, bt = c.pool(np.maximum(last + 1, 0))
-    live_pages = int(last.max()) // pg + 1
-    W = min(c.maxp, 1 << (live_pages - 1).bit_length())
-    ss, sp, _ = ft.build_schedule(last, pg, n_slots=c.S)
-    q = c.normal(c.S, C, c.nh, c.hd)
-    args = (q, k, v, bt[:, :W], jnp.asarray(t0), jnp.asarray(last),
-            jnp.asarray(dec), jnp.asarray(ss), jnp.asarray(sp))
-    got = c.run(lambda q, k, v, bt, t0, last, dec, ss, sp:
-                ft.fused_tick_attention(q, k, v, bt, t0, last, dec, ss, sp,
-                                        sm_scale=c.scale), args)
-    want = c.ref(lambda q, k, v, bt, t0, dec: ft._ref_fused_tick(
-        q, k, v, bt, t0, dec, c.scale), q, k, v, args[3], args[4], args[6])
-    live = _live_rows(C, take)
-    c.close(f"fused_tick C={C} W={W} G={len(ss)}",
-            jnp.where(live, got.astype(jnp.float32), 0.0),
-            jnp.where(live, want, 0.0))
-
-
 def kernel_flash(c):
     """Flash attention at the trainer's geometry: forward and the three
     gradients against a seeded cotangent."""
@@ -459,14 +426,8 @@ def kernel_cases(P, rehearse):
 def phase_kernels(P, rehearse):
     bad = []
     for case in kernel_cases(P, rehearse):
-        for kernel in (kernel_paged, kernel_ragged, kernel_flash,
-                       kernel_fused):
-            try:
-                kernel(case)
-            except NotImplementedError as e:
-                # a kernel may refuse this platform outright (the fused
-                # tick on a real TPU, ROADMAP A1); it may not fall back
-                say(f"    {kernel.__name__}[{case.name}]: refused: {e}")
+        for kernel in (kernel_paged, kernel_ragged, kernel_flash):
+            kernel(case)
         bad += case.bad
     bad += kernel_qmm(P, rehearse)
     check(not bad, "; ".join(bad))
@@ -544,12 +505,14 @@ def check_tokens(name, ref_logits, prompt, emitted):
           f"the f32 reference's maximum (margin {LOGIT_MARGIN_STD})")
 
 
-def inspect_programs(cat, weight_bytes, pool_bytes, expect, rehearse):
+def inspect_programs(cat, weight_bytes, pool_bytes, rehearse):
     """Every serving program the catalog compiled: generated code under
     CODE_SHARE_MAX of the weights, temporaries under half the page pool
     (a tick that copies, slices or relays out the pool shows up as a
     pool-sized temp: tests/test_tick_programs_v5e.py holds the same
-    here, without the chip), and the Mosaic calls it should hold."""
+    here, without the chip), and a Mosaic call in every decode and every
+    prefill program (the prefill program loops over its query tiles: one
+    kernel call)."""
     seen = {}
     for op, prog in cat.programs():
         exe = prog.executable
@@ -572,20 +535,17 @@ def inspect_programs(cat, weight_bytes, pool_bytes, expect, rehearse):
               f"{op}: temp {mem.temp_size_in_bytes} B is over half the "
               f"page pool ({pool_bytes} B): the program holds a copy of "
               f"the pool instead of updating it in place")
-    for op, at_least in expect.items():
-        base = op.split("_mp")[0]
-        got = [c for o, cs in seen.items() if o.split("_mp")[0] == base
+    for op in ("decode", "prefill"):
+        got = [c for o, cs in seen.items() if o.split("_mp")[0] == op
                for c in cs]
         check(got, f"no {op!r} program was compiled")
         if not rehearse:
-            check(min(got) >= at_least,
-                  f"{op}: a compiled program holds {min(got)} Mosaic "
-                  f"calls, expected >= {at_least}: a kernel gave way to "
-                  f"its reference")
+            check(min(got) >= 1,
+                  f"{op}: a compiled program holds no Mosaic call: a "
+                  f"kernel gave way to its reference")
 
 
-def serve_once(P, model, mode, mesh, rehearse, watch, ref_logits,
-               also_resident):
+def serve_once(P, model, mesh, rehearse, watch, ref_logits, also_resident):
     from paddle_tpu.inference import ContinuousBatchingServer
     from paddle_tpu.telemetry import CostCatalog
 
@@ -593,11 +553,11 @@ def serve_once(P, model, mode, mesh, rehearse, watch, ref_logits,
     srv = ContinuousBatchingServer(
         model, cache_backend="paged", max_slots=P["slots"],
         max_cache_len=P["cache_len"], page_size=P["page"],
-        serving_mode=mode, mesh=mesh, costs=cat)
+        mesh=mesh, costs=cat)
     check(srv.prefill_mode == "ragged", "ragged prefill is not the default")
     (w_tree,) = model._pt_stacked_weights.values()
     w_bytes, pool_bytes = tree_bytes(w_tree), tree_bytes(srv._caches["pool"])
-    say(f"  serve[{mode}]: stacked weights {w_bytes / 2**30:.3f} GiB (one "
+    say(f"  serve: stacked weights {w_bytes / 2**30:.3f} GiB (one "
         f"tree, shared by the dense and paged bundles), pool "
         f"{pool_bytes / 2**30:.3f} GiB")
     if mesh is not None:
@@ -640,13 +600,12 @@ def serve_once(P, model, mode, mesh, rehearse, watch, ref_logits,
         srv.stop(drain=True, timeout=900.0)
     n_all, _ = watch.since(mark)
     after = dict(cat.compiles())
-    say(f"  serve[{mode}]: serving-program compiles warm-up {warm_compiles}"
+    say(f"  serve: serving-program compiles warm-up {warm_compiles}"
         f" -> after {after}; every executable built after warm-up "
         f"(eager ops included): {n_all}")
-    if mode == "split":
-        check(after == warm_compiles,
-              f"a serving program compiled after the warm-up wave: "
-              f"{warm_compiles} -> {after}")
+    check(after == warm_compiles,
+          f"a serving program compiled after the warm-up wave: "
+          f"{warm_compiles} -> {after}")
     hits = srv.stats["prefix_auto_hits"]
     check(hits == 2, f"exactly the two shared-prefix requests should hit "
                      f"the prefix cache (prefix_auto_hits={hits})")
@@ -654,21 +613,18 @@ def serve_once(P, model, mode, mesh, rehearse, watch, ref_logits,
                                  "serving program ahead of time")
     free, live, pinned, cached = srv.pool_balance()
     check(live == 0, f"pages leaked: pool_balance() live == {live}")
-    say(f"  serve[{mode}]: prefix hits {hits} "
+    say(f"  serve: prefix hits {hits} "
         f"({srv.stats['prefix_auto_hit_tokens']} tokens), pool free={free} "
         f"live={live} pinned={pinned} cached={cached}, "
         f"dispatches {srv.stats['tick_dispatches']} ticks / "
         f"{srv.stats['prefill_dispatches']} prefill")
     for (tag, i), (p, out) in results.items():
         if tag != "warm-tail":
-            check_tokens(f"{mode}/{tag}#{i}", ref_logits, p, out)
-    # the prefill program loops over its query tiles: one kernel call
-    expect = ({"decode": 1, "prefill": 1} if mode == "split"
-              else {"fused": 1})
-    inspect_programs(cat, w_bytes, pool_bytes, expect, rehearse)
+            check_tokens(f"{tag}#{i}", ref_logits, p, out)
+    inspect_programs(cat, w_bytes, pool_bytes, rehearse)
     resident = dict({"stacked weights": w_bytes, "pool": pool_bytes},
                     **also_resident)
-    mem_line(f"serve[{mode}]", "; known residents: " + " + ".join(
+    mem_line("serve", "; known residents: " + " + ".join(
         f"{k} {b / 2**30:.3f}" for k, b in resident.items())
         + f" = {sum(resident.values()) / 2**30:.3f} GiB")
     del srv, cat, w_tree
@@ -684,20 +640,7 @@ def phase_serve(P, phases, rehearse, watch, mesh=None, label="serve"):
     also = {"the model's own parameters": own,
             "their f32 copy for the reference": 2 * own}
     with phases.run(f"{label}-split"):
-        serve_once(P, model, "split", mesh, rehearse, watch, ref_logits,
-                   also)
-    if mesh is None:
-        # fused+mesh is a documented refusal (ROADMAP A8)
-        with phases.run(f"{label}-fused"):
-            try:
-                serve_once(P, model, "fused", None, rehearse, watch,
-                           ref_logits, also)
-            except NotImplementedError as e:
-                # the server may refuse the mode on this platform at
-                # construction (ROADMAP A1); what it may not do is serve
-                # it through the XLA reference — the Mosaic-call count in
-                # inspect_programs would fail that
-                say(f"  serve[fused]: refused: {e}")
+        serve_once(P, model, mesh, rehearse, watch, ref_logits, also)
     model.reset_generate_cache()
     del model, ref_logits
     gc.collect()

@@ -35,16 +35,6 @@ from ..telemetry.serving import TickBoundary
 
 __all__ = ["ContinuousBatchingServer", "PreemptionPolicy", "PoolBalance"]
 
-# Process-wide cache of jitted fused-tick programs, keyed by (bundle
-# entry, sampling params): N servers over the same model share one
-# compile per geometry point instead of re-tracing per instance.
-# Bounded (oldest-out, like the decode-bundle LRU in generation.py):
-# each entry's fused_fn closes over a full stacked weight copy, so an
-# unbounded cache would pin every model a long-lived process ever
-# served. 8 covers a replica fleet's greedy + sampled pairs.
-_FUSED_STEP_CACHE = {}
-_FUSED_STEP_CACHE_MAX = 8
-
 
 class _Pending:
     """A queued request awaiting a slot."""
@@ -286,30 +276,6 @@ class ContinuousBatchingServer:
     Tokens are bit-identical across all three of dense backend, paged+
     dense prefill, and paged+ragged prefill.
 
-    ``serving_mode="fused"`` (paged + ragged only; default
-    ``"split"``) folds the WHOLE tick into ONE device program
-    (ops/pallas/fused_tick.py; FlashFuser / "Tile-Level Activation
-    Overlap", PAPERS.md): every mid-prefill slot's next prompt chunk
-    and every live slot's s=1 decode row run as one launch — rope,
-    cache-page writes, online-softmax paged attention, logits and the
-    SAMPLING epilogue all inside it — and the per-tick inputs (packed
-    tokens, offsets, the live block-table slice, PRNG keys) ride as
-    program arguments instead of device-resident state, so steady-
-    state AND admission ticks dispatch exactly once ({"fused": 1} in
-    the tick profile). The launch's DMA schedule covers only LIVE
-    pages per slot, lifting the split kernels' full-table-width
-    masked-DMA cut (the goodput ledger's ``skipped_page_dma`` shrinks
-    to the schedule's ladder pad), and mid-prefill slots are real
-    prefill rows instead of null-redirected decode rides. Tokens stay
-    bit-identical to the split path (greedy and seeded sampling):
-    decode rows route through an s=1-shaped program on the XLA
-    fallback, prefill rows keep the min-2 chunk parity rule, and the
-    in-program sampling replays the exact host-side PRNG chains.
-    Geometry (chunk width, live table width, schedule length) rides
-    pow2 ladders — compiles stay O(log); ``tick_block`` must be 1
-    (multi-token decode rows are the speculative-verify shape,
-    ROADMAP item 6).
-
     ``admission="optimistic"`` (paged backend only; default
     ``"reserve"``) lifts the full-extent admission pessimism: a
     request is admitted with only its PROMPT pages plus
@@ -399,8 +365,7 @@ class ContinuousBatchingServer:
                  admission="reserve", headroom_pages=1,
                  preemption_policy=None,
                  prefill_mode=None, prefill_tokens_per_tick=None,
-                 max_admissions_per_tick=None, serving_mode=None,
-                 telemetry=None,
+                 max_admissions_per_tick=None, telemetry=None,
                  recorder=None, ledger=None, journeys=None, costs=None,
                  host_tier=None, host_tier_bytes=None,
                  max_queue=None, shed_policy="reject",
@@ -525,8 +490,6 @@ class ContinuousBatchingServer:
             self._auto_prefix = bool(auto_prefix_cache)
             self._ragged_fn = (self._paged_bundle[5]
                                if len(self._paged_bundle) > 5 else None)
-            self._fused_fn = (self._paged_bundle[6]
-                              if len(self._paged_bundle) > 6 else None)
         else:
             if host_tier is True or (host_tier is not None
                                      and host_tier.enabled):
@@ -541,7 +504,6 @@ class ContinuousBatchingServer:
             self._prefix = None
             self._auto_prefix = False
             self._ragged_fn = None
-            self._fused_fn = None
         # ------------------------------------------------ prefill mode
         # "ragged" (the paged default): admissions reserve pages only;
         # their prompt chunks run BATCHED as one ragged launch per tick
@@ -600,7 +562,7 @@ class ContinuousBatchingServer:
                 "[max_cache_len] KV rows up front, so there is no pool "
                 "to admit optimistically against — virtualizing dense "
                 "slot buffers is the same page-pool work as the "
-                "quantized paged pool in ROADMAP (item 3); use "
+                "quantized paged pool (ROADMAP A7); use "
                 "cache_backend='paged'")
         self.admission = admission
         self._optimistic = admission == "optimistic"
@@ -609,71 +571,6 @@ class ContinuousBatchingServer:
             raise ValueError("headroom_pages must be >= 0")
         self._preempt_policy = preemption_policy \
             if preemption_policy is not None else PreemptionPolicy()
-        # ------------------------------------------------ serving mode
-        # "split" (default): the PR-6 tick — one ragged-prefill launch
-        # for the admission wave, the s=1 decode program for live
-        # slots, batched state pushes between them. "fused" (ISSUE 14):
-        # the WHOLE tick is ONE program — prefill chunks and decode
-        # rows packed into a single fused-tick launch whose DMA
-        # schedule covers only live pages (ops/pallas/fused_tick.py),
-        # sampling folded into the same program, per-tick inputs
-        # (tokens, offsets, live block-table slice, PRNG keys) riding
-        # as program arguments instead of device-resident state — the
-        # per-tick dispatch histogram collapses to {"fused": 1} on
-        # steady-state AND admission ticks.
-        if serving_mode is None:
-            serving_mode = "split"
-        if serving_mode not in ("split", "fused"):
-            raise ValueError(f"serving_mode must be 'split' or "
-                             f"'fused', got {serving_mode!r}")
-        if serving_mode == "fused":
-            if cache_backend != "paged":
-                raise ValueError(
-                    "serving_mode='fused' needs cache_backend='paged' "
-                    "(the fused tick writes straight into pool pages "
-                    "through a live-page DMA schedule)")
-            if not self._ragged:
-                raise ValueError(
-                    "serving_mode='fused' needs prefill_mode='ragged' "
-                    "(the fused launch packs the ragged scheduler's "
-                    "prompt chunks; dense prefill is the split-mode "
-                    "baseline)")
-            if self._fused_fn is None:
-                raise ValueError(
-                    "serving_mode='fused' but this model's paged "
-                    "decode bundle has no fused-tick entry point "
-                    "(7th element); use serving_mode='split'")
-            # on a real TPU the fused kernel halts the core (ROADMAP
-            # A1): refuse here, at construction, not at the first tick
-            from ..ops.pallas.fused_tick import refuse_on_tpu
-            refuse_on_tpu()
-            if mesh is not None:
-                raise NotImplementedError(
-                    "fused+mesh is not wired yet: the sharded paged "
-                    "pool serves through the SPLIT tick (ragged "
-                    "prefill + decode programs shard per kv-head with "
-                    "block tables replicated), but the fused tick's "
-                    "live-page DMA schedule and folded sampling "
-                    "epilogue still assume one device — making the "
-                    "megakernel shard-aware is the mesh half of "
-                    "ROADMAP item 2 on top of item 1's sharded pool; "
-                    "use serving_mode='split' on meshes")
-            if self.tick_block != 1:
-                raise NotImplementedError(
-                    "serving_mode='fused' runs ONE decode row per slot "
-                    "per launch; tick_block > 1 needs multi-token rows "
-                    "per slot — exactly the ragged s>1 verify shape "
-                    "speculative decoding adds (ROADMAP item 6: "
-                    "verify rows fold into the fused tick); use "
-                    "tick_block=1 or serving_mode='split'")
-        self.serving_mode = serving_mode
-        self._fused = serving_mode == "fused"
-        self._fused_jit = None    # sampling-fused tick program
-        self._fused_progs = {}    # (C, W, G) -> priced program (costs=)
-        # fused mode keeps the sampling PRNG keys HOST-side: they ride
-        # the launch as arguments and come back with the tokens, so no
-        # state_push dispatch ever fires on the tick path
-        self._host_keys = np.zeros((self.max_slots, 2), np.uint32)
         self._preempted = []      # _Preempted records awaiting re-admission
         self._migrating = {}      # rid -> (slot, tele t0, prior phase):
         #                           paused slots whose gathered pages are
@@ -825,9 +722,9 @@ class ContinuousBatchingServer:
         self.journeys = journeys
         self._jrec = journeys if (journeys is not None
                                   and journeys.enabled) else None
-        # per-tick host->device dispatch profile {op: count} — the
-        # dispatches-per-decode-tick baseline ROADMAP item 4 is
-        # measured against; reset at each tick, published to telemetry
+        # per-tick host->device dispatch profile {op: count} — what
+        # ROADMAP A2 prices (host work inside the tick); reset at each
+        # tick, published to telemetry
         # + recorder when nonempty (plain dict ops: always maintained,
         # costs no locks/clock)
         self._tick_disp = {}
@@ -1244,12 +1141,9 @@ class ContinuousBatchingServer:
         no expert's group (``models/generation.py``), so the tick pays
         for the slots that decode. The write rides ``_pending_t``, the
         state push the next decode dispatch makes; activation
-        (``_activate``) and resume overwrite it with the real position.
-        Fused mode keeps no slot state on the device: its launch
-        carries the sentinel as an argument."""
+        (``_activate``) and resume overwrite it with the real position."""
         self._active[slot] = False
-        if not self._fused:
-            self._pending_t[slot] = self.max_cache_len
+        self._pending_t[slot] = self.max_cache_len
 
     def _release_slot(self, slot, cold=False):
         """Tear down a slot's host + page state (no result recording).
@@ -1479,8 +1373,8 @@ class ContinuousBatchingServer:
                 if self._costs is not None:
                     # priced like the gather/scatter detours: bytes
                     # moved both ways, zero FLOPs — and NOT a tick
-                    # dispatch (restores must not count against the
-                    # megakernel's serving_tick_dispatches profile)
+                    # dispatch (restores must not count in the
+                    # serving_tick_dispatches profile)
                     self._charge_transfer(
                         "page_restore",
                         2 * len(fresh) * self._kv.page_size
@@ -1717,16 +1611,12 @@ class ContinuousBatchingServer:
 
     def _skipped_dma(self, live_tokens):
         """The goodput ledger's host-side MODEL of one slot's masked
-        page traffic in one kernel launch UNDER ``serving_mode=
-        "split"``: the split kernels' grid covers the full block-table
-        width, so every page wholly beyond the slot's live length is
-        DMAed but masked (PR-6 known cut) — ``(table_width -
-        ceil(live/pg)) * pg`` token-equivalents; this is the ONE
-        definition both the split decode and prefill hooks charge.
-        ``serving_mode="fused"`` (ISSUE 14) lifted the cut: its DMA
-        schedule covers only live pages, so ``_step_fused`` never
-        calls this — the only masked DMA it charges is the schedule's
-        pow2-ladder pad."""
+        page traffic in one kernel launch: the paged kernels' grid
+        covers the full block-table width, so every page wholly beyond
+        the slot's live length is DMAed but masked (ROADMAP A3) —
+        ``(table_width - ceil(live/pg)) * pg`` token-equivalents; this
+        is the ONE definition both the decode and prefill hooks
+        charge."""
         live = -(-int(live_tokens) // self.page_size)
         return max(0, self._bt_pages - live) * self.page_size
 
@@ -1991,8 +1881,7 @@ class ContinuousBatchingServer:
         self._slots[slot] = st
         self._prefill_fifo.append(slot)
         # parked until activation: its decode rows must not write into
-        # the pages being prefilled. (Fused mode: mid-prefill slots ride
-        # the launch as real prefill rows, idle ones are kernel-skipped.)
+        # the pages being prefilled
         self._park_slot(slot)
 
     def _bind_request(self, st, req, slot):
@@ -2641,273 +2530,6 @@ class ContinuousBatchingServer:
         # the trace's ``jit_decode_tick``
         return hoisted_jit(decode_tick, donate_argnums=(1,))
 
-    def _build_fused_step(self):
-        """One jitted program running a WHOLE serving tick: the model
-        bundle's raw fused-tick entry (prefill chunks + s=1 decode
-        rows over a live-page DMA schedule) with the sampling epilogue
-        folded in — first-token draws for slots completing their
-        prompt this launch (``fresh`` slots seed their chain from
-        ``seeds`` INSIDE the program, bit-identical to the host-eager
-        ``PRNGKey``/split/categorical chain the split path runs) and
-        decode-row draws continuing carried ``keys``. Non-emitting
-        slots pass their keys through untouched, so the per-request
-        chains stay exactly ``sample_generate``'s. One dispatch per
-        tick: {"fused": 1}.
-
-        The jitted program is cached process-wide per (bundle entry,
-        sampling params): N servers over the same model — a replica
-        fleet, or a bench's split/fused pair — share one compile per
-        geometry point instead of re-tracing per instance."""
-        fused_fn = self._fused_fn
-        do_sample = self.do_sample
-        temperature, top_k, top_p = (self._temperature, self._top_k,
-                                     self._top_p)
-        key = (fused_fn, do_sample, temperature, top_k, top_p)
-        cached = _FUSED_STEP_CACHE.get(key)
-        if cached is not None:
-            return cached
-
-        def fused_tick(tokens, t0, last, dec, emit, fresh, seeds,
-                       out_idx, keys, bt_live, ss, sp, caches):
-            logits, caches = fused_fn(tokens, t0, last, dec, caches,
-                                      out_idx, bt_live, ss, sp)
-            if do_sample:
-                from .decode_loop import process_logits
-                fresh_keys = jax.vmap(jax.random.PRNGKey)(seeds)
-                keys_in = jnp.where((fresh > 0)[:, None], fresh_keys,
-                                    keys)
-
-                def samp(k, row):
-                    # identical draw chain to sample_generate.body /
-                    # _activate: split this slot's key, sample over
-                    # its [1, V] row
-                    k2, sub = jax.random.split(k)
-                    nxt = jax.random.categorical(
-                        sub, process_logits(row[None], temperature,
-                                            top_k, top_p), axis=-1)[0]
-                    return k2, nxt.astype(jnp.int32)
-
-                new_keys, nxt = jax.vmap(samp)(keys_in, logits)
-                keys_out = jnp.where((emit > 0)[:, None], new_keys,
-                                     keys_in)
-            else:
-                nxt = jnp.argmax(logits, -1).astype(jnp.int32)
-                keys_out = keys
-            return nxt, keys_out, caches
-
-        prog = hoisted_jit(fused_tick, donate_argnums=(12,))
-        _FUSED_STEP_CACHE[key] = prog
-        while len(_FUSED_STEP_CACHE) > _FUSED_STEP_CACHE_MAX:
-            _FUSED_STEP_CACHE.pop(next(iter(_FUSED_STEP_CACHE)))
-        return prog
-
-    def _activate_fused(self, slot, first):
-        """A slot's prompt completed inside the fused launch and its
-        first token was drawn there too: flip it into the decode
-        phase. The split path's device state pushes (``_pending_*``)
-        don't exist here — the next tick's launch carries the token
-        and key as arguments."""
-        st = self._slots[slot]
-        st.phase = "decode"
-        self._active[slot] = True
-        self._prefill_fifo.remove(slot)
-        st.emitted.append(first)
-        if st.journey is not None:
-            st.journey.event("first_token")
-        st.stream(self._deferred_cbs)
-        self.stats["admissions"] += 1
-        if self._tele is not None:
-            self._tele.on_first_token(st.rid, st.prompt_len - st.n_pre,
-                                      st.n_pre)
-
-    def _step_fused(self):
-        """One fused serving tick (``serving_mode="fused"``): admit
-        (reservations only), pack every slot's work — the next prompt
-        chunk of each mid-prefill slot under the per-tick token
-        budget, the single decode row of each live slot — and run it
-        as ONE program over a DMA schedule covering only live pages.
-        Mid-prefill slots are REAL prefill rows (no null-redirected
-        decode rides), idle slots are kernel-skipped, and the
-        admission-tick extras of the split path (separate prefill
-        launch, state pushes, block-table sync) ride the launch as
-        program arguments — the tick's dispatch profile is
-        {"fused": 1}."""
-        self._prefill_used = 0
-        self._expire_locked()
-        self._admit(run_prefill=False)     # reserve; chunks ride the launch
-        b = self._boundary
-        if b is not None:
-            # everything up to the launch's tokens back on the host
-            t_launch = b.mark("fused_launch")
-        # harvest BEFORE packing: a slot whose budget is spent (or that
-        # emitted eos at activation) must not decode further
-        self._harvest()
-        if self._optimistic and self._active.any():
-            # grow every decode slot about to cross its coverage NOW —
-            # the launch must never write a needed row through a
-            # missing page (rows past the extent null-redirect as in
-            # split mode)
-            self._grow_locked()
-        S = self.max_slots
-        pg = self.page_size
-        budget = self._prefill_budget
-        plan = []                          # (slot, start, take)
-        used = 0
-        for slot in self._prefill_fifo:
-            if used >= budget:
-                break
-            st = self._slots[slot]
-            take = min(st.prompt_len - st.fill_pos, budget - used)
-            plan.append((slot, st.fill_pos, take))
-            used += take
-        dec_slots = [s for s in range(S) if self._active[s]]
-        if not plan and not dec_slots:
-            if self._tele is not None:
-                self._tele.set_active_slots(0)
-            return 0
-        self._prefill_used += used
-        # pack geometry rides pow2 ladders. The min-2 chunk-width floor
-        # keeps the PR-6 multi-row bit-parity guarantee for PREFILL
-        # rows only; decode rows take the s=1 fallback path whatever C
-        # is, so a decode-only tick (no plan) packs C=1 — the
-        # steady-state shape — instead of burning a zero pad row per
-        # slot (one extra ladder signature, half the per-token pad).
-        if plan:
-            max_take = max(t for _, _, t in plan)
-            C = max(2, 1 << (max_take - 1).bit_length())
-        else:
-            C = 1
-        tokens = np.zeros((S, C), np.int32)
-        t0 = np.full((S,), self.max_cache_len, np.int32)   # idle sentinel
-        last = np.full((S,), -1, np.int32)
-        dec = np.zeros((S,), np.int32)
-        emit = np.zeros((S,), np.int32)
-        fresh = np.zeros((S,), np.int32)
-        seeds = np.zeros((S,), np.int32)
-        out_idx = np.zeros((S,), np.int32)
-        done = []
-        for slot, start, take in plan:
-            st = self._slots[slot]
-            tokens[slot, :take] = st.ids[start:start + take]
-            t0[slot] = start
-            last[slot] = start + take - 1
-            if start + take == st.prompt_len:
-                out_idx[slot] = take - 1
-                emit[slot] = fresh[slot] = 1
-                # two's-complement wrap to int32 (np.int32(big) RAISES
-                # under NumPy 2): PRNGKey of the wrapped value is
-                # bit-identical to the host path's PRNGKey(st.seed)
-                s = st.seed & 0xffffffff
-                seeds[slot] = s - 0x100000000 if s >= 0x80000000 else s
-                done.append(slot)
-        for slot in dec_slots:
-            st = self._slots[slot]
-            t = st.prompt_len + len(st.emitted) - 1
-            tokens[slot, 0] = st.emitted[-1]
-            t0[slot] = last[slot] = t
-            dec[slot] = emit[slot] = 1
-        # live block-table slice + DMA schedule: the launch's page
-        # traffic covers exactly the live frontier, whatever the
-        # configured table width (the skipped-page-DMA cut, lifted)
-        from ..ops.pallas.fused_tick import build_schedule
-        live_pages = max(int(l) // pg + 1 for l in last if l >= 0)
-        W = min(self._bt_pages,
-                max(1, 1 << (live_pages - 1).bit_length()))
-        bt_live = np.ascontiguousarray(self._kv.block_table[:, :W])
-        ss, sp, n_live = build_schedule(last, pg, n_slots=S)
-        self._kv.dirty = False     # the slice IS the device's view
-        if self._faults is not None:
-            # chaos failure point: a dying fused tick is a SERVER-level
-            # transient — the supervisor retries it (host state is
-            # consistent: slot bookkeeping happens after the dispatch)
-            self._faults.check(faults.DECODE_TICK)
-        tele = self._tele
-        n_active = len(dec_slots)
-        if self._fused_jit is None:
-            self._fused_jit = self._build_fused_step()
-        args = (jnp.asarray(tokens), jnp.asarray(t0), jnp.asarray(last),
-                jnp.asarray(dec), jnp.asarray(emit), jnp.asarray(fresh),
-                jnp.asarray(seeds), jnp.asarray(out_idx),
-                jnp.asarray(self._host_keys), jnp.asarray(bt_live),
-                jnp.asarray(ss), jnp.asarray(sp), self._caches)
-        fn = self._fused_jit
-        if self._costs is not None:
-            # one priced program per (C, W, G) ladder point, cached
-            # host-side like _decode_prog (no per-tick pytree hashing)
-            key = (C, W, len(ss))
-            prog = self._fused_progs.get(key)
-            if prog is None:
-                prog = self._cost_program(self._cost_op("fused"),
-                                          self._fused_jit, args)
-                self._fused_progs[key] = prog
-            fn = prog
-        nxt, keys_out, self._caches = fn(*args)
-        nxt = np.asarray(nxt)              # syncs the dispatch
-        self._host_keys = np.asarray(keys_out)
-        if plan:
-            # the launch carries this tick's admission-path prefill
-            # work: it IS the admission dispatch (stats/telemetry keep
-            # their per-admission meaning)
-            self._count_dispatches(1, op="fused")
-        else:
-            self._tick_dispatch("fused")
-        if b is not None:
-            wall = b.mark("bookkeeping") - t_launch
-        led = self._led
-        for slot, start, take in plan:
-            st = self._slots[slot]
-            st.fill_pos = st.filled = start + take
-            self.stats["prefill_tokens"] += take
-            if led is not None:
-                if st.preempts:
-                    led.add("replay", take)
-                else:
-                    tail = max(0, min(start + take,
-                                      st.reprefill_upto) - start)
-                    led.add("tail_reprefill", tail)
-                    led.add("goodput", take - tail)
-                led.add("chunk_pad", C - take)
-            if st.journey is not None:
-                st.journey.event("prefill_chunk", start=start,
-                                 take=take)
-        for slot in done:
-            self._activate_fused(slot, int(nxt[slot]))
-        decoded = 0
-        for slot in dec_slots:
-            st = self._slots[slot]
-            st.emitted.append(int(nxt[slot]))
-            if led is not None:
-                # a resumed slot's rows below its pre-preemption
-                # offset re-generate tokens the waiter already has
-                led.add("replay"
-                        if len(st.emitted) <= len(st.replayed)
-                        else "goodput", 1)
-                led.add("chunk_pad", C - 1)   # the decode row's C-1 pad
-            decoded += 1
-            st.stream(self._deferred_cbs)
-        if led is not None and len(ss) > n_live:
-            # the ONLY masked DMA left: the schedule's quarter-octave
-            # ladder pad entries (kernel-skipped compute, modeled as
-            # page DMAs like the split mode's full-width cut they
-            # replace; bounded at ~25% of live entries)
-            led.add("skipped_page_dma", (len(ss) - n_live) * pg)
-        if plan and b is not None:
-            self.stats["prefill_wall_s"] += wall
-        if tele is not None:
-            tele.on_tick(wall, n_active, decoded)
-            if plan:
-                # the launch wall covers decode rows too — documented:
-                # fused prefill seconds are launch seconds
-                tele.on_prefill_batch(wall)
-        self._harvest()
-        # end-of-tick admissions reserve only: their chunks ride the
-        # NEXT tick's launch (the token budget is per tick)
-        self._admit(run_prefill=False)
-        n = int(self._active.sum())
-        if tele is not None:
-            tele.set_active_slots(n)
-        return n
-
     def step(self):
         """One server tick: admit waiting requests, run ``tick_block``
         batched decode steps as one program, harvest finished rows.
@@ -2960,8 +2582,7 @@ class ContinuousBatchingServer:
         if ct is not None or self._tele is not None:
             self._tick_seq += 1
             b = self._boundary = TickBoundary(
-                ct, self._tele, "admission" if self._fused else "expire",
-                tick=self._tick_seq)
+                ct, self._tele, "expire", tick=self._tick_seq)
         try:
             n = self._step_inner()
         except BaseException:
@@ -3004,9 +2625,6 @@ class ContinuousBatchingServer:
                 ct.flush_tick()
 
     def _step_inner(self):
-        if self._fused:
-            # serving_mode="fused": the whole tick is one program
-            return self._step_fused()
         self._prefill_used = 0       # per-tick prefill token budget
         # the tick opened in "expire"; each mark below opens the phase
         # it names (TICK_PHASES says which leave the chip idle)
@@ -3975,8 +3593,8 @@ class ContinuousBatchingServer:
                 "sha256": [_sha256(p) for p in payloads],
             }
             # pause: the decode tick skips inactive rows, the ragged
-            # prefill planner skips slots out of the fifo, and (split
-            # mode) the device write cursor parks on the null page —
+            # prefill planner skips slots out of the fifo, and the
+            # device write cursor parks on the null page —
             # resume re-pushes tok/t/key exactly as _activate does (or
             # re-queues the fifo for a prefill slot), so nothing the
             # device scribbles while paused is ever read
@@ -4109,15 +3727,14 @@ class ContinuousBatchingServer:
                 self._park_slot(slot)
             else:
                 st.phase = "decode"
-                if not self._fused:
-                    key = jax.random.PRNGKey(st.seed)
-                    if self.do_sample:
-                        for _ in range(len(st.emitted)):
-                            key, _ = jax.random.split(key)
-                    self._pending_key[slot] = key
-                    self._pending_tok[slot] = int(st.emitted[-1])
-                    self._pending_t[slot] = \
-                        st.prompt_len + len(st.emitted) - 1
+                key = jax.random.PRNGKey(st.seed)
+                if self.do_sample:
+                    for _ in range(len(st.emitted)):
+                        key, _ = jax.random.split(key)
+                self._pending_key[slot] = key
+                self._pending_tok[slot] = int(st.emitted[-1])
+                self._pending_t[slot] = \
+                    st.prompt_len + len(st.emitted) - 1
                 self._active[slot] = True
             self.stats["migration_fallbacks"] += 1
             if self._rec is not None:
@@ -4223,12 +3840,9 @@ class ContinuousBatchingServer:
             if self.do_sample:
                 for _ in range(len(emitted)):
                     key, _ = jax.random.split(key)
-            if self._fused:
-                self._host_keys[slot] = np.asarray(key, np.uint32)
-            else:
-                self._pending_key[slot] = key
-                self._pending_tok[slot] = int(emitted[-1])
-                self._pending_t[slot] = written
+            self._pending_key[slot] = key
+            self._pending_tok[slot] = int(emitted[-1])
+            self._pending_t[slot] = written
             self._active[slot] = True
         self.stats["migrated_in"] += 1
         if journey is not None:

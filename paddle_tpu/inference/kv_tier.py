@@ -96,8 +96,8 @@ class HostTier:
         self.entries = 0
         # the memory kind the backend would place pinned host buffers
         # in (probe only: payloads are plain numpy today — promoting
-        # them to pinned-host jax buffers with async DMA is the
-        # remaining half of ROADMAP item 5)
+        # them to pinned-host jax buffers with async DMA is ROADMAP
+        # A6, page movement off the tick)
         self.memory_kind = host_memory_kind()
         # cumulative churn (the server mirrors these into telemetry
         # and the cost ledger after each commit)

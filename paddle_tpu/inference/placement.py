@@ -1,4 +1,4 @@
-"""Disaggregated prefill/decode placement (ISSUE 20, ROADMAP item 4).
+"""Disaggregated prefill/decode placement (ISSUE 20; ROADMAP C7, W7).
 
 The fleet stops being N interchangeable replicas and becomes a PLACED,
 phase-specialized system: prefill-specialist replicas run ragged
@@ -50,7 +50,8 @@ def normalize_placement(name):
             "network — a WAN hop needs bandwidth-aware frame "
             "scheduling (batch pages by link budget, overlap chunk "
             "streams behind prefill ticks) and locality-tiered "
-            "specialist pools; ROADMAP item 4 follow-on")
+            "specialist pools. ROADMAP 'Still dropped': no new fleet "
+            "feature before B7's four-replica cell on the chip")
     raise ValueError(
         f"placement must be None, 'affinity', 'disaggregated' or "
         f"'cross-datacenter', got {name!r}")

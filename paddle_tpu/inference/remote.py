@@ -1,5 +1,5 @@
 """Process-isolated replicas behind the typed wire transport
-(ISSUE 12, ROADMAP item 4's architectural gate).
+(ISSUE 12; ROADMAP C7, the fleet layer).
 
 ``ReplicaHost`` runs one ``ContinuousBatchingServer`` behind the
 length-prefixed JSON protocol (inference/transport.py): submit /

@@ -1,5 +1,5 @@
 """Multi-replica front door: cache-aware routing, failover, rolling
-restarts (ROADMAP item 5).
+restarts (ROADMAP C7; no cell before B7 and W7).
 
 One ``ContinuousBatchingServer`` is a survivable process (PR 3's
 supervision, PR 5's prefix cache, PR 6's ragged prefill) — but a single

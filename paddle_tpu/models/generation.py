@@ -136,7 +136,7 @@ def _mesh_paged_caches(init_caches, mesh, kv_heads, axis="mp"):
     pools shard their merged ``kv_heads * head_dim`` axis (the minor
     one, see ``paged_pool_shape``) over the mesh's ``axis`` — contiguous
     blocks of whole kv heads, so per-device pool bytes shrink by 1/mp
-    at fixed page capacity, the capacity unlock of ROADMAP item 1 —
+    at fixed page capacity (the mesh column, ROADMAP A8) —
     while the block table stays REPLICATED: page ids are global, so the
     host-side allocator, grow/preempt/donate, and the prefix radix tree
     never learn the mesh exists. A ``kv_heads`` count (the model
@@ -273,7 +273,7 @@ def _check_paged_config(max_cache_len, page_size, num_pages, cache_dtype,
     if cache_dtype == "int8":
         raise NotImplementedError(
             "cache_dtype='int8' is not wired for the paged backend yet "
-            "(ROADMAP item 3: quantized paged KV pool); use "
+            "(ROADMAP A7: quantized paged KV pool); use "
             "cache_backend='dense' with int8 caches")
     del mesh
     if not page_size or int(page_size) < 1:
@@ -383,7 +383,7 @@ def _paged_attend(q, pool, layer, bt, t, scale, mesh=None):
                            scale, mesh=mesh, layer=layer)[:, None]
 
 
-def _page_write(pool, layer, kv, bt, t, last=None):
+def _page_write(pool, layer, kv, bt, t):
     """Page write: pool [L, P, pg, h*hd] <- kv [B, s, h, hd] into layer
     ``layer`` at per-slot position runs [t_b, t_b + s) — a decode row
     (s = 1) or a ragged-prefill chunk. The write is a scatter of
@@ -403,13 +403,7 @@ def _page_write(pool, layer, kv, bt, t, last=None):
     block-table entries point at the null page). The wasted block steps
     of a LIVE slot past its budget land inside the table but past its
     allocation, in its NULL_PAGE tail entries — finite garbage the
-    length masks hide.
-
-    ``last`` ([B] int32, optional): each slot's last VALID position —
-    rows past it are null-redirected zeroed too. The fused tick passes
-    it so a decode slot's C-row group writes exactly its one token
-    (the C-1 pad rows never touch the slot's real pages) and an idle
-    slot (``last = -1``) writes nothing at all."""
+    length masks hide."""
     pg = pool.shape[2]
     b, s = kv.shape[0], kv.shape[1]
     maxp = bt.shape[1]
@@ -418,8 +412,6 @@ def _page_write(pool, layer, kv, bt, t, last=None):
     P = _positions(t, b, s)                              # [B, s]
     pidx = P // pg
     oob = pidx >= maxp
-    if last is not None:
-        oob = jnp.logical_or(oob, P > last[:, None])
     page = jnp.where(
         oob, jnp.int32(0),
         jnp.take_along_axis(bt, jnp.minimum(pidx, maxp - 1), axis=1))
@@ -452,40 +444,15 @@ def _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=None):
                                     layer=layer)
 
 
-def _fused_attend(q, pool, layer, bt, t, last, dec, ss, sp, scale):
-    """Fused mixed prefill/decode tick attention through the LIVE
-    block-table slice: q [B, C, nh, hd] packed row groups (a prefill
-    chunk, a single decode row, or idle garbage per slot) at per-slot
-    offsets ``t``, DMA schedule ``(ss, sp)`` covering only live pages
-    (ops/pallas/fused_tick.py). Decode slots (``dec``) route through
-    an s=1-shaped fallback einsum so fused serving stays bit-identical
-    to the unfused decode program; idle slots (``last < 0``) read as
-    zeros. The fused kernel takes ONE layer's pools per head
-    (``[P, pg, kvh, hd]``): it is handed the ``pool[layer]`` slice,
-    viewed per head — a copy of one layer a call, which nothing on a
-    TPU pays (the kernel refuses there, ROADMAP A1)."""
-    from ..ops.pallas.fused_tick import fused_tick_attention
-    kvh = pool["k"].shape[-1] // q.shape[-1]
-    return fused_tick_attention(
-        q, pool_heads(pool["k"][layer], kvh),
-        pool_heads(pool["v"][layer], kvh), bt, t, last, dec, ss, sp,
-        sm_scale=scale)
-
-
-def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, fused=None,
-                   mesh=None, select=None):
+def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
+                   select=None):
     """One layer's page write and attention over the CARRIED pools
     [L, P, pg, lanes]: the new rows k/v [B, s, kvh, hd] land in layer
     ``layer``'s pages through the block table, then q [B, s, nh, hd]
     attends through it. s == 1 is a decode step (ragged paged-attention
     kernel); s > 1 a RAGGED PREFILL chunk at per-slot offsets ``t`` —
     which is what lets the server prefill several admissions as one
-    launch with no dense-cache detour. ``fused`` (a ``(last, dec, ss,
-    sp)`` tuple) switches to the FUSED TICK: ``bt`` is then the live
-    block-table slice, rows past ``last`` null-redirect zeroed on
-    write, and attention runs the fused kernel whose DMA schedule
-    ``(ss, sp)`` covers only live pages — prefill chunks and s=1 decode
-    rows (``dec``) of one serving tick in a single launch.
+    launch with no dense-cache detour.
 
     ``select`` (``(qi, wi, ki, topk)``: indexer queries [B, s, J, D],
     head weights [B, s, J], the rows' indexer keys [B, s, 1, D]) is
@@ -495,28 +462,18 @@ def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, fused=None,
     for decode rows and prefill chunks). Returns ``(att [B, s, nh,
     hd], pool, kept)``: ``kept`` [B, s] is the number of keys the
     selection let each row attend (None without ``select``)."""
-    last = fused[0] if fused is not None else None
     rows = {"k": k, "v": v}
     if select is not None:
         rows["ki"] = select[2]
-    pool = {n: _page_write(pool[n], layer, rows[n], bt, t, last=last)
-            for n in pool}
+    pool = {n: _page_write(pool[n], layer, rows[n], bt, t) for n in pool}
     kept = None
     if select is not None:
-        if fused is not None:
-            raise NotImplementedError(
-                "the fused tick has no key selection (ROADMAP A1: the "
-                "fused kernel is refused on a TPU anyway): serve a "
-                "model with an indexer through serving_mode='split'")
         from ..ops.key_selection import sparse_paged_attention
         b = q.shape[0]
         if jnp.ndim(t) == 0:
             t = jnp.full((b,), t, jnp.int32)
         att, kept = sparse_paged_attention(q, select[0], select[1], pool,
                                            layer, bt, t, select[3], scale)
-    elif fused is not None:
-        last, dec, ss, sp = fused
-        att = _fused_attend(q, pool, layer, bt, t, last, dec, ss, sp, scale)
     elif q.shape[1] > 1:
         att = _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=mesh)
     else:
@@ -563,15 +520,14 @@ def _run_layers(layer_fn, x, blk_tree, caches, paged):
 
 
 def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
-                   fused=None, mesh=None, layer=None, qk_norm=False,
-                   indexer=None):
+                   mesh=None, layer=None, qk_norm=False, indexer=None):
     """Shared llama-family attention sublayer for the decode loop:
     pre-RMSNorm, rope at absolute positions, GQA cache write + masked
     cached attention, output projection + residual. ``lc`` is this
     layer's cache dict (fp or int8 codec) — or, when ``bt`` (a per-slot
     block table) is given, the WHOLE page pools, written and attended
-    at ``layer`` through the table (paged backend: ``_paged_kv_step``,
-    which also explains ``fused``). ``qk_norm``: RMSNorm over each
+    at ``layer`` through the table (paged backend:
+    ``_paged_kv_step``). ``qk_norm``: RMSNorm over each
     head's dims with the learned gains ``blk["qn"]``/``blk["kn"]``,
     before the rope (Qwen3's). ``indexer`` (``(heads, dim, topk, (cos,
     sin))``): learned key selection — indexer queries ``blk["iq"]``,
@@ -604,8 +560,7 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
         select = (qi, _mm(h, blk["iw"]), ki, topk)
     if bt is not None:
         att, lc, kept = _paged_kv_step(lc, layer, q, k, v, bt, t, scale,
-                                       fused=fused, mesh=mesh,
-                                       select=select)
+                                       mesh=mesh, select=select)
     else:
         lc = _kv_write(lc, "k", k, t)
         lc = _kv_write(lc, "v", v, t)
@@ -657,44 +612,6 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
     return prefill_tick
 
 
-def _make_fused_tick_fn(fused_step, head_fn, embed_tokens):
-    """Build the paged bundle's FUSED-TICK entry point (ISSUE 14): one
-    whole serving tick — every slot's prefill chunk at its prefix
-    offset AND every live slot's s=1 decode row — as ONE program, K/V
-    written straight into pool pages and attended through a DMA
-    schedule that covers only live pages (ops/pallas/fused_tick.py).
-
-    Signature: ``(tokens [S, C], t0 [S], last [S], dec [S], caches,
-    out_idx [S], bt_live [S, W], sched_slot [G], sched_page [G]) ->
-    (logits [S, V], caches)``. Per slot: a prefill chunk carries
-    ``t0 = fill position``, ``last = t0 + take - 1``; a decode row
-    carries its token in column 0 with ``t0 = last = t`` (the write
-    position) and ``dec = 1``; an idle slot carries ``last = -1`` (its
-    writes null-redirect zeroed, the kernel skips it entirely).
-    ``out_idx`` picks the logits row — the last prompt token for a
-    completing prefill, row 0 for decode. ``bt_live`` is the block
-    tables SLICED to the live page frontier and ``(sched_slot,
-    sched_page)`` the pow2-padded live-page DMA schedule
-    (``fused_tick.build_schedule``), so the compiled program's HBM
-    traffic scales with live tokens, not the configured cache length.
-    Geometry (C, W, G) rides pow2 ladders — compiles stay O(log).
-
-    Returned RAW (unjitted), unlike the prefill/ragged entries: the
-    server composes its sampling epilogue around it and jits the WHOLE
-    tick as one program, which is what collapses the per-tick dispatch
-    histogram to ``{"fused": 1}``."""
-    def fused_tick(tokens, t0, last, dec, caches, out_idx, bt_live,
-                   sched_slot, sched_page):
-        S = tokens.shape[0]
-        x = embed_tokens(tokens, t0)
-        out, caches = fused_step(x, caches, t0, last, dec, bt_live,
-                                 sched_slot, sched_page)
-        rows = out[jnp.arange(S), out_idx][:, None]        # [S, 1, H]
-        return head_fn(rows)[:, -1], caches
-
-    return fused_tick
-
-
 # bundle leaf -> the per-block parameter a LlamaBlock / MixtralBlock holds
 _LLAMA_ATTN = {"ln1": "input_layernorm.weight",
                "ln2": "post_attention_layernorm.weight",
@@ -731,8 +648,8 @@ def _llama_family_weights(model, moe):
 def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                 cache_dtype=None, cache_backend="dense", page_size=None,
                 num_pages=None):
-    """(init_caches, embed_fn, step_fn, head_fn[, ragged, fused]) for
-    the llama family: pre-RMSNorm blocks, rope at absolute positions,
+    """(init_caches, embed_fn, step_fn, head_fn[, ragged]) for the
+    llama family: pre-RMSNorm blocks, rope at absolute positions,
     GQA (kv heads cached unrepeated), SwiGLU. What a block has BESIDES
     that is read from ``model.cfg`` — one builder, no copy per model:
 
@@ -806,7 +723,7 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     skip = ("table", "norm", "head") + (_EXPERT_LEAVES if moe else ())
     blk_tree = {k_: v_ for k_, v_ in p.items() if k_ not in skip}
 
-    def _forward(x, caches, t, bt, fused=None):
+    def _forward(x, caches, t, bt):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
         # an idle slot's offset is parked past the table: its rows are
@@ -821,7 +738,7 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         def layer(xx, blk, lc, l):
             xx, lc, h2, kept = _rope_gqa_attn(
                 blk, xx, lc, t, pos, (b, s, nh, kvh, hd, scale),
-                (cos, sin), eps, bt=bt, fused=fused, mesh=mesh, layer=l,
+                (cos, sin), eps, bt=bt, mesh=mesh, layer=l,
                 qk_norm=qk_norm, indexer=indexer)
             # what each slot's LAST row did, for the decode tick's
             # read-back: the keys it attended, the experts it chose
@@ -846,10 +763,6 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     def step_fn(x, caches, t):
         return _forward(x, caches, t, caches["bt"] if paged else None)
 
-    def fused_step(x, caches, t, last, dec, bt_live, ss, sp):
-        return _forward(x, caches, t, bt_live,
-                        fused=(last, dec, ss, sp))
-
     def head_fn(out):
         return (_rms(unwrap(out), p["norm"], eps) @ p["head"]
                 ).astype(jnp.float32)
@@ -857,10 +770,7 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     if paged:
         embed_tokens = lambda tokens, t0: p["table"][tokens]
         ragged = _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens)
-        if indexer is not None:      # no fused entry: the server says so
-            return init_caches, embed_fn, step_fn, head_fn, ragged
-        fused = _make_fused_tick_fn(fused_step, head_fn, embed_tokens)
-        return init_caches, embed_fn, step_fn, head_fn, ragged, fused
+        return init_caches, embed_fn, step_fn, head_fn, ragged
     return init_caches, embed_fn, step_fn, head_fn
 
 
@@ -924,7 +834,7 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             pos_emb = pos_emb[None]
         return (p["table"][tok] + pos_emb)[:, None, :]
 
-    def _forward(x, caches, t, bt, fused=None):
+    def _forward(x, caches, t, bt):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
 
@@ -935,7 +845,7 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             if paged:
                 att, lc, _ = _paged_kv_step(lc, l, q, k, v, bt, t, scale,
-                                            fused=fused, mesh=mesh)
+                                            mesh=mesh)
             else:
                 lc = _kv_write(lc, "k", k, t)
                 lc = _kv_write(lc, "v", v, t)
@@ -958,10 +868,6 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     def step_fn(x, caches, t):
         return _forward(x, caches, t, caches["bt"] if paged else None)
 
-    def fused_step(x, caches, t, last, dec, bt_live, ss, sp):
-        return _forward(x, caches, t, bt_live,
-                        fused=(last, dec, ss, sp))
-
     def head_fn(out):
         h = _ln(unwrap(out), p["lnf_w"], p["lnf_b"], eps)
         return (h @ p["table"].T).astype(jnp.float32)
@@ -976,9 +882,7 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
 
         ragged = _make_ragged_prefill_fn(step_fn, head_fn,
                                          gpt_embed_tokens)
-        fused = _make_fused_tick_fn(fused_step, head_fn,
-                                    gpt_embed_tokens)
-        return init_caches, embed_fn, step_fn, head_fn, ragged, fused
+        return init_caches, embed_fn, step_fn, head_fn, ragged
     return init_caches, embed_fn, step_fn, head_fn
 
 
@@ -1013,17 +917,12 @@ class GenerationMixin:
         bundle = build(self, max_cache_len, weight_dtype, mesh,
                        cache_dtype, **kw)
         # one prefill program per (bundle, prompt-shape): jit here, not
-        # inside generate(), so repeated calls reuse the compile. Paged
-        # bundles carry a SIXTH element — the jitted ragged-prefill
-        # entry point (packed multi-slot prompt chunks straight into
-        # pool pages; see _make_ragged_prefill_fn) — and a SEVENTH:
-        # the RAW fused-tick entry point (_make_fused_tick_fn; one
-        # whole serving tick — prefill chunks + s=1 decode rows — as
-        # one program over a live-page DMA schedule). The fused entry
-        # stays unjitted so the server can compose its sampling
-        # epilogue around it and jit the WHOLE tick as one dispatch.
-        # Dense bundles stay 5-tuples for existing consumers
-        # (deploy_decode, speculative).
+        # inside generate(), so repeated calls reuse the compile. A
+        # bundle has 5 elements (dense) or 6 (paged): paged bundles
+        # carry a SIXTH — the jitted ragged-prefill entry point (packed
+        # multi-slot prompt chunks straight into pool pages; see
+        # _make_ragged_prefill_fn). Dense bundles stay 5-tuples for
+        # existing consumers (deploy_decode, speculative).
         # The bundle functions close over the stacked weight tree, so
         # every program over them is built with hoisted_jit: the
         # weights ride as runtime arguments, never as constants of the
@@ -1039,8 +938,6 @@ class GenerationMixin:
         bundle = bundle[:4] + (hoisted_jit(decode_step, donate_argnums=(1,)),)
         if extras:
             bundle = bundle + (hoisted_jit(extras[0], donate_argnums=(2,)),)
-            if len(extras) > 1:
-                bundle = bundle + (extras[1],)
         cached[key] = bundle
         # a bundle pins its stacked weight tree (shared between the
         # bundles of one weight_dtype/mesh, see _stacked_weights) and

@@ -2,11 +2,10 @@
 compiled programs (ISSUE 13).
 
 PR 10's goodput ledger attributes device TOKENS; nothing yet prices
-them. The ROADMAP's fused-megakernel work (item 2) will claim wins in
-roofline terms — FLOPs and HBM bytes, the axes FlashFuser and
-"Tile-Level Activation Overlap" (PAPERS.md) are evaluated on — so this
-layer turns the serving stack's host->device dispatch profile (PR 9's
-``_count_dispatches(op=)`` labels) into a priced ledger:
+them. Kernel work claims its wins in roofline terms — FLOPs and HBM
+bytes — so this layer turns the serving stack's host->device dispatch
+profile (PR 9's ``_count_dispatches(op=)`` labels) into a priced
+ledger:
 
 - **Cost catalog**: each jitted serving program is priced ONCE per
   (op, shape-signature) at compile time via the compiler's own numbers
@@ -28,9 +27,9 @@ layer turns the serving stack's host->device dispatch profile (PR 9's
   RECOMPILE — the server lands it as a flight-recorder event and a
   ``compile_stall`` journey phase on every request parked behind the
   stalled tick, so an XLA-induced latency spike is attributable
-  instead of mystery. Per-op warmup keeps ops independent: the fused
-  program's pow2 geometry ladder (new chunk-width / schedule-length
-  signatures while traffic shapes are still being explored) neither
+  instead of mystery. Per-op warmup keeps ops independent: the
+  prefill program's pow2 width ladder (new chunk-width signatures
+  while traffic shapes are still being explored) neither
   trips alarms for an op still climbing its own ladder nor holds the
   decode program's shape-leak watch hostage. ``warmed`` (the global
   view) is true once every compiled op has warmed.
@@ -74,7 +73,7 @@ DENSE-mode admission prefill rides ``model._run_prefill``'s internal
 jit entries and is counted in the dispatch profile but not
 compiled-priced (its wall still lands in the phase split, so
 dense-mode MFU reads low); the ragged path — the paged default and
-the ROADMAP perf target — is fully priced.
+the one the benchmark's cells run — is fully priced.
 
 Published surfaces: the metrics above, ``snapshot()`` under
 ``/stats["costs"]``, a ``costs`` postmortem section (with the last
@@ -94,17 +93,13 @@ COMPILE_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
 # a phase of a tick runs from tens of microseconds to a prefill launch
 PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                  0.025, 0.05, 0.1, 0.25, 0.5, 1.0)
-# The split tick's phases separate the host from the chip: the chip
-# works through prefill_wait and decode_wait (dispatch to the value
-# read back) and is idle through the rest, but for the three tiny
-# programs of state_push. The fused tick keeps its three (admission,
-# fused_launch, bookkeeping); the dense admission path its
-# prefill_launch.
+# The tick's phases separate the host from the chip: the chip works
+# through prefill_wait and decode_wait (dispatch to the value read
+# back) and is idle through the rest, but for the three tiny programs
+# of state_push. The dense admission path marks prefill_launch.
 TICK_PHASES = ("expire", "admit", "prefill_pack", "prefill_wait",
                "activate", "grow", "state_push", "decode_wait", "emit",
-               "harvest", "callbacks",
-               "admission", "prefill_launch", "fused_launch",
-               "bookkeeping")
+               "harvest", "callbacks", "prefill_launch")
 
 # The one peaks table: ``device_kind`` (as ``jax.devices()[0]`` reports
 # it) -> (bf16 FLOP/s, HBM bytes/s) of one chip. Every utilisation in
@@ -380,7 +375,7 @@ class CostCatalog:
         tick, self._tick = self._tick, {}
         phases, self._phases = self._phases, {}
         if not tick:
-            # idle serve-loop poll: its admission/bookkeeping scraps
+            # idle serve-loop poll: its expire/admit scraps
             # are discarded, but pending callbacks time (the one
             # phase generated OUTSIDE a tick) is carried forward so a
             # request-sparse loop doesn't systematically drop it — it

@@ -9,9 +9,9 @@ they then mask out, and a preemption replays its whole chain from token
 0 — waste that was previously scattered across two ad-hoc counters
 (``kv_null_redirected_writes_total``,
 ``serving_wasted_block_tokens_total``) or not measured at all. The
-ROADMAP's next perf tier (fused megakernel, quantized pool, speculative
-decode) will claim wins in exactly these categories, so this ledger is
-the baseline those PRs are judged against.
+ROADMAP's kernel work (the live-page grid A3, the quantized pool A7,
+multi-token steps B6) claims wins in exactly these categories, so this
+ledger is the baseline those PRs are judged against.
 
 Taxonomy — every device token each tick lands in EXACTLY ONE kind:
 
@@ -108,8 +108,7 @@ class GoodputLedger:
             self._g_ratio = registry.gauge(
                 "serving_goodput_ratio",
                 "goodput / total device tokens for the last non-empty "
-                "tick (the fused-megakernel and speculative-decode "
-                "success metric)")
+                "tick")
 
     # ----------------------------------------------------------- write
     def add(self, kind, n):

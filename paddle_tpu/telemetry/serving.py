@@ -36,7 +36,7 @@ Per-tick engine signals. Every time below comes from the reads of
   admission/prefill path — the ragged prefill path's counter-asserted
   win is this dropping per admission vs the dense baseline
 - ``serving_tick_dispatches``     host->device dispatches per server
-  tick (histogram) — the ROADMAP item-4 fused-megakernel baseline
+  tick (histogram) — the host work ROADMAP A2 prices
 - ``server_dispatches_total{op}`` the same dispatches by op: decode /
   prefill / state_push / block_table / page_gather / page_scatter
 
@@ -323,16 +323,13 @@ class ServerTelemetry:
             "serving_prefill_launches_total",
             "Ragged prefill launches by chunk width",
             labelnames=("width",))
-        # dispatches-per-decode-tick: THE success metric for the fused
-        # decode megakernel (ROADMAP item 4) — today a tick costs one
-        # decode program plus state pushes / block-table syncs /
-        # prefill launches; the megakernel's win is this histogram's
-        # mass moving toward 1. The per-op counter names where the
-        # remaining dispatches go.
+        # dispatches per tick: a steady decode tick costs one decode
+        # program, an admission tick a prefill launch, state pushes and
+        # block-table syncs on top (ROADMAP A2 prices them). The per-op
+        # counter names where the dispatches go.
         self._h_tick_disp = r.histogram(
             "serving_tick_dispatches",
-            "Host->device dispatches per server tick (ROADMAP item-4 "
-            "megakernel baseline)",
+            "Host->device dispatches per server tick (ROADMAP A2)",
             buckets=(1, 2, 3, 5, 8, 13, 21, 34, 55))
         self._c_disp = r.counter(
             "server_dispatches_total",

@@ -18,14 +18,9 @@ cuts discoverable instead of buried. Deliberate non-cuts (abstract
 methods raise bare; API refusals) opt out with a ``# no-roadmap:
 <reason>`` comment on the raise line, which is itself grep-able.
 
-Required-cut rule (ISSUE 8): some dispatch sites must KEEP a
-ROADMAP-pointered refusal — ``REQUIRED_CUTS`` lists (file, keyword)
-pairs, and the lint fails if the file no longer contains a pointered
-``NotImplementedError`` mentioning the keyword — silently "supporting"
-a combo, or deleting a refusal wholesale, is exactly the kind of quiet
-contract change this lint exists to surface. Lifting a cut for real
-(as ISSUE 16 did for paged+mesh) means removing its entry here in the
-same change that makes the combo work.
+That a documented combination still REFUSES is held where people look:
+``tests/test_chip_bringup.py::test_documented_refusals`` constructs
+each one and reads its message.
 
 Usage: python scripts/check_no_bare_except.py [root ...]
 Exit status 1 lists every offending file:line. Wired into the test
@@ -52,40 +47,6 @@ SCOPE_CUT_DIRS = (
     os.path.join("paddle_tpu", "telemetry"),
 )
 OPT_OUT = "no-roadmap:"
-
-# dispatch sites that must KEEP a ROADMAP-pointered
-# NotImplementedError: (repo-relative file, keyword its message must
-# mention). ISSUE 8: the optimistic-admission mode dispatch — the
-# optimistic+dense combo must refuse with a pointer, not silently
-# half-work or lose its annotation. ISSUE 14: the fused serving tick
-# runs ONE decode row per slot — tick_block > 1 is the speculative
-# multi-token verify shape (ROADMAP item 6) and must refuse with a
-# pointer until that lands. (ISSUE 14 LIFTED the PR-6 skipped-page-DMA
-# and null-redirect cuts for serving_mode="fused"; the split kernels
-# keep them as the documented baseline, no refusal site involved.)
-# ISSUE 16 LIFTED the paged+mesh cut (the pool now shards on the
-# kv-head dim over the mp axis) and left two pointered refusals in its
-# place: the int8 paged pool (generation.py, ROADMAP item 3) and the
-# fused tick on a mesh (continuous_batching.py, ROADMAP item 2 — the
-# megakernel's DMA schedule and sampling epilogue are still
-# single-device; split mode serves meshes). ISSUE 20 LIFTED the
-# pre-first-token migrate_out refusal (an empty-``emitted`` migration
-# IS a prefill->decode handoff now) and points the next cut instead:
-# disaggregated placement stops at one datacenter's flat network —
-# placement="cross-datacenter" (bandwidth-aware frame scheduling,
-# ROADMAP item 4 follow-on) must refuse with a pointer until it lands.
-REQUIRED_CUTS = (
-    (os.path.join("paddle_tpu", "models", "generation.py"),
-     "int8"),
-    (os.path.join("paddle_tpu", "inference", "continuous_batching.py"),
-     "optimistic"),
-    (os.path.join("paddle_tpu", "inference", "continuous_batching.py"),
-     "tick_block"),
-    (os.path.join("paddle_tpu", "inference", "continuous_batching.py"),
-     "fused+mesh"),
-    (os.path.join("paddle_tpu", "inference", "placement.py"),
-     "cross-datacenter"),
-)
 
 
 def _raise_strings(node):
@@ -160,38 +121,6 @@ def bare_excepts(root):
     return scan(root, repo)[0]
 
 
-def missing_required_cuts(repo):
-    """[(relpath, keyword), ...] of ``REQUIRED_CUTS`` entries whose
-    file no longer holds a ROADMAP-pointered ``NotImplementedError``
-    mentioning the keyword (or cannot be parsed)."""
-    missing = []
-    for rel, keyword in REQUIRED_CUTS:
-        path = os.path.join(repo, rel)
-        try:
-            with open(path, "rb") as f:
-                tree = ast.parse(f.read(), filename=path)
-        except (OSError, SyntaxError):
-            missing.append((rel, keyword))
-            continue
-        found = False
-        for node in ast.walk(tree):
-            if not isinstance(node, ast.Raise) or node.exc is None:
-                continue
-            exc = node.exc
-            if not (isinstance(exc, ast.Call)
-                    and isinstance(exc.func, ast.Name)
-                    and exc.func.id == "NotImplementedError"):
-                continue
-            strings = _raise_strings(exc)
-            if any("ROADMAP" in s for s in strings) \
-                    and any(keyword in s for s in strings):
-                found = True
-                break
-        if not found:
-            missing.append((rel, keyword))
-    return missing
-
-
 def main(argv):
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     roots = argv[1:] or [os.path.join(repo, d) for d in DEFAULT_DIRS]
@@ -200,9 +129,6 @@ def main(argv):
         b, c = scan(root, repo)
         bare += b
         cuts += c
-    # positive obligations are repo-level, independent of which roots
-    # were passed (a partial run must not skip them)
-    required = missing_required_cuts(repo)
     for path, line in bare:
         print(f"{path}:{line}: bare 'except:' — name the exception type "
               "(at least 'except Exception')")
@@ -210,15 +136,10 @@ def main(argv):
         print(f"{path}:{line}: NotImplementedError without a ROADMAP "
               "pointer — name the ROADMAP item that lifts this scope "
               f"cut, or opt out with '# {OPT_OUT} <reason>'")
-    for rel, keyword in required:
-        print(f"{rel}: required scope cut missing — expected a "
-              f"ROADMAP-pointered NotImplementedError mentioning "
-              f"{keyword!r} (see REQUIRED_CUTS)")
-    if bare or cuts or required:
+    if bare or cuts:
         return 1
     print(f"OK: no bare excepts / unpointered scope cuts under "
-          f"{', '.join(roots)}; {len(REQUIRED_CUTS)} required cut(s) "
-          f"present")
+          f"{', '.join(roots)}")
     return 0
 
 

@@ -114,45 +114,8 @@ class StubModel:
                                         dtype=jnp.float32) * 10.0
                 return logits, dict(caches, pool=pool)
 
-            def fused_tick(tokens, t0, last, dec, caches, out_idx,
-                           bt_live, ss, sp):
-                """Fused-tick contract (paged bundle element 6,
-                ISSUE 14): one launch carries every slot's work —
-                prefill chunks, single decode rows (column 0, dec=1),
-                idle slots (last=-1, all writes null-redirect
-                zeroed). ``bt_live`` is the block tables sliced to
-                the live page width; the schedule args ride along
-                unused (the stub has no kernel to drive). Writes
-                token VALUES into pool pages like the ragged entry
-                and returns the oracle's next-token logits at each
-                slot's ``out_idx`` row."""
-                pool = caches["pool"]
-                S, Cc = tokens.shape
-                W = bt_live.shape[1]
-                pos = t0[:, None] + jnp.arange(Cc, dtype=jnp.int32)[None]
-                pidx = pos // pg
-                oob = (pidx >= W) | (pos > last[:, None])
-                page = jnp.where(
-                    oob, 0, jnp.take_along_axis(
-                        bt_live, jnp.minimum(pidx, W - 1), axis=1))
-                vals = jnp.where(oob, 0.0, tokens.astype(jnp.float32))
-                n = S * Cc
-                flat = pool_lanes(jnp.broadcast_to(
-                    vals.reshape(n)[:, None, None], (n, h, hd)))
-                fp, fo = page.reshape(n), (pos % pg).reshape(n)
-                pool = {"k": pool["k"].at[:, fp, fo].set(flat[None]),
-                        "v": pool["v"].at[:, fp, fo].set(flat[None])}
-                last_tok = jnp.take_along_axis(
-                    tokens, out_idx[:, None], axis=1)[:, 0]
-                last_pos = t0 + out_idx
-                nxt = (7 * last_tok + last_pos + 1) % vocab
-                logits = jax.nn.one_hot(nxt, vocab,
-                                        dtype=jnp.float32) * 10.0
-                return logits, dict(caches, pool=pool)
-
             return (init_caches, embed_fn, step_fn, head_fn, None,
-                    jax.jit(ragged_prefill, donate_argnums=(2,)),
-                    fused_tick)
+                    jax.jit(ragged_prefill, donate_argnums=(2,)))
         return init_caches, embed_fn, step_fn, head_fn, None
 
     def _run_prefill(self, bundle, ids_np, chunk=None, caches=None, t0=0):

@@ -4,13 +4,15 @@
   platform named, no result line); ``--rehearse`` passes end to end
   (slow: ~2.5 min of Pallas interpreter).
 - Weights are ARGUMENTS of every compiled serving program: the lowered
-  decode / ragged-prefill / fused programs of a ``gpt2_tiny`` paged
-  server and ``generate()``'s loops hold no constant of a weight's
-  shape, and the dense and paged bundles share one stacked tree.
+  decode and ragged-prefill programs of a ``gpt2_tiny`` paged server
+  and ``generate()``'s loops hold no constant of a weight's shape, and
+  the dense and paged bundles share one stacked tree.
 - No fallback that hides the device: ``on_tpu()`` raises when the
   backend cannot initialise, ``set_device`` refuses a platform JAX does
-  not have, the fused tick refuses a real TPU (ROADMAP A1) instead of
-  running as its reference, DataLoader workers refuse device arrays.
+  not have, a kernel that refuses the platform fails the smoke's
+  ``kernels`` phase, DataLoader workers refuse device arrays.
+- The refusals the ROADMAP documents still refuse, and name it by
+  letter; no string under ``paddle_tpu/`` cites a roadmap NUMBER.
 - The compile cache honours ``JAX_COMPILATION_CACHE_DIR`` and otherwise
   sits at the fixed ``<checkout>/.jax_cache``.
 """
@@ -19,6 +21,7 @@ import os
 import re
 import subprocess
 import sys
+from types import SimpleNamespace
 from unittest import mock
 
 import numpy as np
@@ -71,7 +74,7 @@ def test_chip_smoke_rehearsal_passes():
     lines = r.stdout.splitlines()
     assert lines and all(ln.startswith("REHEARSAL") for ln in lines)
     assert json.loads(lines[-1].split(" ", 1)[1])["ok"] is True
-    for phase in ("kernels", "serve-split", "serve-fused", "train",
+    for phase in ("kernels", "serve-split", "train",
                   "mesh4-serve-split", "mesh4-train"):
         assert re.search(rf"phase {phase}: ok", r.stdout), phase
 
@@ -121,16 +124,6 @@ def test_serving_programs_take_the_weights_as_arguments():
     z = jnp.zeros((S,), jnp.int32)
     text = srv._ragged_fn.lower(toks, z, srv._caches, z).as_text()
     _assert_no_weight_constants(text, shapes, "ragged prefill")
-
-    fsrv = ContinuousBatchingServer(model, cache_backend="paged",
-                                    max_slots=2, max_cache_len=64,
-                                    page_size=8, serving_mode="fused")
-    fused = fsrv._build_fused_step()
-    g = jnp.zeros((8,), jnp.int32)
-    args = (toks, z, z, z, z, z, z, z, jnp.zeros((S, 2), jnp.uint32),
-            jnp.zeros((S, 2), jnp.int32), g, g, fsrv._caches)
-    _assert_no_weight_constants(fused.lower(*args).as_text(), shapes,
-                                "fused tick")
 
     # the control: plain jax.jit over the same closure DOES bake them in
     baked = jax.jit(srv._step_fn).lower(
@@ -258,25 +251,69 @@ def test_set_device_refuses_a_platform_jax_does_not_have():
             pt.set_device(bad)
 
 
-def test_fused_tick_refuses_a_real_tpu_instead_of_its_reference():
-    """On a TPU the fused kernel halts the core (ROADMAP A1): the kernel
-    entry and ``serving_mode="fused"`` raise; neither reaches
-    ``_ref_fused_tick``. (``interpret=True`` and off-TPU are untouched —
-    tests/test_fused_tick.py.)"""
-    from paddle_tpu.ops.pallas import fused_tick as ft
-    q = jnp.zeros((1, 2, 2, 8))
-    pool = jnp.zeros((3, 4, 2, 8))
-    i = jnp.zeros((1,), jnp.int32)
-    with mock.patch.object(jax, "default_backend", return_value="tpu"), \
-            mock.patch.object(ft, "_ref_fused_tick",
-                              side_effect=AssertionError("reference ran")):
-        with pytest.raises(NotImplementedError, match="ROADMAP A1"):
-            ft.fused_tick_attention(q, pool, pool, jnp.zeros((1, 1),
-                                    jnp.int32), i, i, i, i, i)
-        with pytest.raises(NotImplementedError, match="serving_mode='split'"):
-            ContinuousBatchingServer(_tiny_gpt(), cache_backend="paged",
-                                     max_slots=2, max_cache_len=64,
-                                     page_size=8, serving_mode="fused")
+def test_a_kernel_that_refuses_fails_the_phase(monkeypatch):
+    """No kernel of the main path refuses a platform, so a refusal in
+    the smoke's ``kernels`` phase is a failure, not a line of output."""
+    import importlib.util
+    spec = importlib.util.spec_from_file_location("chip_smoke", SMOKE)
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)
+
+    def refuses(case):
+        raise NotImplementedError("not on this platform")
+
+    monkeypatch.setattr(smoke, "kernel_paged", refuses)
+    phases = smoke.Phases(SimpleNamespace(mark=lambda: (0, 0.0),
+                                          since=lambda mark: (0, 0.0)))
+    with phases.run("kernels"):
+        smoke.phase_kernels(smoke.presets(True), True)
+    assert phases.failed == ["kernels"]
+
+
+# ------------------------------------------------- the documented refusals
+def _refuse_int8_paged_pool():
+    ContinuousBatchingServer(_tiny_gpt(), cache_backend="paged",
+                             max_cache_len=64, page_size=8,
+                             cache_dtype="int8")
+
+
+def _refuse_optimistic_on_dense():
+    ContinuousBatchingServer(_tiny_gpt(), max_cache_len=64,
+                             admission="optimistic")
+
+
+def _refuse_cross_datacenter():
+    from paddle_tpu.inference.placement import normalize_placement
+    normalize_placement("cross-datacenter")
+
+
+@pytest.mark.parametrize("refuse,names", [
+    (_refuse_int8_paged_pool, ("ROADMAP A7",)),
+    (_refuse_optimistic_on_dense, ("ROADMAP A7",)),
+    (_refuse_cross_datacenter, ("ROADMAP", "Still dropped", "B7")),
+], ids=["int8-paged-pool", "optimistic-on-dense", "cross-datacenter"])
+def test_documented_refusals(refuse, names):
+    """Each combination ROADMAP.md lists under "Refusals standing in the
+    code" raises, and its message names the item that would lift it."""
+    with pytest.raises(NotImplementedError) as e:
+        refuse()
+    for name in names:
+        assert name in str(e.value)
+
+
+def test_no_string_cites_a_dead_roadmap_number():
+    """ROADMAP.md's items have letters (A7, B6, C1); "ROADMAP item 3"
+    names a list that no longer exists."""
+    hits = []
+    for dirpath, _, files in os.walk(os.path.join(REPO, "paddle_tpu")):
+        for fn in files:
+            if fn.endswith(".py"):
+                path = os.path.join(dirpath, fn)
+                text = re.sub(r"\s+", " ", open(path).read())
+                hits += [(os.path.relpath(path, REPO), m.group(0))
+                         for m in re.finditer(
+                             r"ROADMAP[^.;:]{0,12}item[- ]\d", text)]
+    assert hits == []
 
 
 def test_flash_attention_partitions_itself_under_a_mesh():

@@ -189,7 +189,7 @@ class TestCostCatalogUnit:
 
     def test_warmup_is_per_op(self):
         """ISSUE 14 satellite (lifts the PR-12 global-warmup cut): each
-        op warms independently, so the fused program's legitimate new
+        op warms independently, so the prefill program's legitimate new
         chunk-width signatures while ITS ladder is still climbing never
         fire a recompile alarm just because decode already warmed —
         and decode's shape-leak watch isn't reset by them either."""
@@ -202,30 +202,30 @@ class TestCostCatalogUnit:
             cat.program("decode", fn, (x,))(x)
             cat.flush_tick()
         assert cat.warmed_op("decode") and cat.warmed
-        # a FIRST fused compile after decode warmed: not a recompile
+        # a FIRST prefill compile after decode warmed: not a recompile
         y = jnp.ones((8,))
-        p1 = cat.program("fused", fn, (y,))
+        p1 = cat.program("prefill", fn, (y,))
         p1(y)
         cat.flush_tick()
         assert p1.compiled_now and not p1.recompile
         assert cat.recompiles == 0
-        assert not cat.warmed               # fused still climbing
-        # fused climbs its pow2 ladder while unwarm: still no alarm,
+        assert not cat.warmed               # prefill still climbing
+        # prefill climbs its pow2 ladder while unwarm: still no alarm,
         # and decode's armed watch is untouched by the churn
         z = jnp.ones((16,))
-        p2 = cat.program("fused", fn, (z,))
+        p2 = cat.program("prefill", fn, (z,))
         p2(z)
         cat.flush_tick()
         assert not p2.recompile and cat.recompiles == 0
         assert cat.warmed_op("decode")
-        for _ in range(2):                  # fused warms too
-            cat.program("fused", fn, (z,))(z)
+        for _ in range(2):                  # prefill warms too
+            cat.program("prefill", fn, (z,))(z)
             cat.flush_tick()
-        assert cat.warmed_op("fused") and cat.warmed
-        assert sorted(cat.snapshot()["warm_ops"]) == ["decode", "fused"]
-        # NOW a new fused signature is a real recompile — and it trips
-        # only fused's alarm, not a decode one
-        p3 = cat.program("fused", fn, (jnp.ones((32,)),))
+        assert cat.warmed_op("prefill") and cat.warmed
+        assert sorted(cat.snapshot()["warm_ops"]) == ["decode", "prefill"]
+        # NOW a new prefill signature is a real recompile — and it trips
+        # only prefill's alarm, not a decode one
+        p3 = cat.program("prefill", fn, (jnp.ones((32,)),))
         assert p3.recompile and cat.recompiles == 1
         p4 = cat.program("decode", fn, (x,))
         assert not p4.compiled_now          # cache hit, no new alarm
@@ -355,14 +355,6 @@ class TestProgramNames:
         up in the device trace."""
         assert served_programs[op] == module
         assert "jit_run" not in served_programs.values()
-
-    def test_fused_tick_program_name(self):
-        srv = _paged_server(costs=CostCatalog(), serving_mode="fused")
-        srv.submit(_prompt(1, 2, 3), max_new_tokens=3)
-        srv.run()
-        (text,) = {p.executable.as_text().split()[1].rstrip(",")
-                   for _, p in srv.costs.programs()}
-        assert text == "jit_fused_tick"
 
     def test_dense_prefill_program_is_decode_step(self):
         """``_decode_bundle``'s jitted step (the dense prefill program,
@@ -539,6 +531,35 @@ class TestServerCosting:
         assert ticks and "phases" in ticks[-1]
         assert set(ticks[-1]["phases"]) <= set(TICK_PHASES)
 
+    def test_tick_phases_are_the_phases_a_tick_marks(self):
+        """``TICK_PHASES`` lists what the tick loop marks, no more and
+        no less: a paged server (ragged launches, an optimistic grow, a
+        streamed callback) and a dense one (its admission's
+        ``prefill_launch``) between them mark every phase, and mark
+        nothing the tuple does not name."""
+        marked = set()
+
+        def served(**kw):
+            cat = CostCatalog()
+            add = cat.add_phase
+            cat.add_phase = lambda phase, s: (marked.add(phase),
+                                              add(phase, s))
+            srv = ContinuousBatchingServer(StubModel(), max_slots=2,
+                                           max_cache_len=32, costs=cat,
+                                           **kw)
+            for p in ((1, 2, 3), (4, 5, 6, 7, 8)):
+                srv.submit(_prompt(*p), max_new_tokens=8,
+                           on_token=lambda rid, toks: None)
+            srv.run()
+            return srv
+
+        paged = served(cache_backend="paged", page_size=4,
+                       admission="optimistic", headroom_pages=0)
+        assert paged.stats["grow_pages"] > 0
+        served()
+        assert marked == set(TICK_PHASES)
+        assert len(TICK_PHASES) == len(set(TICK_PHASES)) == 12
+
     def test_postmortem_freezes_costs_section(self):
         rec = FlightRecorder()
         srv = _paged_server(costs=True, recorder=rec)
@@ -708,41 +729,6 @@ class TestSkippedDmaCrossValidation:
         # wider band, same linear-tracking property
         assert 1.0 <= ratio <= 25.0, \
             f"ragged compiled-vs-model ratio {ratio:.2f} left [1, 25]"
-
-    def test_fused_tick_live_slice_deletes_masked_page_bytes(self):
-        """ISSUE 14: the fused-tick program still pays gather bytes
-        AFFINE in whatever table width it is handed (same structure as
-        the split kernels above) — the win is that the server only
-        ever hands it the LIVE slice. Priced at the live width, the
-        launch's bytes undercut even the narrowest full-width launch;
-        the server-level flatness-in-CONFIGURED-width assertion (fixed
-        live pages, 4x table growth, <10% byte drift) lives in
-        tests/test_fused_tick.py."""
-        from paddle_tpu.ops.pallas.fused_tick import (
-            build_schedule, fused_tick_attention)
-
-        last_np = np.full((self.S,), 5, np.int32)    # 1 live page/slot
-        ss, sp, _ = build_schedule(last_np, self.PG, n_slots=self.S)
-
-        def bytes_at(maxp):
-            q = jnp.ones((self.S, 2, self.NH, self.HD), jnp.float32)
-            k = jnp.ones((self.POOL, self.PG, self.KVH, self.HD),
-                         jnp.float32)
-            v = jnp.ones_like(k)
-            bt = jnp.zeros((self.S, maxp), jnp.int32)
-            t0 = jnp.zeros((self.S,), jnp.int32)
-            last = jnp.asarray(last_np)
-            dec = jnp.zeros((self.S,), jnp.int32)
-            ca = jax.jit(fused_tick_attention).lower(
-                q, k, v, bt, t0, last, dec, jnp.asarray(ss),
-                jnp.asarray(sp)).compile().cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            return float(ca["bytes accessed"])
-
-        b_live, b8, b32 = bytes_at(1), bytes_at(8), bytes_at(32)
-        assert (b32 - b8) / (32 - 8) > 0      # handed width still costs
-        assert b_live < b8                    # ...so hand it the slice
 
 
 # --------------------------------------------------------------------------

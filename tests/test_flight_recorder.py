@@ -262,8 +262,7 @@ class TestTickDispatchProfile:
         assert first["prefill"] >= 1 and first["decode"] == 1
         assert first["state_push"] >= 1 and first["block_table"] >= 1
         assert ticks[0]["total"] == sum(first.values())
-        # steady-state decode ticks: decode only — the megakernel
-        # baseline this PR exists to record
+        # steady-state decode ticks: decode only
         assert any(e["dispatches"] == {"decode": 1} for e in ticks)
         assert srv.stats["tick_dispatches"] == \
             sum(e["total"] for e in ticks)

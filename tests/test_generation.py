@@ -368,3 +368,39 @@ def test_process_logits_filters():
     # temperature scales
     t = np.asarray(process_logits(logits, temperature=2.0))
     np.testing.assert_allclose(t[0], [0.5, 1.5, 1.0, -0.5])
+
+
+def _tiny(family):
+    if family == "gpt":
+        from paddle_tpu.models.gpt import GPTForCausalLM, gpt2_tiny
+        return GPTForCausalLM(gpt2_tiny())
+    if family == "llama":
+        from paddle_tpu.models.llama import LlamaForCausalLM, llama_tiny
+        return LlamaForCausalLM(llama_tiny())
+    if family == "mixtral":
+        from paddle_tpu.models.mixtral import (MixtralForCausalLM,
+                                               mixtral_tiny)
+        return MixtralForCausalLM(mixtral_tiny())
+    from paddle_tpu.models.keye_vl import KeyeVL2ForCausalLM, keye_vl2_tiny
+    return KeyeVL2ForCausalLM(keye_vl2_tiny())
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama", "mixtral", "keye"])
+def test_paged_bundle_brings_the_ragged_entry(family):
+    """A decode bundle has five elements dense and six paged, whatever
+    the family, and the sixth is the program the device trace knows as
+    ``jit_prefill_tick``: the server's default ``prefill_mode`` rests on
+    it, and the benchmark refuses a server whose default is not
+    ragged."""
+    import jax.numpy as jnp
+    pt.seed(7)
+    model = _tiny(family)
+    model.eval()
+    assert len(model._decode_bundle(32)) == 5
+    paged = model._decode_bundle(32, cache_backend="paged", page_size=8,
+                                 num_pages=9)
+    assert len(paged) == 6
+    z = jnp.zeros((2,), jnp.int32)
+    text = paged[5].lower(jnp.zeros((2, 4), jnp.int32), z, paged[0](2),
+                          z).as_text()
+    assert "module @jit_prefill_tick" in text

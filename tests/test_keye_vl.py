@@ -322,11 +322,6 @@ def test_kept_keys_are_counted_where_the_mask_is_made(model, monkeypatch):
         model.reset_generate_cache()
 
 
-def test_fused_mode_is_refused_not_silently_unselected(model):
-    with pytest.raises(ValueError, match="fused-tick entry point"):
-        _server(model, serving_mode="fused")
-
-
 def test_generate_dense_cache_matches_reference(model, sizes):
     """``generate()`` (the dense cache with its own indexer-key leaf)
     emits the reference's argmax at every step."""

@@ -447,7 +447,6 @@ class TestPagedServer:
         srv.run()
         assert srv._kv.used_pages() == 0
 
-    @pytest.mark.slow
     def test_gpt_and_mixtral_paged_parity(self):
         from paddle_tpu.models.gpt import GPTForCausalLM, gpt2_tiny
         from paddle_tpu.models.mixtral import (MixtralForCausalLM,

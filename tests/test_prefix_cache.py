@@ -551,16 +551,14 @@ class TestEvictionChaos:
         assert a[0], "deterministic run injected nothing"
 
 
-# ----------------------------------------------------------------- bench
+# ------------------------------------------------- shared-prompt workload
 
 
-@pytest.mark.slow
-@pytest.mark.bench
 class TestPrefixCacheBenchGuard:
     def test_shared_prompt_hit_rate_and_savings(self):
-        """Counter-based guard for benchmarks/prefix_cache_bench.py:
-        the shared-system-prompt workload must hit on every follow-up
-        request and cut prefill tokens by the shared page run."""
+        """Counter-based guard: the shared-system-prompt workload must
+        hit on every follow-up request and cut prefill tokens by the
+        shared page run."""
         rng = np.random.default_rng(0)
         system = rng.integers(0, 16, (16,)).astype(np.int32)
         prompts = [np.concatenate(

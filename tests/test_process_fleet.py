@@ -71,8 +71,8 @@ def procs():
     "do_sample",
     [False,
      # the greedy drill stays tier-1; sampled doubles the spawn+compile
-     # cost to cover seed replay, which test_fused_tick/test_preemption
-     # already pin in-process
+     # cost to cover seed replay, which test_preemption already pins
+     # in-process
      pytest.param(True, marks=pytest.mark.slow)],
     ids=["greedy", "sampled"])
 def test_sigkill_drill_under_net_storm(procs, tmp_path, do_sample):

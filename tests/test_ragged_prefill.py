@@ -176,8 +176,7 @@ class TestRaggedPrefillBundle:
         dense = m._decode_bundle(MCL)
         paged = m._decode_bundle(MCL, cache_backend="paged",
                                  page_size=PG, num_pages=NP)
-        assert len(paged) >= 6          # ragged entry is element 5
-        #                                 (element 6 = fused tick, ISSUE 14)
+        assert len(paged) == 6          # ragged entry is element 5
         init_p, ragged_jit = paged[0], paged[5]
         rng = np.random.default_rng(0)
         ids_a = rng.integers(0, 256, (12,)).astype(np.int32)
@@ -263,12 +262,10 @@ class TestRaggedServerParity:
             np.testing.assert_array_equal(got_ragged, got_dense)
         return servers[2]
 
-    @pytest.mark.slow
     def test_greedy_parity_mixed_lengths(self):
         """Mixed prompt lengths: 1, page_size-1, page_size, multi-page
         — 5 requests through 2 slots (refill mid-run), all three
-        prefill paths bit-identical. (slow: 3 servers x 5 requests;
-        chunk-straddling + sampled keep three-way parity tier-1.)"""
+        prefill paths bit-identical."""
         model = _model()
         rng = np.random.default_rng(0)
         prompts = [rng.integers(0, 256, (n,)).astype(np.int32)
@@ -278,7 +275,6 @@ class TestRaggedServerParity:
         free, live, pinned, cached = srv.pool_balance()
         assert live == 0
 
-    @pytest.mark.slow
     def test_greedy_parity_chunk_straddling_budget(self):
         """A 4-token-per-tick budget slices every prompt across ticks
         at arbitrary (non-page-aligned) cut points; tokens must not
@@ -361,6 +357,32 @@ def _stub_srv(**kw):
     kw.setdefault("cache_backend", "paged")
     kw.setdefault("page_size", 4)
     return ContinuousBatchingServer(StubModel(), **kw)
+
+
+@pytest.mark.parametrize("tick", ["steady", "admission"])
+def test_split_tick_dispatch_profile(tick):
+    """What the paged tick dispatches, counted (ROADMAP A2 prices each):
+    a steady decode tick is ONE program; a tick that admits adds one
+    prefill launch, the block-table sync and the three batched slot-state
+    pushes (token, position, key), whether the other slot is empty or
+    decoding."""
+    from paddle_tpu.telemetry import FlightRecorder
+    rec = FlightRecorder()
+    srv = _stub_srv(recorder=rec)
+    srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=8)
+    srv.step()
+    srv.step()
+    srv.step()
+    srv.submit(np.asarray([1, 2, 3, 4, 5], np.int32), max_new_tokens=3)
+    srv.step()
+    prof = [e["dispatches"] for e in rec.events(kind="tick")]
+    assert len(prof) == 4
+    if tick == "steady":
+        assert prof[1] == prof[2] == {"decode": 1}
+    else:
+        assert prof[0] == prof[3] == {"prefill": 1, "block_table": 1,
+                                      "state_push": 3, "decode": 1}
+    srv.run()
 
 
 class TestInterleavedScheduler:

@@ -17,9 +17,7 @@ Contracts pinned here:
   CostCatalog SHARED across servers at different mp never trips the
   post-warmup recompile alarm (ops are namespaced ``decode_mp4``);
 - the shard_map'd Pallas kernels (interpret mode) match the unsharded
-  launches bit-for-bit;
-- ``fused+mesh`` stays a ROADMAP-pointered refusal (split mode is the
-  mesh serving path).
+  launches bit-for-bit.
 
 Runs under conftest's forced 8 host devices; skips cleanly elsewhere.
 """
@@ -214,14 +212,6 @@ class TestShardedPagedParity:
             np.testing.assert_array_equal(oa[a], ob[b])
         assert sharded.stats["prefix_auto_hits"] \
             == oracle.stats["prefix_auto_hits"]
-
-    def test_fused_mesh_refuses_with_roadmap_pointer(self, model4):
-        with pytest.raises(NotImplementedError, match="ROADMAP"):
-            ContinuousBatchingServer(model4, max_slots=2,
-                                     max_cache_len=64,
-                                     cache_backend="paged", page_size=8,
-                                     num_pages=24, serving_mode="fused",
-                                     mesh=_mesh(4))
 
 
 class TestShardedCosts:

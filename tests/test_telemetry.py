@@ -506,15 +506,6 @@ def _kernel_cases():
             sds((2, 8, 4, 64)), sds((8, 16, 4, 64)), sds((8, 16, 4, 64)),
             sds((2, 4), i32), sds((2,), i32), sds((2,), i32))
 
-    def fused():
-        from paddle_tpu.ops.pallas.fused_tick import _fused_tick_pallas
-        return jax.make_jaxpr(
-            lambda q, k, v, bt, t0, ss, sp: _fused_tick_pallas(
-                q, k, v, bt, t0, ss, sp, 0.125))(
-            sds((2, 8, 4, 64)), sds((8, 16, 4, 64)), sds((8, 16, 4, 64)),
-            sds((2, 4), i32), sds((2,), i32), sds((8,), i32),
-            sds((8,), i32))
-
     def flash_fwd():
         from paddle_tpu.ops.pallas.flash_attention import _flash_fwd_pallas
         return jax.make_jaxpr(
@@ -560,7 +551,7 @@ def _kernel_cases():
 
     return [("paged_attention_decode", paged),
             ("ragged_prefill_attention", ragged),
-            ("fused_tick", fused), ("flash_fwd", flash_fwd),
+            ("flash_fwd", flash_fwd),
             ("flash_bwd_dq", flash_bwd), ("flash_bwd_dkv", flash_bwd),
             ("quant_matmul", quant), ("gemm_epilogue", gemm),
             ("rms_norm_fwd", rms_fwd), ("rms_norm_bwd", rms_bwd),
