@@ -295,24 +295,43 @@ class KernelCase:
 
 
 def kernel_paged(c):
-    """Decode attention: ragged lengths incl. 1, a page boundary, one past
-    it, and a full table."""
+    """Decode attention over the live pages' grid: ragged lengths incl.
+    1, a page boundary, one past it and a full table; the same with
+    every other slot idle; ONE live slot; a full house; nothing live.
+    A slot of length 0 must come back as exact zeros."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas import paged_attention as pa
     pg, T = c.pg, c.T
-    lens = np.array([1, pg, pg + 1, T, 3 * pg + 5, 2, T // 2, T - 1][:c.S],
-                    np.int32)
-    k, v, bt = c.pool(lens)
-    q, lens_d = c.normal(c.S, c.nh, c.hd), jnp.asarray(lens)
+    ragged = np.array([1, pg, pg + 1, T, 3 * pg + 5, 2, T // 2, T - 1][:c.S],
+                      np.int32)
+    idle = np.arange(c.S) % 2 == 1
+    one = np.zeros(c.S, np.int32)
+    one[c.S // 2] = 3 * pg + 5
+    q = c.normal(c.S, c.nh, c.hd)
+
     def call(q, k, v, bt, ln, layer=None):
         return pa.paged_attention(q, k, v, bt, ln, c.scale, layer=layer)
 
-    got = c.run(call, (q, k, v, bt, lens_d))
-    c.same_at_layer("paged_attention", call, got, q, k, v, (bt, lens_d))
-    want = c.ref(lambda q, k, v, bt, ln: pa._ref_paged_attention(
-        q, k, v, bt, ln, c.scale), q, k, v, bt, lens_d)
-    c.close("paged_attention", got, want)
+    for tag, lens in (("ragged", ragged),
+                      ("half idle", np.where(idle, 0, ragged)),
+                      ("one live", one),
+                      ("full house", np.full(c.S, T, np.int32)),
+                      ("none live", np.zeros(c.S, np.int32))):
+        k, v, bt = c.pool(lens)
+        lens_d = jnp.asarray(lens)
+        got = c.run(call, (q, k, v, bt, lens_d))
+        if tag == "ragged":
+            c.same_at_layer("paged_attention", call, got, q, k, v,
+                            (bt, lens_d))
+        if not bool((got[lens == 0] == 0).all()):
+            c.bad.append(f"paged_attention {tag}[{c.name}]: a slot of "
+                         f"length 0 is not zeros")
+        if lens.any():
+            want = c.ref(lambda q, k, v, bt, ln: pa._ref_paged_attention(
+                q, k, v, bt, ln, c.scale), q, k, v, bt, lens_d)
+            c.close(f"paged_attention {tag}", got[lens > 0],
+                    want[lens > 0])
 
 
 def _live_rows(C, take):

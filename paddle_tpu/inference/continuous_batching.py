@@ -624,7 +624,12 @@ class ContinuousBatchingServer:
                       "handoff_pages_out": 0, "handoff_pages_in": 0,
                       # rows the decode ticks carried (slots x block a
                       # tick) and those of a slot that was decoding:
-                      # the rest rode parked on the idle sentinel.
+                      # the rest rode parked on the idle sentinel. The
+                      # steps the paged decode kernel's grid took, a
+                      # layer at a time, and the pages its live rows
+                      # spanned (ops.pallas.paged_attention.decode_grid
+                      # on the host's lengths: the function that sizes
+                      # the grid on the device).
                       # What a routed-expert / key-selecting model's
                       # launches did (zero for models with neither):
                       # rows the expert FFN computed (those of the
@@ -637,7 +642,8 @@ class ContinuousBatchingServer:
                       # and keys the selection kept of them, which the
                       # device counts where it makes the mask
                       "decode_ticks": 0, "decode_rows": 0,
-                      "decode_live_rows": 0, "moe_rows": 0,
+                      "decode_live_rows": 0, "decode_grid_steps": 0,
+                      "decode_live_pages": 0, "moe_rows": 0,
                       "moe_live_rows": 0, "moe_experts_touched": 0,
                       "attn_keys_context": 0, "attn_keys_selected": 0}
         cfg = getattr(model, "cfg", None)
@@ -1611,12 +1617,15 @@ class ContinuousBatchingServer:
 
     def _skipped_dma(self, live_tokens):
         """The goodput ledger's host-side MODEL of one slot's masked
-        page traffic in one kernel launch: the paged kernels' grid
-        covers the full block-table width, so every page wholly beyond
-        the slot's live length is DMAed but masked (ROADMAP A3) —
-        ``(table_width - ceil(live/pg)) * pg`` token-equivalents; this
-        is the ONE definition both the decode and prefill hooks
-        charge."""
+        page traffic in one launch: the ragged prefill kernel's grid
+        (ROADMAP A2b-c) and the decode step's XLA fallback cover the
+        full block-table width, so every page wholly beyond the slot's
+        live length is read but masked — ``(table_width -
+        ceil(live/pg)) * pg`` token-equivalents; this is the ONE
+        definition both the decode and prefill hooks charge. The decode
+        KERNEL steps over live pages only (``decode_grid_steps``
+        counts them): on the chip the decode hook's charge models the
+        fallback, not the kernel."""
         live = -(-int(live_tokens) // self.page_size)
         return max(0, self._bt_pages - live) * self.page_size
 
@@ -2728,6 +2737,7 @@ class ContinuousBatchingServer:
         if self._moe_k:
             self._count_routed(route, live_rows, live_rows)
         decoded = wasted = keys_ctx = keys_sel = 0
+        lens = []          # valid tokens of each decoding slot's first row
         led = self._led
         if led is not None:
             led.add("null_redirect", rows - live_rows)
@@ -2735,6 +2745,7 @@ class ContinuousBatchingServer:
             if not self._active[slot]:
                 continue
             st = self._slots[slot]
+            lens.append(st.prompt_len + len(st.emitted))
             if led is not None and self._kv is not None:
                 led.add("skipped_page_dma", self._skipped_dma(
                     st.prompt_len + len(st.emitted)))
@@ -2760,6 +2771,8 @@ class ContinuousBatchingServer:
                     break              # later block tokens are waste
             decoded += min(j + 1, toks.shape[1])
             st.stream(self._deferred_cbs)
+        if self._kv is not None and not self._select_k:
+            self._count_decode_grid(np.asarray(lens), toks.shape[1])
         if keys_ctx:
             self.stats["attn_keys_context"] += keys_ctx
             self.stats["attn_keys_selected"] += keys_sel
@@ -2788,6 +2801,28 @@ class ContinuousBatchingServer:
         if tele is not None:
             tele.set_active_slots(n)
         return n
+
+    def _count_decode_grid(self, lens, block):
+        """Decode-kernel accounting of one tick: the steps its grid
+        took and the pages the decoding slots' valid tokens spanned, a
+        layer at a time. ``lens`` are the decoding slots' lengths at
+        the block's first step (a parked slot has length 0 and no
+        page); step ``j`` attends ``lens + j``, or nothing once that
+        passes the table's span (the sentinel). Counted by
+        ``decode_grid``, which sizes the grid on the device."""
+        from ..ops.pallas.paged_attention import decode_grid
+        steps = live = 0
+        for j in range(block):
+            at = lens + j
+            pages, n = decode_grid(np.where(at <= self.max_cache_len, at, 0),
+                                   self.page_size)
+            steps += int(n)
+            live += int(pages.sum())
+        steps, live = steps * self._n_layers, live * self._n_layers
+        self.stats["decode_grid_steps"] += steps
+        self.stats["decode_live_pages"] += live
+        if self._tele is not None:
+            self._tele.on_decode_grid(steps, live)
 
     def _count_routed(self, route, live_rows, rows):
         """Expert-FFN accounting of one launch: ``rows`` computed (the

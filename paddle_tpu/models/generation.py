@@ -357,30 +357,45 @@ def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
     return tree
 
 
-def _paged_attend(q, pool, layer, bt, t, scale, mesh=None):
+def _paged_decode_plan(bt, t, page_size):
+    """What every layer of one paged decode step attends, made ONCE a
+    step outside the layer loop: ``(lengths, schedule)``. ``lengths``
+    [B] are the valid tokens t+1 (cache written through t); a slot
+    carrying the scheduler's idle sentinel (``t`` past the block-table
+    extent: it holds no decoding request) gets length 0, as
+    ``_paged_prefill_attend`` hands it ``last = -1``. ``schedule`` is
+    the decode kernel's grid made of those lengths (``decode_schedule``:
+    one step a live page), which the kernel reads by scalar prefetch
+    beside the block table."""
+    from ..ops.pallas.paged_attention import decode_schedule
+    if jnp.ndim(t) == 0:
+        t = jnp.full((bt.shape[0],), t, jnp.int32)
+    limit = bt.shape[1] * page_size                # tokens a table spans
+    lengths = jnp.where(t < limit, t + 1, jnp.int32(0))
+    return lengths, decode_schedule(lengths, page_size, bt.shape[1])
+
+
+def _paged_attend(q, pool, layer, bt, t, scale, mesh=None, plan=None):
     """Decode-step attention through the block table: q [B, 1, nh, hd],
     ``pool`` the whole K/V pools ``{"k", "v"}`` [L, P, pg, kvh*hd] read
-    at ``layer``, valid lengths t+1 (cache already written through t).
-    A slot carrying the scheduler's idle sentinel (``t`` past the
-    block-table extent: it holds no decoding request) is handed length
-    0, as ``_paged_prefill_attend`` hands it ``last = -1``: the kernel
-    skips its every page and writes zeros, the fallback masks every
-    position (a finite mean of the null page nobody reads). Handed
-    ``t + 1`` it would be the kernel's dearest row: a sweep over its
-    whole table of null pages.
+    at ``layer``, over ``plan`` (``_paged_decode_plan(bt, t, pg)``, which
+    the layer loop's caller makes once for all its layers). A slot of
+    length 0 has no step in the kernel's grid and gets zeros; the
+    fallback masks its every position (a finite mean of the null page
+    nobody reads). Handed ``t + 1`` an idle slot would be the kernel's
+    dearest row: a sweep over its whole table of null pages.
     Pallas ragged kernel on TPU (per-kv-head-shard launches under
     ``mesh`` — XLA cannot partition a custom call, so the kernel path
     shard_maps itself), bit-exact dense-mirroring gather composition
     elsewhere (GSPMD partitions it from the pool's input sharding).
     Returns [B, 1, nh, hd]."""
     from ..ops.pallas.paged_attention import paged_attention
-    b = q.shape[0]
-    if jnp.ndim(t) == 0:
-        t = jnp.full((b,), t, jnp.int32)
-    limit = bt.shape[1] * pool["k"].shape[2]       # tokens a table spans
-    lengths = jnp.where(t < limit, t + 1, jnp.int32(0))
+    if plan is None:
+        plan = _paged_decode_plan(bt, t, pool["k"].shape[2])
+    lengths, schedule = plan
     return paged_attention(q[:, 0], pool["k"], pool["v"], bt, lengths,
-                           scale, mesh=mesh, layer=layer)[:, None]
+                           scale, mesh=mesh, layer=layer,
+                           schedule=schedule)[:, None]
 
 
 def _page_write(pool, layer, kv, bt, t):
@@ -445,14 +460,15 @@ def _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=None):
 
 
 def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
-                   select=None):
+                   select=None, plan=None):
     """One layer's page write and attention over the CARRIED pools
     [L, P, pg, lanes]: the new rows k/v [B, s, kvh, hd] land in layer
     ``layer``'s pages through the block table, then q [B, s, nh, hd]
     attends through it. s == 1 is a decode step (ragged paged-attention
-    kernel); s > 1 a RAGGED PREFILL chunk at per-slot offsets ``t`` —
-    which is what lets the server prefill several admissions as one
-    launch with no dense-cache detour.
+    kernel over ``plan``, the step's ``_paged_decode_plan``); s > 1 a
+    RAGGED PREFILL chunk at per-slot offsets ``t`` — which is what lets
+    the server prefill several admissions as one launch with no
+    dense-cache detour.
 
     ``select`` (``(qi, wi, ki, topk)``: indexer queries [B, s, J, D],
     head weights [B, s, J], the rows' indexer keys [B, s, 1, D]) is
@@ -477,7 +493,8 @@ def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
     elif q.shape[1] > 1:
         att = _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=mesh)
     else:
-        att = _paged_attend(q, pool, layer, bt, t, scale, mesh=mesh)
+        att = _paged_attend(q, pool, layer, bt, t, scale, mesh=mesh,
+                            plan=plan)
     return att, pool, kept
 
 
@@ -520,14 +537,15 @@ def _run_layers(layer_fn, x, blk_tree, caches, paged):
 
 
 def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
-                   mesh=None, layer=None, qk_norm=False, indexer=None):
+                   mesh=None, layer=None, qk_norm=False, indexer=None,
+                   plan=None):
     """Shared llama-family attention sublayer for the decode loop:
     pre-RMSNorm, rope at absolute positions, GQA cache write + masked
     cached attention, output projection + residual. ``lc`` is this
     layer's cache dict (fp or int8 codec) — or, when ``bt`` (a per-slot
     block table) is given, the WHOLE page pools, written and attended
     at ``layer`` through the table (paged backend:
-    ``_paged_kv_step``). ``qk_norm``: RMSNorm over each
+    ``_paged_kv_step``, a decode step over its ``plan``). ``qk_norm``: RMSNorm over each
     head's dims with the learned gains ``blk["qn"]``/``blk["kn"]``,
     before the rope (Qwen3's). ``indexer`` (``(heads, dim, topk, (cos,
     sin))``): learned key selection — indexer queries ``blk["iq"]``,
@@ -560,7 +578,7 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
         select = (qi, _mm(h, blk["iw"]), ki, topk)
     if bt is not None:
         att, lc, kept = _paged_kv_step(lc, layer, q, k, v, bt, t, scale,
-                                       mesh=mesh, select=select)
+                                       mesh=mesh, select=select, plan=plan)
     else:
         lc = _kv_write(lc, "k", k, t)
         lc = _kv_write(lc, "v", v, t)
@@ -734,12 +752,16 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         # INSIDE a live slot's chunk stay live (the program is told no
         # row count a slot)
         live = jnp.repeat(jnp.broadcast_to(t < max_cache_len, (b,)), s)
+        # the decode kernel's lengths and grid, once for every layer
+        # (key selection attends without that kernel)
+        plan = (_paged_decode_plan(bt, t, page_size)
+                if paged and s == 1 and indexer is None else None)
 
         def layer(xx, blk, lc, l):
             xx, lc, h2, kept = _rope_gqa_attn(
                 blk, xx, lc, t, pos, (b, s, nh, kvh, hd, scale),
                 (cos, sin), eps, bt=bt, mesh=mesh, layer=l,
-                qk_norm=qk_norm, indexer=indexer)
+                qk_norm=qk_norm, indexer=indexer, plan=plan)
             # what each slot's LAST row did, for the decode tick's
             # read-back: the keys it attended, the experts it chose
             aux = {} if kept is None else {"kept": kept[:, -1]}
@@ -837,6 +859,9 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     def _forward(x, caches, t, bt):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
+        # the decode kernel's lengths and grid, once for every layer
+        plan = (_paged_decode_plan(bt, t, page_size)
+                if paged and s == 1 else None)
 
         def layer(xx, blk, lc, l):
             h = _ln(xx, blk["ln1.weight"], blk["ln1.bias"], eps)
@@ -845,7 +870,7 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             if paged:
                 att, lc, _ = _paged_kv_step(lc, l, q, k, v, bt, t, scale,
-                                            mesh=mesh)
+                                            mesh=mesh, plan=plan)
             else:
                 lc = _kv_write(lc, "k", k, t)
                 lc = _kv_write(lc, "v", v, t)

@@ -14,14 +14,21 @@ attention gathers pages through the block table, masks by the slot's
 ACTUAL length, and early-exits pages wholly beyond it, so both memory
 and bandwidth scale with real tokens.
 
-Kernel shape: one query token per slot (decode step). Grid is
-``(slots, pages_per_slot)`` with the page axis innermost ("arbitrary"),
-accumulating an online softmax in VMEM scratch exactly like
-``flash_attention._fwd_kernel``; the block table and per-slot lengths
-ride ``PrefetchScalarGridSpec`` scalar prefetch so the page DMA for grid
-step ``(s, p)`` is issued from ``block_tables[s, p]`` before the body
-runs. GQA is handled in-kernel (query-head groups attend to their kv
-head) so the pool stores kv heads unrepeated.
+Kernel shape: one query token per slot (decode step). The grid is FLAT
+and as long as the call has live pages: one step for every page a slot's
+valid tokens span, slot after slot, a slot's pages in position order —
+``decode_grid`` counts them from the lengths, ``decode_schedule`` lists
+them, and the count is a DYNAMIC grid bound, so a tick with three
+decoding slots of a dozen pages takes three dozen steps and not ``slots
+x pages_per_slot``. A step accumulates an online softmax in VMEM scratch
+exactly like ``flash_attention._fwd_kernel``, initialised at a slot's
+first page and written out at its last; the schedule, the block table
+and the per-slot lengths ride ``PrefetchScalarGridSpec`` scalar prefetch,
+so the page DMA for step ``g`` is issued from the block-table entry
+``schedule[g]`` names before the body runs. A slot of length 0 (idle, or
+mid-prefill) has no step and its output rows are zeros. GQA is handled
+in-kernel (query-head groups attend to their kv head) so the pool stores
+kv heads unrepeated.
 
 The XLA fallback (`_ref_paged_attention`) gathers pages into the
 contiguous ``[slot, pages*page_size, ...]`` frame and then mirrors
@@ -41,7 +48,8 @@ from . import on_tpu
 
 NEG_INF = -1e30
 
-__all__ = ["paged_attention", "available"]
+__all__ = ["paged_attention", "decode_grid", "decode_schedule",
+           "available"]
 
 
 def available() -> bool:
@@ -51,22 +59,60 @@ def available() -> bool:
 # ----------------------------------------------------------------- kernel
 
 
-def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                       o_ref, m_scr, l_scr, acc_scr, *, page_size,
-                       pages_per_slot, kv_heads, rep, sm_scale):
-    """Grid (slots, pages_per_slot); one query row per slot.
+def decode_grid(lengths, page_size):
+    """``(pages, steps)`` of one decode call: the pages each slot's
+    ``lengths`` valid tokens span ([slots]; 0 for a slot of length 0)
+    and the steps the kernel's grid takes — their sum, the live pages,
+    and one step where nothing is live (it finds length 0 and computes
+    nothing). Plain operators, so it counts NumPy lengths on the host
+    (the server's ``decode_grid_steps``) as it sizes the grid from
+    traced ones on the device: one owner of the count."""
+    pages = (lengths + (page_size - 1)) // page_size
+    live = pages.sum()
+    return pages, live + (live == 0)
 
-    q_ref  [1, nh, hd]       this slot's query token
+
+def decode_schedule(lengths, page_size, pages_per_slot):
+    """``(entries, steps)``: what each step of the decode grid reads.
+    ``entries[g]`` is the FLAT block-table index ``slot * pages_per_slot
+    + page`` of step ``g < steps``, slot-major with a slot's pages in
+    position order, so a slot's steps are one consecutive run (its
+    online softmax never interleaves with another's and its output
+    block is visited once). ``entries`` has the static length ``slots *
+    pages_per_slot``; past ``steps`` it holds valid indices nobody
+    visits. A function of the lengths alone: every layer of a tick
+    attends the same lengths, so the tick computes it once."""
+    slots = lengths.shape[0]
+    pages, steps = decode_grid(lengths.astype(jnp.int32), page_size)
+    ends = jnp.cumsum(pages)
+    g = jnp.arange(slots * pages_per_slot, dtype=jnp.int32)
+    # the slot whose run [end - pages, end) holds g
+    slot = jnp.minimum(jnp.sum(g[:, None] >= ends[None, :], axis=1),
+                       slots - 1)
+    page = jnp.clip(g - (ends - pages)[slot], 0, pages_per_slot - 1)
+    return ((slot * pages_per_slot + page).astype(jnp.int32),
+            steps.astype(jnp.int32))
+
+
+def _paged_attn_kernel(sched_ref, bt_ref, len_ref, layer_ref, q_ref, k_ref,
+                       v_ref, o_ref, m_scr, l_scr, acc_scr, *, page_size,
+                       pages_per_slot, kv_heads, rep, sm_scale):
+    """Grid (steps,); step g attends page p of slot s, ``sched_ref[g] ==
+    s * pages_per_slot + p`` (``decode_schedule``).
+
+    q_ref  [1, nh, hd]       slot s's query token
     k_ref  [1, 1, page_size, kvh*hd]  the page block_tables[s, p] points
                              at, in layer layer_ref[0]; kv head g is
                              lanes [g*hd, (g+1)*hd)
     len_ref[s]               valid KV tokens for slot s (ragged lengths)
-    Scratch m/l/acc carry the online softmax across the page axis.
+    Scratch m/l/acc carry the online softmax across a slot's run of
+    steps, from its page 0 to the page that holds its last token.
     """
     from jax.experimental import pallas as pl
 
-    s = pl.program_id(0)
-    p = pl.program_id(1)
+    entry = sched_ref[pl.program_id(0)]
+    s = entry // pages_per_slot
+    p = entry % pages_per_slot
 
     @pl.when(p == 0)
     def _init():
@@ -76,8 +122,8 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
 
     length = len_ref[s]
 
-    # early-exit: a page whose first position is past the slot's length
-    # holds no valid tokens — skip all compute for it
+    # a scheduled page holds valid tokens; only the lone step of a call
+    # with nothing live (length 0) finds none and computes nothing
     @pl.when(p * page_size < length)
     def _compute():
         q = q_ref[0].astype(jnp.float32)            # [nh, hd]
@@ -120,7 +166,8 @@ def _paged_attn_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
         acc_scr[:] = acc_scr[:] * corr + jnp.concatenate(pv, axis=0)
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
 
-    @pl.when(p == pages_per_slot - 1)
+    # the slot's last page: the next step, if any, is another slot's
+    @pl.when((p + 1) * page_size >= length)
     def _finalize():
         l = l_scr[:, :1]
         l = jnp.where(l == 0.0, 1.0, l)              # empty slot guard
@@ -142,11 +189,14 @@ def as_layered(k_pages, v_pages, layer):
 
 
 def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
-                            sm_scale, interpret=False, layer=None):
+                            sm_scale, interpret=False, layer=None,
+                            schedule=None):
     """q [S, nh, hd]; pages [L, P, pg, kvh*hd] read at ``layer``, or one
     layer's [P, pg, kvh, hd] (``as_layered``); block_tables [S, maxp]
     int32 (unused tail entries must hold any VALID page id, e.g. 0);
-    lengths [S] int32. Returns [S, nh, hd]."""
+    lengths [S] int32; ``schedule`` what ``decode_schedule`` makes of
+    them (made here when the caller has none to share between layers).
+    Returns [S, nh, hd]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -160,39 +210,47 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
         raise ValueError(f"query heads ({nh}) must be a multiple of kv "
                          f"heads ({kvh})")
 
+    lengths = lengths.astype(jnp.int32)
+    if schedule is None:
+        schedule = decode_schedule(lengths, pg, maxp)
+    entries, steps = schedule
     flat_bt = block_tables.reshape(-1).astype(jnp.int32)
     kernel = functools.partial(
         _paged_attn_kernel, page_size=pg, pages_per_slot=maxp,
         kv_heads=kvh, rep=rep, sm_scale=sm_scale)
 
-    def page(s, p, bt, ln, l):
-        return (l[0], bt[s * maxp + p], 0, 0)
+    def row(g, sched, bt, ln, l):
+        return (sched[g] // maxp, 0, 0)
+
+    def page(g, sched, bt, ln, l):
+        return (l[0], bt[sched[g]], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(S, maxp),
+        num_scalar_prefetch=4,
+        grid=(steps,),
         in_specs=[
-            pl.BlockSpec((1, nh, hd), lambda s, p, bt, ln, l: (s, 0, 0)),
+            pl.BlockSpec((1, nh, hd), row),
             pl.BlockSpec((1, 1, pg, width), page),
             pl.BlockSpec((1, 1, pg, width), page),
         ],
-        out_specs=pl.BlockSpec((1, nh, hd),
-                               lambda s, p, bt, ln, l: (s, 0, 0)),
+        out_specs=pl.BlockSpec((1, nh, hd), row),
         scratch_shapes=[
             pltpu.VMEM((nh, 128), jnp.float32),
             pltpu.VMEM((nh, 128), jnp.float32),
             pltpu.VMEM((nh, hd), jnp.float32),
         ],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         name="paged_attention_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(flat_bt, lengths.astype(jnp.int32), layer, q, k_pages, v_pages)
+    )(entries, flat_bt, lengths, layer, q, k_pages, v_pages)
+    # a slot of length 0 has no step: nothing wrote its output block
+    return jnp.where((lengths > 0)[:, None, None], out, 0)
 
 
 # ------------------------------------------------- mesh-sharded kernel path
@@ -215,13 +273,15 @@ def kv_head_shards(mesh, num_kv_heads, num_heads=None, axis="mp"):
 
 
 def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths,
-                             layer, sm_scale, mesh, axis, interpret):
+                             layer, schedule, sm_scale, mesh, axis,
+                             interpret):
     """Per-shard Pallas launches over the mesh's ``axis``: the page
     pools arrive sharded on their merged kv-head axis (contiguous
     blocks of whole heads), q splits into the matching query-head
     groups (a GQA group never straddles a shard — consecutive head
     blocks keep each kv head with its own rep query heads), the block
-    table, lengths and layer index ride replicated, and the out_spec's
+    table, lengths, layer index and grid schedule ride replicated
+    (every shard takes the same steps), and the out_spec's
     head-axis concatenation IS the attention all-gather GSPMD would
     insert on the fallback path. XLA cannot partition a custom call,
     so the kernel path must shard_map itself; returns None when the
@@ -232,17 +292,18 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths,
     kvh = k_pages.shape[-1] // q.shape[-1]
     if kv_head_shards(mesh, kvh, q.shape[1], axis) <= 1:
         return None
-    def fn(q, k_pages, v_pages, block_tables, lengths, layer):
+    def fn(q, k_pages, v_pages, block_tables, lengths, layer, schedule):
         return _paged_attention_pallas(q, k_pages, v_pages, block_tables,
-                                       lengths, sm_scale, interpret, layer)
+                                       lengths, sm_scale, interpret, layer,
+                                       schedule)
 
     pool = P(None, None, None, axis)
     return jax.shard_map(
         fn, mesh=mesh,
         in_specs=(P(None, axis, None), pool, pool, P(None, None), P(None),
-                  P(None)),
+                  P(None), (P(None), P())),
         out_specs=P(None, axis, None), check_vma=False,
-    )(q, k_pages, v_pages, block_tables, lengths, layer)
+    )(q, k_pages, v_pages, block_tables, lengths, layer, schedule)
 
 
 # ------------------------------------------------------ XLA reference path
@@ -281,7 +342,8 @@ def _ref_paged_attention(q, k_pages, v_pages, block_tables, lengths,
 
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                    sm_scale=None, interpret=False, mesh=None, layer=None):
+                    sm_scale=None, interpret=False, mesh=None, layer=None,
+                    schedule=None):
     """Ragged paged-attention decode step.
 
     q            [slots, num_heads, head_dim]   one query token per slot
@@ -297,6 +359,9 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
                  order; entries past a slot's allocation must hold a
                  valid id (the manager fills them with 0)
     lengths      [slots] int32  valid KV tokens per slot (ragged)
+    schedule     ``decode_schedule(lengths, page_size, pages_per_slot)``
+                 where the caller already has it — the serving tick
+                 makes it once for all its layers; made here otherwise
     mesh         optional ``jax.sharding.Mesh`` whose ``mp`` axis the
                  page pools are sharded over on their kv-head axis
                  (sharded paged serving): the Pallas path then runs one
@@ -315,14 +380,18 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
         sm_scale = 1.0 / math.sqrt(q.shape[-1])
     k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
     if available() or interpret:
+        if schedule is None:
+            schedule = decode_schedule(lengths, k_pages.shape[2],
+                                       block_tables.shape[1])
         if mesh is not None:
             out = _paged_attention_sharded(
                 q, k_pages, v_pages, block_tables, lengths, layer,
-                sm_scale, mesh, "mp", interpret)
+                schedule, sm_scale, mesh, "mp", interpret)
             if out is not None:
                 return out
         return _paged_attention_pallas(q, k_pages, v_pages, block_tables,
                                        lengths, sm_scale,
-                                       interpret=interpret, layer=layer)
+                                       interpret=interpret, layer=layer,
+                                       schedule=schedule)
     return _ref_paged_attention(q, k_pages, v_pages, block_tables,
                                 lengths, sm_scale, layer=layer)

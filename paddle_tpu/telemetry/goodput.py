@@ -25,13 +25,16 @@ Taxonomy — every device token each tick lands in EXACTLY ONE kind:
 - ``chunk_pad``        prefill rows padded past the real chunk: the
                        ragged pow2 ladder (PR 6) and the dense
                        ``prefill_chunk`` remainder pad
-- ``skipped_page_dma`` page tokens the paged decode / ragged-prefill
-                       kernels DMA but mask: the kernel grid covers the
-                       full block-table width per slot, so pages wholly
-                       beyond a slot's live length still cost a DMA
-                       (PR 6 known cut; counted for LIVE slots only —
-                       an idle slot's whole ride is already
-                       ``null_redirect``)
+- ``skipped_page_dma`` page tokens the ragged-prefill kernel and the
+                       decode step's XLA fallback read but mask: they
+                       cover the full block-table width per slot, so
+                       pages wholly beyond a slot's live length still
+                       cost a read (PR 6 known cut; counted for LIVE
+                       slots only — an idle slot's whole ride is
+                       already ``null_redirect``; the decode KERNEL's
+                       grid holds live pages only, so on the chip the
+                       decode share of this kind is a model of the
+                       fallback)
 - ``replay``           preemption recompute (PR 8 known cut): prompt
                        re-prefill rows of a resumed request, and decode
                        rows re-generating tokens its waiter was already

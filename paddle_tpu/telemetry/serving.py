@@ -347,6 +347,13 @@ class ServerTelemetry:
             labelnames=("kind",))
         self._c_rows = rows.labels(kind="launched")
         self._c_rows_live = rows.labels(kind="live")
+        grid = r.counter(
+            "serving_decode_grid_total",
+            "The paged decode kernel's grid, a layer at a time: the "
+            "steps it took, and the pages its live rows spanned",
+            labelnames=("kind",))
+        self._c_grid_steps = grid.labels(kind="steps")
+        self._c_grid_live = grid.labels(kind="live_pages")
         # what a routed-expert / key-selecting model's launches did
         # (the server counts them; models with neither leave these 0)
         moe = r.counter(
@@ -572,6 +579,13 @@ class ServerTelemetry:
         if self.enabled:
             self._c_rows.inc(rows)
             self._c_rows_live.inc(live)
+
+    def on_decode_grid(self, steps, live_pages):
+        """One decode tick's kernel grid, over its layers: the steps
+        taken and the pages live rows spanned."""
+        if self.enabled:
+            self._c_grid_steps.inc(steps)
+            self._c_grid_live.inc(live_pages)
 
     def on_moe_rows(self, rows, live, touched=0):
         """One launch's expert-FFN rows: those computed (the live

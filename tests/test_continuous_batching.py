@@ -470,3 +470,56 @@ def test_preempted_slot_is_parked(tick_block):
     rows, live = _assert_parked(seen, tick_block)
     assert (srv.stats["decode_rows"], srv.stats["decode_live_rows"]) \
         == (rows, live)
+
+
+@pytest.mark.parametrize("tick_block", [1, 4])
+def test_decode_grid_counters_follow_the_dispatched_lengths(tick_block):
+    """``decode_grid_steps`` / ``decode_live_pages``: what the host adds
+    a tick equals what ``decode_grid`` (the function that sizes the
+    kernel's grid on the device) makes of the ``t`` each dispatch was
+    handed, a layer at a time; a stretch without a decode tick adds
+    nothing, and a dense server, which runs no paged kernel, counts
+    none."""
+    from paddle_tpu.models.gpt import GPTForCausalLM, gpt2_tiny
+    from paddle_tpu.ops.pallas.paged_attention import decode_grid
+    pt.seed(23)
+    model = GPTForCausalLM(gpt2_tiny())
+    model.eval()
+    kw = dict(max_slots=4, max_cache_len=CACHE, tick_block=tick_block)
+    srv = ContinuousBatchingServer(model, cache_backend="paged",
+                                   page_size=8, num_pages=33,
+                                   telemetry=True, **kw)
+    seen = _watch_dispatches(srv)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, model.cfg.vocab_size, (n,)).astype(np.int32)
+               for n in (5, 11)]
+    rids = [srv.submit(p, max_new_tokens=n)
+            for p, n in zip(prompts, (9, 20))]
+    grid = lambda: (srv.stats["decode_grid_steps"],
+                    srv.stats["decode_live_pages"])
+    assert grid() == (0, 0)
+    outs = srv.run()
+    assert all(len(outs[r]) for r in rids)
+    steps = live = 0
+    for t, _ in seen:
+        for j in range(tick_block):
+            at = t + j
+            pages, n = decode_grid(np.where(at < CACHE, at + 1, 0), 8)
+            steps, live = steps + int(n), live + int(pages.sum())
+    layers = model.cfg.num_layers
+    assert grid() == (steps * layers, live * layers)
+    assert steps >= live > 0
+    samples = srv.telemetry.registry.snapshot()[
+        "serving_decode_grid_total"]["samples"]
+    assert (samples[("steps",)], samples[("live_pages",)]) == grid()
+    done = grid()
+    for _ in range(3):                     # nothing left to decode
+        srv.step()
+    assert grid() == done
+
+    dense = ContinuousBatchingServer(model, **kw)
+    dense.submit(prompts[0], max_new_tokens=4)
+    dense.run()
+    assert dense.stats["decode_ticks"] > 0
+    assert (dense.stats["decode_grid_steps"],
+            dense.stats["decode_live_pages"]) == (0, 0)

@@ -37,6 +37,120 @@ def _solo(model, ids, n_new, **kw):
     return out[len(ids):]
 
 
+def _static_grid_attention(q, k_pages, v_pages, block_tables, lengths,
+                           sm_scale, layer=None):
+    """The decode kernel as it stood before its grid followed the live
+    pages: a static ``(slots, pages_per_slot)`` sweep, every page's step
+    taken and the dead ones skipped by a ``pl.when``. The test's local
+    oracle for "bit for bit on the live rows": same page-at-a-time
+    online softmax, same order within a slot."""
+    import functools
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+    k_pages, v_pages, layer = pa.as_layered(k_pages, v_pages, layer)
+    S, nh, hd = q.shape
+    _, _, pg, width = k_pages.shape
+    kvh, maxp = width // hd, block_tables.shape[1]
+    rep = nh // kvh
+
+    def kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
+               m_scr, l_scr, acc_scr):
+        s, p = pl.program_id(0), pl.program_id(1)
+
+        @pl.when(p == 0)
+        def _init():
+            m_scr[:] = jnp.full_like(m_scr, pa.NEG_INF)
+            l_scr[:] = jnp.zeros_like(l_scr)
+            acc_scr[:] = jnp.zeros_like(acc_scr)
+
+        length = len_ref[s]
+
+        @pl.when(p * pg < length)
+        def _compute():
+            q = q_ref[0].astype(jnp.float32)
+            k = k_ref[0, 0].astype(jnp.float32)
+            v = v_ref[0, 0].astype(jnp.float32)
+            m_prev, l_prev = m_scr[:], l_scr[:]
+            col = p * pg + jax.lax.broadcasted_iota(jnp.int32, (nh, pg), 1)
+            valid = col < length
+            s_log = jnp.concatenate([jax.lax.dot_general(
+                q[g * rep:(g + 1) * rep], k[:, g * hd:(g + 1) * hd],
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+                for g in range(kvh)], axis=0) * sm_scale
+            s_log = jnp.where(valid, s_log, pa.NEG_INF)
+            m_new = jnp.maximum(m_prev[:, :1],
+                                jnp.max(s_log, axis=-1, keepdims=True))
+            corr = jnp.exp(m_prev[:, :1] - m_new)
+            pexp = jnp.where(valid, jnp.exp(s_log - m_new), 0.0)
+            l_scr[:] = jnp.broadcast_to(
+                corr * l_prev[:, :1] + jnp.sum(pexp, -1, keepdims=True),
+                l_scr.shape)
+            acc_scr[:] = acc_scr[:] * corr + jnp.concatenate([
+                jax.lax.dot_general(
+                    pexp[g * rep:(g + 1) * rep], v[:, g * hd:(g + 1) * hd],
+                    (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)
+                for g in range(kvh)], axis=0)
+            m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
+
+        @pl.when(p == maxp - 1)
+        def _finalize():
+            l = l_scr[:, :1]
+            o_ref[0] = (acc_scr[:] / jnp.where(l == 0.0, 1.0, l)).astype(
+                o_ref.dtype)
+
+    def page(s, p, bt, ln, l):
+        return (l[0], bt[s * maxp + p], 0, 0)
+
+    row = lambda s, p, bt, ln, l: (s, 0, 0)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(S, maxp),
+            in_specs=[pl.BlockSpec((1, nh, hd), row),
+                      pl.BlockSpec((1, 1, pg, width), page),
+                      pl.BlockSpec((1, 1, pg, width), page)],
+            out_specs=pl.BlockSpec((1, nh, hd), row),
+            scratch_shapes=[pltpu.VMEM((nh, 128), jnp.float32),
+                            pltpu.VMEM((nh, 128), jnp.float32),
+                            pltpu.VMEM((nh, hd), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct((S, nh, hd), q.dtype),
+        interpret=True,
+    )(block_tables.reshape(-1).astype(jnp.int32),
+      lengths.astype(jnp.int32), layer, q, k_pages, v_pages)
+
+
+# live patterns of one decode call: 5 slots, page 8, 4 pages a slot
+_PG, _MAXP = 8, 4
+_LIVE = {
+    "none": [0, 0, 0, 0, 0],
+    "one-slot": [0, 0, 13, 0, 0],
+    "first-and-last": [9, 0, 0, 0, _MAXP * _PG],
+    "full-house": [_MAXP * _PG] * 5,
+    "page-edges": [1, _PG, _PG + 1, _MAXP * _PG, 0],
+}
+
+
+def _live_case(lengths, kvh, hd, layered):
+    """Pools, block tables (distinct live pages, tails on a LOUD null
+    page, as the allocator leaves them) and lengths of one pattern."""
+    lengths = np.asarray(lengths, np.int32)
+    S, P = len(lengths), 1 + len(lengths) * _MAXP
+    kp = _rand(P, _PG, kvh, hd, seed=22).at[0].set(1e3)
+    vp = _rand(P, _PG, kvh, hd, seed=23).at[0].set(-1e3)
+    free = np.random.RandomState(24).permutation(np.arange(1, P))
+    bt = np.zeros((S, _MAXP), np.int32)
+    for s_, n in enumerate(-(-lengths // _PG)):
+        bt[s_, :n] = free[s_ * _MAXP:s_ * _MAXP + n]
+    layer = None
+    if layered:        # the serving loop's call: whole pool, layer index
+        from paddle_tpu.models.generation import pool_lanes
+        kp, vp = (pool_lanes(jnp.stack([-a, a])) for a in (kp, vp))
+        layer = 1
+    return kp, vp, jnp.asarray(bt), jnp.asarray(lengths), layer
+
+
 # ------------------------------------------------------------- kernel
 
 
@@ -60,6 +174,70 @@ class TestPagedAttentionKernel:
                                       1.0 / np.sqrt(hd))
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=2e-5, atol=2e-5)
+
+    @pytest.mark.parametrize("pattern", list(_LIVE))
+    @pytest.mark.parametrize("layered", [False, True],
+                             ids=["one-layer", "layered-pool"])
+    @pytest.mark.parametrize("kvh,nh", [(2, 2), (2, 4)],
+                             ids=["mha", "gqa"])
+    def test_grid_over_live_pages_matches_oracles(self, kvh, nh, layered,
+                                                  pattern):
+        """The grid takes one step a live page; whatever is live, a
+        live row comes out as the gather oracle has it and BIT FOR BIT
+        as the static ``slots x pages`` sweep computed it, and a slot
+        of length 0 is exact zeros."""
+        hd = 32
+        kp, vp, bt, lengths, layer = _live_case(_LIVE[pattern], kvh, hd,
+                                                layered)
+        q = _rand(len(_LIVE[pattern]), nh, hd, seed=21)
+        scale = 1.0 / np.sqrt(hd)
+        out = np.asarray(pa._paged_attention_pallas(
+            q, kp, vp, bt, lengths, scale, interpret=True, layer=layer))
+        live = np.asarray(lengths) > 0
+        assert (out[~live] == 0).all()
+        old = np.asarray(_static_grid_attention(q, kp, vp, bt, lengths,
+                                                scale, layer=layer))
+        np.testing.assert_array_equal(out[live], old[live])
+        ref = np.asarray(pa._ref_paged_attention(q, kp, vp, bt, lengths,
+                                                 scale, layer=layer))
+        np.testing.assert_allclose(out[live], ref[live], rtol=2e-5,
+                                   atol=2e-5)
+
+    @pytest.mark.parametrize("pattern", list(_LIVE))
+    def test_grid_takes_the_steps_the_host_counts(self, pattern,
+                                                  monkeypatch):
+        """The bound the ``pallas_call`` is handed equals what
+        ``decode_grid`` makes of the same lengths in NumPy (the
+        server's ``decode_grid_steps``), and the schedule's first
+        ``steps`` entries are the live pages, slot-major, each once."""
+        from jax.experimental import pallas as pl
+        lens = np.asarray(_LIVE[pattern], np.int32)
+        pages, steps = pa.decode_grid(lens, _PG)
+        np.testing.assert_array_equal(pages, -(-lens // _PG))
+        assert int(steps) == max(int(pages.sum()), 1)
+        want = [s_ * _MAXP + p for s_, n in enumerate(pages)
+                for p in range(n)]
+        entries, dev_steps = pa.decode_schedule(jnp.asarray(lens), _PG,
+                                                _MAXP)
+        entries = np.asarray(entries)
+        assert int(dev_steps) == int(steps)
+        assert entries[:len(want)].tolist() == want
+        # past the live pages: valid block-table indices nobody visits
+        assert entries.shape == (len(lens) * _MAXP,)
+        assert ((0 <= entries) & (entries < len(lens) * _MAXP)).all()
+
+        seen = []
+        real = pl.pallas_call
+
+        def spy(kernel, *a, grid_spec, **kw):
+            seen.append(int(grid_spec.grid[0]))
+            return real(kernel, *a, grid_spec=grid_spec, **kw)
+
+        monkeypatch.setattr(pl, "pallas_call", spy)
+        kp, vp, bt, lengths, _ = _live_case(lens, 2, 32, False)
+        pa._paged_attention_pallas(_rand(len(lens), 2, 32, seed=21), kp, vp,
+                                   bt, lengths, 0.2, interpret=True)
+        assert seen == [int(steps)]
 
     def test_kernel_ignores_stale_tail_pages(self):
         """Block-table entries past a slot's length point at the null

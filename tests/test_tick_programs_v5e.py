@@ -109,25 +109,26 @@ def _weight_shapes(cfg, dtype=jnp.bfloat16):
     return {k: jax.ShapeDtypeStruct(s, dtype) for k, s in shapes.items()}
 
 
-def _paged_bundle(cfg, weights, num_pages):
+def _paged_bundle(cfg, weights, num_pages, mesh=None):
     """The paged GPT decode bundle over ``weights`` (arrays or tracers):
     the bundle builder finds its stacked tree already made, so nothing
     is materialised."""
     model = types.SimpleNamespace(
-        cfg=cfg, _pt_stacked_weights={(None, None): weights})
+        cfg=cfg, _pt_stacked_weights={
+            (None, None if mesh is None else id(mesh)): weights})
     return generation._make_gpt_decode_fns(
-        model, CACHE_LEN, cache_backend="paged", page_size=PAGE,
+        model, CACHE_LEN, mesh=mesh, cache_backend="paged", page_size=PAGE,
         num_pages=num_pages)
 
 
-def _decode_tick(cfg, num_pages):
+def _decode_tick(cfg, num_pages, mesh=None):
     """The server's own ``decode_tick`` (greedy, one step a tick) over
     a bundle built from the traced weights."""
     from paddle_tpu.inference.continuous_batching import (
         ContinuousBatchingServer)
 
     def decode_tick(weights, tok, caches, t, keys):
-        b = _paged_bundle(cfg, weights, num_pages)
+        b = _paged_bundle(cfg, weights, num_pages, mesh)
         srv = types.SimpleNamespace(
             _embed_fn=b[1], _step_fn=b[2], _head_fn=b[3], do_sample=False,
             _temperature=1.0, _top_k=0, _top_p=1.0, tick_block=1,
@@ -206,6 +207,17 @@ def _decode_specs(cfg, slots, num_pages):
                     jax.ShapeDtypeStruct((slots, 2), jnp.uint32))
 
 
+def _one_decode_kernel(exe):
+    """ONE decode-attention custom call a program (the layer loop's),
+    and its grid's bound a runtime operand: the call's first operand is
+    a scalar ``s32[]``, the step count ``decode_schedule`` made."""
+    calls = [line for line in exe.as_text().splitlines()
+             if "custom-call(" in line and "paged_attention_decode" in
+             line.split("custom-call(")[0]]
+    assert len(calls) == 1, calls
+    assert "operand_layout_constraints={s32[]," in calls[0]
+
+
 @pytest.mark.parametrize("geometry", [MEDIUM, XL], ids=["medium", "xl"])
 def test_decode_tick_leaves_the_pool_in_place(geometry, one_chip,
                                               as_on_chip):
@@ -213,8 +225,68 @@ def test_decode_tick_leaves_the_pool_in_place(geometry, one_chip,
                              ("cfg", "slots", "num_pages"))
     caches, specs = _decode_specs(cfg, slots, num_pages)
     exe = _compile(_decode_tick(cfg, num_pages), (2,), one_chip, *specs)
-    assert "paged_attention_decode" in exe.as_text()
+    _one_decode_kernel(exe)
     _assert_pool_stays(exe, caches)
+    if geometry is MEDIUM:
+        # the cell's program: its temporaries round to 0.00 GiB
+        assert exe.memory_analysis().temp_size_in_bytes < 2 ** 30 / 200
+
+
+def test_decode_tick_compiles_for_the_four_chip_mesh(topo, as_on_chip):
+    """The mesh path at the cell's geometry on ``v5e:2x2``: one launch a
+    kv-head shard under ``shard_map``, the grid's schedule replicated,
+    each device's quarter of the pool aliased and left in place."""
+    from jax.sharding import Mesh, NamedSharding
+    from jax.sharding import PartitionSpec as P
+    cfg, slots, num_pages = (MEDIUM[k] for k in
+                             ("cfg", "slots", "num_pages"))
+    mesh = Mesh(np.array(topo.devices), ("mp",))
+    dims = {"attn.qkv.weight": 2, "attn.proj.weight": 1,
+            "mlp.fc1.weight": 2, "mlp.fc2.weight": 1}
+
+    def on(spec, *axes):
+        return jax.ShapeDtypeStruct(spec.shape, spec.dtype,
+                                    sharding=NamedSharding(mesh, P(*axes)))
+
+    weights = {k: on(v, *[("mp" if i == dims.get(k) else None)
+                          for i in range(len(v.shape))])
+               for k, v in _weight_shapes(cfg).items()}
+    caches = _cache_shapes(cfg, slots, num_pages)
+    cache_specs = {"bt": on(caches["bt"]),
+                   "pool": {n: on(a, None, None, None, "mp")
+                            for n, a in caches["pool"].items()}}
+    i32 = lambda *s: on(jax.ShapeDtypeStruct(s, jnp.int32))
+    exe = (jax.jit(_decode_tick(cfg, num_pages, mesh), donate_argnums=(2,))
+           .trace(weights, i32(slots), cache_specs, i32(slots),
+                  on(jax.ShapeDtypeStruct((slots, 2), jnp.uint32)))
+           .lower(lowering_platforms=("tpu",)).compile())
+    _one_decode_kernel(exe)
+    # per device: a quarter of the lanes, still row-major and in place
+    shard = {n: jax.ShapeDtypeStruct(a.shape[:-1] + (a.shape[-1] // 4,),
+                                     a.dtype)
+             for n, a in caches["pool"].items()}
+    _assert_pool_stays(exe, {"pool": shard})
+
+
+def test_grid_schedule_is_made_outside_the_layer_loop(as_on_chip):
+    """Every layer of a tick attends the same lengths: the cumulative
+    sum that makes the kernel's schedule sits in the decode program's
+    body ONCE, before the layer loop, and the loop's body (the scan's
+    ``while``) holds the kernel and no cumulative sum."""
+    cfg, slots, num_pages = (MEDIUM[k] for k in
+                             ("cfg", "slots", "num_pages"))
+    _, specs = _decode_specs(cfg, slots, num_pages)
+    text = (jax.jit(_decode_tick(cfg, num_pages), donate_argnums=(2,))
+            .trace(*specs).lower(lowering_platforms=("tpu",)).as_text())
+    funcs = text.split("func.func ")
+    made = [f for f in funcs if "call @cumsum(" in f]
+    assert len(made) == 1 and made[0].count("call @cumsum(") == 1
+    # ... in the step's function, ahead of its layer loop
+    assert -1 < made[0].index("call @cumsum(") < made[0].index(
+        "stablehlo.while")
+    (body,) = [f for f in funcs if "tpu_custom_call" in f]
+    assert body.count("tpu_custom_call") == 1
+    assert "cumsum" not in body and "stablehlo.while" not in body
 
 
 def test_prefill_tick_leaves_the_pool_in_place(one_chip, as_on_chip):
