@@ -11,6 +11,14 @@ full width of GPT-2 345M (24 layers, hidden 1024, 16 heads x 64, vocab
 - ``serve``: ``ContinuousBatchingServer(cache_backend="paged")`` (ragged
   prefill + split tick) answering mixed-length requests, checked on logits
   against a plain f32 forward.
+- ``hybrid-serve``: the same server over a model whose layers are NOT alike
+  (the ``lfm2`` family: gated short-convolution layers with per-slot state
+  beside the page pool, attention layers, dense and routed-expert FFNs in one
+  layer loop), at small lane-legal widths, prompts spanning several launches
+  with other slots decoding between them, checked on logits against the
+  model's own uncached f32 forward. The benchmark's third configuration runs
+  these programs at published widths; this phase compiles them for the chip
+  outside the benchmark too.
 - ``train``: ``jit.train_step_fn(model, ce, AdamW)`` at B=8, S=1024.
 - ``mesh4`` (only where JAX reports >= 4 devices): the serve phase over an
   ``mp=4`` mesh plus ``parallel.parallel_train_step``.
@@ -665,6 +673,83 @@ def phase_serve(P, phases, rehearse, watch, mesh=None, label="serve"):
     gc.collect()
 
 
+# =================================================================== hybrid
+def hybrid_preset(rehearse):
+    """A 6-layer ``lfm2``-shaped model (conv conv | attn conv attn conv; 2
+    dense layers, then 8 experts top-2) at lane-legal widths: hidden 256,
+    heads of 64, 2 K/V heads (128 pool lanes). The experts' ``w2`` is drawn
+    at a tenth of the range, as the benchmark's configuration draws it, so
+    that a rounding that flips an untrained router's fourth choice does not
+    move the logits."""
+    from paddle_tpu.models.lfm2 import lfm2_tiny
+    if rehearse:
+        return dict(cfg=lfm2_tiny(), slots=4, cache_len=64, page=8,
+                    prompts=(9, 20, 13, 6), new=5, budget=8)
+    cfg = lfm2_tiny(vocab_size=1024, hidden_size=256, intermediate_size=512,
+                    moe_intermediate_size=128, max_position_embeddings=1024,
+                    dtype="bfloat16")
+    return dict(cfg=cfg, slots=8, cache_len=512, page=16,
+                prompts=(33, 150, 90, 57, 200, 17), new=12, budget=64)
+
+
+def phase_hybrid(phases, rehearse, watch):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.inference import ContinuousBatchingServer
+    from paddle_tpu.models import lfm2
+    H = hybrid_preset(rehearse)
+    cfg = H["cfg"]
+    with phases.run("hybrid-serve"):
+        model = lfm2.Lfm2MoeForCausalLM(cfg, weights=lfm2.init_weights(
+            cfg, seed=0, scale={"model.moe_layers.experts_w2": 0.1}))
+        model.eval()
+        params32 = {n: a.astype(jnp.float32)
+                    for n, a in model.raw_params().items()}
+        fwd = jax.jit(lambda ps, ids: lfm2._forward(cfg, ids, ps))
+        width = max(H["prompts"]) + H["new"]
+
+        def ref_logits(ids):
+            row = np.zeros((1, width), np.int32)
+            row[0, :len(ids)] = ids
+            with jax.default_matmul_precision("highest"):
+                out = fwd(params32, jnp.asarray(row))
+            check(out.dtype == jnp.float32, f"reference ran in {out.dtype}")
+            return np.asarray(out[0, :len(ids)])
+
+        srv = ContinuousBatchingServer(
+            model, cache_backend="paged", max_slots=H["slots"],
+            max_cache_len=H["cache_len"], page_size=H["page"],
+            prefill_tokens_per_tick=H["budget"])
+        layers = lfm2.layer_counts(cfg)
+        check(srv._caches["pool"]["k"].shape[0] == layers[0],
+              "the pool has a layer that is no attention layer's")
+        check(srv._caches["state"].shape[:2] == (layers[1], H["slots"]),
+              "the slot state is not [conv layers, slots, ...]")
+        rng = np.random.default_rng(6)
+        prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+                   for n in H["prompts"]]
+        # twice: the second wave lands in slots the first one left
+        for wave in range(2):
+            rids = [srv.submit(p, max_new_tokens=H["new"]) for p in prompts]
+            outs = srv.run()
+            for i, (rid, p) in enumerate(zip(rids, prompts)):
+                check(len(outs[rid]) == H["new"], f"request {rid} is short")
+                check_tokens(f"hybrid wave {wave} #{i}", ref_logits, p,
+                             outs[rid])
+        s = srv.stats
+        say(f"  hybrid: {s['prefill_chunks']} slot-chunks, "
+            f"{s['prefill_chunks_carried']} carried state; "
+            f"{s['decode_ticks']} decode ticks touched "
+            f"{s['moe_experts_touched']} experts")
+        check(s["prefill_chunks_carried"] > 0,
+              "no prompt spanned two launches")
+        free, live, *_ = srv.pool_balance()
+        check(live == 0, f"pages leaked: pool_balance() live == {live}")
+        mem_line("hybrid-serve")
+    gc.collect()
+
+
 # ==================================================================== train
 def ce_loss(logits, labels):
     import jax
@@ -817,6 +902,7 @@ def main(argv=None):
         phase_kernels(P, args.rehearse)
     mem_line("kernels")
     phase_serve(P, phases, args.rehearse, watch)
+    phase_hybrid(phases, args.rehearse, watch)
     with phases.run("train"):
         phase_train(P, args.rehearse, watch)
     gc.collect()

@@ -36,6 +36,19 @@ from ..telemetry.serving import TickBoundary
 __all__ = ["ContinuousBatchingServer", "PreemptionPolicy", "PoolBalance"]
 
 
+# Rows (chunks x width) one prefill launch may compute. A launch is PACKED
+# (``_prefill_tick``): it has a row for the slots in its plan and not for
+# every slot, because its dense matmuls and its attention kernel's static
+# sweep run over all of its rows, whatever is live: 64 slots x 1,024 is
+# 65,536 rows, whose activations do not fit beside a 10 GB model, and at
+# 16,384 rows a launch of at most 1,024 prompt tokens held every decoding
+# slot for 310 ms (PERF.md section 6, PR 31). Four times the default
+# budget's worth of rows, so that a plan of a prompt's tail, a whole short
+# prompt and the next one's head still fits one launch at the widest
+# width. ROADMAP A2b-c ranks a lower limit in every cell.
+_LAUNCH_ROWS = 4096
+
+
 class _Pending:
     """A queued request awaiting a slot."""
 
@@ -250,7 +263,8 @@ class ContinuousBatchingServer:
     stay bit-identical to the dense backend. When the pool is full,
     admission waits (FIFO) for a harvest to free pages.
 
-    With ``auto_prefix_cache=True`` (the paged default; see
+    With ``auto_prefix_cache=True`` (the paged default — None reads as
+    True wherever pages are a request's whole state; see
     inference/prefix_cache.py) prefix reuse needs no operator calls at
     all: every finished request donates its full prompt pages into a
     radix tree keyed by token content, every admission looks up the
@@ -260,6 +274,16 @@ class ContinuousBatchingServer:
     and shrinks under load with zero correctness impact (auto hits are
     bit-identical to cold runs). ``register_prefix`` entries live in
     the same tree as PINNED nodes that eviction never touches.
+
+    A model with PER-SLOT STATE beside the pool (a hybrid's short-
+    convolution layers: ``caches["state"]``, addressed by the slot and
+    not by the block table) serves through the same tick. What assumes
+    that pages are the whole state is refused for it BY NAME, at
+    construction or at the call: the prefix cache (``auto_prefix_cache``
+    reads as False for such a model; True, and ``register_prefix``,
+    raise), ``admission="optimistic"`` (a replayed victim resumes from
+    donated pages), ``host_tier`` and ``migrate_*`` (ROADMAP B5: state
+    snapshots at page boundaries lift all four).
 
     Paged serving prefills RAGGED by default (``prefill_mode="ragged"``):
     admissions only reserve pages, and every tick runs the next chunk
@@ -361,7 +385,7 @@ class ContinuousBatchingServer:
                  eos_token_id=None, seed=0, weight_dtype=None,
                  prefill_chunk=None, mesh=None, tick_block=1,
                  cache_dtype=None, cache_backend="dense", page_size=16,
-                 num_pages=None, auto_prefix_cache=True,
+                 num_pages=None, auto_prefix_cache=None,
                  admission="reserve", headroom_pages=1,
                  preemption_policy=None,
                  prefill_mode=None, prefill_tokens_per_tick=None,
@@ -434,6 +458,14 @@ class ContinuousBatchingServer:
                                     self.max_slots, pages_per_slot,
                                     fault_injector=fault_injector)
             self._caches = self._paged_bundle[0](self.max_slots)
+            # per-slot recurrent state beside the pool (a short
+            # convolution's last inputs): carried and donated like the
+            # pool, addressed by the slot — no page holds it
+            self._slot_state = "state" in self._caches
+            if self._slot_state:
+                auto_prefix_cache = self._refuse_for_slot_state(
+                    auto_prefix_cache, admission, host_tier,
+                    host_tier_bytes, prefill_mode)
             # how many ways the pool actually sharded (1 = replicated
             # fallback: kv heads not divisible by the mp axis) — the
             # host-side bookkeeping's ONLY mesh knowledge, feeding the
@@ -487,7 +519,8 @@ class ContinuousBatchingServer:
                                        host_tier=self._host,
                                        spill=self._spill_payload)
             self._kv.reclaimer = self._reclaim_pages
-            self._auto_prefix = bool(auto_prefix_cache)
+            self._auto_prefix = (True if auto_prefix_cache is None
+                                 else bool(auto_prefix_cache))
             self._ragged_fn = (self._paged_bundle[5]
                                if len(self._paged_bundle) > 5 else None)
         else:
@@ -501,6 +534,7 @@ class ContinuousBatchingServer:
             self._bt_pages = None
             self._pool_shards = 1
             self._caches = self._init_caches(self.max_slots)
+            self._slot_state = False    # dense: state rides the slot's rows
             self._prefix = None
             self._auto_prefix = False
             self._ragged_fn = None
@@ -636,11 +670,16 @@ class ContinuousBatchingServer:
                       # slots that rode the launch live: a parked
                       # slot's join no expert's group) and those of a
                       # live token; distinct experts the live rows of a
-                      # DECODE tick chose, summed over layers and
-                      # ticks; keys in the context of live decode rows
+                      # DECODE tick chose, summed over expert layers
+                      # and ticks; keys in the context of live decode rows
                       # (a tick's last row a slot, a layer at a time)
                       # and keys the selection kept of them, which the
                       # device counts where it makes the mask
+                      # slot-chunks the prefill launches ran (one a
+                      # slot a launch) and those that began past a
+                      # prompt's start: a model with slot state reads
+                      # the state its last chunk left there
+                      "prefill_chunks": 0, "prefill_chunks_carried": 0,
                       "decode_ticks": 0, "decode_rows": 0,
                       "decode_live_rows": 0, "decode_grid_steps": 0,
                       "decode_live_pages": 0, "moe_rows": 0,
@@ -651,7 +690,11 @@ class ContinuousBatchingServer:
             if getattr(cfg, "num_experts", 0) else 0
         indexer = getattr(cfg, "indexer", None)
         self._select_k = int(indexer[2]) if indexer else 0
-        self._n_layers = int(getattr(cfg, "num_layers", 0) or 0)
+        # layers that attend: the pool's own count on the paged backend
+        # (a model with a layer spec has fewer than it has layers)
+        self._n_layers = (int(self._caches["pool"]["k"].shape[0])
+                          if self._kv is not None
+                          else int(getattr(cfg, "num_layers", 0) or 0))
         # telemetry (paddle_tpu.telemetry.ServerTelemetry): True builds
         # a default-enabled one; None (default) keeps the hot path at
         # a single attribute check — no locks, no clock reads
@@ -772,6 +815,37 @@ class ContinuousBatchingServer:
         if self._tele is not None:
             self._tele.set_health(HEALTHY)
 
+    def _refuse_for_slot_state(self, auto_prefix_cache, admission,
+                               host_tier, host_tier_bytes, prefill_mode):
+        """Construction-time refusals for a model with slot state; the
+        resolved ``auto_prefix_cache`` (None reads as False here)."""
+        asked = [what for what, on in (
+            ("auto_prefix_cache=True (a prefix hit resumes at a page "
+             "boundary)", bool(auto_prefix_cache)),
+            ("admission='optimistic' (a preempted request is replayed "
+             "from the pages it donated)", admission == "optimistic"),
+            ("host_tier (spilled pages are restored into prefix hits)",
+             host_tier is not None and host_tier is not False
+             or host_tier_bytes is not None),
+            ("prefill_mode='dense' on the paged backend (a dense "
+             "batch-1 prefill hands the slot its pages only)",
+             prefill_mode == "dense")) if on]
+        if asked:
+            self._refuse_slot_state("; ".join(asked))
+        return False
+
+    def _refuse_slot_state(self, what):
+        """THE refusal of whatever assumes that pages are a request's
+        whole state, for a model that keeps state beside them."""
+        if self._slot_state:
+            raise NotImplementedError(
+                "this model keeps per-slot recurrent state (its short-"
+                "convolution layers' last inputs, caches['state']) beside "
+                f"the page pool, and {what} assumes that pages are a "
+                "request's whole state: a run of pages would be resumed "
+                "with no state to go with it. State snapshots at page "
+                "boundaries are ROADMAP B5")
+
     # ------------------------------------------------------ prefix cache
     def register_prefix(self, prefix_ids):
         """Prefill a shared prompt prefix (e.g. a system prompt) ONCE and
@@ -786,6 +860,8 @@ class ContinuousBatchingServer:
         donating decode program). Paged backend: full pages the auto
         prefix cache already holds for these tokens are adopted (and
         pinned) rather than re-allocated."""
+        self._refuse_slot_state("register_prefix (a prefix hit resumes "
+                                "at a page boundary)")
         ids = np.asarray(unwrap(prefix_ids)).astype(np.int32).reshape(-1)
         T = ids.shape[0]
         if T + 1 > self.max_cache_len:
@@ -1939,7 +2015,9 @@ class ContinuousBatchingServer:
         ticks. Chunk width C is padded up a power-of-two ladder (min 2:
         single-row matmuls take XLA's fused-reduce path and break
         bit-parity with the dense prefill) so compiles stay
-        O(log max_cache_len)."""
+        O(log max_cache_len). The launch is PACKED: row j is the
+        plan's j-th slot, and width C has ``_LAUNCH_ROWS // C`` rows
+        (at most a row a slot), so one program a width."""
         budget = self._prefill_budget - self._prefill_used
         if not self._prefill_fifo or budget <= 0:
             return
@@ -1947,53 +2025,69 @@ class ContinuousBatchingServer:
         if b is not None:
             b.mark("prefill_pack")
         plan = []                        # (slot, start, take)
-        used = 0
+        used = widest = 0
+        S = self.max_slots
+        width = lambda take: max(2, 1 << (take - 1).bit_length())
         for slot in self._prefill_fifo:
             if used >= budget:
                 break
             st = self._slots[slot]
             take = min(st.prompt_len - st.fill_pos, budget - used)
+            # a launch computes every one of its rows, so it has
+            # _LAUNCH_ROWS // C of them at width C, and a slot that
+            # would not fit waits for the next launch
+            C = width(max(widest, take))
+            if plan and (len(plan) + 1) * C > _LAUNCH_ROWS:
+                break
             plan.append((slot, st.fill_pos, take))
-            used += take
+            used, widest = used + take, max(widest, take)
         if not plan:
             return
         self._prefill_used += used
-        C = max(2, 1 << (max(t for _, _, t in plan) - 1).bit_length())
-        S = self.max_slots
-        toks = np.zeros((S, C), np.int32)
-        t0 = np.full((S,), self.max_cache_len, np.int32)  # idle sentinel
-        out_idx = np.zeros((S,), np.int32)
-        done = []
-        for slot, start, take in plan:
+        C = width(widest)
+        # row j is the plan's j-th slot; the rest are padding rows: no
+        # slot's (slot S), parked on the idle sentinel
+        P = min(S, max(1, _LAUNCH_ROWS // C))
+        toks = np.zeros((P, C), np.int32)
+        t0 = np.full((P,), self.max_cache_len, np.int32)
+        out_idx = np.zeros((P,), np.int32)
+        takes = np.zeros((P,), np.int32)
+        slots = np.full((P,), S, np.int32)
+        done = []                        # (slot, row)
+        for row, (slot, start, take) in enumerate(plan):
             st = self._slots[slot]
-            toks[slot, :take] = st.ids[start:start + take]
-            t0[slot] = start
+            toks[row, :take] = st.ids[start:start + take]
+            t0[row], takes[row], slots[row] = start, take, slot
             if start + take == st.prompt_len:
-                out_idx[slot] = take - 1
-                done.append(slot)
+                out_idx[row] = take - 1
+                done.append((slot, row))
         self._sync_block_table()
-        toks_d, t0_d, out_d = (jnp.asarray(toks), jnp.asarray(t0),
-                               jnp.asarray(out_idx))
+        args = (jnp.asarray(toks), jnp.asarray(t0), self._caches,
+                jnp.asarray(out_idx), jnp.asarray(takes),
+                jnp.asarray(slots))
         prefill_fn = self._ragged_fn
         if self._costs is not None:
             # one priced program per chunk width on the pow2 ladder —
             # a width first seen AFTER warmup is exactly the recompile
             # the watch exists to surface
             prefill_fn = self._cost_program(
-                self._cost_op("prefill"), self._ragged_fn,
-                (toks_d, t0_d, self._caches, out_d))
+                self._cost_op("prefill"), self._ragged_fn, args)
         if b is not None:
             # the chip's from here to the first value read back (in
             # _activate, which marks "activate")
             t_launch = b.mark(
                 "prefill_wait", width=C, rows=len(plan),
                 rids=[self._slots[slot].rid for slot, _, _ in plan])
-        logits, self._caches = prefill_fn(toks_d, t0_d, self._caches,
-                                          out_d)
+        logits, self._caches = prefill_fn(*args)
         self._count_dispatches(1, op="prefill")
+        carried = sum(1 for _, start, _ in plan if start > 0)
+        self.stats["prefill_chunks"] += len(plan)
+        self.stats["prefill_chunks_carried"] += carried
+        if self._tele is not None:
+            self._tele.on_prefill_chunks(len(plan), carried)
         if self._moe_k:
             # the experts compute the rows of the slots in the plan;
-            # every other slot rides this launch on the sentinel
+            # a padding row rides this launch on the sentinel
             self._count_routed(None, used, len(plan) * C)
         led = self._led
         for slot, start, take in plan:
@@ -2002,7 +2096,7 @@ class ContinuousBatchingServer:
             self.stats["prefill_tokens"] += take
             if led is not None:
                 # the launch runs C query rows for each participating
-                # slot (idle slots are kernel-skipped): `take` real
+                # slot (padding rows are kernel-skipped): `take` real
                 # rows + pow2-ladder pad, and maxp page DMAs of which
                 # only the covered prefix is unmasked
                 if st.preempts:
@@ -2020,8 +2114,8 @@ class ContinuousBatchingServer:
             if st.journey is not None:
                 st.journey.event("prefill_chunk", start=start,
                                  take=take)
-        for slot in done:
-            self._activate(slot, logits[slot:slot + 1])
+        for slot, row in done:
+            self._activate(slot, logits[row:row + 1])
         if b is not None:
             wall = b.mark("admit") - t_launch
             self.stats["prefill_wall_s"] += wall
@@ -3548,6 +3642,8 @@ class ContinuousBatchingServer:
         flight); an injected ``migrate.gather`` fault fires BEFORE the
         pause, so a faulted attempt leaves the slot untouched — never
         a leak."""
+        self._refuse_slot_state("migration (send_pages ships a "
+                                "request's pages and nothing else)")
         from .kv_tier import _sha256
         with self._lock:
             if self._kv is None:
@@ -3916,6 +4012,8 @@ class ContinuousBatchingServer:
         free slot / pool exhausted) propagates from the admit; a
         scatter failure rolls the fresh pages back. The source aborts
         and the caller replays — never a request failure."""
+        self._refuse_slot_state("migration (send_pages ships a "
+                                "request's pages and nothing else)")
         from .kv_tier import _sha256
         with self._lock:
             if self._kv is None:
@@ -4001,6 +4099,8 @@ class ContinuousBatchingServer:
         ``in_flight`` (it holds real pool pages) but never ticks: it
         is not active, not on the prefill fifo, and has no deadline
         until commit."""
+        self._refuse_slot_state("migration (send_pages ships a "
+                                "request's pages and nothing else)")
         with self._lock:
             if self._kv is None:
                 raise MigrationError(

@@ -263,7 +263,11 @@ class PagedKVCache:
     @staticmethod
     def paged_hbm_bytes(num_pages, page_size, layers, kv_heads, head_dim,
                         itemsize=4):
-        """K+V pool bytes for a paged cache config."""
+        """K+V pool bytes for a paged cache config. ``layers`` are the
+        pool's: the model's ATTENTION layers, which is every layer
+        unless the model has a layer spec (a hybrid's convolution
+        layers keep per-slot state, ``slots x rows x hidden`` a layer,
+        beside the pool and not in it)."""
         return 2 * layers * num_pages * page_size * kv_heads * head_dim \
             * itemsize
 

@@ -326,21 +326,31 @@ def paged_kv_heads(cfg):
 
 
 def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
-                   kvh, hd, dtype, extra=None, route_k=None, kept=False):
-    """Paged decode cache tree: one global page pool over all layers
-    (``paged_pool_shape``) plus the per-slot block table (a RUNTIME
-    argument of the decode program — page churn never recompiles).
+                   kvh, hd, dtype, extra=None, route_k=None, kept=False,
+                   route_layers=None, state=None):
+    """Paged decode cache tree: one global page pool over the model's
+    ATTENTION layers (``paged_pool_shape``; ``layers`` is their count,
+    which is every layer unless the model has a layer spec) plus the
+    per-slot block table (a RUNTIME argument of the decode program —
+    page churn never recompiles).
     The pool's leaves are ``k`` and ``v`` and whatever ``extra`` names
     (``{leaf: (heads, head_dim)}``: the key-selection indexer's keys
     ``ki`` ride here). EVERY leaf is addressed by the SAME block table
     and page ids: a page holds all of a token run's state, so
     allocation, prefix sharing, preemption, spill and migration move
     the leaves together (the server walks the pool's leaves and never
-    spells their names). ``route_k`` adds ``route`` ``[L, batch,
-    route_k]``: the experts each slot's LAST decode row chose, which
-    the decode tick packs into the read-back it already makes; ``kept``
-    adds ``kept`` ``[L, batch]``, the keys the selection kept for that
-    row, which rides the same read-back."""
+    spells their names). ``route_k`` adds ``route`` ``[route_layers,
+    batch, route_k]``: the experts each slot's LAST decode row chose in
+    each expert layer, which the decode tick packs into the read-back it
+    already makes; ``kept`` adds ``kept`` ``[L, batch]``, the keys the
+    selection kept for that row, which rides the same read-back.
+
+    ``state`` (``(layers, rows, width)``) adds ``state`` ``[layers,
+    batch, rows, width]``: PER-SLOT recurrent state beside the pool (a
+    short convolution's last inputs, a layer a conv layer). It is
+    addressed by the slot and NOT by the block table: no page holds it,
+    so nothing that moves pages (prefix sharing, spill, migration)
+    moves it, and the server refuses those for a model that has it."""
     def leaf(heads, dim):
         return jnp.zeros(paged_pool_shape(layers, num_pages, page_size,
                                           heads, dim), dtype)
@@ -351,9 +361,13 @@ def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
     tree = {"pool": pool,
             "bt": jnp.zeros((batch, pages_per_slot), jnp.int32)}
     if route_k:
-        tree["route"] = jnp.zeros((layers, batch, int(route_k)), jnp.int32)
+        tree["route"] = jnp.zeros((route_layers or layers, batch,
+                                   int(route_k)), jnp.int32)
     if kept:
         tree["kept"] = jnp.zeros((layers, batch), jnp.int32)
+    if state:
+        tree["state"] = jnp.zeros((state[0], batch) + tuple(state[1:]),
+                                  dtype)
     return tree
 
 
@@ -498,7 +512,44 @@ def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
     return att, pool, kept
 
 
-def _run_layers(layer_fn, x, blk_tree, caches, paged):
+# which stack of a layer-spec model a bundle leaf belongs to: the norms
+# are stacked over every layer, the rest over the layers of their kind
+_LEAF_KIND = dict(
+    {n: "layer" for n in ("ln1", "ln2")},
+    **{n: "attn" for n in ("wq", "wk", "wv", "wo", "qn", "kn")},
+    **{n: "conv" for n in ("ci", "cw", "co")},
+    **{n: "dense" for n in ("dg", "du", "dd")},
+    **{n: "moe" for n in ("router", "rbias")})
+
+
+def _layer_spec(cfg):
+    """A model whose layers are NOT alike says so in its config
+    (``layer_types``: each layer's mixer, ``"full_attention"`` or
+    ``"conv"``; ``num_dense_layers``: how many leading layers keep a
+    dense FFN in an expert model). Returns None for a model of identical
+    layers, else one dict a layer: the layer's index within each stack
+    it reads (``_LEAF_KIND``) — ``{"layer": l, "attn" | "conv": i,
+    "dense" | "moe": j}``. ``attn`` is also the layer's index in the
+    page pool, ``conv`` in the slot state, ``moe`` in the expert
+    stacks."""
+    types = getattr(cfg, "layer_types", None)
+    experts = bool(getattr(cfg, "num_experts", 0))
+    n_dense = int(getattr(cfg, "num_dense_layers", 0) or 0) if experts else 0
+    if types is None and not n_dense:
+        return None
+    types = tuple(types or ("full_attention",) * cfg.num_layers)
+    spec, count = [], {}
+    for l, mixer in enumerate(types):
+        kinds = ("conv" if mixer == "conv" else "attn",
+                 "moe" if experts and l >= n_dense else "dense")
+        at = {"layer": l}
+        for kind in kinds:
+            at[kind] = count[kind] = count.get(kind, -1) + 1
+        spec.append(at)
+    return tuple(spec)
+
+
+def _run_layers(layer_fn, x, blk_tree, caches, paged, spec=None):
     """THE layer loop of every decode bundle. ``layer_fn(xx, blk, lc,
     l) -> (xx, lc, aux)`` runs one layer over its slice ``blk`` of the
     stacked weights; ``aux`` (None, or a small dict such as the experts
@@ -506,16 +557,41 @@ def _run_layers(layer_fn, x, blk_tree, caches, paged):
     Returns ``(x, caches, aux)``.
 
     Dense: a scan with the per-layer caches as ``xs``/``ys`` (``lc`` is
-    layer ``l``'s cache dict). Paged: the hidden state AND the whole
-    page pools are the loop's CARRY (``lc`` is the pool dict, written
-    and read at layer index ``l``); the stacked weights are scanned as
-    before. The pool must be carried, not scanned: as ``xs``/``ys`` a
-    donated pool can never be its own result, so the compiled tick
-    copied the pool whole, sliced each layer out and wrote it back
-    into a new one — pool-sized HBM traffic five times a tick and a
-    second copy of the pool in temp. Carried, with the layer's rows
-    scattered in at ``[l, page, offset]`` and the kernels indexing the
-    layer themselves, the donated argument's buffer IS the result's."""
+    layer ``l``'s cache dict). Paged: the hidden state AND what the
+    layers write (``lc``: the cache tree's ``pool``, whose leaves are
+    written and read at layer index ``l``, and ``state`` where the model
+    has per-slot state) are the loop's CARRY; the stacked weights are
+    scanned as before. The pool must be carried, not scanned: as
+    ``xs``/``ys`` a donated pool can never be its own result, so the
+    compiled tick copied the pool whole, sliced each layer out and wrote
+    it back into a new one — pool-sized HBM traffic five times a tick
+    and a second copy of the pool in temp. Carried, with the layer's
+    rows scattered in at ``[l, page, offset]`` and the kernels indexing
+    the layer themselves, the donated argument's buffer IS the result's.
+
+    ``spec`` (``_layer_spec``): the layers are not alike, so there is no
+    one body to scan. The same loop runs unrolled: layer ``l`` gets the
+    slices of the stacks it reads, each at the layer's index WITHIN that
+    stack (static, so nothing is copied out), and ``l`` is ``spec[l]``
+    itself; ``lc`` is the carried tree on both backends (dense: the
+    whole cache dict, its leaves stacked over the layers of their
+    kind). ``aux`` comes back stacked, a key at a time, over the layers
+    that returned that key."""
+    if spec is not None:
+        lc = ({n: caches[n] for n in ("pool", "state") if n in caches}
+              if paged else caches)
+        auxes = []
+        for at in spec:
+            # (tree_map: an int8 weight is an (int8, scale) pair)
+            blk = {n: jax.tree_util.tree_map(
+                       lambda w, i=at.get(_LEAF_KIND[n]): w[i], a)
+                   for n, a in blk_tree.items() if _LEAF_KIND[n] in at}
+            x, lc, aux = layer_fn(x, blk, lc, at)
+            auxes.append(aux or {})
+        aux = {k: jnp.stack([a[k] for a in auxes if k in a])
+               for k in sorted({k for a in auxes for k in a})}
+        return x, dict(caches, **lc), aux
+
     layers = jnp.arange(jax.tree_util.tree_leaves(blk_tree)[0].shape[0],
                         dtype=jnp.int32)
     if not paged:
@@ -528,12 +604,41 @@ def _run_layers(layer_fn, x, blk_tree, caches, paged):
         return x, caches, aux
 
     def body(carry, xs):
-        xx, pool, aux = layer_fn(carry[0], xs[0], carry[1], xs[1])
-        return (xx, pool), aux
+        xx, lc, aux = layer_fn(carry[0], xs[0], {"pool": carry[1]}, xs[1])
+        return (xx, lc["pool"]), aux
 
     (x, pool), aux = jax.lax.scan(body, (x, caches["pool"]),
                                   (blk_tree, layers))
     return x, dict(caches, pool=pool), aux
+
+
+def _short_conv_mixer(blk, xx, state, layer, t, take, live, eps):
+    """The gated short-convolution sublayer (``lfm2``'s ``conv`` layer)
+    for the decode loop: pre-RMSNorm, ``B, C, X = split(h W_in)``, a
+    depthwise causal convolution of ``u = B * X`` over time, ``(C * c)
+    W_out``, residual. Its only state is a sequence's last ``K - 1``
+    rows of ``u``: ``state`` ``[conv layers, slots, K - 1, H]``, read and
+    written at ``layer`` — per SLOT, not through the block table.
+
+    ``xx`` [B, s, H] are chunk rows at per-slot offsets ``t`` (a decode
+    row: s = 1), of which the first ``take`` [B] are real (the rest is
+    chunk padding). A row run that starts a sequence (``t == 0``)
+    convolves from zeros whatever the slot held; the state it leaves is
+    that of its last REAL row; a slot that is not ``live`` (parked on
+    the idle sentinel: it rides the launch with garbage rows) keeps its
+    state untouched."""
+    from ..ops.short_conv import short_conv
+    with jax.named_scope("short_conv"):
+        k = blk["cw"].shape[0]
+        h = _rms(xx, blk["ln1"], eps)
+        gb, gc, gx = jnp.split(_mm(h, blk["ci"]), 3, axis=-1)
+        held = state[layer]
+        prev = jnp.where((t == 0)[:, None, None], jnp.zeros_like(held), held)
+        c, full = short_conv(gb * gx, blk["cw"], prev)
+        rows = take[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None]
+        new = jnp.take_along_axis(full, rows[:, :, None], axis=1)
+        new = jnp.where(live[:, None, None], new, held)
+        return xx + _mm(gc * c, blk["co"]), state.at[layer].set(new)
 
 
 def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
@@ -608,23 +713,43 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
     per-slot prefix offsets, so an auto-prefix-cache hit resumes over
     its already-cached pages exactly like decode does.
 
-    Signature: ``(tokens [S, C], t0 [S], caches, out_idx [S]) ->
-    (logits [S, V], caches)``. ``tokens`` holds one right-padded chunk
-    per slot, ``t0`` the chunk's absolute start position (a slot with
-    no prefill work this launch carries t0 = max_cache_len: every one
+    Signature: ``(tokens [P, C], t0 [P], caches, out_idx [P], take [P],
+    slots [P]) -> (logits [P, V], caches)``. ``tokens`` holds one
+    right-padded chunk a row, ``t0`` the chunk's absolute start position
+    (a row with no prefill work carries t0 = max_cache_len: every one
     of its writes null-redirects and its rows are garbage nobody
-    reads), ``out_idx`` the row of each slot's LAST prompt token —
-    ``logits[s]`` is that row's next-token distribution, valid only for
+    reads), ``out_idx`` the row of each chunk's LAST prompt token —
+    ``logits[j]`` is that row's next-token distribution, valid only for
     slots whose prompt completes in this launch. All chunk geometry is
-    static per (S, C): the server pads C up a power-of-two ladder so
+    static per (P, C): the server pads C up a power-of-two ladder so
     compiles stay O(log max_cache_len), not O(distinct prompt lengths).
+
+    A launch is PACKED: it has a row for P slots and not for all of
+    them, row j being slot ``slots[j]`` (the server puts its plan's j-th
+    slot there and gives the launch the rows its row limit allows); a
+    padding row names a slot past the last, carries the idle sentinel in
+    ``t0`` and writes nothing. The step runs over the launch's VIEW of
+    the per-slot leaves (the block table's rows and, where the model has
+    it, the slot state, gathered at ``slots``) and the state is
+    scattered back. ``take`` [P]: the REAL rows of each chunk; a model
+    with per-slot state must know where a chunk that ends mid-prompt
+    really ends (the K/V of padding rows are hidden by lengths, a
+    recurrent state would carry them), and only it is told.
     """
-    def prefill_tick(tokens, t0, caches, out_idx):
-        S = tokens.shape[0]
+    def prefill_tick(tokens, t0, caches, out_idx, take, slots):
+        P = tokens.shape[0]
         x = embed_tokens(tokens, t0)
-        out, caches = step_fn(x, caches, t0)
-        rows = out[jnp.arange(S), out_idx][:, None]        # [S, 1, H]
-        return head_fn(rows)[:, -1], caches
+        at = jnp.minimum(slots, caches["bt"].shape[0] - 1)
+        view, rows = dict(caches, bt=caches["bt"][at]), {}
+        if "state" in caches:
+            view["state"], rows = caches["state"][:, at], {"take": take}
+        out, new = step_fn(x, view, t0, **rows)
+        new = dict(new, bt=caches["bt"])
+        if "state" in caches:
+            new["state"] = caches["state"].at[:, slots].set(
+                new["state"], mode="drop")
+        last = out[jnp.arange(P), out_idx][:, None]         # [P, 1, H]
+        return head_fn(last)[:, -1], new
 
     # hoisted_jit names the program after it: ``jit_prefill_tick``
     return prefill_tick
@@ -676,7 +801,16 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
       no capacity, only the chosen experts computed) — Mixtral, Keye;
     - ``qk_norm``: per-head RMSNorm of q and k before the rope;
     - ``indexer`` (``(heads, dim, topk)``): learned key selection with
-      an indexer-key cache beside K and V (``ops/key_selection.py``).
+      an indexer-key cache beside K and V (``ops/key_selection.py``);
+    - ``router_score`` (``"sigmoid"``; ``use_expert_bias``,
+      ``routed_scaling_factor``): each expert scores on its own and a
+      bias chooses without weighing (``route_topk``);
+    - ``layer_types`` / ``num_dense_layers``: the layers are NOT alike
+      (``_layer_spec``): a layer's mixer is attention or a gated short
+      convolution (``_short_conv_mixer``, whose state is per slot:
+      ``caches["state"]``, a layer a conv layer), its FFN dense or
+      routed; the weights are stacked a kind of sublayer, the loop runs
+      the spec, and the caches have a layer an ATTENTION layer.
 
     ``cache_backend="paged"`` swaps the dense per-slot cache for a
     global page pool + per-slot block tables."""
@@ -688,6 +822,13 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     moe = bool(getattr(cfg, "num_experts", 0))
     top_k = int(getattr(cfg, "top_k", 0)) if moe else 0
     norm_topk = bool(getattr(cfg, "norm_topk_prob", True))
+    # how the router scores: softmax gates, or (``"sigmoid"``) each
+    # expert's own sigmoid with a bias that chooses and does not weigh
+    route_kw = {}
+    if getattr(cfg, "router_score", "softmax") != "softmax":
+        route_kw = dict(score=cfg.router_score, scale=float(
+            getattr(cfg, "routed_scaling_factor", 1.0)))
+    use_bias = bool(getattr(cfg, "use_expert_bias", True))
     qk_norm = bool(getattr(cfg, "qk_norm", False))
     indexer = getattr(cfg, "indexer", None)
     if indexer is not None and mesh is not None:
@@ -695,6 +836,10 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             "key selection is not wired for a mesh (ROADMAP A8, the "
             "mesh column): its indexer-key pool has one head, which no "
             "kv-head sharding divides")
+    # layers that are not alike: the loop below runs the spec
+    spec = _layer_spec(cfg)
+    n_of = lambda kind: sum(kind in at for at in spec)
+    conv_rows = int(getattr(cfg, "conv_L_cache", 1)) - 1
 
     ffn_dims = ({"wg": 1, "wu": 1, "wd": 1} if moe     # expert-parallel
                 else {"wg": 2, "wu": 2, "wd": 1})
@@ -710,7 +855,11 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                    rope_mod.precompute_freqs(int(dim), max_cache_len,
                                              cfg.rope_theta))
     dtype = p["table"].dtype
-    L = cfg.num_layers
+    # the caches have a layer an ATTENTION layer, the slot state a layer
+    # a conv layer, the route read-back a layer an expert layer
+    L = cfg.num_layers if spec is None else n_of("attn")
+    state = ((n_of("conv"), conv_rows, p["table"].shape[1])
+             if spec is not None and n_of("conv") else None)
     scale = 1.0 / np.sqrt(hd)
     paged = cache_backend == "paged"
     if paged:
@@ -723,10 +872,15 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                 batch, L, num_pages, page_size, max_cache_len // page_size,
                 kvh, hd, dtype,
                 extra={"ki": (1, indexer[1])} if indexer else None,
-                route_k=top_k, kept=indexer is not None)
-        return _init_kv((L, batch, max_cache_len, kvh, hd), dtype,
+                route_k=top_k, kept=indexer is not None,
+                route_layers=None if spec is None else n_of("moe"),
+                state=state)
+        tree = _init_kv((L, batch, max_cache_len, kvh, hd), dtype,
                         cache_dtype,
                         index_dim=indexer[1] if indexer else None)
+        if state:
+            tree["state"] = jnp.zeros((state[0], batch) + state[1:], dtype)
+        return tree
 
     if mesh is not None:
         init_caches = (_mesh_paged_caches(init_caches, mesh, kvh) if paged
@@ -741,7 +895,7 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     skip = ("table", "norm", "head") + (_EXPERT_LEAVES if moe else ())
     blk_tree = {k_: v_ for k_, v_ in p.items() if k_ not in skip}
 
-    def _forward(x, caches, t, bt):
+    def _forward(x, caches, t, bt, take=None):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
         # an idle slot's offset is parked past the table: its rows are
@@ -749,44 +903,84 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         pos = jnp.minimum(_positions(t, b, s), max_cache_len - 1)
         # ... and they are sent to no expert: every row of a slot parked
         # on the sentinel is dead to the routed FFN. The padding rows
-        # INSIDE a live slot's chunk stay live (the program is told no
-        # row count a slot)
-        live = jnp.repeat(jnp.broadcast_to(t < max_cache_len, (b,)), s)
+        # INSIDE a live slot's chunk stay live (only a model with slot
+        # state is told a slot's row count, ``take``)
+        alive = jnp.broadcast_to(t < max_cache_len, (b,))
+        live = jnp.repeat(alive, s)
         # the decode kernel's lengths and grid, once for every layer
         # (key selection attends without that kernel)
         plan = (_paged_decode_plan(bt, t, page_size)
                 if paged and s == 1 and indexer is None else None)
+        if state:
+            t_b = jnp.broadcast_to(t, (b,))
+            rows_b = (jnp.full((b,), s, jnp.int32) if take is None
+                      else take)
 
-        def layer(xx, blk, lc, l):
-            xx, lc, h2, kept = _rope_gqa_attn(
-                blk, xx, lc, t, pos, (b, s, nh, kvh, hd, scale),
-                (cos, sin), eps, bt=bt, mesh=mesh, layer=l,
-                qk_norm=qk_norm, indexer=indexer, plan=plan)
+        def attend(blk, xx, lc, l):
+            """The attention sublayer over the carried caches ``lc``:
+            the page pool (paged), a layer's dense caches (scanned), or
+            under a layer spec the whole dense tree, read at ``l``."""
+            if paged:
+                xx, pool, h2, kept = _rope_gqa_attn(
+                    blk, xx, lc["pool"], t, pos, (b, s, nh, kvh, hd, scale),
+                    (cos, sin), eps, bt=bt, mesh=mesh, layer=l,
+                    qk_norm=qk_norm, indexer=indexer, plan=plan)
+                return xx, dict(lc, pool=pool), h2, kept
+            own = lc if spec is None else {n: lc[n][l] for n in lc
+                                           if n != "state"}
+            xx, own, h2, kept = _rope_gqa_attn(
+                blk, xx, own, t, pos, (b, s, nh, kvh, hd, scale),
+                (cos, sin), eps, qk_norm=qk_norm, indexer=indexer)
+            if spec is not None:
+                own = dict(lc, **{n: lc[n].at[l].set(own[n]) for n in own})
+            return xx, own, h2, kept
+
+        def layer(xx, blk, lc, at):
+            # ``at``: the scan's layer index or, under a layer spec,
+            # this layer's index within each stack it reads
+            if "ci" in blk:
+                xx, held = _short_conv_mixer(
+                    blk, xx, lc["state"], at["conv"], t_b, rows_b, alive,
+                    eps)
+                lc, kept = dict(lc, state=held), None
+                h2 = _rms(xx, blk["ln2"], eps)
+            else:
+                xx, lc, h2, kept = attend(blk, xx, lc,
+                                          at if spec is None else at["attn"])
             # what each slot's LAST row did, for the decode tick's
             # read-back: the keys it attended, the experts it chose
             aux = {} if kept is None else {"kept": kept[:, -1]}
-            if not moe:
-                return xx + _mm(jax.nn.silu(_mm(h2, blk["wg"]))
-                                * _mm(h2, blk["wu"]), blk["wd"]), lc, aux
+            if "dg" in blk or not moe:
+                wg, wu, wd = (blk[n] for n in (
+                    ("dg", "du", "dd") if "dg" in blk
+                    else ("wg", "wu", "wd")))
+                return xx + _mm(jax.nn.silu(_mm(h2, wg)) * _mm(h2, wu),
+                                wd), lc, aux
             with jax.named_scope("moe_ffn"):
                 rows = h2.reshape(b * s, h2.shape[-1])
+                kw = (dict(route_kw, bias=blk["rbias"])
+                      if "rbias" in blk and use_bias else route_kw)
                 idx, gate = route_topk(rows, blk["router"], top_k,
-                                       normalize=norm_topk)
+                                       normalize=norm_topk, **kw)
                 y = routed_ffn(rows, idx, gate, p["wg"], p["wu"], p["wd"],
-                               layer=l, live=live)
+                               layer=at if spec is None else at["moe"],
+                               live=live)
             aux["route"] = idx.reshape(b, s, top_k)[:, -1]
             return xx + y.reshape(xx.shape), lc, aux
 
-        x, caches, aux = _run_layers(layer, x, blk_tree, caches, paged)
+        x, caches, aux = _run_layers(layer, x, blk_tree, caches, paged,
+                                     spec)
         if paged and s == 1:
             caches = dict(caches, **aux)
         return x, caches
 
-    def step_fn(x, caches, t):
-        return _forward(x, caches, t, caches["bt"] if paged else None)
+    def step_fn(x, caches, t, take=None):
+        return _forward(x, caches, t, caches["bt"] if paged else None,
+                        take)
 
     def head_fn(out):
-        return (_rms(unwrap(out), p["norm"], eps) @ p["head"]
+        head = p["head"] if "head" in p else p["table"].T    # tied
+        return (_rms(unwrap(out), p["norm"], eps) @ head
                 ).astype(jnp.float32)
 
     if paged:
@@ -869,8 +1063,10 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                    ).reshape(b, s, 3, nh, hd)
             q, k, v = qkv[:, :, 0], qkv[:, :, 1], qkv[:, :, 2]
             if paged:
-                att, lc, _ = _paged_kv_step(lc, l, q, k, v, bt, t, scale,
-                                            mesh=mesh, plan=plan)
+                att, pool, _ = _paged_kv_step(lc["pool"], l, q, k, v, bt,
+                                              t, scale, mesh=mesh,
+                                              plan=plan)
+                lc = {"pool": pool}
             else:
                 lc = _kv_write(lc, "k", k, t)
                 lc = _kv_write(lc, "v", v, t)
@@ -997,6 +1193,13 @@ class GenerationMixin:
         B, T = ids_np.shape
         if caches is None:
             caches = init_caches(B)
+        if chunk and chunk < T and "state" in caches:
+            raise NotImplementedError(
+                "prefill_chunk pads the prompt's last chunk, and this "
+                "model's per-slot recurrent state (caches['state']) would "
+                "be that of the padding rows: prefill it unchunked here, or "
+                "through the paged server's ragged launches, which tell the "
+                "program each chunk's real rows (ROADMAP B5)")
         if not chunk or chunk >= T:
             x0 = self._prefill_embed(jnp.asarray(ids_np), bundle, t0=t0)
             out, caches = prefill_jit(x0, caches, jnp.int32(t0))

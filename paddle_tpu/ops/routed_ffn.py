@@ -17,18 +17,37 @@ import jax.numpy as jnp
 __all__ = ["route_topk", "routed_ffn"]
 
 
-def route_topk(h, router_w, top_k, normalize=True):
+def route_topk(h, router_w, top_k, normalize=True, score="softmax",
+               bias=None, scale=1.0):
     """``h`` [N, H] -> (experts [N, k] int32, gates [N, k] float32).
-    Router logits accumulate in float32 and the softmax is float32; the
+    Router logits accumulate in float32 and the score is float32; the
     top-k is EXACT (``jax.lax.top_k``: ties to the lower expert id).
-    ``normalize`` divides the kept gates by their sum (Mixtral's and
-    Qwen-MoE's ``norm_topk_prob``)."""
+
+    ``score="softmax"`` (Mixtral, Qwen-MoE): the gates are the kept
+    probabilities, divided by their sum under ``normalize``
+    (``norm_topk_prob``). ``score="sigmoid"`` (the DeepSeek-V3 form the
+    ``lfm2_moe`` decoder uses): each expert scores ``sigmoid(logit)`` on
+    its own; ``bias`` [E] (the load-balancing ``expert_bias``) is added
+    to the scores that CHOOSE the k experts and to nothing else, so the
+    gates are the chosen experts' unbiased scores, divided by ``sum +
+    1e-6`` under ``normalize``, then times ``scale``
+    (``routed_scaling_factor``)."""
     logits = jnp.dot(h, router_w, preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gate, idx = jax.lax.top_k(probs, top_k)
+    if score == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        gate, idx = jax.lax.top_k(probs, top_k)
+        if normalize:
+            gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
+        return idx.astype(jnp.int32), gate
+    if score != "sigmoid":
+        raise ValueError(f"unknown router score {score!r}")
+    scores = jax.nn.sigmoid(logits)
+    choose = scores if bias is None else scores + bias.astype(jnp.float32)
+    idx = jax.lax.top_k(choose, top_k)[1]
+    gate = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
-        gate = gate / jnp.sum(gate, axis=-1, keepdims=True)
-    return idx.astype(jnp.int32), gate
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-6)
+    return idx.astype(jnp.int32), gate * scale
 
 
 def _tile_rows(pairs, experts):

@@ -367,6 +367,14 @@ class ServerTelemetry:
             "serving_moe_experts_touched_total",
             "Distinct experts chosen by the live rows of a decode "
             "tick, summed over layers and ticks")
+        chunks = r.counter(
+            "serving_prefill_chunks_total",
+            "Slot-chunks the prefill launches ran (one a slot a launch), "
+            "and those that began past a prompt's start: a model with "
+            "slot state reads the state its last chunk left",
+            labelnames=("kind",))
+        self._c_chunks = chunks.labels(kind="launched")
+        self._c_chunks_carried = chunks.labels(kind="carried")
         keys = r.counter(
             "serving_attn_keys_total",
             "Keys of live decode rows: in their context, and kept by "
@@ -597,6 +605,14 @@ class ServerTelemetry:
         self._c_moe_live.inc(live)
         if touched:
             self._c_moe_touched.inc(touched)
+
+    def on_prefill_chunks(self, chunks, carried):
+        """One prefill launch: the slot-chunks it ran, and those that
+        continued a prompt an earlier launch began."""
+        if self.enabled:
+            self._c_chunks.inc(chunks)
+            if carried:
+                self._c_chunks_carried.inc(carried)
 
     def on_selected_keys(self, context, selected):
         """A decode tick's live rows: keys in context, keys kept."""

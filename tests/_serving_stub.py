@@ -83,15 +83,20 @@ class StubModel:
             return jax.nn.one_hot(nxt, vocab, dtype=jnp.float32) * 10.0
 
         if cache_backend == "paged":
-            def ragged_prefill(tokens, t0, caches, out_idx):
+            def ragged_prefill(tokens, t0, caches, out_idx, take, slots):
                 """Ragged-prefill contract (paged bundle element 5):
-                tokens [S, C] packed chunks, t0 [S] start positions
-                (idle slots carry t0 = max_cache_len — every write
-                null-redirects zeroed), out_idx [S] row of each slot's
-                last prompt token. Writes token VALUES into pool pages
-                (page fills move real data, like _run_prefill) and
-                returns the oracle's next-token logits per slot."""
-                pool, bt = caches["pool"], caches["bt"]
+                tokens [P, C] packed chunks, row j being slot slots[j],
+                t0 [P] start positions (padding rows name a slot past
+                the last and carry t0 = max_cache_len — every write
+                null-redirects zeroed), out_idx [P] row of each chunk's
+                last prompt token, take [P] each chunk's real rows
+                (unread: pages are this model's whole state). Writes
+                token VALUES into pool pages (page fills move real data,
+                like _run_prefill) and returns the oracle's next-token
+                logits per row."""
+                pool = caches["pool"]
+                bt = caches["bt"][jnp.minimum(slots,
+                                              caches["bt"].shape[0] - 1)]
                 S, Cc = tokens.shape
                 pos = t0[:, None] + jnp.arange(Cc, dtype=jnp.int32)[None]
                 pidx = pos // pg

@@ -74,7 +74,7 @@ def test_chip_smoke_rehearsal_passes():
     lines = r.stdout.splitlines()
     assert lines and all(ln.startswith("REHEARSAL") for ln in lines)
     assert json.loads(lines[-1].split(" ", 1)[1])["ok"] is True
-    for phase in ("kernels", "serve-split", "train",
+    for phase in ("kernels", "serve-split", "hybrid-serve", "train",
                   "mesh4-serve-split", "mesh4-train"):
         assert re.search(rf"phase {phase}: ok", r.stdout), phase
 
@@ -122,7 +122,7 @@ def test_serving_programs_take_the_weights_as_arguments():
 
     toks = jnp.zeros((S, 8), jnp.int32)
     z = jnp.zeros((S,), jnp.int32)
-    text = srv._ragged_fn.lower(toks, z, srv._caches, z).as_text()
+    text = srv._ragged_fn.lower(toks, z, srv._caches, z, z, z).as_text()
     _assert_no_weight_constants(text, shapes, "ragged prefill")
 
     # the control: plain jax.jit over the same closure DOES bake them in
@@ -287,11 +287,56 @@ def _refuse_cross_datacenter():
     normalize_placement("cross-datacenter")
 
 
+def _slot_state_server(**kw):
+    """A paged server over a model with per-slot recurrent state beside
+    the page pool (the ``lfm2`` family's short-convolution layers)."""
+    from paddle_tpu.models.lfm2 import Lfm2MoeForCausalLM, lfm2_tiny
+    global _LFM2
+    if _LFM2 is None:
+        _LFM2 = Lfm2MoeForCausalLM(lfm2_tiny(), seed=0)
+    return ContinuousBatchingServer(_LFM2, cache_backend="paged",
+                                    max_cache_len=64, page_size=8,
+                                    max_slots=2, **kw)
+
+
+_LFM2 = None
+
+
+def _refuse_prefix_hit_with_slot_state():
+    _slot_state_server(auto_prefix_cache=True)
+
+
+def _refuse_preemption_replay_with_slot_state():
+    _slot_state_server(admission="optimistic")
+
+
+def _refuse_host_tier_with_slot_state():
+    _slot_state_server(host_tier=True)
+
+
+def _refuse_migration_with_slot_state():
+    srv = _slot_state_server()
+    rid = srv.submit(np.arange(9, dtype=np.int32), max_new_tokens=4)
+    srv.step()
+    srv.migrate_out(rid)
+
+
+_SLOT_STATE = ("per-slot recurrent state", "ROADMAP B5")
+
+
 @pytest.mark.parametrize("refuse,names", [
     (_refuse_int8_paged_pool, ("ROADMAP A7",)),
     (_refuse_optimistic_on_dense, ("ROADMAP A7",)),
     (_refuse_cross_datacenter, ("ROADMAP", "Still dropped", "B7")),
-], ids=["int8-paged-pool", "optimistic-on-dense", "cross-datacenter"])
+    (_refuse_prefix_hit_with_slot_state,
+     _SLOT_STATE + ("auto_prefix_cache=True",)),
+    (_refuse_preemption_replay_with_slot_state,
+     _SLOT_STATE + ("admission='optimistic'",)),
+    (_refuse_host_tier_with_slot_state, _SLOT_STATE + ("host_tier",)),
+    (_refuse_migration_with_slot_state, _SLOT_STATE + ("migration",)),
+], ids=["int8-paged-pool", "optimistic-on-dense", "cross-datacenter",
+        "slot-state-prefix-hit", "slot-state-preemption-replay",
+        "slot-state-host-tier", "slot-state-migration"])
 def test_documented_refusals(refuse, names):
     """Each combination ROADMAP.md lists under "Refusals standing in the
     code" raises, and its message names the item that would lift it."""

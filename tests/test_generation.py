@@ -402,5 +402,5 @@ def test_paged_bundle_brings_the_ragged_entry(family):
     assert len(paged) == 6
     z = jnp.zeros((2,), jnp.int32)
     text = paged[5].lower(jnp.zeros((2, 4), jnp.int32), z, paged[0](2),
-                          z).as_text()
+                          z, z, z).as_text()
     assert "module @jit_prefill_tick" in text

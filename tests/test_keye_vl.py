@@ -132,7 +132,9 @@ def _paged_logits(model, ids, prompt_len, chunk):
         toks[1, :take] = ids[start:start + take]
         t0 = np.asarray([WIDTH, start], np.int32)       # slot 0 idle
         logits, caches = prefill(jnp.asarray(toks), jnp.asarray(t0), caches,
-                                 jnp.asarray([0, take - 1], np.int32))
+                                 jnp.asarray([0, take - 1], np.int32),
+                                 jnp.asarray([0, take], np.int32),
+                                 jnp.arange(slots, dtype=jnp.int32))
         out[start + take - 1] = np.asarray(logits[1])
     for t in range(prompt_len, len(ids)):
         tt = jnp.asarray([WIDTH, t], jnp.int32)

@@ -293,6 +293,6 @@ class TestPoolStaysInPlace:
         before = self._ptrs(srv._caches)
         z = jnp.zeros((2,), jnp.int32)
         logits, caches = srv._ragged_fn(jnp.zeros((2, 8), jnp.int32), z,
-                                        srv._caches, z)
+                                        srv._caches, z, z, jnp.arange(2))
         jax.block_until_ready(logits)
         assert self._ptrs(caches) == before

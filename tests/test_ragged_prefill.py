@@ -190,12 +190,14 @@ class TestRaggedPrefillBundle:
         bt[1, :1] = [3]
         caches = dict(caches, bt=jnp.asarray(bt))
         C = 16
+        rows = jnp.arange(S, dtype=jnp.int32)       # row j is slot j
         toks = np.zeros((S, C), np.int32)
         toks[0, :12] = ids_a
         toks[1, :7] = ids_b
         logits, caches = ragged_jit(
             jnp.asarray(toks), jnp.asarray(np.zeros((S,), np.int32)),
-            caches, jnp.asarray(np.array([11, 6], np.int32)))
+            caches, jnp.asarray(np.array([11, 6], np.int32)),
+            jnp.asarray(np.array([12, 7], np.int32)), rows)
         np.testing.assert_array_equal(np.asarray(logits[0:1]),
                                       np.asarray(lg_a))
         np.testing.assert_array_equal(np.asarray(logits[1:2]),
@@ -215,12 +217,14 @@ class TestRaggedPrefillBundle:
         c1[0, :8] = ids_a[:8]
         _, caches2 = ragged_jit(
             jnp.asarray(c1), jnp.asarray(np.array([0, MCL], np.int32)),
-            caches2, jnp.asarray(np.zeros((S,), np.int32)))
+            caches2, jnp.asarray(np.zeros((S,), np.int32)),
+            jnp.asarray(np.array([8, 0], np.int32)), rows)
         c2 = np.zeros((S, 8), np.int32)
         c2[0, :4] = ids_a[8:12]
         lg2, caches2 = ragged_jit(
             jnp.asarray(c2), jnp.asarray(np.array([8, MCL], np.int32)),
-            caches2, jnp.asarray(np.array([3, 0], np.int32)))
+            caches2, jnp.asarray(np.array([3, 0], np.int32)),
+            jnp.asarray(np.array([4, 0], np.int32)), rows)
         np.testing.assert_array_equal(np.asarray(lg2[0:1]),
                                       np.asarray(lg_a))
         pool_k2 = pool_heads(np.asarray(caches2["pool"]["k"]),

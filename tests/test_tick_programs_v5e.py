@@ -140,9 +140,9 @@ def _decode_tick(cfg, num_pages, mesh=None):
 
 
 def _prefill_tick(cfg, num_pages):
-    def prefill_tick(weights, tokens, t0, caches, out_idx):
+    def prefill_tick(weights, tokens, t0, caches, out_idx, take, slots):
         return _paged_bundle(cfg, weights, num_pages)[4](
-            tokens, t0, caches, out_idx)
+            tokens, t0, caches, out_idx, take, slots)
 
     return prefill_tick
 
@@ -298,7 +298,7 @@ def test_prefill_tick_leaves_the_pool_in_place(one_chip, as_on_chip):
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     exe = _compile(_prefill_tick(cfg, num_pages), (3,), one_chip,
                    _weight_shapes(cfg), i32(slots, 64), i32(slots), caches,
-                   i32(slots))
+                   i32(slots), i32(slots), i32(slots))
     assert "ragged_prefill_attention" in exe.as_text()
     _assert_pool_stays(exe, caches)
 
@@ -413,10 +413,11 @@ def test_keye_prefill_tick_compiles_and_fits(one_chip, as_on_chip):
     caches = jax.eval_shape(
         lambda: _keye_bundle(cfg, shapes)[0](KEYE["slots"]))
 
-    def prefill_tick(weights, tokens, t0, caches, out_idx):
-        return _keye_bundle(cfg, weights)[4](tokens, t0, caches, out_idx)
+    def prefill_tick(weights, tokens, t0, caches, out_idx, take, slots):
+        return _keye_bundle(cfg, weights)[4](tokens, t0, caches, out_idx,
+                                             take, slots)
 
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     exe = _compile(prefill_tick, (3,), one_chip, shapes, i32(8, 128),
-                   i32(8), caches, i32(8))
+                   i32(8), caches, i32(8), i32(8), i32(8))
     _assert_keye_fits(exe, caches)
