@@ -348,35 +348,43 @@ def _live_rows(C, take):
 
 
 def kernel_ragged(c):
-    """Ragged prefill: a chunk wider than the query tile, prefix offsets
-    t0 > 0, ragged takes, an idle slot (last = -1)."""
+    """Ragged prefill over the live query tiles' grid: a chunk of four
+    tiles, prefix offsets t0 > 0, ragged takes (one ends mid-tile, one
+    on a tile's edge), an idle slot (take = 0); the same with ONE slot
+    live; nothing live. Rows past a take's last live tile must come
+    back as exact zeros."""
     import jax.numpy as jnp
 
     from paddle_tpu.ops.pallas import ragged_prefill as rp
     pg, T = c.pg, c.T
-    C = 4 * rp._QUERY_TILE
+    C = 4 * rp.QUERY_TILE
     t0 = np.array([0, pg, T, 3 * pg + 4, 5, 0, 2 * pg, 7][:c.S], np.int32)
-    take = np.array([C, C - 12, 0, C, 1, pg + 1, pg, 9][:c.S], np.int32)
-    last = np.where(take > 0, t0 + take - 1, -1).astype(np.int32)
-    k, v, bt = c.pool(np.maximum(last + 1, 0))
+    ragged = np.array([C, C - 12, 0, C, 1, pg + 1, pg, 9][:c.S], np.int32)
+    one = np.where(np.arange(c.S) == c.S // 2 + 1, ragged, 0).astype(np.int32)
     q = c.normal(c.S, C, c.nh, c.hd)
-    t0_d, last_d = jnp.asarray(t0), jnp.asarray(last)
-    def call(q, k, v, bt, t0, last, layer=None):
-        return rp.ragged_prefill_attention(q, k, v, bt, t0, last=last,
+
+    def call(q, k, v, bt, t0, take, layer=None):
+        return rp.ragged_prefill_attention(q, k, v, bt, t0, take=take,
                                            sm_scale=c.scale, layer=layer)
 
-    got = c.run(call, (q, k, v, bt, t0_d, last_d))
-    c.same_at_layer(f"ragged_prefill C={C}", call, got, q, k, v,
-                    (bt, t0_d, last_d))
-    want = c.ref(lambda q, k, v, bt, t0: rp._ref_ragged_prefill(
-        q, k, v, bt, t0, c.scale), q, k, v, bt, t0_d)
-    live = _live_rows(C, take)             # rows past a take are padding
-    c.close(f"ragged_prefill C={C}",
-            jnp.where(live, got.astype(jnp.float32), 0.0),
-            jnp.where(live, want, 0.0))
-    if not bool((got[np.flatnonzero(take == 0)] == 0).all()):
-        c.bad.append("ragged_prefill: an idle slot (last=-1) must read "
-                     "as zeros")
+    for tag, take in (("ragged", ragged), ("one live", one),
+                      ("none live", np.zeros(c.S, np.int32))):
+        name = f"ragged_prefill {tag} C={C}"
+        k, v, bt = c.pool(np.where(take > 0, t0 + take, 0))
+        t0_d, take_d = jnp.asarray(t0), jnp.asarray(take)
+        got = c.run(call, (q, k, v, bt, t0_d, take_d))
+        if tag == "ragged":
+            c.same_at_layer(name, call, got, q, k, v, (bt, t0_d, take_d))
+        tiles = -(-take // rp.QUERY_TILE) * rp.QUERY_TILE
+        if not bool(jnp.where(_live_rows(C, tiles), True, got == 0).all()):
+            c.bad.append(f"{name}[{c.name}]: rows of a query tile no "
+                         f"grid step visits must read as zeros")
+        if take.any():
+            want = c.ref(lambda q, k, v, bt, t0: rp._ref_ragged_prefill(
+                q, k, v, bt, t0, c.scale), q, k, v, bt, t0_d)
+            live = _live_rows(C, take)     # rows past a take are padding
+            c.close(name, jnp.where(live, got.astype(jnp.float32), 0.0),
+                    jnp.where(live, want, 0.0))
 
 
 def kernel_flash(c):
@@ -644,7 +652,9 @@ def serve_once(P, model, mesh, rehearse, watch, ref_logits, also_resident):
         f"({srv.stats['prefix_auto_hit_tokens']} tokens), pool free={free} "
         f"live={live} pinned={pinned} cached={cached}, "
         f"dispatches {srv.stats['tick_dispatches']} ticks / "
-        f"{srv.stats['prefill_dispatches']} prefill")
+        f"{srv.stats['prefill_dispatches']} prefill; the prefill "
+        f"kernel's grid took {srv.stats['prefill_grid_steps']} steps, "
+        f"{srv.stats['prefill_live_steps']} on a live tile's page")
     for (tag, i), (p, out) in results.items():
         if tag != "warm-tail":
             check_tokens(f"{tag}#{i}", ref_logits, p, out)

@@ -663,7 +663,11 @@ class ContinuousBatchingServer:
                       # layer at a time, and the pages its live rows
                       # spanned (ops.pallas.paged_attention.decode_grid
                       # on the host's lengths: the function that sizes
-                      # the grid on the device).
+                      # the grid on the device). The same of the ragged
+                      # prefill kernel's launches: the steps its grid
+                      # took and those that attended a page of a live
+                      # query tile (ops.pallas.paged_attention.
+                      # prefill_grid on the launch's offsets and takes).
                       # What a routed-expert / key-selecting model's
                       # launches did (zero for models with neither):
                       # rows the expert FFN computed (those of the
@@ -682,7 +686,8 @@ class ContinuousBatchingServer:
                       "prefill_chunks": 0, "prefill_chunks_carried": 0,
                       "decode_ticks": 0, "decode_rows": 0,
                       "decode_live_rows": 0, "decode_grid_steps": 0,
-                      "decode_live_pages": 0, "moe_rows": 0,
+                      "decode_live_pages": 0, "prefill_grid_steps": 0,
+                      "prefill_live_steps": 0, "moe_rows": 0,
                       "moe_live_rows": 0, "moe_experts_touched": 0,
                       "attn_keys_context": 0, "attn_keys_selected": 0}
         cfg = getattr(model, "cfg", None)
@@ -2085,6 +2090,8 @@ class ContinuousBatchingServer:
         self.stats["prefill_chunks_carried"] += carried
         if self._tele is not None:
             self._tele.on_prefill_chunks(len(plan), carried)
+        if not self._select_k:
+            self._count_prefill_grid(t0, takes, C)
         if self._moe_k:
             # the experts compute the rows of the slots in the plan;
             # a padding row rides this launch on the sentinel
@@ -2917,6 +2924,24 @@ class ContinuousBatchingServer:
         self.stats["decode_live_pages"] += live
         if self._tele is not None:
             self._tele.on_decode_grid(steps, live)
+
+    def _count_prefill_grid(self, t0, takes, width):
+        """Prefill-kernel accounting of one launch: the steps its grid
+        took and those that attended a page of a live query tile, a
+        layer at a time, from the launch's offsets ``t0`` (the sentinel
+        on a padding row) and real rows ``takes``. Counted by
+        ``prefill_grid``, which sizes the grid on the device."""
+        from ..ops.pallas.paged_attention import prefill_grid
+        from ..ops.pallas.ragged_prefill import QUERY_TILE
+        pages, steps = prefill_grid(t0, takes, width, QUERY_TILE,
+                                    self.page_size,
+                                    self.max_cache_len // self.page_size)
+        steps = int(steps) * self._n_layers
+        live = int(pages.sum()) * self._n_layers
+        self.stats["prefill_grid_steps"] += steps
+        self.stats["prefill_live_steps"] += live
+        if self._tele is not None:
+            self._tele.on_prefill_grid(steps, live)
 
     def _count_routed(self, route, live_rows, rows):
         """Expert-FFN accounting of one launch: ``rows`` computed (the

@@ -389,6 +389,26 @@ def _paged_decode_plan(bt, t, page_size):
     return lengths, decode_schedule(lengths, page_size, bt.shape[1])
 
 
+def _paged_prefill_plan(bt, t, take, width, page_size):
+    """What every layer of one ragged prefill launch attends, made ONCE
+    a launch outside the layer loop: ``(take, schedule)``. ``take`` [B]
+    are the REAL rows of each slot's ``width``-row chunk (None: every
+    row), ``t`` [B] the chunks' offsets; a row carrying the scheduler's
+    idle sentinel (``t`` past the block-table extent) has no live query
+    tile whatever its ``take``. ``schedule`` is the prefill kernel's
+    grid made of them (``prefill_schedule``: one step a page of a live
+    query tile), which the kernel reads by scalar prefetch beside the
+    block table."""
+    from ..ops.pallas.paged_attention import prefill_schedule
+    from ..ops.pallas.ragged_prefill import QUERY_TILE
+    b = bt.shape[0]
+    t = jnp.broadcast_to(t, (b,)).astype(jnp.int32)
+    take = (jnp.full((b,), width, jnp.int32) if take is None
+            else take.astype(jnp.int32))
+    return take, prefill_schedule(t, take, width, QUERY_TILE, page_size,
+                                  bt.shape[1])
+
+
 def _paged_attend(q, pool, layer, bt, t, scale, mesh=None, plan=None):
     """Decode-step attention through the block table: q [B, 1, nh, hd],
     ``pool`` the whole K/V pools ``{"k", "v"}`` [L, P, pg, kvh*hd] read
@@ -451,26 +471,31 @@ def _page_write(pool, layer, kv, bt, t):
         vals.reshape(n, vals.shape[-1]))
 
 
-def _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=None):
+def _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=None,
+                          plan=None):
     """Ragged packed-prefill attention through the block table: q
     [B, s, nh, hd] chunk rows starting at per-slot offsets ``t``,
-    ``pool`` the whole K/V pools read at ``layer``; row j of slot b
+    ``pool`` the whole K/V pools read at ``layer``, over ``plan``
+    (``_paged_prefill_plan(bt, t, take, s, pg)``, which the layer
+    loop's caller makes once for all its layers); row j of slot b
     attends to positions <= t_b + j (cache already written through the
     chunk). Pallas kernel on TPU, bit-exact dense-mirroring gather
     composition elsewhere. A slot carrying the scheduler's idle
-    sentinel (``t`` past the block-table extent) is handed ``last =
-    -1`` so the kernel skips its every page instead of sweeping NaN
-    garbage; live slots scan at most one chunk width past their real
-    frontier (the chunk's own padding rows)."""
+    sentinel (``t`` past the block-table extent) has no step in the
+    kernel's grid, nor has a query tile past a chunk's real rows: both
+    read zeros instead of sweeping NaN garbage. A live slot scans at
+    most one query tile past its real frontier (the padding rows of its
+    last live tile)."""
     from ..ops.pallas.ragged_prefill import ragged_prefill_attention
     b, s = q.shape[0], q.shape[1]
     if jnp.ndim(t) == 0:
         t = jnp.full((b,), t, jnp.int32)
-    limit = bt.shape[1] * pool["k"].shape[2]       # tokens a table spans
-    last = jnp.where(t >= limit, jnp.int32(-1), t + s - 1)
+    if plan is None:
+        plan = _paged_prefill_plan(bt, t, None, s, pool["k"].shape[2])
+    take, schedule = plan
     return ragged_prefill_attention(q, pool["k"], pool["v"], bt, t,
-                                    last=last, sm_scale=scale, mesh=mesh,
-                                    layer=layer)
+                                    take=take, sm_scale=scale, mesh=mesh,
+                                    layer=layer, schedule=schedule)
 
 
 def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
@@ -480,9 +505,10 @@ def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
     ``layer``'s pages through the block table, then q [B, s, nh, hd]
     attends through it. s == 1 is a decode step (ragged paged-attention
     kernel over ``plan``, the step's ``_paged_decode_plan``); s > 1 a
-    RAGGED PREFILL chunk at per-slot offsets ``t`` — which is what lets
-    the server prefill several admissions as one launch with no
-    dense-cache detour.
+    RAGGED PREFILL chunk at per-slot offsets ``t`` (ragged prefill
+    kernel over ``plan``, the launch's ``_paged_prefill_plan``) — which
+    is what lets the server prefill several admissions as one launch
+    with no dense-cache detour.
 
     ``select`` (``(qi, wi, ki, topk)``: indexer queries [B, s, J, D],
     head weights [B, s, J], the rows' indexer keys [B, s, 1, D]) is
@@ -505,7 +531,8 @@ def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
         att, kept = sparse_paged_attention(q, select[0], select[1], pool,
                                            layer, bt, t, select[3], scale)
     elif q.shape[1] > 1:
-        att = _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=mesh)
+        att = _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=mesh,
+                                    plan=plan)
     else:
         att = _paged_attend(q, pool, layer, bt, t, scale, mesh=mesh,
                             plan=plan)
@@ -731,19 +758,20 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
     ``t0`` and writes nothing. The step runs over the launch's VIEW of
     the per-slot leaves (the block table's rows and, where the model has
     it, the slot state, gathered at ``slots``) and the state is
-    scattered back. ``take`` [P]: the REAL rows of each chunk; a model
-    with per-slot state must know where a chunk that ends mid-prompt
-    really ends (the K/V of padding rows are hidden by lengths, a
-    recurrent state would carry them), and only it is told.
+    scattered back. ``take`` [P]: the REAL rows of each chunk: the
+    prefill kernel's grid has no step for a query tile past them, and a
+    model with per-slot state must know where a chunk that ends
+    mid-prompt really ends (the K/V of padding rows are hidden by
+    lengths, a recurrent state would carry them).
     """
     def prefill_tick(tokens, t0, caches, out_idx, take, slots):
         P = tokens.shape[0]
         x = embed_tokens(tokens, t0)
         at = jnp.minimum(slots, caches["bt"].shape[0] - 1)
-        view, rows = dict(caches, bt=caches["bt"][at]), {}
+        view = dict(caches, bt=caches["bt"][at])
         if "state" in caches:
-            view["state"], rows = caches["state"][:, at], {"take": take}
-        out, new = step_fn(x, view, t0, **rows)
+            view["state"] = caches["state"][:, at]
+        out, new = step_fn(x, view, t0, take)
         new = dict(new, bt=caches["bt"])
         if "state" in caches:
             new["state"] = caches["state"].at[:, slots].set(
@@ -903,14 +931,16 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         pos = jnp.minimum(_positions(t, b, s), max_cache_len - 1)
         # ... and they are sent to no expert: every row of a slot parked
         # on the sentinel is dead to the routed FFN. The padding rows
-        # INSIDE a live slot's chunk stay live (only a model with slot
-        # state is told a slot's row count, ``take``)
+        # INSIDE a live slot's chunk stay live
         alive = jnp.broadcast_to(t < max_cache_len, (b,))
         live = jnp.repeat(alive, s)
-        # the decode kernel's lengths and grid, once for every layer
-        # (key selection attends without that kernel)
-        plan = (_paged_decode_plan(bt, t, page_size)
-                if paged and s == 1 and indexer is None else None)
+        # the attention kernel's grid, once for every layer: a decode
+        # step's live pages, a prefill launch's live query tiles and
+        # theirs (key selection attends without either kernel)
+        plan = None
+        if paged and indexer is None:
+            plan = (_paged_decode_plan(bt, t, page_size) if s == 1
+                    else _paged_prefill_plan(bt, t, take, s, page_size))
         if state:
             t_b = jnp.broadcast_to(t, (b,))
             rows_b = (jnp.full((b,), s, jnp.int32) if take is None
@@ -1050,12 +1080,16 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             pos_emb = pos_emb[None]
         return (p["table"][tok] + pos_emb)[:, None, :]
 
-    def _forward(x, caches, t, bt):
+    def _forward(x, caches, t, bt, take=None):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
-        # the decode kernel's lengths and grid, once for every layer
-        plan = (_paged_decode_plan(bt, t, page_size)
-                if paged and s == 1 else None)
+        # the attention kernel's grid, once for every layer: a decode
+        # step's live pages, a prefill launch's live query tiles and
+        # theirs
+        plan = None
+        if paged:
+            plan = (_paged_decode_plan(bt, t, page_size) if s == 1
+                    else _paged_prefill_plan(bt, t, take, s, page_size))
 
         def layer(xx, blk, lc, l):
             h = _ln(xx, blk["ln1.weight"], blk["ln1.bias"], eps)
@@ -1086,8 +1120,9 @@ def _make_gpt_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                     if k_ not in ("table", "wpe", "lnf_w", "lnf_b")}
         return _run_layers(layer, x, blk_tree, caches, paged)[:2]
 
-    def step_fn(x, caches, t):
-        return _forward(x, caches, t, caches["bt"] if paged else None)
+    def step_fn(x, caches, t, take=None):
+        return _forward(x, caches, t, caches["bt"] if paged else None,
+                        take)
 
     def head_fn(out):
         h = _ln(unwrap(out), p["lnf_w"], p["lnf_b"], eps)

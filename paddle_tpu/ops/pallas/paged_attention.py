@@ -28,7 +28,10 @@ so the page DMA for step ``g`` is issued from the block-table entry
 ``schedule[g]`` names before the body runs. A slot of length 0 (idle, or
 mid-prefill) has no step and its output rows are zeros. GQA is handled
 in-kernel (query-head groups attend to their kv head) so the pool stores
-kv heads unrepeated.
+kv heads unrepeated. The ragged prefill kernel (``ragged_prefill.py``)
+follows its live work the same way and its grid is owned here too:
+``prefill_grid`` counts the pages of every live (row, query tile) pair
+of a launch, ``prefill_schedule`` lists them.
 
 The XLA fallback (`_ref_paged_attention`) gathers pages into the
 contiguous ``[slot, pages*page_size, ...]`` frame and then mirrors
@@ -43,13 +46,14 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from . import on_tpu
 
 NEG_INF = -1e30
 
 __all__ = ["paged_attention", "decode_grid", "decode_schedule",
-           "available"]
+           "prefill_grid", "prefill_schedule", "available"]
 
 
 def available() -> bool:
@@ -92,6 +96,92 @@ def decode_schedule(lengths, page_size, pages_per_slot):
     page = jnp.clip(g - (ends - pages)[slot], 0, pages_per_slot - 1)
     return ((slot * pages_per_slot + page).astype(jnp.int32),
             steps.astype(jnp.int32))
+
+
+# entries the prefill schedule's coarse index may take of scalar memory
+# (1 MiB on a v5e, which the block table shares): past it the index names
+# the pair of every ``block``-th step only
+_INDEX_ENTRIES = 32768
+
+
+def prefill_grid(t0, take, width, tile, page_size, pages_per_slot):
+    """``(pages, steps)`` of one prefill launch: the pages each (row,
+    query tile) pair attends ([rows, tiles]; 0 for a pair that is not
+    live) and the steps the kernel's grid takes — their sum, and one
+    step where nothing is live (it finds no page and computes nothing).
+    ``t0`` [rows] is each row's first position (the idle sentinel, at
+    or past the table's span, for a row with no work), ``take`` [rows]
+    its REAL rows of the ``width`` the launch carries. Tile ``i`` is
+    live while ``i * tile < take`` and attends through its last row's
+    page: the padding rows INSIDE a row's last live tile are computed
+    with it, a tile wholly past ``take`` is not. Plain operators, so it
+    counts NumPy values on the host (the server's
+    ``prefill_grid_steps``) as it sizes the grid from traced ones on
+    the device: one owner of the count."""
+    first = np.arange(0, width, tile, dtype=np.int32)          # [tiles]
+    ends = np.minimum(first + tile, width)
+    live = (t0 < page_size * pages_per_slot)[:, None] \
+        & (first[None, :] < take[:, None])
+    reach = (t0[:, None] + (ends[None, :] - 1)) // page_size + 1
+    over = reach - pages_per_slot       # padding rows past the table
+    pages = live * (reach - over * (over > 0))
+    total = pages.sum()
+    return pages, total + (total == 0)
+
+
+def prefill_index_block(pairs, pages_per_slot):
+    """Steps a coarse-index entry stands for: 1 while an index of every
+    step a launch of this shape can take fits ``_INDEX_ENTRIES``."""
+    return -(-pairs * pages_per_slot // _INDEX_ENTRIES)
+
+
+def prefill_schedule(t0, take, width, tile, page_size, pages_per_slot):
+    """``(pair, bounds, index, steps)``: what each step of the prefill
+    grid reads. The LIVE pairs of ``prefill_grid`` in row-major,
+    tile-major order: ``pair[n]`` is the n-th's ``row * tiles + tile``
+    and its steps are ``bounds[n] <= g < bounds[n + 1]``, one a page in
+    position order — one consecutive run (its online softmax never
+    interleaves with another's and its output block is visited once).
+    ``index[g // block]`` (``prefill_index_block``) is the n whose run
+    holds step ``g - g % block``; every live pair has a step, so step
+    ``g``'s is at most ``g % block`` further on. Static lengths ``rows *
+    tiles``, that plus one, and the launch's most steps over ``block``;
+    past the live pairs they hold valid values nobody visits. A
+    function of the launch's ``t0`` and ``take`` alone: every layer
+    attends the same chunks, so the launch computes it once."""
+    pages, steps = prefill_grid(t0.astype(jnp.int32),
+                                take.astype(jnp.int32), width, tile,
+                                page_size, pages_per_slot)
+    pages = pages.reshape(-1).astype(jnp.int32)
+    pairs = pages.shape[0]
+    n = jnp.arange(pairs, dtype=jnp.int32)
+    # the n-th live pair: the first with n + 1 live ones through it
+    seen = jnp.cumsum(pages > 0)
+    pair = jnp.minimum(jnp.sum(seen[None, :] <= n[:, None], axis=1),
+                       pairs - 1).astype(jnp.int32)
+    ends = jnp.cumsum(pages)[pair]           # the total past the live ones
+    block = prefill_index_block(pairs, pages_per_slot)
+    at = block * jnp.arange(-(-pairs * pages_per_slot // block),
+                            dtype=jnp.int32)
+    index = jnp.minimum(jnp.sum(ends[None, :] <= at[:, None], axis=1),
+                        pairs - 1).astype(jnp.int32)
+    bounds = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                              ends.astype(jnp.int32)])
+    return pair, bounds, index, steps.astype(jnp.int32)
+
+
+def prefill_step_pair(g, bounds_ref, index_ref, pairs, block):
+    """The n of the live pair whose run holds grid step ``g`` (see
+    ``prefill_schedule``): the coarse index's, moved on past every pair
+    that ends at or before ``g``."""
+    # lax's truncating division: every operand is non-negative, and an
+    # index map is traced and lowered once a call (plain ``//`` is a
+    # dozen scalar operations)
+    n = index_ref[g if block == 1 else jax.lax.div(g, np.int32(block))]
+    for _ in range(block - 1):
+        n = jax.lax.min(n + (bounds_ref[n + 1] <= g).astype(jnp.int32),
+                        np.int32(pairs - 1))
+    return n
 
 
 def _paged_attn_kernel(sched_ref, bt_ref, len_ref, layer_ref, q_ref, k_ref,

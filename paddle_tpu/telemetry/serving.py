@@ -354,6 +354,13 @@ class ServerTelemetry:
             labelnames=("kind",))
         self._c_grid_steps = grid.labels(kind="steps")
         self._c_grid_live = grid.labels(kind="live_pages")
+        pgrid = r.counter(
+            "serving_prefill_grid_total",
+            "The ragged prefill kernel's grid, a layer at a time: the "
+            "steps it took, and those that attended a page of a live "
+            "query tile", labelnames=("kind",))
+        self._c_pgrid_steps = pgrid.labels(kind="steps")
+        self._c_pgrid_live = pgrid.labels(kind="live_steps")
         # what a routed-expert / key-selecting model's launches did
         # (the server counts them; models with neither leave these 0)
         moe = r.counter(
@@ -594,6 +601,13 @@ class ServerTelemetry:
         if self.enabled:
             self._c_grid_steps.inc(steps)
             self._c_grid_live.inc(live_pages)
+
+    def on_prefill_grid(self, steps, live_steps):
+        """One prefill launch's kernel grid, over its layers: the steps
+        taken and those that attended a page of a live query tile."""
+        if self.enabled:
+            self._c_pgrid_steps.inc(steps)
+            self._c_pgrid_live.inc(live_steps)
 
     def on_moe_rows(self, rows, live, touched=0):
         """One launch's expert-FFN rows: those computed (the live

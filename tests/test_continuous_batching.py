@@ -523,3 +523,59 @@ def test_decode_grid_counters_follow_the_dispatched_lengths(tick_block):
     assert dense.stats["decode_ticks"] > 0
     assert (dense.stats["decode_grid_steps"],
             dense.stats["decode_live_pages"]) == (0, 0)
+
+
+@pytest.mark.parametrize("budget", [None, 12])
+def test_prefill_grid_counters_follow_the_dispatched_launches(budget):
+    """``prefill_grid_steps`` / ``prefill_live_steps``: what the host
+    adds a launch equals what ``prefill_grid`` (the function that sizes
+    the kernel's grid on the device) makes of the ``t0`` and ``take``
+    the launch was handed, a layer at a time — chunks carried over
+    launches by a small budget included; a flat grid has no dead step
+    but a launch's lone one, and a dense server, which runs no paged
+    kernel, counts none."""
+    from paddle_tpu.models.gpt import GPTForCausalLM, gpt2_tiny
+    from paddle_tpu.ops.pallas.paged_attention import prefill_grid
+    from paddle_tpu.ops.pallas.ragged_prefill import QUERY_TILE
+    pt.seed(23)
+    model = GPTForCausalLM(gpt2_tiny())
+    model.eval()
+    kw = dict(max_slots=4, max_cache_len=CACHE)
+    srv = ContinuousBatchingServer(model, cache_backend="paged",
+                                   page_size=8, num_pages=33,
+                                   telemetry=True,
+                                   prefill_tokens_per_tick=budget, **kw)
+    seen, real = [], srv._ragged_fn
+
+    def spy(tokens, t0, caches, out_idx, take, slots):
+        seen.append((np.asarray(t0), np.asarray(take), tokens.shape[1]))
+        return real(tokens, t0, caches, out_idx, take, slots)
+
+    srv._ragged_fn = spy
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, model.cfg.vocab_size, (n,)).astype(np.int32)
+               for n in (5, 11, 29)]
+    grid = lambda: (srv.stats["prefill_grid_steps"],
+                    srv.stats["prefill_live_steps"])
+    assert grid() == (0, 0)
+    for p in prompts:
+        srv.submit(p, max_new_tokens=3)
+    srv.run()
+    assert seen and (budget is None or len(seen) > 2)
+    steps = live = 0
+    for t0, take, width in seen:
+        pages, n = prefill_grid(t0, take, width, QUERY_TILE, 8, CACHE // 8)
+        steps, live = steps + int(n), live + int(pages.sum())
+    layers = model.cfg.num_layers
+    assert grid() == (steps * layers, live * layers)
+    assert steps == live > 0               # every launch had work
+    samples = srv.telemetry.registry.snapshot()[
+        "serving_prefill_grid_total"]["samples"]
+    assert (samples[("steps",)], samples[("live_steps",)]) == grid()
+
+    dense = ContinuousBatchingServer(model, **kw)
+    dense.submit(prompts[0], max_new_tokens=4)
+    dense.run()
+    assert dense.stats["prefill_tokens"] > 0
+    assert (dense.stats["prefill_grid_steps"],
+            dense.stats["prefill_live_steps"]) == (0, 0)

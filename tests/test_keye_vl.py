@@ -271,6 +271,10 @@ def test_counters_in_stats_and_registry(model):
     assert s["attn_keys_context"] == 2 * sum(
         len(p) + j for p in prompts for j in range(1, 5))
     assert s["attn_keys_selected"] == 2 * 8 * TOPK
+    # key selection attends through XLA: neither paged kernel's grid ran
+    assert s["prefill_chunks"] > 0 and s["decode_ticks"] > 0
+    assert (s["prefill_grid_steps"], s["prefill_live_steps"],
+            s["decode_grid_steps"]) == (0, 0, 0)
     snap = srv.telemetry.registry.snapshot()
 
     def total(name, **labels):

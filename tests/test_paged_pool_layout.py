@@ -140,21 +140,21 @@ def _decode_calls(mesh):
 
 def _prefill_calls(mesh):
     r, k, v, bt = _layered_pool(42)
-    C = 2 * rp._QUERY_TILE            # two query tiles a launch
+    C = 2 * rp.QUERY_TILE            # two query tiles a launch
     q = r(S, C, NH, HD)
     t0 = jnp.asarray(np.array([0, 5, 16, 3], np.int32))
-    last = jnp.asarray(np.array([15, 9, -1, 18], np.int32))  # an idle slot
+    take = jnp.asarray(np.array([16, 5, 0, 16], np.int32))   # an idle slot
     if mesh is not None:
         k, v = _shard(k, mesh), _shard(v, mesh)
 
     def layered(l, **kw):
-        return rp.ragged_prefill_attention(q, k, v, bt, t0, last, layer=l,
+        return rp.ragged_prefill_attention(q, k, v, bt, t0, take, layer=l,
                                            mesh=mesh, **kw)
 
     def alone(l, **kw):
         return rp.ragged_prefill_attention(
             q, gen.pool_heads(k[l], KVH), gen.pool_heads(v[l], KVH), bt,
-            t0, last, mesh=mesh, **kw)
+            t0, take, mesh=mesh, **kw)
 
     return layered, alone
 
@@ -188,26 +188,26 @@ class TestLayerIndex:
 
 
 def test_prefill_tiles_are_one_looped_launch():
-    """A chunk wider than the query tile is a loop over ONE kernel
-    launch (traced and lowered once a program), whatever the width; a
-    width that is no multiple of the tile pads its last tile and reads
-    the same rows."""
+    """A chunk wider than the query tile is ONE kernel launch whose
+    grid steps through the tiles (traced and lowered once a program),
+    whatever the width; a width that is no multiple of the tile pads its
+    last tile and reads the same rows."""
     r, k, v, bt = _layered_pool(43)
-    C = 4 * rp._QUERY_TILE
+    C = 4 * rp.QUERY_TILE
     q = r(S, C, NH, HD)
     t0 = jnp.asarray(np.array([0, 5, 16, 3], np.int32))
-    last = t0 + jnp.asarray(np.array([C, 11, 0, 20], np.int32)) - 1
+    take = jnp.asarray(np.array([C, 11, 0, 20], np.int32))
 
-    def call(q, last):
-        return rp.ragged_prefill_attention(q, k, v, bt, t0, last, layer=1,
+    def call(q, take):
+        return rp.ragged_prefill_attention(q, k, v, bt, t0, take, layer=1,
                                            interpret=True)
 
-    jaxpr = str(jax.make_jaxpr(call)(q, last))
+    jaxpr = str(jax.make_jaxpr(call)(q, take))
     assert jaxpr.count("name=ragged_prefill_attention") == 1
-    whole = np.asarray(call(q, last))
+    whole = np.asarray(call(q, take))
     odd = C - 5                            # 27 rows: a padded last tile
     np.testing.assert_array_equal(
-        np.asarray(call(q[:, :odd], jnp.minimum(last, t0 + odd - 1))),
+        np.asarray(call(q[:, :odd], jnp.minimum(take, odd))),
         whole[:, :odd])
 
 

@@ -79,8 +79,8 @@ class TestRaggedPrefillKernel:
         t0 = jnp.asarray(np.array([0, 5, pg], np.int32))
         takes = np.array([C, 2, 3], np.int32)
         out = rp._ragged_prefill_pallas(q, kp, vp, bt, t0,
-                                        t0 + jnp.asarray(takes) - 1,
-                                        0.2, interpret=True)
+                                        jnp.asarray(takes), 0.2,
+                                        interpret=True)
         ref = rp._ref_ragged_prefill(q, kp, vp, bt, t0, 0.2)
         for s in range(S):                  # live rows only
             np.testing.assert_allclose(
@@ -88,7 +88,7 @@ class TestRaggedPrefillKernel:
                 np.asarray(ref)[s, :takes[s]], rtol=2e-5, atol=2e-5)
 
     def test_kernel_skips_idle_slots_and_masks_future(self):
-        """An idle slot (last = -1) produces no NaN/Inf, and poisoning
+        """An idle slot (take = 0) produces no NaN/Inf, and poisoning
         pool rows beyond every row's causal frontier must not change a
         single output bit."""
         S, C, nh, kvh, hd, P, pg, maxp = 2, 4, 2, 2, 16, 8, 4, 4
@@ -98,35 +98,35 @@ class TestRaggedPrefillKernel:
         bt = jnp.asarray(np.array([[1, 2, 0, 0], [3, 4, 5, 0]],
                                   np.int32))
         t0 = jnp.asarray(np.array([2, 64], np.int32))
-        last = jnp.asarray(np.array([2 + 4 - 1, -1], np.int32))
-        out1 = rp._ragged_prefill_pallas(q, kp, vp, bt, t0, last, 0.3,
-                                         interpret=True)
+        take = jnp.asarray(np.array([4, 0], np.int32))
+        out1 = rp.ragged_prefill_attention(q, kp, vp, bt, t0, take, 0.3,
+                                           interpret=True)
         assert np.isfinite(np.asarray(out1)).all()
         # slot 0's last visible position is t0+C-1 = 5 (page 1, row 1):
         # poison everything after it
         kp2 = kp.at[2, 2:].set(1e3).at[5:].set(-1e3)
         vp2 = vp.at[2, 2:].set(1e3).at[5:].set(-1e3)
-        out2 = rp._ragged_prefill_pallas(q, kp2, vp2, bt, t0, last, 0.3,
-                                         interpret=True)
+        out2 = rp.ragged_prefill_attention(q, kp2, vp2, bt, t0, take, 0.3,
+                                           interpret=True)
         np.testing.assert_array_equal(np.asarray(out1)[0],
                                       np.asarray(out2)[0])
 
     def test_wide_chunk_tiles_query_rows(self):
-        """Chunks wider than _QUERY_TILE run as several shifted-offset
-        launches (bounded VMEM scratch on real TPUs — review finding);
-        the tiled composition must match the untiled reference,
-        including a slot whose live rows end mid-tile and an idle
-        slot."""
+        """Chunks wider than QUERY_TILE are cut into query tiles, each
+        a run of grid steps at its own offset (bounded VMEM scratch on
+        real TPUs — review finding); the tiled composition must match
+        the untiled reference, including a slot whose live rows end
+        mid-tile and an idle slot."""
         S, C, nh, kvh, hd, P, pg, maxp = 2, 16, 4, 2, 16, 16, 8, 8
-        assert C > rp._QUERY_TILE
+        assert C > rp.QUERY_TILE
         q = _rand(S, C, nh, hd, seed=11)
         kp = _rand(P, pg, kvh, hd, seed=12)
         vp = _rand(P, pg, kvh, hd, seed=13)
         bt = jnp.asarray(np.array([[1, 2, 3, 4, 0, 0, 0, 0],
                                    [5, 6, 7, 8, 9, 0, 0, 0]], np.int32))
         t0 = jnp.asarray(np.array([3, 64], np.int32))
-        last = jnp.asarray(np.array([3 + 10 - 1, -1], np.int32))
-        out = rp.ragged_prefill_attention(q, kp, vp, bt, t0, last=last,
+        take = jnp.asarray(np.array([10, 0], np.int32))
+        out = rp.ragged_prefill_attention(q, kp, vp, bt, t0, take=take,
                                           sm_scale=0.25, interpret=True)
         ref = rp._ref_ragged_prefill(q, kp, vp, bt, t0, 0.25)
         np.testing.assert_allclose(np.asarray(out)[0, :10],

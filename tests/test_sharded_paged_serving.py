@@ -294,10 +294,10 @@ class TestShardedKernels:
         r, kp, vp, bt = self._pool(S, kvh, hd, P, pg, maxp, seed=32)
         q = r(S, C, nh, hd)
         t0 = jnp.asarray(np.array([0, 5, 16], np.int32))
-        last = jnp.asarray(np.array([7, 9, -1], np.int32))  # idle slot
-        want = rp.ragged_prefill_attention(q, kp, vp, bt, t0, last,
+        take = jnp.asarray(np.array([8, 5, 0], np.int32))   # idle slot
+        want = rp.ragged_prefill_attention(q, kp, vp, bt, t0, take,
                                            interpret=True)
-        got = rp.ragged_prefill_attention(q, kp, vp, bt, t0, last,
+        got = rp.ragged_prefill_attention(q, kp, vp, bt, t0, take,
                                           interpret=True, mesh=_mesh(4))
         np.testing.assert_array_equal(np.asarray(want), np.asarray(got))
 

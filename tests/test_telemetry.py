@@ -501,8 +501,8 @@ def _kernel_cases():
         from paddle_tpu.ops.pallas.ragged_prefill import \
             _ragged_prefill_pallas
         return jax.make_jaxpr(
-            lambda q, k, v, bt, t0, last: _ragged_prefill_pallas(
-                q, k, v, bt, t0, last, 0.125))(
+            lambda q, k, v, bt, t0, take: _ragged_prefill_pallas(
+                q, k, v, bt, t0, take, 0.125))(
             sds((2, 8, 4, 64)), sds((8, 16, 4, 64)), sds((8, 16, 4, 64)),
             sds((2, 4), i32), sds((2,), i32), sds((2,), i32))
 

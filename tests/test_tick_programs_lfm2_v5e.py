@@ -16,8 +16,9 @@ import types
 import jax
 import jax.numpy as jnp
 import numpy as np
-from test_tick_programs_v5e import (_INSTR, _compile, as_on_chip,  # noqa: F401
-                                    one_chip, topo)
+from test_tick_programs_v5e import (_INSTR, _compile,  # noqa: F401
+                                    _prefill_kernels, as_on_chip, one_chip,
+                                    topo)
 
 from paddle_tpu.models import generation
 
@@ -133,7 +134,11 @@ def test_lfm2_prefill_launches_compile_and_fit(one_chip, as_on_chip):
     exe = _compile(launch, (3,), one_chip, shapes, i32(4, 1024), i32(4),
                    caches, i32(4), i32(4), i32(4))
     assert "ragged_prefill_attention" in exe.as_text()
+    # one call an ATTENTION layer of the unrolled spec, each over a
+    # dynamic grid (131,072 steps a layer as a static sweep)
+    assert len(_prefill_kernels(exe)) == 2
     _assert_fits(exe, caches)
     exe = _compile(launch, (3,), one_chip, shapes, i32(64, 64), i32(64),
                    caches, i32(64), i32(64), i32(64))
+    assert len(_prefill_kernels(exe)) == 2
     _assert_fits(exe, caches)

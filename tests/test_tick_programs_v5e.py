@@ -139,9 +139,9 @@ def _decode_tick(cfg, num_pages, mesh=None):
     return decode_tick
 
 
-def _prefill_tick(cfg, num_pages):
+def _prefill_tick(cfg, num_pages, mesh=None):
     def prefill_tick(weights, tokens, t0, caches, out_idx, take, slots):
-        return _paged_bundle(cfg, weights, num_pages)[4](
+        return _paged_bundle(cfg, weights, num_pages, mesh)[4](
             tokens, t0, caches, out_idx, take, slots)
 
     return prefill_tick
@@ -207,15 +207,25 @@ def _decode_specs(cfg, slots, num_pages):
                     jax.ShapeDtypeStruct((slots, 2), jnp.uint32))
 
 
-def _one_decode_kernel(exe):
-    """ONE decode-attention custom call a program (the layer loop's),
-    and its grid's bound a runtime operand: the call's first operand is
-    a scalar ``s32[]``, the step count ``decode_schedule`` made."""
+def _kernel_calls(exe, name):
+    """The custom calls of one paged kernel in a program, each with its
+    grid's bound a runtime operand: the call's first operand is a
+    scalar ``s32[]``, the step count its schedule made."""
     calls = [line for line in exe.as_text().splitlines()
-             if "custom-call(" in line and "paged_attention_decode" in
+             if "custom-call(" in line and name in
              line.split("custom-call(")[0]]
-    assert len(calls) == 1, calls
-    assert "operand_layout_constraints={s32[]," in calls[0]
+    for call in calls:
+        assert "operand_layout_constraints={s32[]," in call
+    return calls
+
+
+def _one_decode_kernel(exe):
+    """ONE decode-attention custom call a program (the layer loop's)."""
+    assert len(_kernel_calls(exe, "paged_attention_decode")) == 1
+
+
+def _prefill_kernels(exe):
+    return _kernel_calls(exe, "ragged_prefill_attention")
 
 
 @pytest.mark.parametrize("geometry", [MEDIUM, XL], ids=["medium", "xl"])
@@ -232,14 +242,13 @@ def test_decode_tick_leaves_the_pool_in_place(geometry, one_chip,
         assert exe.memory_analysis().temp_size_in_bytes < 2 ** 30 / 200
 
 
-def test_decode_tick_compiles_for_the_four_chip_mesh(topo, as_on_chip):
-    """The mesh path at the cell's geometry on ``v5e:2x2``: one launch a
-    kv-head shard under ``shard_map``, the grid's schedule replicated,
-    each device's quarter of the pool aliased and left in place."""
+def _on_mesh(topo, cfg, slots, num_pages):
+    """The cell's geometry on ``v5e:2x2``: ``(mesh, on, weights, caches,
+    shard)`` — ``on(spec, *axes)`` places a shape on the mesh, the
+    weights split on ``mp`` as the bundle splits them, the pool on its
+    lanes, and ``shard`` is each device's quarter of the pool."""
     from jax.sharding import Mesh, NamedSharding
     from jax.sharding import PartitionSpec as P
-    cfg, slots, num_pages = (MEDIUM[k] for k in
-                             ("cfg", "slots", "num_pages"))
     mesh = Mesh(np.array(topo.devices), ("mp",))
     dims = {"attn.qkv.weight": 2, "attn.proj.weight": 1,
             "mlp.fc1.weight": 2, "mlp.fc2.weight": 1}
@@ -255,16 +264,44 @@ def test_decode_tick_compiles_for_the_four_chip_mesh(topo, as_on_chip):
     cache_specs = {"bt": on(caches["bt"]),
                    "pool": {n: on(a, None, None, None, "mp")
                             for n, a in caches["pool"].items()}}
+    # per device: a quarter of the lanes, still row-major and in place
+    shard = {n: jax.ShapeDtypeStruct(a.shape[:-1] + (a.shape[-1] // 4,),
+                                     a.dtype)
+             for n, a in caches["pool"].items()}
+    return mesh, on, weights, cache_specs, shard
+
+
+def test_decode_tick_compiles_for_the_four_chip_mesh(topo, as_on_chip):
+    """The mesh path at the cell's geometry on ``v5e:2x2``: one launch a
+    kv-head shard under ``shard_map``, the grid's schedule replicated,
+    each device's quarter of the pool aliased and left in place."""
+    cfg, slots, num_pages = (MEDIUM[k] for k in
+                             ("cfg", "slots", "num_pages"))
+    mesh, on, weights, cache_specs, shard = _on_mesh(topo, cfg, slots,
+                                                     num_pages)
     i32 = lambda *s: on(jax.ShapeDtypeStruct(s, jnp.int32))
     exe = (jax.jit(_decode_tick(cfg, num_pages, mesh), donate_argnums=(2,))
            .trace(weights, i32(slots), cache_specs, i32(slots),
                   on(jax.ShapeDtypeStruct((slots, 2), jnp.uint32)))
            .lower(lowering_platforms=("tpu",)).compile())
     _one_decode_kernel(exe)
-    # per device: a quarter of the lanes, still row-major and in place
-    shard = {n: jax.ShapeDtypeStruct(a.shape[:-1] + (a.shape[-1] // 4,),
-                                     a.dtype)
-             for n, a in caches["pool"].items()}
+    _assert_pool_stays(exe, {"pool": shard})
+
+
+def test_prefill_tick_compiles_for_the_four_chip_mesh(topo, as_on_chip):
+    """The same for a packed launch (32 rows x 128): one prefill call a
+    kv-head shard under ``shard_map``, its two-level schedule and step
+    count replicated, the pool's quarters left in place."""
+    cfg, slots, num_pages = (MEDIUM[k] for k in
+                             ("cfg", "slots", "num_pages"))
+    mesh, on, weights, cache_specs, shard = _on_mesh(topo, cfg, slots,
+                                                     num_pages)
+    i32 = lambda *s: on(jax.ShapeDtypeStruct(s, jnp.int32))
+    exe = (jax.jit(_prefill_tick(cfg, num_pages, mesh), donate_argnums=(3,))
+           .trace(weights, i32(slots, 128), i32(slots), cache_specs,
+                  i32(slots), i32(slots), i32(slots))
+           .lower(lowering_platforms=("tpu",)).compile())
+    assert len(_prefill_kernels(exe)) == 1
     _assert_pool_stays(exe, {"pool": shard})
 
 
@@ -289,18 +326,64 @@ def test_grid_schedule_is_made_outside_the_layer_loop(as_on_chip):
     assert "cumsum" not in body and "stablehlo.while" not in body
 
 
+def _prefill_specs(cfg, slots, num_pages, width):
+    """A packed launch of the server's: ``4,096 // width`` rows, at
+    most one a slot."""
+    caches = _cache_shapes(cfg, slots, num_pages)
+    rows = min(slots, 4096 // width)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
+    return caches, (_weight_shapes(cfg), i32(rows, width), i32(rows), caches,
+                    i32(rows), i32(rows), i32(rows))
+
+
 def test_prefill_tick_leaves_the_pool_in_place(one_chip, as_on_chip):
     """One ragged-prefill width of the cell's ladder (C = 64, its most
     frequent: 13 of 46 launches in PR 25's window)."""
     cfg, slots, num_pages = (MEDIUM[k] for k in
                              ("cfg", "slots", "num_pages"))
-    caches = _cache_shapes(cfg, slots, num_pages)
-    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
-    exe = _compile(_prefill_tick(cfg, num_pages), (3,), one_chip,
-                   _weight_shapes(cfg), i32(slots, 64), i32(slots), caches,
-                   i32(slots), i32(slots), i32(slots))
+    caches, specs = _prefill_specs(cfg, slots, num_pages, 64)
+    exe = _compile(_prefill_tick(cfg, num_pages), (3,), one_chip, *specs)
     assert "ragged_prefill_attention" in exe.as_text()
+    assert len(_prefill_kernels(exe)) == 1
     _assert_pool_stays(exe, caches)
+
+
+@pytest.mark.parametrize("geometry,width", [(MEDIUM, 512), (XL, 128)],
+                         ids=["medium-512", "xl-128"])
+def test_prefill_kernel_is_one_call_over_a_dynamic_grid(geometry, width,
+                                                        one_chip,
+                                                        as_on_chip):
+    """ONE prefill-attention call a program (the layer loop's), whatever
+    the launch's width — its query tiles are grid steps, not a loop of
+    launches — with the step count a runtime scalar: the cell's widest
+    launch (8 rows x 512) and the 1,600-lane width."""
+    cfg, slots, num_pages = (geometry[k] for k in
+                             ("cfg", "slots", "num_pages"))
+    _, specs = _prefill_specs(cfg, slots, num_pages, width)
+    exe = _compile(_prefill_tick(cfg, num_pages), (3,), one_chip, *specs)
+    assert len(_prefill_kernels(exe)) == 1
+
+
+def test_prefill_schedule_is_made_outside_the_layer_loop(as_on_chip):
+    """Every layer of a launch attends the same chunks: the cumulative
+    sums that make the kernel's schedule sit in the prefill program's
+    body ONCE, before the layer loop, and the loop's body holds the
+    kernel, no cumulative sum and no loop over query tiles."""
+    cfg, slots, num_pages = (MEDIUM[k] for k in
+                             ("cfg", "slots", "num_pages"))
+    _, specs = _prefill_specs(cfg, slots, num_pages, 128)
+    text = (jax.jit(_prefill_tick(cfg, num_pages), donate_argnums=(3,))
+            .trace(*specs).lower(lowering_platforms=("tpu",)).as_text())
+    funcs = text.split("func.func ")
+    made = [f for f in funcs if "call @cumsum" in f
+            and not f.startswith("private @cumsum")]
+    assert len(made) == 1
+    # ... in the step's function, ahead of its layer loop
+    assert -1 < made[0].rindex("call @cumsum") < made[0].index(
+        "stablehlo.while")
+    (body,) = [f for f in funcs if "tpu_custom_call" in f]
+    assert body.count("tpu_custom_call") == 1
+    assert "cumsum" not in body and "stablehlo.while" not in body
 
 
 def test_weight_shapes_are_the_bundles_own():
