@@ -19,6 +19,14 @@ full width of GPT-2 345M (24 layers, hidden 1024, 16 heads x 64, vocab
   model's own uncached f32 forward. The benchmark's third configuration runs
   these programs at published widths; this phase compiles them for the chip
   outside the benchmark too.
+- ``ssm-serve``: the same server over a ``nemotron_h``-shaped model (a layer
+  is ONE sublayer: Mamba-2 layers whose float32 recurrent state and
+  convolution window are a per-slot state TREE, an attention layer with no
+  positional term, latent expert layers that hold half of the router's
+  experts) at small lane-legal widths, prompts spanning several launches and
+  several chunks of the scan, checked on logits against the model's own
+  uncached f32 forward. The benchmark's fourth configuration runs these
+  programs at published widths.
 - ``train``: ``jit.train_step_fn(model, ce, AdamW)`` at B=8, S=1024.
 - ``mesh4`` (only where JAX reports >= 4 devices): the serve phase over an
   ``mp=4`` mesh plus ``parallel.parallel_train_step``.
@@ -702,11 +710,50 @@ def hybrid_preset(rehearse):
                 prompts=(33, 150, 90, 57, 200, 17), new=12, budget=64)
 
 
-def phase_hybrid(phases, rehearse, watch):
+def serve_two_waves(tag, model, forward, H):
+    """A paged server over ``model`` answering ``H["prompts"]`` twice (the
+    second wave lands in slots the first one left), every request checked
+    on logits against ``forward(params, ids)``, the model's own uncached
+    forward, in float32. Returns the server, drained."""
     import jax
     import jax.numpy as jnp
 
     from paddle_tpu.inference import ContinuousBatchingServer
+    cfg = H["cfg"]
+    params32 = {n: a.astype(jnp.float32)
+                for n, a in model.raw_params().items()}
+    fwd = jax.jit(forward)
+    width = max(H["prompts"]) + H["new"]
+
+    def ref_logits(ids):
+        row = np.zeros((1, width), np.int32)
+        row[0, :len(ids)] = ids
+        with jax.default_matmul_precision("highest"):
+            out = fwd(params32, jnp.asarray(row))
+        check(out.dtype == jnp.float32, f"reference ran in {out.dtype}")
+        return np.asarray(out[0, :len(ids)])
+
+    srv = ContinuousBatchingServer(
+        model, cache_backend="paged", max_slots=H["slots"],
+        max_cache_len=H["cache_len"], page_size=H["page"],
+        prefill_tokens_per_tick=H["budget"])
+    rng = np.random.default_rng(6)
+    prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
+               for n in H["prompts"]]
+    for wave in range(2):
+        rids = [srv.submit(p, max_new_tokens=H["new"]) for p in prompts]
+        outs = srv.run()
+        for i, (rid, p) in enumerate(zip(rids, prompts)):
+            check(len(outs[rid]) == H["new"], f"request {rid} is short")
+            check_tokens(f"{tag} wave {wave} #{i}", ref_logits, p, outs[rid])
+    check(srv.stats["prefill_chunks_carried"] > 0,
+          "no prompt spanned two launches")
+    free, live, *_ = srv.pool_balance()
+    check(live == 0, f"pages leaked: pool_balance() live == {live}")
+    return srv
+
+
+def phase_hybrid(phases, rehearse, watch):
     from paddle_tpu.models import lfm2
     H = hybrid_preset(rehearse)
     cfg = H["cfg"]
@@ -714,49 +761,75 @@ def phase_hybrid(phases, rehearse, watch):
         model = lfm2.Lfm2MoeForCausalLM(cfg, weights=lfm2.init_weights(
             cfg, seed=0, scale={"model.moe_layers.experts_w2": 0.1}))
         model.eval()
-        params32 = {n: a.astype(jnp.float32)
-                    for n, a in model.raw_params().items()}
-        fwd = jax.jit(lambda ps, ids: lfm2._forward(cfg, ids, ps))
-        width = max(H["prompts"]) + H["new"]
-
-        def ref_logits(ids):
-            row = np.zeros((1, width), np.int32)
-            row[0, :len(ids)] = ids
-            with jax.default_matmul_precision("highest"):
-                out = fwd(params32, jnp.asarray(row))
-            check(out.dtype == jnp.float32, f"reference ran in {out.dtype}")
-            return np.asarray(out[0, :len(ids)])
-
-        srv = ContinuousBatchingServer(
-            model, cache_backend="paged", max_slots=H["slots"],
-            max_cache_len=H["cache_len"], page_size=H["page"],
-            prefill_tokens_per_tick=H["budget"])
+        srv = serve_two_waves(
+            "hybrid", model, lambda ps, ids: lfm2._forward(cfg, ids, ps), H)
         layers = lfm2.layer_counts(cfg)
         check(srv._caches["pool"]["k"].shape[0] == layers[0],
               "the pool has a layer that is no attention layer's")
         check(srv._caches["state"].shape[:2] == (layers[1], H["slots"]),
               "the slot state is not [conv layers, slots, ...]")
-        rng = np.random.default_rng(6)
-        prompts = [rng.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
-                   for n in H["prompts"]]
-        # twice: the second wave lands in slots the first one left
-        for wave in range(2):
-            rids = [srv.submit(p, max_new_tokens=H["new"]) for p in prompts]
-            outs = srv.run()
-            for i, (rid, p) in enumerate(zip(rids, prompts)):
-                check(len(outs[rid]) == H["new"], f"request {rid} is short")
-                check_tokens(f"hybrid wave {wave} #{i}", ref_logits, p,
-                             outs[rid])
         s = srv.stats
         say(f"  hybrid: {s['prefill_chunks']} slot-chunks, "
             f"{s['prefill_chunks_carried']} carried state; "
             f"{s['decode_ticks']} decode ticks touched "
             f"{s['moe_experts_touched']} experts")
-        check(s["prefill_chunks_carried"] > 0,
-              "no prompt spanned two launches")
-        free, live, *_ = srv.pool_balance()
-        check(live == 0, f"pages leaked: pool_balance() live == {live}")
         mem_line("hybrid-serve")
+    gc.collect()
+
+
+# ============================================================== state space
+def ssm_preset(rehearse):
+    """A ``nemotron_h``-shaped model ``MEM*E`` (two Mamba-2 layers, two
+    latent expert layers holding 8 of the router's 16 experts top-3, one
+    attention layer) at lane-legal widths: hidden 256, 8 Mamba heads of 64 in
+    2 groups with a state of 128 and chunks of 32, 4 query / 2 K/V heads of
+    64 (128 pool lanes). The convolution's taps are drawn 25 times the range,
+    as the benchmark's configuration draws them, so that the recurrent state
+    moves the logits."""
+    from paddle_tpu.models.nemotron_h import nemotron_h_tiny
+    if rehearse:
+        return dict(cfg=nemotron_h_tiny(), slots=4, cache_len=64, page=8,
+                    prompts=(9, 20, 13, 6), new=5, budget=8)
+    cfg = nemotron_h_tiny(
+        vocab_size=1024, hidden_size=256, mamba_num_heads=8,
+        mamba_head_dim=64, ssm_state_size=128, n_groups=2, chunk_size=32,
+        num_attention_heads=4, num_key_value_heads=2, head_dim=64,
+        moe_intermediate_size=256, moe_latent_size=128,
+        moe_shared_expert_intermediate_size=512,
+        max_position_embeddings=1024, dtype="bfloat16")
+    return dict(cfg=cfg, slots=8, cache_len=512, page=16,
+                prompts=(33, 150, 90, 57, 200, 17), new=12, budget=64)
+
+
+def phase_ssm(phases, rehearse, watch):
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import nemotron_h as nh
+    H = ssm_preset(rehearse)
+    cfg = H["cfg"]
+    with phases.run("ssm-serve"):
+        model = nh.NemotronHForCausalLM(cfg, weights=nh.init_weights(
+            cfg, seed=0, scale={"model.mamba_layers.conv_weight": 25.0}))
+        model.eval()
+        srv = serve_two_waves(
+            "ssm", model, lambda ps, ids: nh._forward(cfg, ids, ps), H)
+        mamba, attn, _ = nh.layer_counts(cfg)
+        state = srv._caches["state"]
+        check(srv._caches["pool"]["k"].shape[0] == attn,
+              "the pool has a layer that is no attention layer's")
+        check(state["ssm"].shape[:2] == (mamba, H["slots"])
+              and state["ssm"].dtype == jnp.float32
+              and state["conv"].dtype == jnp.dtype(cfg.dtype),
+              "the slot state is not {conv, ssm float32} [ssm layers, slots]")
+        s = srv.stats
+        say(f"  ssm: {s['prefill_chunks']} slot-chunks, "
+            f"{s['prefill_chunks_carried']} carried state; "
+            f"{s['decode_ticks']} decode ticks, {s['moe_pairs_held']} of "
+            f"{s['moe_pairs_routed']} routed pairs held, "
+            f"{s['moe_experts_touched']} held experts touched")
+        check(0 < s["moe_pairs_held"] < s["moe_pairs_routed"],
+              "the share held every routed pair, or none")
+        mem_line("ssm-serve")
     gc.collect()
 
 
@@ -913,6 +986,7 @@ def main(argv=None):
     mem_line("kernels")
     phase_serve(P, phases, args.rehearse, watch)
     phase_hybrid(phases, args.rehearse, watch)
+    phase_ssm(phases, args.rehearse, watch)
     with phases.run("train"):
         phase_train(P, args.rehearse, watch)
     gc.collect()

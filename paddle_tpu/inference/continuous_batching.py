@@ -276,8 +276,9 @@ class ContinuousBatchingServer:
     the same tree as PINNED nodes that eviction never touches.
 
     A model with PER-SLOT STATE beside the pool (a hybrid's short-
-    convolution layers: ``caches["state"]``, addressed by the slot and
-    not by the block table) serves through the same tick. What assumes
+    convolution or state-space layers: ``caches["state"]``, a tree of
+    leaves addressed by the slot and not by the block table) serves
+    through the same tick. What assumes
     that pages are the whole state is refused for it BY NAME, at
     construction or at the call: the prefix cache (``auto_prefix_cache``
     reads as False for such a model; True, and ``register_prefix``,
@@ -459,8 +460,9 @@ class ContinuousBatchingServer:
                                     fault_injector=fault_injector)
             self._caches = self._paged_bundle[0](self.max_slots)
             # per-slot recurrent state beside the pool (a short
-            # convolution's last inputs): carried and donated like the
-            # pool, addressed by the slot — no page holds it
+            # convolution's last inputs, a state-space layer's window
+            # and float32 state: a tree of leaves): carried and donated
+            # like the pool, addressed by the slot — no page holds it
             self._slot_state = "state" in self._caches
             if self._slot_state:
                 auto_prefix_cache = self._refuse_for_slot_state(
@@ -689,10 +691,17 @@ class ContinuousBatchingServer:
                       "decode_live_pages": 0, "prefill_grid_steps": 0,
                       "prefill_live_steps": 0, "moe_rows": 0,
                       "moe_live_rows": 0, "moe_experts_touched": 0,
+                      # the (row, expert) choices of the decode ticks'
+                      # live rows, and those that fell on an expert this
+                      # model holds (a share of the router's, or all)
+                      "moe_pairs_routed": 0, "moe_pairs_held": 0,
                       "attn_keys_context": 0, "attn_keys_selected": 0}
         cfg = getattr(model, "cfg", None)
         self._moe_k = int(getattr(cfg, "top_k", 0) or 0) \
             if getattr(cfg, "num_experts", 0) else 0
+        # the share of the router's experts the model holds: (first,
+        # count), None where it holds them all
+        self._moe_held = getattr(cfg, "experts_held", None)
         indexer = getattr(cfg, "indexer", None)
         self._select_k = int(indexer[2]) if indexer else 0
         # layers that attend: the pool's own count on the paged backend
@@ -845,8 +854,8 @@ class ContinuousBatchingServer:
         if self._slot_state:
             raise NotImplementedError(
                 "this model keeps per-slot recurrent state (its short-"
-                "convolution layers' last inputs, caches['state']) beside "
-                f"the page pool, and {what} assumes that pages are a "
+                "convolution or state-space layers', caches['state']) "
+                f"beside the page pool, and {what} assumes that pages are a "
                 "request's whole state: a run of pages would be resumed "
                 "with no state to go with it. State snapshots at page "
                 "boundaries are ROADMAP B5")
@@ -2949,18 +2958,28 @@ class ContinuousBatchingServer:
         ``live_rows`` of them a live token's. ``route`` (decode ticks:
         ``[slots, layers * k]`` expert ids off the token read-back, or
         None) gives the distinct experts the live slots' rows chose,
-        a layer at a time."""
-        touched = 0
+        a layer at a time, AMONG those the model holds: a choice that
+        fell on an expert of another share reads no weight here."""
+        touched = routed = held = 0
         if route is not None and route.size:
             k = self._moe_k
             live = route[self._active].reshape(-1, route.shape[1] // k, k)
-            touched = sum(int(np.unique(live[:, l]).size)
+            mine = np.ones(live.shape, bool)
+            if self._moe_held is not None:
+                first, count = self._moe_held
+                mine = (live >= first) & (live < first + count)
+            routed, held = int(live.size), int(mine.sum())
+            touched = sum(int(np.unique(live[:, l][mine[:, l]]).size)
                           for l in range(live.shape[1])) if live.size else 0
         self.stats["moe_rows"] += rows
         self.stats["moe_live_rows"] += live_rows
         self.stats["moe_experts_touched"] += touched
+        self.stats["moe_pairs_routed"] += routed
+        self.stats["moe_pairs_held"] += held
         if self._tele is not None:
             self._tele.on_moe_rows(rows, live_rows, touched)
+            if routed:
+                self._tele.on_moe_pairs(routed, held)
 
     def _busy_locked(self):
         """Work pending: queued requests, decoding slots, slots still

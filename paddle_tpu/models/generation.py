@@ -345,9 +345,11 @@ def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
     already makes; ``kept`` adds ``kept`` ``[L, batch]``, the keys the
     selection kept for that row, which rides the same read-back.
 
-    ``state`` (``(layers, rows, width)``) adds ``state`` ``[layers,
-    batch, rows, width]``: PER-SLOT recurrent state beside the pool (a
-    short convolution's last inputs, a layer a conv layer). It is
+    ``state`` (``_slot_state``'s argument) adds ``state``: PER-SLOT
+    recurrent state beside the pool, a TREE of leaves ``[layers of a
+    kind, batch, ...]``, each of its own type (a short convolution's
+    last inputs, a layer a conv layer; a state-space layer's
+    convolution window and its float32 recurrent state). It is
     addressed by the slot and NOT by the block table: no page holds it,
     so nothing that moves pages (prefix sharing, spill, migration)
     moves it, and the server refuses those for a model that has it."""
@@ -365,10 +367,18 @@ def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
                                    int(route_k)), jnp.int32)
     if kept:
         tree["kept"] = jnp.zeros((layers, batch), jnp.int32)
-    if state:
-        tree["state"] = jnp.zeros((state[0], batch) + tuple(state[1:]),
-                                  dtype)
+    if state is not None:
+        tree["state"] = _slot_state(state, batch)
     return tree
+
+
+def _slot_state(state, batch):
+    """Fresh per-slot state: ``state`` is a tree (one leaf, or a dict of
+    them) of ``jax.ShapeDtypeStruct((layers, ...), dtype)``, what ONE
+    slot holds in the layers of a kind; the batch goes in at axis 1."""
+    return jax.tree_util.tree_map(
+        lambda s: jnp.zeros((s.shape[0], batch) + s.shape[1:], s.dtype),
+        state)
 
 
 def _paged_decode_plan(bt, t, page_size):
@@ -545,30 +555,36 @@ _LEAF_KIND = dict(
     {n: "layer" for n in ("ln1", "ln2")},
     **{n: "attn" for n in ("wq", "wk", "wv", "wo", "qn", "kn")},
     **{n: "conv" for n in ("ci", "cw", "co")},
+    **{n: "ssm" for n in ("si", "sw", "sb", "sa", "sd", "st", "sn", "so")},
     **{n: "dense" for n in ("dg", "du", "dd")},
-    **{n: "moe" for n in ("router", "rbias")})
+    **{n: "moe" for n in ("router", "rbias", "ld", "lu", "s1", "s2")})
 
 
 def _layer_spec(cfg):
     """A model whose layers are NOT alike says so in its config
     (``layer_types``: each layer's mixer, ``"full_attention"`` or
     ``"conv"``; ``num_dense_layers``: how many leading layers keep a
-    dense FFN in an expert model). Returns None for a model of identical
-    layers, else one dict a layer: the layer's index within each stack
-    it reads (``_LEAF_KIND``) — ``{"layer": l, "attn" | "conv": i,
-    "dense" | "moe": j}``. ``attn`` is also the layer's index in the
-    page pool, ``conv`` in the slot state, ``moe`` in the expert
+    dense FFN in an expert model; or ``sublayers``: a layer is ONE
+    sublayer behind one norm, a mixer OR an FFN, named by the stack it
+    reads: ``"ssm"``, ``"attn"``, ``"moe"``). Returns None for a model
+    of identical layers, else one dict a layer: the layer's index within
+    each stack it reads (``_LEAF_KIND``) — ``{"layer": l, "attn" |
+    "conv": i, "dense" | "moe": j}``, or ``{"layer": l, "ssm" | "attn" |
+    "moe": i}``. ``attn`` is also the layer's index in the page pool,
+    ``conv`` and ``ssm`` in the slot state, ``moe`` in the expert
     stacks."""
     types = getattr(cfg, "layer_types", None)
     experts = bool(getattr(cfg, "num_experts", 0))
     n_dense = int(getattr(cfg, "num_dense_layers", 0) or 0) if experts else 0
-    if types is None and not n_dense:
+    solo = getattr(cfg, "sublayers", None)
+    if types is None and not n_dense and solo is None:
         return None
     types = tuple(types or ("full_attention",) * cfg.num_layers)
     spec, count = [], {}
     for l, mixer in enumerate(types):
-        kinds = ("conv" if mixer == "conv" else "attn",
-                 "moe" if experts and l >= n_dense else "dense")
+        kinds = (solo[l],) if solo is not None else (
+            "conv" if mixer == "conv" else "attn",
+            "moe" if experts and l >= n_dense else "dense")
         at = {"layer": l}
         for kind in kinds:
             at[kind] = count[kind] = count.get(kind, -1) + 1
@@ -668,6 +684,93 @@ def _short_conv_mixer(blk, xx, state, layer, t, take, live, eps):
         return xx + _mm(gc * c, blk["co"]), state.at[layer].set(new)
 
 
+def _ssm_core(blk, xbc, z, dt, s0, take, dims, eps):
+    """A Mamba-2 mixer between its convolution and its output
+    projection: ``xbc`` [B, s, I + 2 G N] the convolved, activated
+    channels (``X``, ``B``, ``C``), ``z`` [B, s, I] the gate, ``dt``
+    [B, s, H] the raw step sizes, ``s0`` [B, H, P, N] float32 the state
+    before the rows, ``take`` [B] the real rows (None: all): a row past
+    them takes a step of size 0, so the state that comes back is the
+    last real row's. One row is ``ssm_step``, a run the chunked
+    ``ssm_scan``: the same recurrence. Then the skip ``D X``, the gate
+    BEFORE the norm, and an RMSNorm a group. Returns ``(y [B, s, I],
+    state)``."""
+    from ..ops.ssm_scan import ssm_scan, ssm_step
+    heads, p, groups, n, chunk = dims
+    b, s = xbc.shape[:2]
+    inner = heads * p
+    x, bm, cm = jnp.split(xbc, [inner, inner + groups * n], axis=-1)
+    x = x.reshape(b, s, heads, p)
+    bm, cm = bm.reshape(b, s, groups, n), cm.reshape(b, s, groups, n)
+    d = jax.nn.softplus(dt.astype(jnp.float32) + blk["st"])
+    if take is not None:
+        real = jnp.arange(s, dtype=jnp.int32)[None] < take[:, None]
+        d = jnp.where(real[..., None], d, 0.0)
+    a = -jnp.exp(blk["sa"])
+    if s == 1:
+        y, s1 = ssm_step(x[:, 0], d[:, 0], a, bm[:, 0], cm[:, 0], s0)
+        y = y[:, None]
+    else:
+        y, s1 = ssm_scan(x, d, a, bm, cm, s0, chunk)
+    y = y + blk["sd"][:, None] * x.astype(jnp.float32)
+    y = y.reshape(b, s, inner) * jax.nn.silu(z.astype(jnp.float32))
+    g = y.reshape(b, s, groups, inner // groups)
+    g = g * jax.lax.rsqrt(jnp.mean(jnp.square(g), -1, keepdims=True) + eps)
+    return g.reshape(b, s, inner).astype(z.dtype) * blk["sn"], s1
+
+
+def _ssm_mixer(blk, xx, state, layer, t, take, live, dims, eps, slots=None):
+    """The Mamba-2 sublayer (``nemotron_h``'s ``M`` layer) for the
+    decode loop: pre-RMSNorm, ``z, xBC, dt = split(u W_in)``, a
+    depthwise causal convolution with a bias and a SiLU over ``xBC``,
+    the state-space recurrence (``_ssm_core``), ``W_out``, residual. Its
+    state is a TREE, per SLOT and not through the block table, read and
+    written at ``layer``: ``conv`` ``[ssm layers, slots, K - 1, I + 2 G
+    N]``, a sequence's last ``K - 1`` rows of ``xBC`` in the model's
+    type, and ``ssm`` ``[ssm layers, slots, H, P, N]``, the recurrent
+    state, float32.
+
+    ``xx``, ``t``, ``take`` and ``live`` as ``_short_conv_mixer`` has
+    them, and the same three rules: a run that starts a sequence
+    (``t == 0``) starts from zeros whatever the slot held; the state it
+    leaves is that of its last REAL row; a slot that is not ``live``
+    keeps its state untouched."""
+    from ..ops.short_conv import short_conv
+    with jax.named_scope("ssm_scan"):
+        inner = dims[0] * dims[1]
+        k = blk["sw"].shape[0]
+        u = _rms(xx, blk["ln1"], eps)
+        z, xbc, dt = jnp.split(
+            _mm(u, blk["si"]), [inner, inner + blk["sw"].shape[1]], axis=-1)
+        if slots is None:
+            conv, ssm = state["conv"][layer], state["ssm"][layer]
+        else:
+            at = jnp.minimum(slots, state["ssm"].shape[1] - 1)
+            conv, ssm = state["conv"][layer, at], state["ssm"][layer, at]
+        fresh = t == 0
+        c, full = short_conv(xbc, blk["sw"], jnp.where(
+            fresh[:, None, None], jnp.zeros_like(conv), conv))
+        rows = take[:, None] + jnp.arange(k - 1, dtype=jnp.int32)[None]
+        new = jnp.take_along_axis(full, rows[:, :, None], axis=1)
+        y, s1 = _ssm_core(
+            blk, jax.nn.silu(c + blk["sb"]), z, dt, jnp.where(
+                fresh[:, None, None, None], jnp.zeros_like(ssm), ssm),
+            take, dims, eps)
+        if slots is None:
+            state = {
+                "conv": state["conv"].at[layer].set(
+                    jnp.where(live[:, None, None], new, conv)),
+                "ssm": state["ssm"].at[layer].set(
+                    jnp.where(live[:, None, None, None], s1, ssm))}
+        else:
+            # (a row that is not live names no slot: its write is dropped)
+            to = jnp.where(live, slots, state["ssm"].shape[1])
+            state = {
+                "conv": state["conv"].at[layer, to].set(new, mode="drop"),
+                "ssm": state["ssm"].at[layer, to].set(s1, mode="drop")}
+        return xx + _mm(y, blk["so"]), state
+
+
 def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
                    mesh=None, layer=None, qk_norm=False, indexer=None,
                    plan=None):
@@ -683,11 +786,11 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
     sin))``): learned key selection — indexer queries ``blk["iq"]``,
     one shared key ``blk["ik"]`` (cached beside k and v) and head
     weights ``blk["iw"]`` pick the ``topk`` keys attention runs over.
-    Returns (xx, lc, h2, kept) with h2 = the post-attention norm for the
-    FFN and kept [B, s] the keys the selection let each row attend (None
-    without an indexer)."""
+    ``tables`` None: no positional term (``nemotron_h``). Returns (xx,
+    lc, h2, kept) with h2 = the post-attention norm for the FFN (None
+    where the block has none) and kept [B, s] the keys the selection let
+    each row attend (None without an indexer)."""
     b, s, nh, kvh, hd, scale = dims
-    cos, sin = tables
     from ..ops.pallas import rope as rope_mod
     h = _rms(xx, blk["ln1"], eps)
     q = _mm(h, blk["wq"]).reshape(b, s, nh, hd)
@@ -696,8 +799,10 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
     if qk_norm:
         q = _rms(q, blk["qn"], eps)
         k = _rms(k, blk["kn"], eps)
-    q = rope_mod._apply_rotary_jnp(q, cos, sin, position_ids=pos)
-    k = rope_mod._apply_rotary_jnp(k, cos, sin, position_ids=pos)
+    if tables is not None:      # None: the model has no positional term
+        cos, sin = tables
+        q = rope_mod._apply_rotary_jnp(q, cos, sin, position_ids=pos)
+        k = rope_mod._apply_rotary_jnp(k, cos, sin, position_ids=pos)
     select = kept = None
     if indexer is not None:
         heads, dim, topk, (icos, isin) = indexer
@@ -728,7 +833,8 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
             vv = jnp.repeat(vc, rep, axis=2) if rep > 1 else vc
             att = _cached_attend(q, kk, vv, t, s, scale)
     xx = xx + _mm(att.reshape(b, s, nh * hd), blk["wo"])
-    h2 = _rms(xx, blk["ln2"], eps)
+    # (a layer that is this sublayer alone has no second norm)
+    h2 = _rms(xx, blk["ln2"], eps) if "ln2" in blk else None
     return xx, lc, h2, kept
 
 
@@ -758,7 +864,8 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
     ``t0`` and writes nothing. The step runs over the launch's VIEW of
     the per-slot leaves (the block table's rows and, where the model has
     it, the slot state, gathered at ``slots``) and the state is
-    scattered back. ``take`` [P]: the REAL rows of each chunk: the
+    scattered back; a state that is a TREE of leaves is viewed a layer
+    at a time by the mixers that own it. ``take`` [P]: the REAL rows of each chunk: the
     prefill kernel's grid has no step for a query tile past them, and a
     model with per-slot state must know where a chunk that ends
     mid-prompt really ends (the K/V of padding rows are hidden by
@@ -769,11 +876,15 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
         x = embed_tokens(tokens, t0)
         at = jnp.minimum(slots, caches["bt"].shape[0] - 1)
         view = dict(caches, bt=caches["bt"][at])
-        if "state" in caches:
+        # a state TREE is viewed by its mixers, a layer at a time
+        # (``_ssm_mixer``): the step is handed the whole of it and the
+        # rows' slots
+        own = isinstance(caches.get("state"), dict)
+        if "state" in caches and not own:
             view["state"] = caches["state"][:, at]
-        out, new = step_fn(x, view, t0, take)
+        out, new = step_fn(x, view, t0, take, *((slots,) if own else ()))
         new = dict(new, bt=caches["bt"])
-        if "state" in caches:
+        if "state" in caches and not own:
             new["state"] = caches["state"].at[:, slots].set(
                 new["state"], mode="drop")
         last = out[jnp.arange(P), out_idx][:, None]         # [P, 1, H]
@@ -838,12 +949,18 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
       convolution (``_short_conv_mixer``, whose state is per slot:
       ``caches["state"]``, a layer a conv layer), its FFN dense or
       routed; the weights are stacked a kind of sublayer, the loop runs
-      the spec, and the caches have a layer an ATTENTION layer.
+      the spec, and the caches have a layer an ATTENTION layer;
+    - ``sublayers`` (``nemotron_h``): a layer is ONE sublayer behind
+      one norm: a Mamba-2 mixer (``_ssm_mixer``; ``ssm_dims``; slot
+      state a tree of two leaves), attention (``rope_theta`` None: no
+      positional term) or a latent expert layer (``latent_moe`` below:
+      ``experts_held``, the share of the router's experts the stacks
+      hold; ungated ``relu2`` experts; ``router_eps``).
 
     ``cache_backend="paged"`` swaps the dense per-slot cache for a
     global page pool + per-slot block tables."""
     from ..ops.pallas import rope as rope_mod
-    from ..ops.routed_ffn import route_topk, routed_ffn
+    from ..ops.routed_ffn import held_tile, route_topk, routed_ffn
     cfg = model.cfg
     nh, kvh, hd = cfg.num_heads, cfg.num_kv_heads, cfg.head_dim
     eps = cfg.rms_eps
@@ -856,6 +973,8 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     if getattr(cfg, "router_score", "softmax") != "softmax":
         route_kw = dict(score=cfg.router_score, scale=float(
             getattr(cfg, "routed_scaling_factor", 1.0)))
+        if hasattr(cfg, "router_eps"):
+            route_kw["eps"] = float(cfg.router_eps)
     use_bias = bool(getattr(cfg, "use_expert_bias", True))
     qk_norm = bool(getattr(cfg, "qk_norm", False))
     indexer = getattr(cfg, "indexer", None)
@@ -868,6 +987,9 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     spec = _layer_spec(cfg)
     n_of = lambda kind: sum(kind in at for at in spec)
     conv_rows = int(getattr(cfg, "conv_L_cache", 1)) - 1
+    ssm_dims = getattr(cfg, "ssm_dims", None)
+    # the share of the router's experts the stacks hold: (first, count)
+    held = getattr(cfg, "experts_held", None)
 
     ffn_dims = ({"wg": 1, "wu": 1, "wd": 1} if moe     # expert-parallel
                 else {"wg": 2, "wu": 2, "wd": 1})
@@ -876,7 +998,8 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         lambda: _llama_family_weights(model, moe),
         dict({"wq": 2, "wk": 2, "wv": 2,               # column-parallel
               "wo": 1, "head": 1}, **ffn_dims))        # row-parallel
-    cos, sin = rope_mod.precompute_freqs(hd, max_cache_len, cfg.rope_theta)
+    tables = (None if cfg.rope_theta is None else
+              rope_mod.precompute_freqs(hd, max_cache_len, cfg.rope_theta))
     if indexer is not None:
         heads, dim, topk = indexer
         indexer = (int(heads), int(dim), int(topk),
@@ -886,8 +1009,17 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     # the caches have a layer an ATTENTION layer, the slot state a layer
     # a conv layer, the route read-back a layer an expert layer
     L = cfg.num_layers if spec is None else n_of("attn")
-    state = ((n_of("conv"), conv_rows, p["table"].shape[1])
-             if spec is not None and n_of("conv") else None)
+    state = None
+    if spec is not None and n_of("conv"):
+        state = jax.ShapeDtypeStruct(
+            (n_of("conv"), conv_rows, p["table"].shape[1]), dtype)
+    elif spec is not None and n_of("ssm"):
+        heads, width, _, n, _ = ssm_dims
+        state = {"conv": jax.ShapeDtypeStruct(
+                     (n_of("ssm"), p["sw"].shape[1] - 1, p["sw"].shape[2]),
+                     dtype),
+                 "ssm": jax.ShapeDtypeStruct(
+                     (n_of("ssm"), heads, width, n), jnp.float32)}
     scale = 1.0 / np.sqrt(hd)
     paged = cache_backend == "paged"
     if paged:
@@ -906,8 +1038,8 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         tree = _init_kv((L, batch, max_cache_len, kvh, hd), dtype,
                         cache_dtype,
                         index_dim=indexer[1] if indexer else None)
-        if state:
-            tree["state"] = jnp.zeros((state[0], batch) + state[1:], dtype)
+        if state is not None:
+            tree["state"] = _slot_state(state, batch)
         return tree
 
     if mesh is not None:
@@ -923,7 +1055,7 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     skip = ("table", "norm", "head") + (_EXPERT_LEAVES if moe else ())
     blk_tree = {k_: v_ for k_, v_ in p.items() if k_ not in skip}
 
-    def _forward(x, caches, t, bt, take=None):
+    def _forward(x, caches, t, bt, take=None, slots=None):
         x = unwrap(x)
         b, s = x.shape[0], x.shape[1]
         # an idle slot's offset is parked past the table: its rows are
@@ -941,7 +1073,7 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         if paged and indexer is None:
             plan = (_paged_decode_plan(bt, t, page_size) if s == 1
                     else _paged_prefill_plan(bt, t, take, s, page_size))
-        if state:
+        if state is not None:
             t_b = jnp.broadcast_to(t, (b,))
             rows_b = (jnp.full((b,), s, jnp.int32) if take is None
                       else take)
@@ -953,14 +1085,14 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             if paged:
                 xx, pool, h2, kept = _rope_gqa_attn(
                     blk, xx, lc["pool"], t, pos, (b, s, nh, kvh, hd, scale),
-                    (cos, sin), eps, bt=bt, mesh=mesh, layer=l,
+                    tables, eps, bt=bt, mesh=mesh, layer=l,
                     qk_norm=qk_norm, indexer=indexer, plan=plan)
                 return xx, dict(lc, pool=pool), h2, kept
             own = lc if spec is None else {n: lc[n][l] for n in lc
                                            if n != "state"}
             xx, own, h2, kept = _rope_gqa_attn(
                 blk, xx, own, t, pos, (b, s, nh, kvh, hd, scale),
-                (cos, sin), eps, qk_norm=qk_norm, indexer=indexer)
+                tables, eps, qk_norm=qk_norm, indexer=indexer)
             if spec is not None:
                 own = dict(lc, **{n: lc[n].at[l].set(own[n]) for n in own})
             return xx, own, h2, kept
@@ -968,33 +1100,63 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         def layer(xx, blk, lc, at):
             # ``at``: the scan's layer index or, under a layer spec,
             # this layer's index within each stack it reads
-            if "ci" in blk:
-                xx, held = _short_conv_mixer(
+            kept = None
+            if "si" in blk:
+                xx, held_state = _ssm_mixer(
+                    blk, xx, lc["state"], at["ssm"], t_b, rows_b, alive,
+                    ssm_dims, eps, slots)
+                lc, h2 = dict(lc, state=held_state), None
+            elif "ci" in blk:
+                xx, held_state = _short_conv_mixer(
                     blk, xx, lc["state"], at["conv"], t_b, rows_b, alive,
                     eps)
-                lc, kept = dict(lc, state=held), None
+                lc = dict(lc, state=held_state)
                 h2 = _rms(xx, blk["ln2"], eps)
-            else:
+            elif "wq" in blk:
                 xx, lc, h2, kept = attend(blk, xx, lc,
                                           at if spec is None else at["attn"])
+            else:       # a layer that is an FFN alone, behind its one norm
+                h2 = _rms(xx, blk["ln1"], eps)
             # what each slot's LAST row did, for the decode tick's
             # read-back: the keys it attended, the experts it chose
             aux = {} if kept is None else {"kept": kept[:, -1]}
+            if h2 is None:      # a layer that is a mixer alone
+                return xx, lc, aux
             if "dg" in blk or not moe:
                 wg, wu, wd = (blk[n] for n in (
                     ("dg", "du", "dd") if "dg" in blk
                     else ("wg", "wu", "wd")))
                 return xx + _mm(jax.nn.silu(_mm(h2, wg)) * _mm(h2, wu),
                                 wd), lc, aux
-            with jax.named_scope("moe_ffn"):
-                rows = h2.reshape(b * s, h2.shape[-1])
-                kw = (dict(route_kw, bias=blk["rbias"])
-                      if "rbias" in blk and use_bias else route_kw)
-                idx, gate = route_topk(rows, blk["router"], top_k,
-                                       normalize=norm_topk, **kw)
-                y = routed_ffn(rows, idx, gate, p["wg"], p["wu"], p["wd"],
-                               layer=at if spec is None else at["moe"],
-                               live=live)
+            kw = (dict(route_kw, bias=blk["rbias"])
+                  if "rbias" in blk and use_bias else route_kw)
+            if "ld" in blk:
+                # the latent expert layer: the router and the shared
+                # expert read the full width, the routed experts (two
+                # matrices each, not gated) a latent between two
+                # projections; the stacks hold ``held`` of the experts
+                # the router chose among
+                with jax.named_scope("latent_moe"):
+                    rows = h2.reshape(b * s, h2.shape[-1])
+                    idx, gate = route_topk(rows, blk["router"], top_k,
+                                           normalize=norm_topk, **kw)
+                    with jax.named_scope("moe_ffn"):
+                        y = routed_ffn(
+                            _mm(rows, blk["ld"]), idx, gate, None, p["wu"],
+                            p["wd"], layer=at["moe"], live=live, held=held,
+                            tile=held_tile(rows.shape[0] * top_k, held[1],
+                                           blk["router"].shape[-1]))
+                    y = _mm(y, blk["lu"]) + _mm(jnp.square(jax.nn.relu(
+                        _mm(rows, blk["s1"]))), blk["s2"])
+            else:
+                with jax.named_scope("moe_ffn"):
+                    rows = h2.reshape(b * s, h2.shape[-1])
+                    idx, gate = route_topk(rows, blk["router"], top_k,
+                                           normalize=norm_topk, **kw)
+                    y = routed_ffn(rows, idx, gate, p["wg"], p["wu"],
+                                   p["wd"],
+                                   layer=at if spec is None else at["moe"],
+                                   live=live)
             aux["route"] = idx.reshape(b, s, top_k)[:, -1]
             return xx + y.reshape(xx.shape), lc, aux
 
@@ -1004,9 +1166,9 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             caches = dict(caches, **aux)
         return x, caches
 
-    def step_fn(x, caches, t, take=None):
+    def step_fn(x, caches, t, take=None, slots=None):
         return _forward(x, caches, t, caches["bt"] if paged else None,
-                        take)
+                        take, slots)
 
     def head_fn(out):
         head = p["head"] if "head" in p else p["table"].T    # tied

@@ -18,7 +18,7 @@ __all__ = ["route_topk", "routed_ffn"]
 
 
 def route_topk(h, router_w, top_k, normalize=True, score="softmax",
-               bias=None, scale=1.0):
+               bias=None, scale=1.0, eps=1e-6):
     """``h`` [N, H] -> (experts [N, k] int32, gates [N, k] float32).
     Router logits accumulate in float32 and the score is float32; the
     top-k is EXACT (``jax.lax.top_k``: ties to the lower expert id).
@@ -30,7 +30,8 @@ def route_topk(h, router_w, top_k, normalize=True, score="softmax",
     its own; ``bias`` [E] (the load-balancing ``expert_bias``) is added
     to the scores that CHOOSE the k experts and to nothing else, so the
     gates are the chosen experts' unbiased scores, divided by ``sum +
-    1e-6`` under ``normalize``, then times ``scale``
+    eps`` under ``normalize`` (``lfm2_moe``'s 1e-6; the ``nemotron_h``
+    decoder's is 1e-20), then times ``scale``
     (``routed_scaling_factor``)."""
     logits = jnp.dot(h, router_w, preferred_element_type=jnp.float32)
     if score == "softmax":
@@ -46,7 +47,7 @@ def route_topk(h, router_w, top_k, normalize=True, score="softmax",
     idx = jax.lax.top_k(choose, top_k)[1]
     gate = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
-        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + 1e-6)
+        gate = gate / (jnp.sum(gate, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), gate * scale
 
 
@@ -104,12 +105,29 @@ def _layout(flat, experts, t, n_tiles):
     return order, dst, tile_expert, p_end[-1] // t
 
 
-def routed_ffn(h, idx, gate, wg, wu, wd, layer=None, tile=None, live=None):
+def held_tile(pairs, held, experts):
+    """Rows a tile for a launch of ``pairs`` (row, expert) choices over
+    ``experts`` of which this layer holds ``held``: the tile of the
+    pairs it can expect to fall on a held expert."""
+    return _tile_rows(pairs * held // experts, held)
+
+
+def routed_ffn(h, idx, gate, wg, wu, wd, layer=None, tile=None, live=None,
+               held=None):
     """``sum_j gate[n, j] * SwiGLU_{idx[n, j]}(h[n])`` for rows ``h``
     [N, H]. ``wg``/``wu`` are ``[L, E, H, F]`` and ``wd`` ``[L, E, F,
     H]`` indexed at ``layer`` (or ``[E, ...]`` with ``layer`` None);
     int8 pairs work too. Rows are independent: a NaN row stays in its
-    own output row.
+    own output row. ``wg`` None: the experts are NOT gated, ``W_d
+    relu(W_u x)^2`` (the ``nemotron_h`` decoder's ``relu2``), two
+    matrices an expert.
+
+    ``held`` (``(first, count)``): this layer holds a SHARE of the
+    experts the router chose among, ids ``[first, first + count)``, and
+    the stacks have ``count`` experts. A pair whose expert is not held
+    is a dead pair, by the mechanism a dead row has below: it joins no
+    group, reads no weight and adds zero, so the result is the held
+    experts' part of the sum and the shares of a layer add up to it.
 
     ``live`` ([N] bool, default every row): a dead row (an idle slot's
     garbage) joins no expert's group. Its pairs sort behind every live
@@ -120,14 +138,18 @@ def routed_ffn(h, idx, gate, wg, wu, wd, layer=None, tile=None, live=None):
     static: the tile and the layout's length follow ``N * k``."""
     n, hidden = h.shape
     k = idx.shape[1]
-    main = wg[0] if isinstance(wg, tuple) else wg
+    main = wu[0] if isinstance(wu, tuple) else wu
     experts = main.shape[-3]
     m = n * k
     t = int(tile or _tile_rows(m, experts))
     n_tiles = -(-m // t) + experts           # every group padded to t
     flat = idx.reshape(m)
+    # dead pairs: expert id ``experts``, which sorts last
+    if held is not None:
+        flat = flat - int(held[0])
+        flat = jnp.where((flat >= 0) & (flat < experts), flat, experts)
+        gate = jnp.where(flat.reshape(n, k) < experts, gate, 0.0)
     if live is not None:
-        # dead pairs: expert id ``experts``, which sorts last
         flat = jnp.where(jnp.repeat(live, k), flat, experts)
         gate = jnp.where(live[:, None], gate, 0.0)
     order, dst, tile_expert, used = _layout(flat, experts, t, n_tiles)
@@ -139,8 +161,12 @@ def routed_ffn(h, idx, gate, wg, wu, wd, layer=None, tile=None, live=None):
     def body(i, out):
         e = tile_expert[i]
         x = jax.lax.dynamic_slice(rows, (i * t, 0), (t, hidden))
-        y = _mm(jax.nn.silu(_mm(x, _expert(wg, layer, e)))
-                * _mm(x, _expert(wu, layer, e)), _expert(wd, layer, e))
+        if wg is None:
+            act = jnp.square(jax.nn.relu(_mm(x, _expert(wu, layer, e))))
+        else:
+            act = (jax.nn.silu(_mm(x, _expert(wg, layer, e)))
+                   * _mm(x, _expert(wu, layer, e)))
+        y = _mm(act, _expert(wd, layer, e))
         return jax.lax.dynamic_update_slice(out, y.astype(out.dtype),
                                             (i * t, 0))
 

@@ -374,6 +374,14 @@ class ServerTelemetry:
             "serving_moe_experts_touched_total",
             "Distinct experts chosen by the live rows of a decode "
             "tick, summed over layers and ticks")
+        pairs = r.counter(
+            "serving_moe_pairs_total",
+            "(row, expert) choices of a decode tick's live rows, and those "
+            "that fell on an expert this model holds (all of them unless "
+            "it holds a share of the router's experts)",
+            labelnames=("kind",))
+        self._c_moe_routed = pairs.labels(kind="routed")
+        self._c_moe_held = pairs.labels(kind="held")
         chunks = r.counter(
             "serving_prefill_chunks_total",
             "Slot-chunks the prefill launches ran (one a slot a launch), "
@@ -619,6 +627,12 @@ class ServerTelemetry:
         self._c_moe_live.inc(live)
         if touched:
             self._c_moe_touched.inc(touched)
+
+    def on_moe_pairs(self, routed, held):
+        """A decode tick's (row, expert) choices, and those held here."""
+        if self.enabled:
+            self._c_moe_routed.inc(routed)
+            self._c_moe_held.inc(held)
 
     def on_prefill_chunks(self, chunks, carried):
         """One prefill launch: the slot-chunks it ran, and those that

@@ -74,7 +74,8 @@ def test_chip_smoke_rehearsal_passes():
     lines = r.stdout.splitlines()
     assert lines and all(ln.startswith("REHEARSAL") for ln in lines)
     assert json.loads(lines[-1].split(" ", 1)[1])["ok"] is True
-    for phase in ("kernels", "serve-split", "hybrid-serve", "train",
+    for phase in ("kernels", "serve-split", "hybrid-serve", "ssm-serve",
+                  "train",
                   "mesh4-serve-split", "mesh4-train"):
         assert re.search(rf"phase {phase}: ok", r.stdout), phase
 
@@ -287,38 +288,54 @@ def _refuse_cross_datacenter():
     normalize_placement("cross-datacenter")
 
 
-def _slot_state_server(**kw):
+def _slot_state_server(family="lfm2", **kw):
     """A paged server over a model with per-slot recurrent state beside
-    the page pool (the ``lfm2`` family's short-convolution layers)."""
-    from paddle_tpu.models.lfm2 import Lfm2MoeForCausalLM, lfm2_tiny
-    global _LFM2
-    if _LFM2 is None:
-        _LFM2 = Lfm2MoeForCausalLM(lfm2_tiny(), seed=0)
-    return ContinuousBatchingServer(_LFM2, cache_backend="paged",
-                                    max_cache_len=64, page_size=8,
-                                    max_slots=2, **kw)
+    the page pool: the ``lfm2`` family's short-convolution layers (one
+    leaf), or the ``nemotron_h`` family's Mamba-2 layers (a tree of two
+    leaves of two dtypes)."""
+    if family not in _SLOT_STATE_MODELS:
+        if family == "lfm2":
+            from paddle_tpu.models.lfm2 import Lfm2MoeForCausalLM, lfm2_tiny
+            model = Lfm2MoeForCausalLM(lfm2_tiny(), seed=0)
+        else:
+            from paddle_tpu.models.nemotron_h import (NemotronHForCausalLM,
+                                                      nemotron_h_tiny)
+            model = NemotronHForCausalLM(nemotron_h_tiny(), seed=0)
+        _SLOT_STATE_MODELS[family] = model
+    return ContinuousBatchingServer(_SLOT_STATE_MODELS[family],
+                                    cache_backend="paged", max_cache_len=64,
+                                    page_size=8, max_slots=2, **kw)
 
 
-_LFM2 = None
+_SLOT_STATE_MODELS = {}
 
 
-def _refuse_prefix_hit_with_slot_state():
-    _slot_state_server(auto_prefix_cache=True)
+def _refuse_prefix_hit_with_slot_state(family="lfm2"):
+    _slot_state_server(family, auto_prefix_cache=True)
 
 
-def _refuse_preemption_replay_with_slot_state():
-    _slot_state_server(admission="optimistic")
+def _refuse_preemption_replay_with_slot_state(family="lfm2"):
+    _slot_state_server(family, admission="optimistic")
 
 
-def _refuse_host_tier_with_slot_state():
-    _slot_state_server(host_tier=True)
+def _refuse_host_tier_with_slot_state(family="lfm2"):
+    _slot_state_server(family, host_tier=True)
 
 
-def _refuse_migration_with_slot_state():
-    srv = _slot_state_server()
+def _refuse_dense_prefill_with_slot_state(family="lfm2"):
+    _slot_state_server(family, prefill_mode="dense")
+
+
+def _refuse_migration_with_slot_state(family="lfm2"):
+    srv = _slot_state_server(family)
     rid = srv.submit(np.arange(9, dtype=np.int32), max_new_tokens=4)
     srv.step()
     srv.migrate_out(rid)
+
+
+def _for_state_tree(refuse):
+    """The same refusal over the model whose slot state is a TREE."""
+    return lambda: refuse("nemotron_h")
 
 
 _SLOT_STATE = ("per-slot recurrent state", "ROADMAP B5")
@@ -334,9 +351,22 @@ _SLOT_STATE = ("per-slot recurrent state", "ROADMAP B5")
      _SLOT_STATE + ("admission='optimistic'",)),
     (_refuse_host_tier_with_slot_state, _SLOT_STATE + ("host_tier",)),
     (_refuse_migration_with_slot_state, _SLOT_STATE + ("migration",)),
+    (_for_state_tree(_refuse_prefix_hit_with_slot_state),
+     _SLOT_STATE + ("auto_prefix_cache=True",)),
+    (_for_state_tree(_refuse_preemption_replay_with_slot_state),
+     _SLOT_STATE + ("admission='optimistic'",)),
+    (_for_state_tree(_refuse_host_tier_with_slot_state),
+     _SLOT_STATE + ("host_tier",)),
+    (_for_state_tree(_refuse_migration_with_slot_state),
+     _SLOT_STATE + ("migration",)),
+    (_for_state_tree(_refuse_dense_prefill_with_slot_state),
+     _SLOT_STATE + ("prefill_mode='dense'",)),
 ], ids=["int8-paged-pool", "optimistic-on-dense", "cross-datacenter",
         "slot-state-prefix-hit", "slot-state-preemption-replay",
-        "slot-state-host-tier", "slot-state-migration"])
+        "slot-state-host-tier", "slot-state-migration",
+        "state-tree-prefix-hit", "state-tree-preemption-replay",
+        "state-tree-host-tier", "state-tree-migration",
+        "state-tree-dense-prefill"])
 def test_documented_refusals(refuse, names):
     """Each combination ROADMAP.md lists under "Refusals standing in the
     code" raises, and its message names the item that would lift it."""
