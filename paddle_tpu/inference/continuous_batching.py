@@ -36,17 +36,28 @@ from ..telemetry.serving import TickBoundary
 __all__ = ["ContinuousBatchingServer", "PreemptionPolicy", "PoolBalance"]
 
 
-# Rows (chunks x width) one prefill launch may compute. A launch is PACKED
-# (``_prefill_tick``): it has a row for the slots in its plan and not for
-# every slot, because its dense matmuls and its attention kernel's static
-# sweep run over all of its rows, whatever is live: 64 slots x 1,024 is
-# 65,536 rows, whose activations do not fit beside a 10 GB model, and at
-# 16,384 rows a launch of at most 1,024 prompt tokens held every decoding
-# slot for 310 ms (PERF.md section 6, PR 31). Four times the default
-# budget's worth of rows, so that a plan of a prompt's tail, a whole short
-# prompt and the next one's head still fits one launch at the widest
-# width. ROADMAP A2b-c ranks a lower limit in every cell.
-_LAUNCH_ROWS = 4096
+# The most rows (chunks x width) ANY prefill launch may compute. A launch is
+# PACKED (``_prefill_tick``): it has a row for the slots in its plan and not
+# for every slot, because its dense matmuls run over all of its rows,
+# whatever is live. A server's own limit is ``_launch_row_limit`` of its
+# per-tick prefill budget: a launch CARRIES at most the budget's tokens, so
+# the power of two over the budget is the smallest limit that never shortens
+# a take the budget allows, and every row beyond it is computed for nothing
+# (at four times the budget a launch of at most 1,024 tokens computed 4,096
+# rows in every serving cell: PERF.md section 6, PR 35).
+# 4,096 stays as the CEILING it was introduced as (PR 31): 64 slots x 1,024
+# is 65,536 rows, whose activations do not fit beside a 10 GB model, at
+# 16,384 rows a launch held every decoding slot for 310 ms, and a server
+# left at the default budget with ``max_cache_len`` 16,384 must not pack
+# 16,384 rows of short chunks (a take wider than the ceiling still launches
+# whole, in one row, as it always did).
+_LAUNCH_ROWS_MAX = 4096
+
+
+def _launch_row_limit(budget):
+    """Rows a prefill launch may compute under a per-tick token budget:
+    the power of two over the budget, at most ``_LAUNCH_ROWS_MAX``."""
+    return min(1 << (int(budget) - 1).bit_length(), _LAUNCH_ROWS_MAX)
 
 
 class _Pending:
@@ -571,6 +582,8 @@ class ContinuousBatchingServer:
         self._prefill_budget = int(prefill_tokens_per_tick)
         if self._prefill_budget < 1:
             raise ValueError("prefill_tokens_per_tick must be >= 1")
+        # rows a prefill launch may compute: what its budget can fill
+        self._launch_rows = _launch_row_limit(self._prefill_budget)
         self._admit_cap = None if max_admissions_per_tick is None \
             else int(max_admissions_per_tick)
         if self._admit_cap is not None and self._admit_cap < 1:
@@ -684,8 +697,12 @@ class ContinuousBatchingServer:
                       # slot-chunks the prefill launches ran (one a
                       # slot a launch) and those that began past a
                       # prompt's start: a model with slot state reads
-                      # the state its last chunk left there
+                      # the state its last chunk left there; the dense
+                      # rows the launches computed (rows x width a
+                      # launch, live or not: prefill_tokens over it is
+                      # how full the launches ran)
                       "prefill_chunks": 0, "prefill_chunks_carried": 0,
+                      "prefill_rows": 0,
                       "decode_ticks": 0, "decode_rows": 0,
                       "decode_live_rows": 0, "decode_grid_steps": 0,
                       "decode_live_pages": 0, "prefill_grid_steps": 0,
@@ -2030,8 +2047,14 @@ class ContinuousBatchingServer:
         single-row matmuls take XLA's fused-reduce path and break
         bit-parity with the dense prefill) so compiles stay
         O(log max_cache_len). The launch is PACKED: row j is the
-        plan's j-th slot, and width C has ``_LAUNCH_ROWS // C`` rows
-        (at most a row a slot), so one program a width."""
+        plan's j-th slot, and width C has ``R // C`` rows (at most a
+        row a slot, at least one), so one program a width. ``R`` is
+        ``_launch_row_limit`` of the per-tick budget: a launch carries
+        at most the budget's tokens, so the power of two over it is
+        the smallest limit that never shortens a take (``C <= R``, the
+        head of the FIFO always fits), and its rows are what the
+        budget can fill, not a constant (4,096, which stays as the
+        ceiling of ``_launch_row_limit``) four times that."""
         budget = self._prefill_budget - self._prefill_used
         if not self._prefill_fifo or budget <= 0:
             return
@@ -2040,7 +2063,7 @@ class ContinuousBatchingServer:
             b.mark("prefill_pack")
         plan = []                        # (slot, start, take)
         used = widest = 0
-        S = self.max_slots
+        S, R = self.max_slots, self._launch_rows
         width = lambda take: max(2, 1 << (take - 1).bit_length())
         for slot in self._prefill_fifo:
             if used >= budget:
@@ -2048,10 +2071,10 @@ class ContinuousBatchingServer:
             st = self._slots[slot]
             take = min(st.prompt_len - st.fill_pos, budget - used)
             # a launch computes every one of its rows, so it has
-            # _LAUNCH_ROWS // C of them at width C, and a slot that
-            # would not fit waits for the next launch
+            # R // C of them at width C, and a slot that would not
+            # fit waits for the next launch
             C = width(max(widest, take))
-            if plan and (len(plan) + 1) * C > _LAUNCH_ROWS:
+            if plan and (len(plan) + 1) * C > R:
                 break
             plan.append((slot, st.fill_pos, take))
             used, widest = used + take, max(widest, take)
@@ -2061,7 +2084,7 @@ class ContinuousBatchingServer:
         C = width(widest)
         # row j is the plan's j-th slot; the rest are padding rows: no
         # slot's (slot S), parked on the idle sentinel
-        P = min(S, max(1, _LAUNCH_ROWS // C))
+        P = min(S, max(1, R // C))
         toks = np.zeros((P, C), np.int32)
         t0 = np.full((P,), self.max_cache_len, np.int32)
         out_idx = np.zeros((P,), np.int32)
@@ -2090,15 +2113,16 @@ class ContinuousBatchingServer:
             # the chip's from here to the first value read back (in
             # _activate, which marks "activate")
             t_launch = b.mark(
-                "prefill_wait", width=C, rows=len(plan),
+                "prefill_wait", width=C, rows=len(plan), launch_rows=P * C,
                 rids=[self._slots[slot].rid for slot, _, _ in plan])
         logits, self._caches = prefill_fn(*args)
         self._count_dispatches(1, op="prefill")
         carried = sum(1 for _, start, _ in plan if start > 0)
         self.stats["prefill_chunks"] += len(plan)
         self.stats["prefill_chunks_carried"] += carried
+        self.stats["prefill_rows"] += P * C
         if self._tele is not None:
-            self._tele.on_prefill_chunks(len(plan), carried)
+            self._tele.on_prefill_chunks(len(plan), carried, P * C)
         if not self._select_k:
             self._count_prefill_grid(t0, takes, C)
         if self._moe_k:

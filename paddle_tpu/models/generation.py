@@ -859,7 +859,11 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
 
     A launch is PACKED: it has a row for P slots and not for all of
     them, row j being slot ``slots[j]`` (the server puts its plan's j-th
-    slot there and gives the launch the rows its row limit allows); a
+    slot there and gives the launch ``min(slots, R // C)`` rows). ``R``
+    is ``continuous_batching._launch_row_limit``: the power of two over
+    the server's per-tick token budget, so a launch's dense matmuls are
+    sized by the tokens it may carry; 4,096 survives as the ceiling on
+    ``R``, for a server whose budget is as long as a 16k cache. A
     padding row names a slot past the last, carries the idle sentinel in
     ``t0`` and writes nothing. The step runs over the launch's VIEW of
     the per-slot leaves (the block table's rows and, where the model has

@@ -32,6 +32,8 @@ Per-tick engine signals. Every time below comes from the reads of
                                   dense admission)
 - ``serving_prefill_launches_total{width}``  ragged launches by chunk
                                   width
+- ``serving_prefill_rows_total``  dense rows those launches computed
+                                  (rows x width a launch, live or not)
 - ``server_prefill_dispatches_total``  host dispatches on the
   admission/prefill path — the ragged prefill path's counter-asserted
   win is this dropping per admission vs the dense baseline
@@ -390,6 +392,11 @@ class ServerTelemetry:
             labelnames=("kind",))
         self._c_chunks = chunks.labels(kind="launched")
         self._c_chunks_carried = chunks.labels(kind="carried")
+        self._c_prefill_rows = r.counter(
+            "serving_prefill_rows_total",
+            "Dense rows the prefill launches computed (rows x width a "
+            "launch, live or not); serving_tokens_total{kind=prefill} "
+            "over it is how full the launches ran")
         keys = r.counter(
             "serving_attn_keys_total",
             "Keys of live decode rows: in their context, and kept by "
@@ -634,13 +641,15 @@ class ServerTelemetry:
             self._c_moe_routed.inc(routed)
             self._c_moe_held.inc(held)
 
-    def on_prefill_chunks(self, chunks, carried):
-        """One prefill launch: the slot-chunks it ran, and those that
-        continued a prompt an earlier launch began."""
+    def on_prefill_chunks(self, chunks, carried, rows):
+        """One prefill launch: the slot-chunks it ran, those that
+        continued a prompt an earlier launch began, and the dense rows
+        (rows x width) it computed."""
         if self.enabled:
             self._c_chunks.inc(chunks)
             if carried:
                 self._c_chunks_carried.inc(carried)
+            self._c_prefill_rows.inc(rows)
 
     def on_selected_keys(self, context, selected):
         """A decode tick's live rows: keys in context, keys kept."""

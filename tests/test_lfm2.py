@@ -231,17 +231,17 @@ def _server(model, **kw):
 
 @pytest.mark.parametrize("row_limit", [4096, 8], ids=["a-row-a-slot",
                                                       "one-row"])
-def test_server_prefill_and_decode_equal_reference(model, sizes, monkeypatch,
-                                                   row_limit):
+def test_server_prefill_and_decode_equal_reference(model, sizes, row_limit):
     """Four prompts through two slots, 8 prefill tokens a tick: prompts of
     20 and 13 span three and two launches with the other slot decoding
     between them, and the third and fourth request land in slots the first
     two left. Every launch's logits are held to the reference and every
     emitted token is its argmax, with rows for both slots and, at a row
     limit of 8, with one row a launch of width 8."""
-    from paddle_tpu.inference import continuous_batching as cb
-    monkeypatch.setattr(cb, "_LAUNCH_ROWS", row_limit)
     srv = _server(model, telemetry=True)
+    # the server's own limit is the power of two over its budget (8): hold
+    # a row a slot too
+    srv._launch_rows = row_limit
     seen = []
     launch = srv._ragged_fn
 

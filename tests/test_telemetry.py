@@ -360,7 +360,8 @@ class TestTickBoundary:
         # the launch's span says what it served, and the request's own
         # prefill span carries the same tick
         launch = spans[names.index("prefill_wait")]["args"]
-        assert launch == {"tick": 1, "width": 4, "rows": 1, "rids": [0]}
+        assert launch == {"tick": 1, "width": 4, "rows": 1,
+                          "launch_rows": 2 * 4, "rids": [0]}
         (prefill,) = [e for e in tele.tracer.events()
                       if e["name"] == "request.prefill"]
         assert prefill["args"]["tick"] == 1 and prefill["args"]["rid"] == 0

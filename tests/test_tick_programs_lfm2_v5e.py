@@ -8,7 +8,7 @@ positions, 16,385 pages. The conv layers are XLA compositions and the
 two attention layers go through the ONE paged decode kernel and the ONE
 ragged-prefill kernel, each handed a POOL index. What is held: the chip's
 compiler takes the decode tick and the widest and a narrow prefill launch
-(4,096 rows each) at the real size; they fit beside the 10.53 GB of
+(1,024 rows each) at the real size; they fit beside the 10.53 GB of
 weights; the pool has 2 layers and stays where it is; the slot state is aliased
 (donated and carried like the pool)."""
 import types
@@ -120,8 +120,9 @@ def test_lfm2_decode_tick_compiles_and_fits(one_chip, as_on_chip):
 
 
 def test_lfm2_prefill_launches_compile_and_fit(one_chip, as_on_chip):
-    """The widest launch (4 chunks x 1,024 rows, each row's slot and real
-    row count given), and a narrow one with a row a slot."""
+    """The widest launch (1 chunk x 1,024 rows, its slot and real row
+    count given: the rows a budget of 1,024 tokens can fill), and the
+    narrow one with a row a slot (64 x 16)."""
     cfg = _cfg()
     shapes = _weight_shapes(cfg)
     caches = _caches(cfg, shapes)
@@ -131,14 +132,14 @@ def test_lfm2_prefill_launches_compile_and_fit(one_chip, as_on_chip):
         return _bundle(cfg, weights)[4](tokens, t0, caches, out_idx, take,
                                         slots)
 
-    exe = _compile(launch, (3,), one_chip, shapes, i32(4, 1024), i32(4),
-                   caches, i32(4), i32(4), i32(4))
+    exe = _compile(launch, (3,), one_chip, shapes, i32(1, 1024), i32(1),
+                   caches, i32(1), i32(1), i32(1))
     assert "ragged_prefill_attention" in exe.as_text()
     # one call an ATTENTION layer of the unrolled spec, each over a
-    # dynamic grid (131,072 steps a layer as a static sweep)
+    # dynamic grid
     assert len(_prefill_kernels(exe)) == 2
     _assert_fits(exe, caches)
-    exe = _compile(launch, (3,), one_chip, shapes, i32(64, 64), i32(64),
+    exe = _compile(launch, (3,), one_chip, shapes, i32(64, 16), i32(64),
                    caches, i32(64), i32(64), i32(64))
     assert len(_prefill_kernels(exe)) == 2
     _assert_fits(exe, caches)

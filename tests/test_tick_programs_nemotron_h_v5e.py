@@ -9,7 +9,7 @@ layer of 2 K/V heads of 128), a quarter of the vocabulary, 64 slots, page
 launch, one recurrence step in a decode tick) and the expert layers are XLA
 compositions; the one attention layer goes through the ONE paged decode
 kernel and the ONE ragged-prefill kernel. What is held: the chip's compiler
-takes the decode tick and the widest and a narrow prefill launch (4,096
+takes the decode tick and the widest and a narrow prefill launch (1,024
 rows each) at the real size; they fit beside the 9.30 GB of weights; the
 pool has 1 layer and stays where it is; both leaves of the slot state
 (1.36 GB of float32 recurrent state, the convolution windows) are aliased:
@@ -134,9 +134,10 @@ def test_nemotron_h_decode_tick_compiles_and_fits(one_chip, as_on_chip):
 
 
 def test_nemotron_h_prefill_launches_compile_and_fit(one_chip, as_on_chip):
-    """The widest launch (4 chunks x 1,024 rows: 8 chunks of the scan a
-    row, the state passed between them), and a narrow one with a row a
-    slot (64 x 64: one chunk of 64 rows)."""
+    """The widest launch (1 chunk x 1,024 rows, what a budget of 1,024
+    tokens can fill: 8 chunks of the scan, the state passed between
+    them), and the narrow one with a row a slot (64 x 16: one chunk of 16
+    rows, the most state a launch views)."""
     cfg = _cfg()
     shapes = _weight_shapes(cfg)
     caches = _caches(cfg, shapes)
@@ -146,11 +147,11 @@ def test_nemotron_h_prefill_launches_compile_and_fit(one_chip, as_on_chip):
         return _bundle(cfg, weights)[4](tokens, t0, caches, out_idx, take,
                                         slots)
 
-    exe = _compile(launch, (3,), one_chip, shapes, i32(4, 1024), i32(4),
-                   caches, i32(4), i32(4), i32(4))
+    exe = _compile(launch, (3,), one_chip, shapes, i32(1, 1024), i32(1),
+                   caches, i32(1), i32(1), i32(1))
     assert len(_prefill_kernels(exe)) == 1
     _assert_fits(exe, caches, temp=3.0e9)
-    exe = _compile(launch, (3,), one_chip, shapes, i32(64, 64), i32(64),
+    exe = _compile(launch, (3,), one_chip, shapes, i32(64, 16), i32(64),
                    caches, i32(64), i32(64), i32(64))
     assert len(_prefill_kernels(exe)) == 1
     _assert_fits(exe, caches, temp=3.0e9)

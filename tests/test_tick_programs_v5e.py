@@ -289,7 +289,7 @@ def test_decode_tick_compiles_for_the_four_chip_mesh(topo, as_on_chip):
 
 
 def test_prefill_tick_compiles_for_the_four_chip_mesh(topo, as_on_chip):
-    """The same for a packed launch (32 rows x 128): one prefill call a
+    """The same for a packed launch (8 rows x 128): one prefill call a
     kv-head shard under ``shard_map``, its two-level schedule and step
     count replicated, the pool's quarters left in place."""
     cfg, slots, num_pages = (MEDIUM[k] for k in
@@ -297,9 +297,10 @@ def test_prefill_tick_compiles_for_the_four_chip_mesh(topo, as_on_chip):
     mesh, on, weights, cache_specs, shard = _on_mesh(topo, cfg, slots,
                                                      num_pages)
     i32 = lambda *s: on(jax.ShapeDtypeStruct(s, jnp.int32))
+    rows = _prefill_specs(cfg, slots, num_pages, 128)[1][1].shape[0]
     exe = (jax.jit(_prefill_tick(cfg, num_pages, mesh), donate_argnums=(3,))
-           .trace(weights, i32(slots, 128), i32(slots), cache_specs,
-                  i32(slots), i32(slots), i32(slots))
+           .trace(weights, i32(rows, 128), i32(rows), cache_specs,
+                  i32(rows), i32(rows), i32(rows))
            .lower(lowering_platforms=("tpu",)).compile())
     assert len(_prefill_kernels(exe)) == 1
     _assert_pool_stays(exe, {"pool": shard})
@@ -327,10 +328,12 @@ def test_grid_schedule_is_made_outside_the_layer_loop(as_on_chip):
 
 
 def _prefill_specs(cfg, slots, num_pages, width):
-    """A packed launch of the server's: ``4,096 // width`` rows, at
-    most one a slot."""
+    """A packed launch of the server's: the rows its budget (the cell's
+    default, ``max_cache_len``) can fill at this width, at most one a
+    slot."""
+    from paddle_tpu.inference.continuous_batching import _launch_row_limit
     caches = _cache_shapes(cfg, slots, num_pages)
-    rows = min(slots, 4096 // width)
+    rows = min(slots, _launch_row_limit(CACHE_LEN) // width)
     i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)
     return caches, (_weight_shapes(cfg), i32(rows, width), i32(rows), caches,
                     i32(rows), i32(rows), i32(rows))
@@ -356,7 +359,7 @@ def test_prefill_kernel_is_one_call_over_a_dynamic_grid(geometry, width,
     """ONE prefill-attention call a program (the layer loop's), whatever
     the launch's width — its query tiles are grid steps, not a loop of
     launches — with the step count a runtime scalar: the cell's widest
-    launch (8 rows x 512) and the 1,600-lane width."""
+    launch (2 rows x 512) and the 1,600-lane width."""
     cfg, slots, num_pages = (geometry[k] for k in
                              ("cfg", "slots", "num_pages"))
     _, specs = _prefill_specs(cfg, slots, num_pages, width)
