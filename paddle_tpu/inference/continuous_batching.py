@@ -13,7 +13,9 @@ Host/device split: the device does batched prefill + batched decode
 steps; the host only assigns slots, harvests finished rows, and swaps
 new prompts in — O(requests), not O(tokens), host work.
 """
+import collections
 import contextlib
+import logging
 import threading
 
 import numpy as np
@@ -31,9 +33,11 @@ from ..reliability import (CallbackError, CircuitOpenError, DEAD,
                            RequestCancelled, ServeSupervisor, ServerClosed,
                            faults)
 from ..telemetry.clock import MonotonicClock
-from ..telemetry.serving import TickBoundary
+from ..telemetry.serving import HostEventLog, SLOW_PHASE_S, TickBoundary
 
 __all__ = ["ContinuousBatchingServer", "PreemptionPolicy", "PoolBalance"]
+
+_log = logging.getLogger(__name__)
 
 
 # The most rows (chunks x width) ANY prefill launch may compute. A launch is
@@ -658,6 +662,11 @@ class ContinuousBatchingServer:
                       "prefix_auto_hits": 0, "prefix_auto_hit_tokens": 0,
                       "admissions": 0, "prefill_dispatches": 0,
                       "prefill_wall_s": 0.0, "tick_dispatches": 0,
+                      # tick phases of telemetry.serving.SLOW_PHASE_S or
+                      # longer, and their seconds (flat, so a window's
+                      # difference can be taken); srv.slow_phases has a
+                      # record of each
+                      "slow_phases": 0, "slow_phase_s": 0.0,
                       # admission="optimistic" accounting
                       "preemptions": 0, "preempt_resumed": 0,
                       "grow_pages": 0, "headroom_pages": 0,
@@ -789,6 +798,25 @@ class ContinuousBatchingServer:
         # telemetry and costs are both off
         self._boundary = None
         self._tick_seq = 0          # ticks opened, the spans' tick=<n>
+        # a phase that stalls names itself (_slow_phase, the boundary's
+        # sink): the newest 32 records, and what the host did meanwhile
+        # that no phase names (compiles, cache loads, collector pauses;
+        # telemetry.serving.HostEventLog, on the boundary's clock).
+        # With telemetry and costs both off there is no boundary, no
+        # log, no listener and never a record
+        self.slow_phases = collections.deque(maxlen=32)
+        self._host_events = None
+        if self._tele is not None or self._costs is not None:
+            self._host_events = HostEventLog(
+                self._tele.clock if self._tele is not None
+                else self._costs.clock)
+        self._launch_shapes = set()     # (width, rows) launched so far
+        self._fresh_launch = False      # the last launch's was new
+        # launches enqueued since the host last read a value back: what
+        # the next wait may have to sit through (a long prompt's chunks
+        # launch one after another with nothing read back between them
+        # while no slot decodes, and the last one waits for them all)
+        self._unawaited = 0
         self._decode_prog = None    # priced decode program (static sig)
         self._kv_row_nbytes = None  # lazy: bytes per K+V token row
         # journey recorder for STANDALONE servers (closes the PR-9
@@ -2110,12 +2138,22 @@ class ContinuousBatchingServer:
             prefill_fn = self._cost_program(
                 self._cost_op("prefill"), self._ragged_fn, args)
         if b is not None:
-            # the chip's from here to the first value read back (in
-            # _activate, which marks "activate")
-            t_launch = b.mark(
-                "prefill_wait", width=C, rows=len(plan), launch_rows=P * C,
+            # the host's until the call returns (building and enqueueing
+            # the launch), then a wait for the first value read back (in
+            # _activate, which marks "activate"). A launch that
+            # completes no prompt reads nothing back: blocked=0, its
+            # device time is waited for in a later phase
+            launch = dict(
+                width=C, rows=len(plan), launch_rows=P * C,
                 rids=[self._slots[slot].rid for slot, _, _ in plan])
+            self._fresh_launch = (C, P) not in self._launch_shapes
+            if self._fresh_launch:
+                self._launch_shapes.add((C, P))
+            t_launch = b.mark("prefill_dispatch", **launch)
         logits, self._caches = prefill_fn(*args)
+        self._unawaited += 1
+        if b is not None:
+            b.mark("prefill_wait", blocked=int(bool(done)), **launch)
         self._count_dispatches(1, op="prefill")
         carried = sum(1 for _, start, _ in plan if start > 0)
         self.stats["prefill_chunks"] += len(plan)
@@ -2157,8 +2195,13 @@ class ContinuousBatchingServer:
         for slot, row in done:
             self._activate(slot, logits[row:row + 1])
         if b is not None:
+            # only a launch the host waited for has a wall: dispatch to
+            # its last activation
             wall = b.mark("admit") - t_launch
-            self.stats["prefill_wall_s"] += wall
+            if done:
+                self.stats["prefill_wall_s"] += wall
+            else:
+                wall = None
             if self._tele is not None:
                 self._tele.on_prefill_batch(wall, width=C)
 
@@ -2185,6 +2228,7 @@ class ContinuousBatchingServer:
             # the launch's first value is back on the host: from here
             # the chip is idle (later draws are tiny programs)
             b.mark("activate")
+        self._unawaited = 0
         self._pending_key[slot] = key
         self._pending_tok[slot] = first
         self._pending_t[slot] = st.prompt_len
@@ -2198,7 +2242,8 @@ class ContinuousBatchingServer:
         self.stats["admissions"] += 1
         if self._tele is not None:
             self._tele.on_first_token(st.rid, st.prompt_len - st.n_pre,
-                                      st.n_pre)
+                                      st.n_pre,
+                                      streams=st.on_token is not None)
 
     def _flush_slot_state(self):
         """Push pending per-slot decode state (first token, write
@@ -2244,6 +2289,56 @@ class ContinuousBatchingServer:
         (the decode program itself, block-table syncs) in this tick's
         per-op profile only."""
         self._tick_disp[op] = self._tick_disp.get(op, 0) + n
+
+    def _slow_phase(self, phase, seconds, start, tick, args):
+        """The boundary's sink for a phase of ``SLOW_PHASE_S`` or longer
+        (``TickBoundary._close``), whichever consumers are on: two flat
+        stats, ``serving_slow_phases_total{phase}``, a record in
+        ``slow_phases`` and in the flight recorder, and one WARNING.
+        The record: ``phase``, ``seconds``, ``tick``, ``start`` (the
+        clock's read that opened it), ``args`` (the span's: ``width``,
+        ``rows``, ``launch_rows``, ``rids`` for a launch), ``live`` and
+        ``queued`` requests, ``first_use`` for a program's phases (this
+        server had not launched that width and rows, or decoded,
+        before) and ``host_events``: the (name, seconds) of every
+        compile step, cache load and collector pause that ended inside
+        it (``HostEventLog``). A ``*_wait`` sits through every launch
+        enqueued since the host last read a value back (a long prompt's
+        chunks while nothing decodes: 14 launches of 54 ms before one
+        read-back in the long-context cell), so its limit is
+        ``SLOW_PHASE_S`` a launch awaited, and its record says how many
+        (``launches_awaited``). Warm-up's compiles are slow phases by
+        design: the warning waits until the catalog's compile watch is
+        ``warmed`` (a server without a catalog warns at once)."""
+        waits = phase in ("prefill_wait", "decode_wait")
+        if waits and seconds < SLOW_PHASE_S * self._unawaited:
+            return
+        self.stats["slow_phases"] += 1
+        self.stats["slow_phase_s"] += seconds
+        rec = {"phase": phase, "seconds": seconds, "tick": tick,
+               "start": start,
+               "args": {k: v for k, v in args.items() if k != "tick"},
+               "live": int(self._active.sum()),
+               "queued": len(self._queue),
+               "host_events": self._host_events.ended_in(
+                   start, start + seconds)}
+        if waits:
+            rec["launches_awaited"] = self._unawaited
+        if phase in ("prefill_dispatch", "prefill_wait"):
+            rec["first_use"] = self._fresh_launch
+        elif phase in ("decode_dispatch", "decode_wait"):
+            rec["first_use"] = self.stats["decode_ticks"] == 0
+        self.slow_phases.append(rec)
+        if self._tele is not None:
+            self._tele.on_slow_phase(phase)
+        if self._rec is not None:
+            self._rec.record("slow_phase", **rec)
+        if self._costs is None or self._costs.warmed:
+            _log.warning(
+                "slow phase: tick %s %s %.2f s (live %d, queued %d)%s",
+                tick, phase, seconds, rec["live"], rec["queued"],
+                "".join(f"; {name} {s:.2f} s"
+                        for name, s in rec["host_events"]))
 
     def _cost_op(self, name):
         """Cost-catalog op name for a serving program: suffixed with
@@ -2492,7 +2587,8 @@ class ContinuousBatchingServer:
             self.stats["prefill_wall_s"] += wall
         if tele is not None:
             tele.on_prefill_batch(wall)
-            tele.on_first_token(rid, T - n_pre, n_pre)
+            tele.on_first_token(rid, T - n_pre, n_pre,
+                                streams=on_token is not None)
 
     # ------------------------------------- optimistic growth / preemption
     def _grow_locked(self):
@@ -2695,6 +2791,10 @@ class ContinuousBatchingServer:
         exactly the offending requests."""
         cbs, self._deferred_cbs = self._deferred_cbs, []
         b, self._boundary = self._boundary, None
+        # streaming requests whose first token is drawn and not yet
+        # handed over (telemetry's request.deliver spans); None or empty
+        # but for the turn after an activation
+        owed = None if self._tele is None else self._tele.undelivered
         errors = []
         for cb, rid, toks in cbs:
             try:
@@ -2703,6 +2803,8 @@ class ContinuousBatchingServer:
                 cb(rid, toks)
             except Exception as e:
                 errors.append((rid, e))
+            if owed and rid in owed:
+                self._tele.on_first_delivery(rid)
         if b is not None:
             # the tick's last phase, "callbacks" (opened by
             # _step_locked), ends here, OUTSIDE the lock and after the
@@ -2725,7 +2827,8 @@ class ContinuousBatchingServer:
         if ct is not None or self._tele is not None:
             self._tick_seq += 1
             b = self._boundary = TickBoundary(
-                ct, self._tele, "expire", tick=self._tick_seq)
+                ct, self._tele, "expire", tick=self._tick_seq,
+                slow=self._slow_phase)
         try:
             n = self._step_inner()
         except BaseException:
@@ -2776,7 +2879,8 @@ class ContinuousBatchingServer:
         if b is not None:
             b.mark("admit")
         # a prefill launch marks itself out from inside (prefill_pack,
-        # prefill_wait, activate) and comes back in "admit"
+        # prefill_dispatch, prefill_wait, activate) and comes back in
+        # "admit"
         self._admit()
         if not self._active.any():
             if self._tele is not None:     # keep the gauge live when a
@@ -2844,18 +2948,23 @@ class ContinuousBatchingServer:
                     (self._tok, self._caches, self._t, self._keys))
             decode_fn = self._decode_prog
         if b is not None:
-            # the chip's: dispatch to the tokens back on the host
-            t_launch = b.mark("decode_wait")
+            # the host's until the call returns (enqueueing the tick),
+            # then a wait for the tokens back on the host; the tick's
+            # wall is both
+            t_launch = b.mark("decode_dispatch")
         (self._tok, self._caches, self._t, self._keys,
          toks) = decode_fn(self._tok, self._caches, self._t,
                            self._keys)
         self._tick_dispatch("decode")
+        if b is not None:
+            b.mark("decode_wait")
         toks = np.asarray(toks)                    # [slots, tick_block]
         aux, toks = toks[:, self.tick_block:], toks[:, :self.tick_block]
         route, kept = (aux[:, :-1], aux[:, -1]) if self._select_k \
             else (aux, None)
         if b is not None:
             wall = b.mark("emit") - t_launch
+        self._unawaited = 0
         # rows of slots holding no decoding request still ride the
         # program, parked on the idle sentinel (_park_slot): the paged
         # backend sends their writes to the null page, the dense one
@@ -3208,6 +3317,8 @@ class ContinuousBatchingServer:
         sections = {
             "health": self._health.state,
             "stats": dict(self.stats),
+            # the phases that stalled, with what the host did meanwhile
+            "slow_phases": list(self.slow_phases),
             "queue": [item.rid for item in self._queue],
             "slots": [{"slot": s, "rid": st.rid, "phase": st.phase,
                        "emitted": len(st.emitted),

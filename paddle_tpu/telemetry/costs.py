@@ -93,13 +93,18 @@ COMPILE_BUCKETS = (0.005, 0.01, 0.025, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5,
 # a phase of a tick runs from tens of microseconds to a prefill launch
 PHASE_BUCKETS = (0.0001, 0.00025, 0.0005, 0.001, 0.0025, 0.005, 0.01,
                  0.025, 0.05, 0.1, 0.25, 0.5, 1.0)
-# The tick's phases separate the host from the chip: the chip works
-# through prefill_wait and decode_wait (dispatch to the value read
-# back) and is idle through the rest, but for the three tiny programs
-# of state_push. The dense admission path marks prefill_launch.
-TICK_PHASES = ("expire", "admit", "prefill_pack", "prefill_wait",
-               "activate", "grow", "state_push", "decode_wait", "emit",
-               "harvest", "callbacks", "prefill_launch")
+# The tick's phases separate the host from the chip. A program's time
+# is two phases: *_dispatch, the host building and enqueueing the
+# launch (to the return of the call), and *_wait, from there to its
+# value read back on the host (a launch that completes no prompt reads
+# nothing back: its prefill_wait is bookkeeping, blocked=0 on the
+# span). The chip is idle through the rest, but for the three tiny
+# programs of state_push. The dense admission path marks
+# prefill_launch.
+TICK_PHASES = ("expire", "admit", "prefill_pack", "prefill_dispatch",
+               "prefill_wait", "activate", "grow", "state_push",
+               "decode_dispatch", "decode_wait", "emit", "harvest",
+               "callbacks", "prefill_launch")
 
 # The one peaks table: ``device_kind`` (as ``jax.devices()[0]`` reports
 # it) -> (bf16 FLOP/s, HBM bytes/s) of one chip. Every utilisation in

@@ -4,7 +4,9 @@ One ``ServerTelemetry`` object owns every signal an SLO-aware scheduler
 (or an operator's dashboard) needs from ``ContinuousBatchingServer``:
 
 Request lifecycle (spans ``request.queued`` -> ``request.prefill``
--> ``request.decode`` per rid, plus histograms):
+-> ``request.deliver`` (a streaming request's first token, from its
+draw to the return of its first ``on_token`` callback) ->
+``request.decode`` per rid, plus histograms):
 - ``serving_submit_lock_wait_seconds``  ``submit()``'s wait for the
                                   server's lock, which a tick holds
                                   (also ``lock_wait_s`` on the
@@ -12,6 +14,10 @@ Request lifecycle (spans ``request.queued`` -> ``request.prefill``
 - ``serving_queue_wait_seconds``  submit -> admission pop
 - ``serving_ttft_seconds``        submit -> first token available
                                   (admission prefill emits it)
+- ``serving_first_token_delivery_seconds``  first token drawn -> its
+                                  ``on_token`` callback returned (the
+                                  decode dispatch and read-back of the
+                                  same turn lie between); once a request
 - ``serving_tpot_seconds``        (finish - first token) / (tokens - 1)
 - ``serving_e2e_seconds``         submit -> finish
 - ``serving_requests_total{state=submitted|finished|canceled|failed}``
@@ -25,11 +31,19 @@ Per-tick engine signals. Every time below comes from the reads of
                                   into the profiler's trace
 - ``serving_tick_seconds``        one batched decode dispatch, to its
                                   tokens back on the host
+                                  (``decode_dispatch`` + ``decode_wait``)
+- ``serving_slow_phases_total{phase}``  phase intervals of
+                                  ``SLOW_PHASE_S`` or longer (the
+                                  server keeps a record of each in
+                                  ``srv.slow_phases`` and warns)
 - ``serving_tick_occupancy``      active slots entering the tick
 - ``serving_active_slots`` / ``serving_queue_depth`` gauges
-- ``serving_prefill_seconds``     one prefill batch (a ragged packed
-                                  launch to its last activation, or one
-                                  dense admission)
+- ``serving_prefill_seconds``     one prefill batch that the host
+                                  waited for (a ragged packed launch
+                                  that completed a prompt, dispatch to
+                                  its last activation, or one dense
+                                  admission); a launch that completes no
+                                  prompt blocks nothing and is left out
 - ``serving_prefill_launches_total{width}``  ragged launches by chunk
                                   width
 - ``serving_prefill_rows_total``  dense rows those launches computed
@@ -73,12 +87,18 @@ reads). All calls happen under the server's own lock, so per-request
 state needs no extra synchronization. Host-side only — never call any
 of this from jit-traced code.
 """
+import collections
+import gc
+import time
+import weakref
+
 from .clock import MonotonicClock
 from .costs import PHASE_BUCKETS
 from .metrics import DEFAULT_BUCKETS, MetricRegistry
 from .tracing import Tracer
 
 __all__ = ["ServerTelemetry", "RouterTelemetry", "TickBoundary",
+           "HostEventLog", "SLOW_PHASE_S", "GC_PAUSE_S",
            "TPOT_BUCKETS", "TICK_BUCKETS", "OCCUPANCY_BUCKETS"]
 
 # per-token / per-tick scales are finer than request-level latencies
@@ -86,6 +106,90 @@ TPOT_BUCKETS = (0.0005, 0.001, 0.0025, 0.005, 0.01, 0.025, 0.05, 0.1,
                 0.25, 0.5, 1.0)
 TICK_BUCKETS = TPOT_BUCKETS
 OCCUPANCY_BUCKETS = (1, 2, 4, 8, 16, 32, 64, 128, 256)
+
+
+# A phase this long is a stall, not work: the longest honest phase of
+# any benchmark cell is a prefill launch of some 50 ms (150 before
+# PR 35). A pause of the collector shorter than GC_PAUSE_S is not kept.
+SLOW_PHASE_S = 0.25
+GC_PAUSE_S = 0.010
+
+
+class HostEventLog:
+    """What the host did that can hold a tick up and that no phase
+    names: every duration event JAX's monitoring reports and every
+    pause of the collector of ``GC_PAUSE_S`` or longer, the newest 256
+    as (end, name, seconds), ``end`` read from ``clock`` (the
+    boundary's) when the event is reported. A server that has a
+    boundary owns one; the process's listeners, registered when the
+    first log is built, feed every live log. They fire on compiles and
+    collections, never on the tick path.
+
+    The duration events of the installed JAX (0.9), named here by the
+    last part of their path without ``_duration`` / ``_sec``:
+    ``/jax/core/compile/jaxpr_trace_duration`` (a function traced: a
+    new shape of an eager op too), ``.../jaxpr_to_mlir_module_duration``
+    (lowered), ``.../backend_compile_duration`` (compiled by XLA) and
+    ``/jax/compilation_cache/cache_retrieval_time_sec`` (an executable
+    LOADED from the persistent cache, which ``backend_compile`` does
+    not report). ``/jax/compilation_cache/compile_time_saved_sec`` is a
+    saving, not time that passed, and is left out. Any other duration
+    event a later JAX reports is kept under its own name."""
+
+    __slots__ = ("clock", "events", "__weakref__")
+    _live = weakref.WeakSet()
+    _listening = False
+    _gc_t0 = None
+
+    def __init__(self, clock):
+        self.clock = clock
+        self.events = collections.deque(maxlen=256)
+        HostEventLog._live.add(self)
+        if not HostEventLog._listening:
+            HostEventLog._listening = True
+            import jax
+            jax.monitoring.register_event_duration_secs_listener(
+                HostEventLog._on_duration)
+            gc.callbacks.append(HostEventLog._on_gc)
+
+    def note(self, name, seconds):
+        """One event that ended now."""
+        self.events.append((self.clock.now(), name, seconds))
+
+    def ended_in(self, t0, t1):
+        """[(name, seconds)] of the events that ended in (t0, t1], in
+        the order they first came, those of one name summed (one
+        compile traces dozens of inner functions)."""
+        out = {}
+        for end, name, s in list(self.events):
+            if t0 < end <= t1:
+                out[name] = out.get(name, 0.0) + s
+        return list(out.items())
+
+    @staticmethod
+    def _on_duration(event, duration, **_):
+        name = event.rsplit("/", 1)[-1]
+        if name == "compile_time_saved_sec":
+            return
+        for suffix in ("_duration", "_time_sec", "_sec"):
+            if name.endswith(suffix):
+                name = name[:-len(suffix)]
+                break
+        for log in list(HostEventLog._live):
+            log.note(name, duration)
+
+    @staticmethod
+    def _on_gc(phase, info):
+        if phase == "start":
+            HostEventLog._gc_t0 = time.perf_counter()
+            return
+        t0, HostEventLog._gc_t0 = HostEventLog._gc_t0, None
+        if t0 is None:
+            return
+        seconds = time.perf_counter() - t0
+        if seconds >= GC_PAUSE_S:
+            for log in list(HostEventLog._live):
+                log.note(f"gc gen{info.get('generation')}", seconds)
 
 
 class TickBoundary:
@@ -96,18 +200,22 @@ class TickBoundary:
     ``last_tick_phases`` and the recorder's tick events), and, with
     telemetry, ``serving_tick_phase_seconds{phase}`` and a
     ``serve.<phase>`` span carrying the tick's number, which the
-    tracer mirrors into the profiler's trace. Opened and closed by the
-    thread that drives the tick. The server builds none when both
+    tracer mirrors into the profiler's trace. A phase of
+    ``SLOW_PHASE_S`` or longer is also handed to ``slow``, the server's
+    one sink for stalls, as ``slow(phase, seconds, start, tick, args)``
+    (one comparison a phase where nothing stalls). Opened and closed by
+    the thread that drives the tick. The server builds none when both
     consumers are off."""
 
     __slots__ = ("_costs", "_tele", "_clock", "_tick", "_t", "_span",
-                 "phase")
+                 "_slow", "_args", "phase")
 
-    def __init__(self, costs, tele, phase, tick=None):
+    def __init__(self, costs, tele, phase, tick=None, slow=None):
         self._costs = costs
         self._tele = tele
         self._clock = tele.clock if tele is not None else costs.clock
         self._tick = tick
+        self._slow = slow
         self._span = None
         self.phase = None
         if tele is not None and tick is not None:
@@ -121,6 +229,7 @@ class TickBoundary:
         self._close(t)
         self.phase = phase
         self._t = t
+        self._args = args
         if self._tele is not None:
             if self._tick is not None:
                 args["tick"] = self._tick
@@ -145,6 +254,8 @@ class TickBoundary:
         if self._tele is not None:
             self._tele.on_phase(phase, seconds)
             self._span.end(at=t)
+        if seconds >= SLOW_PHASE_S and self._slow is not None:
+            self._slow(phase, seconds, self._t, self._tick, self._args)
 
 
 class _ReqState:
@@ -185,6 +296,9 @@ class ServerTelemetry:
             else Tracer(clock=self.clock, enabled=self.registry.enabled)
         self.enabled = self.registry.enabled
         self._req = {}
+        # rid -> (draw's read, request.deliver span) of the streaming
+        # requests whose first token is drawn and not yet handed over
+        self.undelivered = {}
         self.tick = None     # the running tick's number (TickBoundary)
         r = self.registry
         req = r.counter("serving_requests_total",
@@ -208,6 +322,12 @@ class ServerTelemetry:
         self._h_ttft = r.histogram("serving_ttft_seconds",
                                    "submit() to first generated token",
                                    buckets=DEFAULT_BUCKETS)
+        self._h_deliver = r.histogram(
+            "serving_first_token_delivery_seconds",
+            "A streaming request's first token: drawn to its on_token "
+            "callback returned (the decode dispatch and read-back of "
+            "the same turn lie between); once a request",
+            buckets=TICK_BUCKETS)
         self._h_tpot = r.histogram("serving_tpot_seconds",
                                    "Mean per-token decode latency at "
                                    "finish", buckets=TPOT_BUCKETS)
@@ -215,15 +335,23 @@ class ServerTelemetry:
                                   "submit() to finish",
                                   buckets=DEFAULT_BUCKETS)
         self._h_tick = r.histogram("serving_tick_seconds",
-                                   "One batched decode dispatch",
+                                   "One batched decode dispatch, to its "
+                                   "tokens back on the host "
+                                   "(decode_dispatch + decode_wait)",
                                    buckets=TICK_BUCKETS)
         self._h_phase = r.histogram(
             "serving_tick_phase_seconds",
             "The serve loop's wall by phase, one observation per phase "
-            "interval: the *_wait phases are the chip's, idle_wait is "
+            "interval: *_dispatch is the host enqueueing a program, "
+            "*_wait the host waiting for its value, idle_wait is "
             "nobody's, the rest is host work that leaves the chip idle",
             labelnames=("phase",), buckets=PHASE_BUCKETS)
         self._phase_children = {}
+        self._c_slow = r.counter(
+            "serving_slow_phases_total",
+            "Phase intervals of SLOW_PHASE_S (0.25 s) or longer, by "
+            "phase: each has a record in srv.slow_phases",
+            labelnames=("phase",))
         self._h_occ = r.histogram("serving_tick_occupancy",
                                   "Active slots entering a tick",
                                   buckets=OCCUPANCY_BUCKETS)
@@ -319,8 +447,11 @@ class ServerTelemetry:
             "slot-state pushes)")
         self._h_prefill = r.histogram(
             "serving_prefill_seconds",
-            "One prefill batch: a ragged packed launch, or one "
-            "admission's dense prefill", buckets=TICK_BUCKETS)
+            "One prefill batch the host waited for: a ragged packed "
+            "launch that completed a prompt (dispatch to its last "
+            "activation), or one admission's dense prefill; a launch "
+            "that completes no prompt blocks nothing and is left out",
+            buckets=TICK_BUCKETS)
         self._c_launches = r.counter(
             "serving_prefill_launches_total",
             "Ragged prefill launches by chunk width",
@@ -476,17 +607,18 @@ class ServerTelemetry:
         # the queue-wait histogram is observed by on_first_token, not
         # here: this attempt may still be DEFERRED back to the queue,
         # and a request must contribute exactly one (full) sample
-        st.t_admit = self.clock.now()
+        # one read: the queued span ends where the prefill span begins
+        t = st.t_admit = self.clock.now()
         self._g_queue.set(queue_depth)
         if st.queued_span is not None:   # None after a deferred admit
-            st.queued_span.end()
+            st.queued_span.end(at=t)
             st.queued_span = None
         # a resumed (previously preempted) request's admission is a
         # REPLAY, not a first prefill — name the span so the parked ->
         # replay detour reads directly off the timeline
         st.prefill_span = self.tracer.begin_span(
             "request.replay" if st.preempted else "request.prefill",
-            rid=rid)
+            at=t, rid=rid)
 
     def on_admission_deferred(self, rid, queue_depth):
         """Admission rolled back (the pool could not be made to fit —
@@ -506,18 +638,24 @@ class ServerTelemetry:
                 "request.parked" if st.preempted else "request.queued",
                 rid=rid, requeued=True)
 
-    def on_first_token(self, rid, prefill_tokens, prefix_hit_tokens):
+    def on_first_token(self, rid, prefill_tokens, prefix_hit_tokens,
+                       streams=False):
         """Admission prefill produced the request's first token. A
         PREEMPTED request re-emits its first token at re-admission:
         the waiter saw it long ago, so TTFT/queue-wait observe only the
         ORIGINAL emission (``t_first`` stays put for TPOT); the token
-        counters still count the replay's real prefill work."""
+        counters still count the replay's real prefill work.
+        ``streams``: the request has an ``on_token`` callback, which
+        ``on_first_delivery`` will report: its ``request.deliver`` span
+        opens here and ``request.decode`` when that closes (a replayed
+        first token is not delivered again)."""
         if not self.enabled:
             return
         st = self._req.get(rid)
         if st is None:
             return
         t = self.clock.now()
+        deliver = streams and st.t_first is None
         if st.t_first is None:
             if st.t_admit is not None:
                 # the wait that ended at the SUCCESSFUL admission
@@ -530,7 +668,7 @@ class ServerTelemetry:
         if st.prefill_span is not None:
             # the tick whose launch served it: the same number its
             # serve.prefill_wait span carries
-            st.prefill_span.end(prefill_tokens=prefill_tokens,
+            st.prefill_span.end(at=t, prefill_tokens=prefill_tokens,
                                 prefix_hit_tokens=prefix_hit_tokens,
                                 tick=self.tick)
             st.prefill_span = None
@@ -541,7 +679,31 @@ class ServerTelemetry:
             self._c_tok_prefix.inc(prefix_hit_tokens)
         else:
             self._c_pfx_miss.inc()
-        st.decode_span = self.tracer.begin_span("request.decode", rid=rid)
+        if deliver:
+            self.undelivered[rid] = (t, self.tracer.begin_span(
+                "request.deliver", at=t, rid=rid, tick=self.tick))
+        else:
+            st.decode_span = self.tracer.begin_span("request.decode",
+                                                    at=t, rid=rid)
+
+    def on_first_delivery(self, rid):
+        """The first ``on_token`` callback of ``rid`` returned (the
+        server asks only for a rid in ``undelivered``)."""
+        t_first, span = self.undelivered.pop(rid)
+        t = self.clock.now()
+        self._h_deliver.observe(t - t_first)
+        span.end(at=t)
+        st = self._req.get(rid)
+        if st is not None and st.decode_span is None \
+                and st.queued_span is None and st.prefill_span is None:
+            # still decoding: not finished, parked or replaying since
+            st.decode_span = self.tracer.begin_span("request.decode",
+                                                    at=t, rid=rid)
+
+    def _drop_delivery(self, rid, **how):
+        pending = self.undelivered.pop(rid, None)
+        if pending is not None:
+            pending[1].end(**how)
 
     def on_finish(self, rid, n_tokens):
         if not self.enabled:
@@ -564,6 +726,7 @@ class ServerTelemetry:
         if st is None:
             return
         self._c_canceled.inc()
+        self._drop_delivery(rid, canceled=True)
         for span in (st.queued_span, st.prefill_span,
                          st.decode_span):
             if span is not None:
@@ -574,6 +737,7 @@ class ServerTelemetry:
             return
         st = self._req.pop(rid, None)
         self._c_failed.inc()
+        self._drop_delivery(rid, error=type(exc).__name__)
         if st is not None:
             for span in (st.queued_span, st.prefill_span,
                          st.decode_span):
@@ -592,9 +756,13 @@ class ServerTelemetry:
                 self._h_phase.labels(phase=phase)
         child.observe(seconds)
 
+    def on_slow_phase(self, phase):
+        """One interval of ``phase`` lasted ``SLOW_PHASE_S`` or more."""
+        self._c_slow.labels(phase=phase).inc()
+
     def on_tick(self, seconds, active_slots, decode_tokens):
-        """One decode dispatch took ``seconds`` (the boundary's reads
-        around it)."""
+        """One decode dispatch took ``seconds``, enqueueing to tokens
+        on the host (the boundary's reads around it)."""
         if not self.enabled:
             return
         self._h_tick.observe(seconds)
@@ -790,11 +958,15 @@ class ServerTelemetry:
     def on_prefill_batch(self, seconds, width=None):
         """One prefill batch took ``seconds`` (the boundary's reads
         around it): a ragged packed launch of chunk width ``width``,
-        or one admission's dense prefill. (Token counters are driven by
-        on_first_token; this only times and counts the batch.)"""
+        or one admission's dense prefill. ``seconds`` is None for a
+        launch that completed no prompt: the host waited for nothing
+        there, so it is counted and not timed. (Token counters are
+        driven by on_first_token; this only times and counts the
+        batch.)"""
         if not self.enabled:
             return
-        self._h_prefill.observe(seconds)
+        if seconds is not None:
+            self._h_prefill.observe(seconds)
         if width is not None:
             self._c_launches.labels(width=width).inc()
 

@@ -130,12 +130,14 @@ class Tracer:
                     self.clock.now() if at is None else at,
                     threading.get_ident(), mirror)
 
-    def begin_span(self, name, **args):
+    def begin_span(self, name, at=None, **args):
         """A span that may be ended from another thread: collected,
-        never mirrored into the profiler."""
+        never mirrored into the profiler. ``at``: the start, when the
+        caller already read the tracer's clock."""
         if not self.enabled:
             return NULL_SPAN
-        return Span(self, name, dict(args), self.clock.now(),
+        return Span(self, name, dict(args),
+                    self.clock.now() if at is None else at,
                     threading.get_ident(), None)
 
     def _append(self, ev):

@@ -558,7 +558,35 @@ class TestServerCosting:
         assert paged.stats["grow_pages"] > 0
         served()
         assert marked == set(TICK_PHASES)
-        assert len(TICK_PHASES) == len(set(TICK_PHASES)) == 12
+        assert len(TICK_PHASES) == len(set(TICK_PHASES)) == 14
+
+    def test_the_split_costs_one_clock_read_a_program(self):
+        """The benchmark's untraced setting (the catalog alone): a tick
+        reads the clock once a mark and nowhere else, so dividing a
+        program's time where its call returns costs ONE read a decode
+        tick and ONE a launch: a tick that launches and decodes makes
+        16 reads (15 phases and the close; 14 before the split), a
+        decode-only tick 11 (10 before). No span, no record, no
+        warning: a sound tick compares each phase with the limit and
+        goes on."""
+        fc = FakeClock()
+        cat = CostCatalog(clock=fc)
+        srv = _paged_server(costs=cat)
+        srv.submit(_prompt(1, 2, 3), max_new_tokens=4)
+        srv.run()                          # compiles (the watch reads)
+        srv.submit(_prompt(4, 5, 6), max_new_tokens=4)
+        reads = []
+        while True:
+            before = fc.reads
+            live = srv.step()
+            reads.append(fc.reads - before)
+            if not live:
+                break
+        assert reads[0] == 16              # admits, launches, decodes
+        assert set(reads[1:-1]) == {11}    # decode ticks
+        phases = cat.snapshot()["last_tick_phases"]
+        assert set(phases) <= set(TICK_PHASES)
+        assert not srv.slow_phases and srv.stats["slow_phases"] == 0
 
     def test_postmortem_freezes_costs_section(self):
         rec = FlightRecorder()

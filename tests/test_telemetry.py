@@ -252,9 +252,11 @@ class _SteppingClock(FakeClock):
         return t
 
 
-# which side of the split a phase of the split tick falls on: the chip
-# works through these and is idle (the host's doing) through the rest
-_CHIP_PHASES = {"prefill_wait", "decode_wait"}
+# which side of the split a phase of the split tick falls on: a
+# program's own (the host enqueueing it, then waiting for its value)
+# against the rest, through which the chip is idle (the host's doing)
+_CHIP_PHASES = {"prefill_dispatch", "prefill_wait", "decode_dispatch",
+                "decode_wait"}
 _HOST_PHASES = {"expire", "admit", "prefill_pack", "activate", "grow",
                 "state_push", "emit", "harvest", "callbacks"}
 
@@ -288,15 +290,16 @@ class TestTickBoundary:
             else None
         tb = TickBoundary(cat, tele, "expire", tick=7)
         assert fc.reads == 1                       # opening reads once
-        for n, phase in enumerate(("admit", "decode_wait", "emit"), 2):
-            fc.advance(0.25)
+        for n, phase in enumerate(("admit", "decode_dispatch",
+                                   "decode_wait", "emit"), 2):
+            fc.advance(0.125)
             tb.mark(phase)
             assert fc.reads == n
-        fc.advance(0.25)
+        fc.advance(0.125)
         tb.close()
-        assert fc.reads == 5
-        want = {"expire": 0.25, "admit": 0.25, "decode_wait": 0.25,
-                "emit": 0.25}
+        assert fc.reads == 6
+        want = {"expire": 0.125, "admit": 0.125, "decode_dispatch": 0.125,
+                "decode_wait": 0.125, "emit": 0.125}
         if cat is not None:
             assert cat.pending_phases() == want
         if tele is not None:
@@ -305,8 +308,8 @@ class TestTickBoundary:
                 == want
             spans = _serve_spans(tele, tick=7)
             assert [e["name"] for e in spans] == [
-                "serve.expire", "serve.admit", "serve.decode_wait",
-                "serve.emit"]
+                "serve.expire", "serve.admit", "serve.decode_dispatch",
+                "serve.decode_wait", "serve.emit"]
             # contiguous: each ends where the next begins
             for a, b in zip(spans, spans[1:]):
                 assert a["ts"] + a["dur"] == pytest.approx(b["ts"])
@@ -316,7 +319,8 @@ class TestTickBoundary:
         """One tick that admits, prefills, activates and decodes: its
         serve.* spans tile the tick's wall with no hole, the programs
         are dispatched inside the *_wait phases and the host's work
-        falls in the phases that leave the chip idle."""
+        falls in the phases that leave the chip idle. A program's time
+        divides where its call returns: ``*_dispatch`` then ``*_wait``."""
         clock = _SteppingClock()
         tele = ServerTelemetry(clock=clock)
         srv = _stub_server(telemetry=tele)
@@ -340,16 +344,17 @@ class TestTickBoundary:
 
         assert at == {"_expire_locked": ["expire"],
                       "_admit_ragged": ["admit", "admit"],
-                      "_ragged_fn": ["prefill_wait"],
+                      "_ragged_fn": ["prefill_dispatch"],
                       "_harvest": ["harvest", "harvest"],
                       "_flush_slot_state": ["state_push"],
-                      "decode": ["decode_wait"]}
+                      "decode": ["decode_dispatch"]}
         spans = _serve_spans(tele, tick=1)
         names = [e["name"][len("serve."):] for e in spans]
         assert names == ["expire", "admit", "prefill_pack",
-                         "prefill_wait", "activate", "admit", "harvest",
-                         "state_push", "decode_wait", "emit", "harvest",
-                         "admit", "callbacks"]
+                         "prefill_dispatch", "prefill_wait", "activate",
+                         "admit", "harvest", "state_push",
+                         "decode_dispatch", "decode_wait", "emit",
+                         "harvest", "admit", "callbacks"]
         assert set(names) <= _CHIP_PHASES | _HOST_PHASES
         wall = spans[-1]["ts"] + spans[-1]["dur"] - spans[0]["ts"]
         assert sum(e["dur"] for e in spans) == pytest.approx(wall)
@@ -359,9 +364,12 @@ class TestTickBoundary:
         assert sum(by_phase.values()) * 1e6 == pytest.approx(wall)
         # the launch's span says what it served, and the request's own
         # prefill span carries the same tick
-        launch = spans[names.index("prefill_wait")]["args"]
+        launch = spans[names.index("prefill_dispatch")]["args"]
         assert launch == {"tick": 1, "width": 4, "rows": 1,
                           "launch_rows": 2 * 4, "rids": [0]}
+        # the wait carries the same, and that the host blocked on it
+        assert spans[names.index("prefill_wait")]["args"] == dict(
+            launch, blocked=1)
         (prefill,) = [e for e in tele.tracer.events()
                       if e["name"] == "request.prefill"]
         assert prefill["args"]["tick"] == 1 and prefill["args"]["rid"] == 0
@@ -371,8 +379,9 @@ class TestTickBoundary:
         # from the boundary's reads: the spans' own lengths
         dur = {n: e["dur"] / 1e6 for n, e in zip(names, spans)}
         assert _hist(tele.registry, "serving_tick_seconds") == \
-            (1, pytest.approx(dur["decode_wait"]))
-        batch = dur["prefill_wait"] + dur["activate"]
+            (1, pytest.approx(dur["decode_dispatch"] + dur["decode_wait"]))
+        batch = dur["prefill_dispatch"] + dur["prefill_wait"] \
+            + dur["activate"]
         assert _hist(tele.registry, "serving_prefill_seconds") == \
             (1, pytest.approx(batch))
         assert srv.stats["prefill_wall_s"] == pytest.approx(batch)
@@ -475,6 +484,416 @@ class TestTickBoundary:
         idle = [e for e in tele.tracer.events()
                 if e["name"] == "serve.idle_wait"]
         assert idle and all("tick" not in e.get("args", {}) for e in idle)
+
+
+# ------------------------------- the time around the chip's programs
+
+class _SlowValue:
+    """Stands in for a decode tick's tokens on the device: reading it
+    back (``np.asarray``) is where the host blocks, and ``on_read``
+    runs there, inside ``decode_wait``."""
+
+    def __init__(self, value, on_read):
+        self.value, self.on_read = value, on_read
+
+    def __array__(self, dtype=None, copy=None):
+        self.on_read()
+        return np.asarray(self.value)
+
+
+def _stall_next_decode_wait(srv, on_read):
+    """Make the next decode tick's read-back run ``on_read`` first."""
+    if srv._decode_jit is None:
+        srv._decode_jit = srv._build_decode_step()
+    attr = "_decode_jit"
+    if srv._costs is not None:
+        attr = "_decode_prog"
+        if srv._decode_prog is None:
+            srv._decode_prog = srv._cost_program(
+                srv._cost_op("decode"), srv._decode_jit,
+                (srv._tok, srv._caches, srv._t, srv._keys))
+    inner = getattr(srv, attr)
+
+    def once(*a):
+        setattr(srv, attr, inner)
+        *state, toks = inner(*a)
+        return (*state, _SlowValue(toks, on_read))
+    setattr(srv, attr, once)
+
+
+_SERVER_LOG = "paddle_tpu.inference.continuous_batching"
+
+
+class TestTickSplit:
+    def test_first_token_delivery_is_a_span_of_its_request(self):
+        """``request.deliver`` runs from the draw to the return of the
+        request's first callback, once, with ``rid`` and ``tick``; the
+        request's spans tile its life and share ``rid``."""
+        clock = _SteppingClock()
+        tele = ServerTelemetry(clock=clock)
+        srv = _stub_server(telemetry=tele)
+        got = []
+        rid = srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=4,
+                         on_token=lambda r, toks: got.append(len(toks)))
+        quiet = srv.submit(np.asarray([4, 5], np.int32), max_new_tokens=4)
+        srv.run()
+        assert sum(got) == 4 and len(got) > 1     # several callbacks
+        by_name = {}
+        for e in tele.tracer.events():
+            if e["name"].startswith("request.") \
+                    and e["args"].get("rid") == rid:
+                by_name.setdefault(e["name"], []).append(e)
+        assert {k: len(v) for k, v in by_name.items()} == {
+            "request.queued": 1, "request.prefill": 1,
+            "request.deliver": 1, "request.decode": 1}
+        order = [by_name["request." + n][0]
+                 for n in ("queued", "prefill", "deliver", "decode")]
+        deliver = order[2]
+        # the launch that drew the token and the span agree on the tick
+        assert deliver["args"] == {"rid": rid, "tick": 1}
+        assert order[1]["args"]["tick"] == 1
+        (launch,) = [e for e in _serve_spans(tele, tick=1)
+                     if e["name"] == "serve.prefill_wait"]
+        assert rid in launch["args"]["rids"]
+        # each span ends at the read that opens the next: no hole
+        for a, b in zip(order, order[1:]):
+            assert a["ts"] + a["dur"] == pytest.approx(b["ts"])
+        # it spans the decode dispatch and read-back of the same turn
+        spans = {e["name"]: e for e in _serve_spans(tele, tick=1)}
+        assert deliver["ts"] < spans["serve.decode_dispatch"]["ts"]
+        assert deliver["ts"] + deliver["dur"] > \
+            spans["serve.decode_wait"]["ts"] + spans["serve.decode_wait"][
+                "dur"]
+        n, total = _hist(tele.registry,
+                         "serving_first_token_delivery_seconds")
+        assert n == 1 and total == pytest.approx(deliver["dur"] / 1e6)
+        assert tele.undelivered == {}
+        # a request that streams nothing has no delivery to time
+        assert not [e for e in tele.tracer.events()
+                    if e["name"] == "request.deliver"
+                    and e["args"]["rid"] == quiet]
+
+    def test_a_request_that_ends_with_its_first_token_is_delivered(self):
+        tele = ServerTelemetry(clock=_SteppingClock())
+        srv = _stub_server(telemetry=tele)
+        got = []
+        rid = srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=1,
+                         on_token=lambda r, toks: got.append(list(toks)))
+        srv.run()
+        assert len(got) == 1 and len(got[0]) == 1
+        names = [e["name"] for e in tele.tracer.events()
+                 if e["name"].startswith("request.")
+                 and e["args"].get("rid") == rid]
+        assert names.count("request.deliver") == 1
+        assert "request.decode" not in names      # finished before
+        assert _hist(tele.registry,
+                     "serving_first_token_delivery_seconds")[0] == 1
+        assert tele.undelivered == {}
+
+    def test_a_replayed_first_token_is_not_delivered_again(self):
+        tele, fc, reg = _scripted_telemetry()
+        tele.on_submit(7, 3, 1)
+        tele.on_admit(7, 0)
+        tele.on_first_token(7, 3, 0, streams=True)
+        fc.advance(0.25)
+        tele.on_first_delivery(7)
+        tele.on_preempt(7, 1)
+        tele.on_admit(7, 0)
+        tele.on_first_token(7, 3, 0, streams=True)    # the replay's
+        assert tele.undelivered == {}
+        assert _hist(reg, "serving_first_token_delivery_seconds") == \
+            (1, pytest.approx(0.25))
+        names = [e["name"] for e in tele.tracer.events()]
+        assert names.count("request.deliver") == 1
+
+    def test_a_cancelled_request_drops_its_delivery(self):
+        tele, fc, reg = _scripted_telemetry()
+        tele.on_submit(3, 3, 1)
+        tele.on_admit(3, 0)
+        tele.on_first_token(3, 3, 0, streams=True)
+        tele.on_cancel(3)
+        assert tele.undelivered == {}
+        (ev,) = [e for e in tele.tracer.events()
+                 if e["name"] == "request.deliver"]
+        assert ev["args"]["canceled"] is True
+        assert _hist(reg, "serving_first_token_delivery_seconds")[0] == 0
+
+    def test_a_launch_that_completes_no_prompt_blocks_nothing(self):
+        """A prompt longer than the per-tick budget takes two launches:
+        the first reads nothing back (``blocked=0``) and adds nothing
+        to ``prefill_wall_s`` / ``serving_prefill_seconds``; both
+        count as launches."""
+        clock = _SteppingClock()
+        tele = ServerTelemetry(clock=clock)
+        srv = _stub_server(telemetry=tele, prefill_tokens_per_tick=4)
+        srv.submit(np.arange(1, 7, dtype=np.int32), max_new_tokens=2)
+        srv.run()
+        waits = [e for e in _serve_spans(tele)
+                 if e["name"] == "serve.prefill_wait"]
+        assert [e["args"]["blocked"] for e in waits] == [0, 1]
+        launches = tele.registry.get("serving_prefill_launches_total")
+        assert sum(launches.samples().values()) == 2
+        # the one that blocked: its dispatch, wait and activation
+        tick = waits[1]["args"]["tick"]
+        dur = {e["name"]: e["dur"] / 1e6
+               for e in _serve_spans(tele, tick=tick)}
+        batch = dur["serve.prefill_dispatch"] + dur["serve.prefill_wait"] \
+            + dur["serve.activate"]
+        assert _hist(tele.registry, "serving_prefill_seconds") == \
+            (1, pytest.approx(batch))
+        assert srv.stats["prefill_wall_s"] == pytest.approx(batch)
+        # the first launch's tick still tiles: its wait closes at "admit"
+        first = _serve_spans(tele, tick=waits[0]["args"]["tick"])
+        names = [e["name"][len("serve."):] for e in first]
+        at = names.index("prefill_wait")
+        assert names[at - 1:at + 2] == ["prefill_dispatch", "prefill_wait",
+                                        "admit"]
+        assert "activate" not in names
+
+    def test_a_stalled_phase_names_itself(self, caplog):
+        """A scripted 5 s ``decode_wait`` in which a compile ended and
+        the collector paused: ONE record with the phase, the tick, its
+        seconds and both causes, the two stats bumped, the counter, a
+        recorder event and a postmortem section; a WARNING once the
+        catalog is warm, none before."""
+        import logging
+
+        from paddle_tpu.telemetry import CostCatalog, FlightRecorder
+        fc = FakeClock()
+        tele = ServerTelemetry(clock=fc)
+        cat = CostCatalog(clock=fc)
+        rec = FlightRecorder(clock=fc)
+        srv = _stub_server(telemetry=tele, costs=cat, recorder=rec)
+        prompt = np.asarray([1, 2, 3], np.int32)
+
+        def stall():
+            fc.advance(4.0)
+            srv._host_events.note("backend_compile", 3.9)
+            fc.advance(1.0)
+            srv._host_events.note("gc gen2", 0.31)
+
+        fc.advance(100.0)
+        srv._host_events.note("backend_compile", 9.0)   # before: not its
+        fc.advance(1.0)
+        assert not cat.warmed
+        with caplog.at_level(logging.WARNING, logger=_SERVER_LOG):
+            _stall_next_decode_wait(srv, stall)
+            srv.submit(prompt, max_new_tokens=6)
+            srv.run()
+        assert caplog.records == []            # warm-up stalls by design
+        (first,) = srv.slow_phases
+        assert (first["phase"], first["tick"], first["seconds"]) == (
+            "decode_wait", 1, 5.0)
+        assert first["first_use"] is True
+        assert first["host_events"] == [("backend_compile", 3.9),
+                                        ("gc gen2", 0.31)]
+        assert cat.warmed
+        stats0 = dict(srv.stats)
+        with caplog.at_level(logging.WARNING, logger=_SERVER_LOG):
+            _stall_next_decode_wait(srv, stall)
+            srv.submit(prompt, max_new_tokens=6)
+            srv.submit(prompt, max_new_tokens=6)
+            srv.submit(prompt, max_new_tokens=6)     # waits for a slot
+            srv.run()
+        assert len(srv.slow_phases) == 2
+        got = srv.slow_phases[-1]
+        assert got["phase"] == "decode_wait" and got["seconds"] == 5.0
+        assert got["tick"] > 1 and got["start"] + 5.0 <= fc.now()
+        assert (got["live"], got["queued"], got["first_use"]) == (
+            2, 1, False)
+        assert got["args"] == {} and got["launches_awaited"] == 0
+        assert got["host_events"] == [("backend_compile", 3.9),
+                                      ("gc gen2", 0.31)]
+        assert srv.stats["slow_phases"] - stats0["slow_phases"] == 1
+        assert srv.stats["slow_phase_s"] - stats0["slow_phase_s"] == 5.0
+        assert tele.registry.get("serving_slow_phases_total").labels(
+            phase="decode_wait").value == 2.0
+        (line,) = [r.getMessage() for r in caplog.records]
+        assert line == (f"slow phase: tick {got['tick']} decode_wait "
+                        f"5.00 s (live 2, queued 1); backend_compile "
+                        f"3.90 s; gc gen2 0.31 s")
+        events = rec.events(kind="slow_phase")
+        assert [e["phase"] for e in events] == ["decode_wait"] * 2
+        assert events[-1]["host_events"] == got["host_events"]
+        with srv._lock:
+            bundle = srv._postmortem_locked("test")
+        assert bundle["slow_phases"] == list(srv.slow_phases)
+        assert bundle["stats"]["slow_phases"] == 2
+
+    def test_a_slow_launch_carries_what_it_served(self, caplog):
+        """A stall in a launch's phase has the span's arguments and
+        says the shape was new; the program's own compile (the jit path
+        compiles inside the first call) is among the causes, heard from
+        JAX itself; a server with no catalog warns at once."""
+        import logging
+        fc = FakeClock()
+        tele = ServerTelemetry(clock=fc)
+        srv = _stub_server(telemetry=tele)
+        inner = srv._ragged_fn
+
+        def slow_launch(*a):
+            fc.advance(0.5)
+            return inner(*a)
+        srv._ragged_fn = slow_launch
+        rid = srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=2)
+        with caplog.at_level(logging.WARNING, logger=_SERVER_LOG):
+            srv.run()
+        (got,) = srv.slow_phases
+        assert got["phase"] == "prefill_dispatch" and got["tick"] == 1
+        assert got["args"] == {"width": 4, "rows": 1, "launch_rows": 8,
+                               "rids": [rid]}
+        assert got["first_use"] is True
+        causes = dict(got["host_events"])
+        assert {"jaxpr_trace", "backend_compile"} <= set(causes)
+        (line,) = [r.getMessage() for r in caplog.records]
+        assert line.startswith("slow phase: tick 1 prefill_dispatch 0.50 s "
+                               "(live 0, queued 0); ")
+        assert f"backend_compile {causes['backend_compile']:.2f} s" in line
+
+    def test_a_wait_sits_through_every_launch_not_yet_awaited(self):
+        """A long prompt's chunks launch one after another with nothing
+        read back between them while no slot decodes, and the last
+        launch's wait is for them all: its limit is ``SLOW_PHASE_S`` a
+        launch awaited, and a record says how many there were."""
+        fc = FakeClock()
+        tele = ServerTelemetry(clock=fc)
+        srv = _stub_server(telemetry=tele, prefill_tokens_per_tick=4)
+        inner = srv._count_dispatches
+        waits = []
+
+        def counted(n=1, op="prefill"):
+            # called inside prefill_wait, right after the launch's call
+            if op == "prefill" and srv._unawaited == 4:
+                fc.advance(waits.pop())
+            return inner(n, op=op)
+        srv._count_dispatches = counted
+        prompt = np.arange(1, 15, dtype=np.int32)     # 4 + 4 + 4 + 2
+        for seconds in (0.9, 1.1):
+            waits.append(seconds)
+            prompt = prompt[::-1].copy()      # no prefix to share
+            rid = srv.submit(prompt, max_new_tokens=2)
+            srv.run()
+            assert not waits
+        blocked = [e["args"]["blocked"] for e in _serve_spans(tele)
+                   if e["name"] == "serve.prefill_wait"]
+        assert blocked == [0, 0, 0, 1] * 2
+        # 0.9 s over four launches is no stall; 1.1 s is
+        (got,) = srv.slow_phases
+        assert got["phase"] == "prefill_wait"
+        assert got["seconds"] == pytest.approx(1.1)
+        assert got["launches_awaited"] == 4
+        assert got["args"] == {"blocked": 1, "width": 2, "rows": 1,
+                               "launch_rows": 4, "rids": [rid]}
+        assert srv.stats["slow_phases"] == 1 and srv._unawaited == 0
+
+    def test_catalog_alone_keeps_the_record_and_its_arguments(self):
+        from paddle_tpu.telemetry import CostCatalog
+        fc = FakeClock()
+        srv = _stub_server(costs=CostCatalog(clock=fc))
+        srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=2)
+        srv.run()                                     # compiles
+        inner = srv._ragged_fn
+        srv._cost_program = lambda op, fn, args: fn   # no priced copy
+
+        def slow_launch(*a):
+            fc.advance(0.5)
+            return inner(*a)
+        srv._ragged_fn = slow_launch
+        rid = srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=2)
+        srv.run()
+        (got,) = srv.slow_phases
+        assert got["phase"] == "prefill_dispatch"
+        assert got["args"] == {"width": 4, "rows": 1, "launch_rows": 8,
+                               "rids": [rid]}
+        assert got["first_use"] is False
+        assert srv.stats["slow_phases"] == 1
+        assert srv.stats["slow_phase_s"] == pytest.approx(0.5)
+
+    def test_idle_wait_and_a_sound_run_keep_no_record(self):
+        clock = _SteppingClock()
+        tele = ServerTelemetry(clock=clock)
+        srv = _stub_server(telemetry=tele)
+        srv.submit(np.asarray([1, 2, 3], np.int32), max_new_tokens=4)
+        srv.run()
+        assert not srv.slow_phases
+        assert (srv.stats["slow_phases"], srv.stats["slow_phase_s"]) == (
+            0, 0.0)
+        assert tele.registry.get("serving_slow_phases_total") \
+            .samples() == {}
+        # the serve loop's sleep is nobody's stall, however long
+        from paddle_tpu.telemetry.serving import TickBoundary
+        fc = FakeClock()
+        wait = TickBoundary(None, ServerTelemetry(clock=fc), "idle_wait")
+        fc.advance(10.0)
+        wait.close()
+
+
+class TestHostEventLog:
+    def test_listeners_are_registered_once_and_only_with_a_boundary(self):
+        """``telemetry=None, costs=None`` builds no log and registers no
+        listener; the first server with a boundary registers the pair,
+        a second adds none."""
+        import gc
+
+        import jax
+        from paddle_tpu.telemetry import CostCatalog
+
+        def listeners():
+            return (len(jax._src.monitoring.get_event_duration_listeners()),
+                    len(gc.callbacks))
+        before = listeners()
+        off = _stub_server()
+        assert off._host_events is None and listeners() == before
+        assert len(off.slow_phases) == 0
+        one = _stub_server(costs=CostCatalog())
+        after = listeners()
+        assert one._host_events is not None
+        assert all(0 <= a - b <= 1 for a, b in zip(after, before))
+        two = _stub_server(telemetry=ServerTelemetry())
+        assert two._host_events is not one._host_events
+        assert listeners() == after
+
+    def test_compiles_and_collector_pauses_reach_every_live_log(self):
+        import gc
+
+        import jax
+        import jax.numpy as jnp
+        from paddle_tpu.telemetry.serving import (GC_PAUSE_S,
+                                                  HostEventLog)
+        fa, fb = FakeClock(), FakeClock(50.0)
+        a, b = HostEventLog(fa), HostEventLog(fb)
+        fa.advance(1.0)
+        jax.jit(lambda x: x * 3 + 1)(jnp.ones((3, 5)))   # a new program
+        names = [n for _, n, _ in a.events]
+        assert {"jaxpr_trace", "jaxpr_to_mlir_module",
+                "backend_compile"} <= set(names)
+        assert [n for _, n, _ in b.events] == names
+        # each on its own clock
+        assert {end for end, _, _ in a.events} == {1.0}
+        assert {end for end, _, _ in b.events} == {50.0}
+        assert a.ended_in(0.5, 1.0) and not a.ended_in(1.5, 2.0)
+        # a saving is not time that passed; another name is kept whole
+        HostEventLog._on_duration(
+            "/jax/compilation_cache/compile_time_saved_sec", 2.0)
+        HostEventLog._on_duration(
+            "/jax/compilation_cache/cache_retrieval_time_sec", 0.4)
+        HostEventLog._on_duration("/jax/some/new_event", 0.1)
+        assert [(n, s) for _, n, s in list(a.events)[-2:]] == [
+            ("cache_retrieval", 0.4), ("new_event", 0.1)]
+        # a collection shorter than GC_PAUSE_S is not kept; a long one is
+        n = len(a.events)
+        gc.collect()
+        assert GC_PAUSE_S >= 0.01
+        HostEventLog._on_gc("start", {"generation": 2})
+        HostEventLog._gc_t0 -= 0.5                    # half a second ago
+        HostEventLog._on_gc("stop", {"generation": 2})
+        kept = list(a.events)[n:]
+        assert kept[-1][1] == "gc gen2" and kept[-1][2] >= 0.5
+        # a log nobody holds is fed no more
+        del b
+        gc.collect()
+        assert len(HostEventLog._live) >= 1
+        assert all(log is not None for log in HostEventLog._live)
 
 
 # ---------------------------------------------------------- kernel names
