@@ -749,7 +749,11 @@ class ContinuousBatchingServer:
                       # slot rows the decode program merged from the
                       # host's push bundle, and the decode dispatches
                       # whose bundle set any
-                      "slot_pushes": 0, "push_ticks": 0}
+                      "slot_pushes": 0, "push_ticks": 0,
+                      # turns that ended after their launch to hand
+                      # first tokens to their streams (the decode they
+                      # owe runs in the next turn)
+                      "handover_turns": 0}
         cfg = getattr(model, "cfg", None)
         self._moe_k = int(getattr(cfg, "top_k", 0) or 0) \
             if getattr(cfg, "num_experts", 0) else 0
@@ -886,6 +890,9 @@ class ContinuousBatchingServer:
         self._thread = None
         self._thread_error = None
         self._deferred_cbs = []   # (cb, rid, tokens) fired OUTSIDE the lock
+        # a turn whose admission queued a first token for a stream ends
+        # after the launch and owes its decode to the next turn
+        self._owed_decode = False
         # ------------------------------------------------- reliability
         # admission control: a bounded queue sheds instead of growing
         # without limit under overload; deadlines bound waiting
@@ -2809,14 +2816,35 @@ class ContinuousBatchingServer:
         return hoisted_jit(decode_tick, donate_argnums=(1,))
 
     def step(self):
-        """One server tick: admit waiting requests, run ``tick_block``
+        """One server turn: admit waiting requests, run ``tick_block``
         batched decode steps as one program, harvest finished rows.
-        Returns the number of active slots after the tick."""
-        with self._lock:
-            n = self._step_locked()
-            if self._prefix is not None:
-                self._prefix.flush_sketch()   # one publish per tick
-        self._fire_callbacks()
+        A turn is one tick, or two where the admission drew a stream's
+        first token: the first ends after the launch and fires its
+        callbacks, the second runs the decode (``_turn``). Each is its
+        own tick to the phase boundary, the flight recorder and the
+        cost catalog. Returns the number of active slots after the
+        turn."""
+        return self._turn()
+
+    def _turn(self, idle=False):
+        """Run one turn under the lock, then its callbacks outside it;
+        where the turn ended after its launch to hand first tokens over,
+        run the decode it owes as a second turn, with its callbacks.
+        ``idle`` (a loop that polls): return None, running nothing, when
+        the server has no work. Returns the active slots after the last
+        turn. Between the two turns the lock is free; a ``CallbackError``
+        of the first leaves the decode owed, and the next turn runs
+        it."""
+        for _ in range(2):
+            with self._lock:
+                if idle and not self._busy_locked():
+                    return None
+                n = self._step_locked()
+                if self._prefix is not None:
+                    self._prefix.flush_sketch()   # one publish per turn
+            self._fire_callbacks()
+            if not self._owed_decode:
+                break
         return n
 
     def _fire_callbacks(self):
@@ -2915,12 +2943,20 @@ class ContinuousBatchingServer:
         # it names (TICK_PHASES says which leave the chip idle)
         b = self._boundary
         self._expire_locked()
-        if b is not None:
-            b.mark("admit")
-        # a prefill launch marks itself out from inside (prefill_pack,
-        # prefill_dispatch, prefill_wait, activate) and comes back in
-        # "admit"
-        self._admit()
+        # the continuation of a turn that handed first tokens over
+        # decodes and admits nothing first (the dense backend prefills
+        # inline, which would put a launch before the decode its slots
+        # are owed); the decode stays owed until it is dispatched, so a
+        # retried turn is a continuation too
+        admit = not (self._owed_decode and self._active.any())
+        if admit:
+            self._owed_decode = False
+            if b is not None:
+                b.mark("admit")
+            # a prefill launch marks itself out from inside
+            # (prefill_pack, prefill_dispatch, prefill_wait, activate)
+            # and comes back in "admit"
+            self._admit()
         if not self._active.any():
             if self._tele is not None:     # keep the gauge live when a
                 self._tele.set_active_slots(0)   # drained tick skips decode
@@ -2934,6 +2970,19 @@ class ContinuousBatchingServer:
             if self._tele is not None:
                 self._tele.set_active_slots(0)
             return 0
+        if admit and self._deferred_cbs:
+            # the admission drew a stream's first token (the previous
+            # turn's callbacks emptied the list, so only this turn's
+            # admission, or a turn that raised, queued what is there):
+            # the turn ends here so that it is handed over before the
+            # decode, which the next turn runs
+            self._owed_decode = True
+            self.stats["handover_turns"] += 1
+            n = int(self._active.sum())
+            if self._tele is not None:
+                self._tele.on_handover()
+                self._tele.set_active_slots(n)
+            return n
         if self._kv is not None:
             # reserve mode: admission took each slot's FULL extent
             # (prompt + budget), so no page growth happens mid-flight.
@@ -2996,6 +3045,7 @@ class ContinuousBatchingServer:
          toks) = decode_fn(self._tok, self._caches, self._t,
                            self._keys, push)
         self._tick_dispatch("decode")
+        self._owed_decode = False
         if b is not None:
             b.mark("decode_wait")
         toks = np.asarray(toks)                    # [slots, tick_block]
@@ -3523,13 +3573,8 @@ class ContinuousBatchingServer:
         accumulate across runs)."""
         ticks = 0
         while ticks < max_ticks:
-            with self._lock:
-                if not self._busy_locked():
-                    break
-                self._step_locked()
-                if self._prefix is not None:
-                    self._prefix.flush_sketch()
-            self._fire_callbacks()
+            if self._turn(idle=True) is None:
+                break
             ticks += 1
         with self._lock:
             out, self._results = self._results, {}
@@ -3600,12 +3645,7 @@ class ContinuousBatchingServer:
                         _time.sleep(idle_sleep)
                         continue
                     try:
-                        with self._lock:
-                            if self._busy_locked():
-                                self._step_locked()
-                            if self._prefix is not None:
-                                self._prefix.flush_sketch()
-                        self._fire_callbacks()
+                        self._turn(idle=True)
                     except CallbackError as ce:
                         # the ENGINE is fine — fail exactly the
                         # requests whose streams are poisoned (typed,

@@ -32,7 +32,10 @@ ledger:
   while traffic shapes are still being explored) neither
   trips alarms for an op still climbing its own ladder nor holds the
   decode program's shape-leak watch hostage. ``warmed`` (the global
-  view) is true once every compiled op has warmed.
+  view) is true once every compiled op has warmed. A server turn that
+  hands first tokens over before its decode flushes two ticks (the
+  launch, then the decode), so the launch's half counts toward every
+  op's quiet run like any charged tick.
 - **Tick-phase attribution**: the server's one phase boundary
   (``telemetry.serving.TickBoundary``) splits each tick's wall into
   ``TICK_PHASES`` and feeds them in through ``add_phase``; they ride

@@ -58,6 +58,9 @@ Per-tick engine signals. Every time below comes from the reads of
 - ``serving_slot_state_pushes_total{kind=token|position}``  slot rows
   the decode program merged from the host's push bundle (the slot
   state rides the decode call: no dispatch of its own)
+- ``serving_handover_turns_total``  turns that ended after their
+  prefill launch to hand first tokens to their streams; the next turn
+  runs the decode they owe
 
 Cache signals:
 - ``serving_tokens_total{kind=prefill|prefix_hit|decode}``
@@ -489,6 +492,11 @@ class ServerTelemetry:
             labelnames=("kind",))
         self._c_push_tok = pushes.labels(kind="token")
         self._c_push_pos = pushes.labels(kind="position")
+        self._c_handover = r.counter(
+            "serving_handover_turns_total",
+            "Turns that ended after their prefill launch to hand first "
+            "tokens to their streams; the decode they owe runs in the "
+            "next turn")
         grid = r.counter(
             "serving_decode_grid_total",
             "The paged decode kernel's grid, a layer at a time: the "
@@ -793,6 +801,11 @@ class ServerTelemetry:
         if self.enabled:
             self._c_push_tok.inc(tokens)
             self._c_push_pos.inc(positions)
+
+    def on_handover(self):
+        """A turn ended after its launch to hand first tokens over."""
+        if self.enabled:
+            self._c_handover.inc()
 
     def on_decode_grid(self, steps, live_pages):
         """One decode tick's kernel grid, over its layers: the steps

@@ -261,9 +261,10 @@ class TestSupervisedLoop:
         srv.stop()
 
     def test_breaker_open_drops_stale_stream_chunks(self):
-        """Review regression: chunks deferred by the tick that tripped
-        the breaker must not fire after recovery — their requests
-        already failed with CircuitOpenError."""
+        """Regression: no chunk of a request that failed with
+        CircuitOpenError fires after recovery. Its first token was
+        handed over before the decode that kept failing; nothing
+        after it reaches the stream."""
         fcb = FakeClock()
         fi = FaultInjector().on(faults.DECODE_TICK, schedule=[0, 1, 2])
         seen = []
@@ -281,9 +282,48 @@ class TestSupervisedLoop:
                           on_token=lambda r, t: seen.append(r))
         np.testing.assert_array_equal(srv.wait(rid2, timeout=60),
                                       stub_tokens(p, 3))
-        assert rid not in seen, "stale chunk for a failed request fired"
+        assert seen.count(rid) == 1, \
+            "stale chunk for a failed request fired"
         assert rid2 in seen
         srv.stop()
+
+    def test_breaker_open_drops_chunks_queued_by_failing_ticks(self):
+        """Regression: a chunk a failing tick queued before it
+        raised (here: one for the live request, queued at the decode
+        fault point) is dropped when the breaker opens — it never fires
+        on the recovered server."""
+        fcb = FakeClock()
+        seen = []
+        on_token = lambda r, t: seen.append((r, t.tolist()))  # noqa: E731
+
+        def fault():
+            # runs inside the failing tick, under the server's lock
+            st = next(s for s in srv._slots if s is not None)
+            srv._deferred_cbs.append(
+                (on_token, st.rid, np.asarray([-7], np.int32)))
+            return faults.InjectedFault(faults.DECODE_TICK, -1)
+
+        fi = FaultInjector().on(faults.DECODE_TICK, schedule=[0, 1, 2],
+                                error=fault)
+        srv = _srv(retry_policy=_fast_retry(),
+                   breaker=CircuitBreaker(failure_threshold=3,
+                                          reset_after_s=5.0, clock=fcb),
+                   fault_injector=fi).start()
+        rid = srv.submit(_prompt(1, 2), max_new_tokens=4,
+                         on_token=on_token)
+        with pytest.raises(CircuitOpenError):
+            srv.wait(rid, timeout=60)
+        fcb.advance(6.0)                       # cooldown -> probe OK
+        p = _prompt(3, 4)
+        rid2 = srv.submit(p, max_new_tokens=3, on_token=on_token)
+        np.testing.assert_array_equal(srv.wait(rid2, timeout=60),
+                                      stub_tokens(p, 3))
+        srv.stop()
+        assert fi.fired(faults.DECODE_TICK) == 3
+        assert [t for r, t in seen if r == rid] == [
+            stub_tokens(_prompt(1, 2), 1).tolist()], \
+            "a chunk queued by a failed tick fired after recovery"
+        assert any(r == rid2 for r, _ in seen)
 
     def test_wait_raises_typed_errors_directly(self):
         srv = _srv(max_slots=1, max_queue=1, max_cache_len=8192,
