@@ -305,13 +305,21 @@ def peak_live_pages(requests, page_size):
     return peak
 
 
+def weights_seed(ctx):
+    """The seed the weights are drawn from: the run's own, or the one the
+    configuration fixes for every run (``assumed.weights_seed``) where the
+    weights would change the work, as a seeded router's choices change how
+    many experts a tick reads. ``--seed`` still draws every token."""
+    return int(ctx.config.get("assumed", {}).get("weights_seed", ctx.seed))
+
+
 def prepare(ctx):
     """Set-up of a serving run: model, server, both warm-up passes."""
     import jax
     family, config, mix = ctx.family, ctx.config, ctx.mix
     vocab = family.vocab(config, ctx.rehearse)
     with ctx.phase("model"):
-        model = family.build_model(config, ctx.seed, ctx.rehearse)
+        model = family.build_model(config, weights_seed(ctx), ctx.rehearse)
         jax.block_until_ready(model.raw_params())
     with ctx.phase("server"):
         srv = build_server(ctx, model)

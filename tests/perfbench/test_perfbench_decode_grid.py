@@ -42,10 +42,13 @@ def test_decode_grid_live_share_reader(obs, want):
 
 
 def test_manifest_entry_names_the_chat_cell():
+    """Found by its name; a later cell may join its list."""
     entry = {m["name"]: m for m in harness.load_manifest()["per_layer"]}[NAME]
-    assert entry == {"name": NAME, "unit": "%", "better": "higher",
-                     "source": "program_counter", "layer": "kernels",
-                     "moves": "itl_p95_ms", "workloads": [CELL]}
+    assert {k: v for k, v in entry.items() if k != "workloads"} == {
+        "name": NAME, "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "kernels",
+        "moves": "itl_p95_ms"}
+    assert CELL in entry["workloads"]
 
 
 def test_rehearsed_chat_cell_prints_the_share():

@@ -132,9 +132,8 @@ def test_cell_reports_what_the_issue_lists(manifest):
             "copy_share.longctx", "mosaic_share.longctx",
             "device_idle.longctx", "gen_lag_p95_ms", "ttft_p90_ms",
             "queue_wait_p90_ms", "preempt_per_100req",
-            # PR 25's five are pinned to the chat cell by its own test
-            # (tests/perfbench/test_perfbench_tick_metrics.py), so this
-            # cell reads them under entries of its own, same readers
+            # the five tick entries list the chat cell alone, so this cell
+            # reads them under entries of its own, same readers
             "decode_tick_ms.longctx", "prefill_tick_ms.longctx",
             "tick_host_share.longctx", "admit_ms_per_req.longctx",
             "submit_lock_wait_ms.longctx"} <= set(layer)

@@ -5,11 +5,16 @@ new readers on hand-made observations, and a rehearsal of the cell on the CPU
 (tiny widths, float32) with and without ``--trace 1``.
 
 The three readers have NO entry in ``BENCHMARK.json`` yet (``PENDING`` below):
-a PR that changes the program may only append to ``per_layer``, and
-``test_perfbench_prefill_grid.py`` holds ``prefill_grid_live_share`` in last
-place, so the entries wait for a ``benchmark`` PR that lifts that pin. Until
-then the cell reads the shared readers through the lists of the ``.assist``
-entries, and the last test here runs the three in a copy of the benchmark."""
+an entry for this cell has it measured anew, so the entries wait for a
+``benchmark`` PR that measures it so. The cell's weights are one draw for every
+seed (``assumed.weights_seed``) and its ``ttft_p75_ms`` is recorded per layer,
+not judged: that percentile sits on the step between requests served at once
+and those behind a launch. Until then the cell reads the
+shared readers through the lists of the ``.assist`` entries, and the last test
+here runs the three in a copy of the benchmark. No test here pins a place in
+``per_layer`` or in a ``workloads`` list: entries are found by name, and this
+cell is held as a member of the lists it reads, so a later PR appends its
+entries and its cell without editing this file."""
 import json
 import os
 import shutil
@@ -30,7 +35,7 @@ PATTERN88 = ("MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
              "EMEMEMEMEM*EMEMEMEM*EMEMEMEME")
 SOURCE = ("https://huggingface.co/nvidia/NVIDIA-Nemotron-3-Super-120B-A12B-"
           "BF16/blob/main/config.json")
-# what a ``benchmark`` PR appends to ``per_layer`` once the last place is free
+# what a ``benchmark`` PR appends to ``per_layer`` once the cell is steady
 PENDING = [
     {"name": "hbm_roofline_nemotron_h.decode", "unit": "%",
      "better": "higher", "source": "device_trace", "layer": "tick programs",
@@ -108,6 +113,24 @@ def test_depth_experts_held_and_vocabulary_are_reduced(manifest, config):
         assert assumed[key]
 
 
+def test_weights_are_one_draw_for_every_seed(config):
+    """The configuration fixes the seed of its weights, so every ``--seed``
+    serves the same model and only the tokens differ; a configuration that
+    fixes none draws its weights from the run's seed."""
+    from types import SimpleNamespace
+
+    from perfbench import serving
+    assert config["assumed"]["weights_seed"] == 0
+    assert "--seed" in config["assumed"]["weights_seed_why"]
+    for seed in (0, 7, 2 ** 31 + 7, 2 ** 40 + 3):
+        assert serving.weights_seed(SimpleNamespace(
+            config=config, seed=seed)) == 0
+    gpt2 = harness.load_config(harness.load_manifest(), "gpt2-medium")
+    assert "weights_seed" not in gpt2.get("assumed", {})
+    assert serving.weights_seed(SimpleNamespace(config=gpt2, seed=2 ** 31 + 7)
+                                ) == 2 ** 31 + 7
+
+
 def test_program_config_is_the_files(config):
     cfg = nemotron_h.program_config(config)
     assert cfg.num_hidden_layers == 11 and cfg.dtype == "bfloat16"
@@ -145,7 +168,7 @@ def test_mix_holds_the_issues_parameters(manifest):
     assert knee["share"] == 0.8
     assert mix["arrivals"]["rate_per_s"] == pytest.approx(
         0.8 * knee["rate_per_s"], rel=1e-9)
-    # ttft_p75_ms is judged only where ten requests lie beyond it
+    # a percentile as high as the 75th keeps ten requests beyond it
     assert round(mix["arrivals"]["rate_per_s"] * manifest["run_seconds"]) \
         >= 40
     # the longest request fits a slot's table
@@ -158,7 +181,10 @@ def test_cell_reports_what_the_issue_lists(manifest):
         CONFIG, "reason-steady", 1)
     e2e = {m["name"] for m in harness.cell_metrics(manifest, "end_to_end",
                                                    CELL)}
-    assert {"ttft_p50_ms", "ttft_p75_ms", "setup_s"} <= e2e
+    assert {"ttft_p50_ms", "itl_p95_ms", "setup_s"} <= e2e
+    # the 75th percentile sits on the step between requests served at once
+    # and those behind a launch: recorded per layer, not judged
+    assert "ttft_p75_ms" not in e2e
     layer = {m["name"]: m for m in harness.cell_metrics(manifest,
                                                         "per_layer", CELL)}
     assert {"decode_tick_ms.assist", "prefill_tick_ms.assist",
@@ -168,16 +194,15 @@ def test_cell_reports_what_the_issue_lists(manifest):
             "decode_grid_live_share.assist",
             "gen_lag_p95_ms", "ttft_p90_ms", "queue_wait_p90_ms",
             "preempt_per_100req", "moe_pad_row_share",
-            "state_carry_share"} == set(layer)
+            "state_carry_share", "ttft_p75_ms.reason"} == set(layer)
     # a per-layer metric moves an end-to-end metric its cell reports
     assert {m["moves"] for m in layer.values()} <= e2e
-    # no entry of this PR in ``per_layer``: the cell's name is appended to
-    # lists that were there, and the list's last entry stays last
+    # no pending entry in ``per_layer`` yet, and every entry the cell reads
+    # names it in a list of its own: a member, wherever a later cell joins
     names = [m["name"] for m in manifest["per_layer"]]
-    assert names[-1] == "prefill_grid_live_share"
     assert not {m["name"] for m in PENDING} & set(names)
     for m in layer.values():
-        assert m["workloads"][-1] == CELL
+        assert CELL in m["workloads"]
     for m in PENDING:
         assert harness.load_module("layer_metrics", m["name"]).read
         assert m["moves"] in e2e
@@ -295,7 +320,7 @@ def test_hbm_roofline_reader(config):
 def test_rehearse_end_to_end():
     line, text = rehearse(ROOT, CELL, 0)
     check_contract(line, CELL, "end_to_end",
-                   ["ttft_p50_ms", "ttft_p75_ms", "setup_s"])
+                   ["ttft_p50_ms", "itl_p95_ms", "setup_s"])
     assert "the second pass built 0 executables" in text
     assert "its argmax" in text
 
@@ -305,7 +330,8 @@ def test_rehearse_traced():
     check_contract(line, CELL, "per_layer",
                    ["gen_lag_p95_ms", "queue_wait_p90_ms",
                     "tick_host_share.assist", "admit_ms_per_req.assist",
-                    "moe_pad_row_share", "state_carry_share"])
+                    "moe_pad_row_share", "state_carry_share",
+                    "ttft_p75_ms.reason"])
     assert 0.0 <= line["metrics"]["state_carry_share"]["value"] < 100.0
     assert "decode_tick_ms.assist: nothing to read, left out" in text
 
