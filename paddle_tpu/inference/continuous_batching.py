@@ -482,21 +482,33 @@ class ContinuousBatchingServer:
             # the paged kernels' grid covers the FULL block-table width
             # per slot — the goodput ledger's skipped-page-DMA model
             self._bt_pages = pages_per_slot
+            # a model with WINDOW layers holds them in a ring of pages a
+            # slot, sized by the most rows one row of a launch writes:
+            # the launch width, the power of two over the token budget
+            from ..models.generation import attention_window, ring_pages
+            self._window = attention_window(getattr(model, "cfg", None))
+            ring_kw = {}
+            if self._window is not None:
+                ring_kw["window_rows"] = 1 << (int(
+                    prefill_tokens_per_tick or max_cache_len) - 1
+                ).bit_length()
             self._paged_bundle = model._decode_bundle(
                 max_cache_len, weight_dtype, mesh, cache_dtype,
                 cache_backend="paged", page_size=page_size,
-                num_pages=int(num_pages))
+                num_pages=int(num_pages), **ring_kw)
             self._step_fn = self._paged_bundle[2]
-            self._kv = PagedKVCache(int(num_pages), page_size,
-                                    self.max_slots, pages_per_slot,
-                                    fault_injector=fault_injector)
+            self._kv = PagedKVCache(
+                int(num_pages), page_size, self.max_slots, pages_per_slot,
+                fault_injector=fault_injector,
+                ring=None if self._window is None else ring_pages(
+                    self._window, ring_kw["window_rows"], page_size))
             self._caches = self._paged_bundle[0](self.max_slots)
             # per-slot recurrent state beside the pool (a short
             # convolution's last inputs, a state-space layer's window
             # and float32 state: a tree of leaves): carried and donated
             # like the pool, addressed by the slot — no page holds it
             self._slot_state = "state" in self._caches
-            if self._slot_state:
+            if self._slot_state or self._window is not None:
                 auto_prefix_cache = self._refuse_for_slot_state(
                     auto_prefix_cache, admission, host_tier,
                     host_tier_bytes, prefill_mode)
@@ -569,6 +581,7 @@ class ContinuousBatchingServer:
             self._pool_shards = 1
             self._caches = self._init_caches(self.max_slots)
             self._slot_state = False    # dense: state rides the slot's rows
+            self._window = None         # dense: a window is a mask
             self._prefix = None
             self._auto_prefix = False
             self._ragged_fn = None
@@ -746,6 +759,12 @@ class ContinuousBatchingServer:
                       # model holds (a share of the router's, or all)
                       "moe_pairs_routed": 0, "moe_pairs_held": 0,
                       "attn_keys_context": 0, "attn_keys_selected": 0,
+                      # keys in the contexts of live decode rows in the
+                      # WINDOW layers, and those their windows attend
+                      # (paged_attention.window_start, which bounds the
+                      # window layers' grid), summed over those layers
+                      "attn_keys_window_context": 0,
+                      "attn_keys_window_attended": 0,
                       # slot rows the decode program merged from the
                       # host's push bundle, and the decode dispatches
                       # whose bundle set any
@@ -767,6 +786,10 @@ class ContinuousBatchingServer:
         self._n_layers = (int(self._caches["pool"]["k"].shape[0])
                           if self._kv is not None
                           else int(getattr(cfg, "num_layers", 0) or 0))
+        # the WINDOW layers, which the ring holds (paged; 0 otherwise)
+        self._n_window = (int(self._caches["ring"]["k"].shape[0])
+                          if self._kv is not None and "ring" in self._caches
+                          else 0)
         # telemetry (paddle_tpu.telemetry.ServerTelemetry): True builds
         # a default-enabled one; None (default) keeps the hot path at
         # a single attribute check — no locks, no clock reads
@@ -911,8 +934,9 @@ class ContinuousBatchingServer:
 
     def _refuse_for_slot_state(self, auto_prefix_cache, admission,
                                host_tier, host_tier_bytes, prefill_mode):
-        """Construction-time refusals for a model with slot state; the
-        resolved ``auto_prefix_cache`` (None reads as False here)."""
+        """Construction-time refusals for a model with slot state or
+        window layers; the resolved ``auto_prefix_cache`` (None reads
+        as False here)."""
         asked = [what for what, on in (
             ("auto_prefix_cache=True (a prefix hit resumes at a page "
              "boundary)", bool(auto_prefix_cache)),
@@ -925,8 +949,28 @@ class ContinuousBatchingServer:
              "batch-1 prefill hands the slot its pages only)",
              prefill_mode == "dense")) if on]
         if asked:
-            self._refuse_slot_state("; ".join(asked))
+            self._refuse_page_moves("; ".join(asked))
         return False
+
+    def _refuse_page_moves(self, what):
+        """Refuse ``what`` for a model whose pages are not a request's
+        whole past: slot state beside them, or window layers' rings."""
+        self._refuse_slot_state(what)
+        self._refuse_ring(what)
+
+    def _refuse_ring(self, what):
+        """THE refusal of whatever assumes that a slot's pages hold its
+        whole past, for a model whose WINDOW layers hold a ring of pages
+        a slot that recycles its pages."""
+        if self._window is not None:
+            raise NotImplementedError(
+                "this model's window layers (sliding_attention, window "
+                f"{self._window}) keep each slot's keys in a ring of pages "
+                "that recycles them, beside the page pool, and "
+                f"{what} assumes that a slot's pages hold its whole past: "
+                "the ring's keys would be served overwritten, or not at "
+                "all. What a donated window-layer prefix means is ROADMAP "
+                "B3")
 
     def _refuse_slot_state(self, what):
         """THE refusal of whatever assumes that pages are a request's
@@ -954,7 +998,7 @@ class ContinuousBatchingServer:
         donating decode program). Paged backend: full pages the auto
         prefix cache already holds for these tokens are adopted (and
         pinned) rather than re-allocated."""
-        self._refuse_slot_state("register_prefix (a prefix hit resumes "
+        self._refuse_page_moves("register_prefix (a prefix hit resumes "
                                 "at a page boundary)")
         ids = np.asarray(unwrap(prefix_ids)).astype(np.int32).reshape(-1)
         T = ids.shape[0]
@@ -3142,20 +3186,34 @@ class ContinuousBatchingServer:
         the block's first step (a parked slot has length 0 and no
         page); step ``j`` attends ``lens + j``, or nothing once that
         passes the table's span (the sentinel). Counted by
-        ``decode_grid``, which sizes the grid on the device."""
-        from ..ops.pallas.paged_attention import decode_grid
-        steps = live = 0
+        ``decode_grid``, which sizes the grid on the device; a model's
+        WINDOW layers count their own grid, from the window's first
+        page, and the keys their rows hold and attend
+        (``window_start``, which bounds that grid)."""
+        from ..ops.pallas.paged_attention import decode_grid, window_start
+        steps = live = ctx = seen = 0
         for j in range(block):
             at = lens + j
-            pages, n = decode_grid(np.where(at <= self.max_cache_len, at, 0),
-                                   self.page_size)
-            steps += int(n)
-            live += int(pages.sum())
-        steps, live = steps * self._n_layers, live * self._n_layers
+            at = np.where(at <= self.max_cache_len, at, 0)
+            pages, n = decode_grid(at, self.page_size)
+            steps += int(n) * self._n_layers
+            live += int(pages.sum()) * self._n_layers
+            if self._n_window:
+                pages, n = decode_grid(at, self.page_size, self._window)
+                steps += int(n) * self._n_window
+                live += int(pages.sum()) * self._n_window
+                ctx += int(at.sum()) * self._n_window
+                seen += int((at - window_start(at, self._window)).sum()
+                            ) * self._n_window
         self.stats["decode_grid_steps"] += steps
         self.stats["decode_live_pages"] += live
         if self._tele is not None:
             self._tele.on_decode_grid(steps, live)
+        if ctx:
+            self.stats["attn_keys_window_context"] += ctx
+            self.stats["attn_keys_window_attended"] += seen
+            if self._tele is not None:
+                self._tele.on_window_keys(ctx, seen)
 
     def _count_prefill_grid(self, t0, takes, width):
         """Prefill-kernel accounting of one launch: the steps its grid
@@ -3170,6 +3228,13 @@ class ContinuousBatchingServer:
                                     self.max_cache_len // self.page_size)
         steps = int(steps) * self._n_layers
         live = int(pages.sum()) * self._n_layers
+        if self._n_window:      # the window layers' own grid
+            pages, n = prefill_grid(t0, takes, width, QUERY_TILE,
+                                    self.page_size,
+                                    self.max_cache_len // self.page_size,
+                                    self._window)
+            steps += int(n) * self._n_window
+            live += int(pages.sum()) * self._n_window
         self.stats["prefill_grid_steps"] += steps
         self.stats["prefill_live_steps"] += live
         if self._tele is not None:
@@ -3901,7 +3966,7 @@ class ContinuousBatchingServer:
         flight); an injected ``migrate.gather`` fault fires BEFORE the
         pause, so a faulted attempt leaves the slot untouched — never
         a leak."""
-        self._refuse_slot_state("migration (send_pages ships a "
+        self._refuse_page_moves("migration (send_pages ships a "
                                 "request's pages and nothing else)")
         from .kv_tier import _sha256
         with self._lock:
@@ -4262,7 +4327,7 @@ class ContinuousBatchingServer:
         free slot / pool exhausted) propagates from the admit; a
         scatter failure rolls the fresh pages back. The source aborts
         and the caller replays — never a request failure."""
-        self._refuse_slot_state("migration (send_pages ships a "
+        self._refuse_page_moves("migration (send_pages ships a "
                                 "request's pages and nothing else)")
         from .kv_tier import _sha256
         with self._lock:
@@ -4349,7 +4414,7 @@ class ContinuousBatchingServer:
         ``in_flight`` (it holds real pool pages) but never ticks: it
         is not active, not on the prefill fifo, and has no deadline
         until commit."""
-        self._refuse_slot_state("migration (send_pages ships a "
+        self._refuse_page_moves("migration (send_pages ships a "
                                 "request's pages and nothing else)")
         with self._lock:
             if self._kv is None:

@@ -44,10 +44,16 @@ class PagedKVCache:
     mirror handed to the decode program (rows are page ids in position
     order; unused entries hold ``NULL_PAGE``). ``dirty`` flags that the
     device copy needs a refresh.
+
+    ``ring``: the pages a slot's RING holds in each window layer of a
+    model that has them (``generation.ring_pages``). Those pages are a
+    fixed ring a slot beside this pool, which the allocator never hands
+    out; the pool's reports count, by layer type, what each slot's pages
+    span of its ring (at most ``ring`` whatever the context).
     """
 
     def __init__(self, num_pages, page_size, max_slots, pages_per_slot,
-                 fault_injector=None):
+                 fault_injector=None, ring=None):
         if page_size < 1 or pages_per_slot < 1:
             raise ValueError("page_size and pages_per_slot must be >= 1")
         if num_pages < 2:
@@ -79,6 +85,7 @@ class PagedKVCache:
         self.freed_total = 0       # pages returned (refcount hit 0)
         self.shared_ref_total = 0  # extra refs taken on shared pages
         self.grown_total = 0       # pages appended mid-decode (grow_slot)
+        self.ring = None if ring is None else int(ring)
 
     # ------------------------------------------------------- allocation
     def _npages(self, n_tokens):
@@ -237,6 +244,8 @@ class PagedKVCache:
                "slots": {s: {"pages": len(p),
                              "shared": self._slot_shared[s]}
                          for s, p in enumerate(self._slot_pages) if p}}
+        if self.ring is not None:
+            occ["pages_by_layer_type"] = self.pages_by_layer_type()
         if num_shards > 1:
             occ["shards"] = [{"shard": i,
                               "free_pages": occ["free_pages"],
@@ -248,17 +257,30 @@ class PagedKVCache:
                                     else host_tier)
         return occ
 
+    def pages_by_layer_type(self):
+        """Pages held a layer, by layer type: a full layer's are the
+        pool's used pages; a window layer's are what the slots' pages
+        span of their rings, at most ``ring`` a slot whatever the
+        context. Only for a pool that has rings."""
+        return {"full_attention": self.used_pages(),
+                "sliding_attention": sum(min(len(p), self.ring)
+                                         for p in self._slot_pages),
+                "ring_pages_per_slot": self.ring}
+
     def telemetry_stats(self):
         """Point-in-time pool state + cumulative churn, plain data —
         the ``/stats`` payload and the page-pool gauges source."""
-        return {"num_pages": self.num_pages,
-                "page_size": self.page_size,
-                "free_pages": self.free_pages(),
-                "used_pages": self.used_pages(),
-                "alloc_total": self.alloc_total,
-                "freed_total": self.freed_total,
-                "shared_ref_total": self.shared_ref_total,
-                "grown_total": self.grown_total}
+        stats = {"num_pages": self.num_pages,
+                 "page_size": self.page_size,
+                 "free_pages": self.free_pages(),
+                 "used_pages": self.used_pages(),
+                 "alloc_total": self.alloc_total,
+                 "freed_total": self.freed_total,
+                 "shared_ref_total": self.shared_ref_total,
+                 "grown_total": self.grown_total}
+        if self.ring is not None:
+            stats["pages_by_layer_type"] = self.pages_by_layer_type()
+        return stats
 
     @staticmethod
     def paged_hbm_bytes(num_pages, page_size, layers, kv_heads, head_dim,

@@ -151,10 +151,12 @@ def _mesh_paged_caches(init_caches, mesh, kv_heads, axis="mp"):
 
     def init(batch):
         tree = init_caches(batch)
+        # the page pool and a window model's ring pool shard alike
+        pools = [n for n in ("pool", "ring") if n in tree]
         return dict({n: jax.device_put(a, rep) for n, a in tree.items()
-                     if n != "pool"},
-                    pool={n: jax.device_put(a, sh)
-                          for n, a in tree["pool"].items()})
+                     if n not in pools},
+                    **{n: {m: jax.device_put(a, sh)
+                           for m, a in tree[n].items()} for n in pools})
 
     return init
 
@@ -191,15 +193,21 @@ def _positions(t, b, s):
     return t[:, None] + row[None, :]
 
 
-def _cached_attend(q, k_cache, v_cache, t, s, scale):
+def _cached_attend(q, k_cache, v_cache, t, s, scale, window=None):
     """q [B,s,nh,hd] at positions [t, t+s); caches [B,T,nh,hd] already
     updated through t+s. Masks unwritten/future slots: key position p is
-    visible to query row r iff p <= t+r. ``t`` scalar or [B]."""
+    visible to query row r iff p <= t+r (and, in a ``window`` layer, p
+    >= ``window_start(t+r+1, window)``: the window is a mask over the
+    whole cache here). ``t`` scalar or [B]."""
+    from ..ops.pallas.paged_attention import window_start
     T = k_cache.shape[1]
     logits = jnp.einsum("bsnd,btnd->bnst", q, k_cache) * scale
     pos = jnp.arange(T)
     row = _positions(t, q.shape[0], s)                   # [B, s]
     ok = pos[None, None] <= row[:, :, None]              # [B, s, T]
+    if window is not None:
+        ok = ok & (pos[None, None]
+                   >= window_start(row + 1, window)[..., None])
     logits = jnp.where(ok[:, None], logits.astype(jnp.float32), -1e30)
     p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bnst,btnd->bsnd", p, v_cache)
@@ -325,9 +333,28 @@ def paged_kv_heads(cfg):
     return int(getattr(cfg, "num_kv_heads", None) or cfg.num_heads)
 
 
+def ring_pages(window, rows, page_size):
+    """Pages of the ring a WINDOW layer holds a slot: enough that no
+    launch overwrites a key one of its own queries still needs. A row of
+    a launch writes at most ``rows`` positions [t0, t0 + rows) and its
+    first query sees back to ``t0 - window + 1``, so the pages from that
+    key's to the last written one, ``ceil((window + rows) / page) + 1``
+    at most, must be distinct entries of the ring."""
+    return -(-(int(window) + int(rows)) // int(page_size)) + 1
+
+
+def ring_table(batch, ring, pages_per_slot):
+    """The block table a window layer reads through: slot ``b``'s
+    logical page ``p`` is its ring's entry ``p mod ring``, page ``1 + b
+    * ring + p mod ring`` of the ring pool (page 0 is the null page).
+    Fixed, so it is made once with the cache tree and never synced."""
+    return (1 + np.arange(batch, dtype=np.int32)[:, None] * ring
+            + np.arange(pages_per_slot, dtype=np.int32)[None] % ring)
+
+
 def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
                    kvh, hd, dtype, extra=None, route_k=None, kept=False,
-                   route_layers=None, state=None):
+                   route_layers=None, state=None, ring=None):
     """Paged decode cache tree: one global page pool over the model's
     ATTENTION layers (``paged_pool_shape``; ``layers`` is their count,
     which is every layer unless the model has a layer spec) plus the
@@ -352,7 +379,17 @@ def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
     convolution window and its float32 recurrent state). It is
     addressed by the slot and NOT by the block table: no page holds it,
     so nothing that moves pages (prefix sharing, spill, migration)
-    moves it, and the server refuses those for a model that has it."""
+    moves it, and the server refuses those for a model that has it.
+
+    ``ring`` (``(layers, pages)``: the model's WINDOW layers and the
+    pages of a slot's ring, ``ring_pages``) adds the window layers'
+    own pool ``ring`` ``{"k", "v"}`` ``[layers, batch * pages + 1,
+    page_size, kvh * hd]`` and its fixed table ``rbt`` (``ring_table``):
+    a window layer holds ``pages`` pages a slot whatever the context,
+    and position ``p`` overwrites the key of position ``p - pages *
+    page_size``. The allocator never sees these pages, so nothing that
+    moves pages can move them, and the server refuses those for a model
+    that has them."""
     def leaf(heads, dim):
         return jnp.zeros(paged_pool_shape(layers, num_pages, page_size,
                                           heads, dim), dtype)
@@ -369,6 +406,12 @@ def _init_paged_kv(batch, layers, num_pages, page_size, pages_per_slot,
         tree["kept"] = jnp.zeros((layers, batch), jnp.int32)
     if state is not None:
         tree["state"] = _slot_state(state, batch)
+    if ring is not None:
+        n, pages = ring
+        shape = paged_pool_shape(n, batch * pages + 1, page_size, kvh, hd)
+        tree["ring"] = {"k": jnp.zeros(shape, dtype),
+                        "v": jnp.zeros(shape, dtype)}
+        tree["rbt"] = jnp.asarray(ring_table(batch, pages, pages_per_slot))
     return tree
 
 
@@ -381,7 +424,7 @@ def _slot_state(state, batch):
         state)
 
 
-def _paged_decode_plan(bt, t, page_size):
+def _paged_decode_plan(bt, t, page_size, window=None):
     """What every layer of one paged decode step attends, made ONCE a
     step outside the layer loop: ``(lengths, schedule)``. ``lengths``
     [B] are the valid tokens t+1 (cache written through t); a slot
@@ -389,17 +432,19 @@ def _paged_decode_plan(bt, t, page_size):
     extent: it holds no decoding request) gets length 0, as
     ``_paged_prefill_attend`` hands it ``last = -1``. ``schedule`` is
     the decode kernel's grid made of those lengths (``decode_schedule``:
-    one step a live page), which the kernel reads by scalar prefetch
-    beside the block table."""
+    one step a live page, from the window's first page in a ``window``
+    layer), which the kernel reads by scalar prefetch beside the block
+    table."""
     from ..ops.pallas.paged_attention import decode_schedule
     if jnp.ndim(t) == 0:
         t = jnp.full((bt.shape[0],), t, jnp.int32)
     limit = bt.shape[1] * page_size                # tokens a table spans
     lengths = jnp.where(t < limit, t + 1, jnp.int32(0))
-    return lengths, decode_schedule(lengths, page_size, bt.shape[1])
+    return lengths, decode_schedule(lengths, page_size, bt.shape[1],
+                                    window)
 
 
-def _paged_prefill_plan(bt, t, take, width, page_size):
+def _paged_prefill_plan(bt, t, take, width, page_size, window=None):
     """What every layer of one ragged prefill launch attends, made ONCE
     a launch outside the layer loop: ``(take, schedule)``. ``take`` [B]
     are the REAL rows of each slot's ``width``-row chunk (None: every
@@ -416,10 +461,11 @@ def _paged_prefill_plan(bt, t, take, width, page_size):
     take = (jnp.full((b,), width, jnp.int32) if take is None
             else take.astype(jnp.int32))
     return take, prefill_schedule(t, take, width, QUERY_TILE, page_size,
-                                  bt.shape[1])
+                                  bt.shape[1], window)
 
 
-def _paged_attend(q, pool, layer, bt, t, scale, mesh=None, plan=None):
+def _paged_attend(q, pool, layer, bt, t, scale, mesh=None, plan=None,
+                  window=None):
     """Decode-step attention through the block table: q [B, 1, nh, hd],
     ``pool`` the whole K/V pools ``{"k", "v"}`` [L, P, pg, kvh*hd] read
     at ``layer``, over ``plan`` (``_paged_decode_plan(bt, t, pg)``, which
@@ -439,7 +485,7 @@ def _paged_attend(q, pool, layer, bt, t, scale, mesh=None, plan=None):
     lengths, schedule = plan
     return paged_attention(q[:, 0], pool["k"], pool["v"], bt, lengths,
                            scale, mesh=mesh, layer=layer,
-                           schedule=schedule)[:, None]
+                           schedule=schedule, window=window)[:, None]
 
 
 def _page_write(pool, layer, kv, bt, t):
@@ -482,7 +528,7 @@ def _page_write(pool, layer, kv, bt, t):
 
 
 def _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=None,
-                          plan=None):
+                          plan=None, window=None):
     """Ragged packed-prefill attention through the block table: q
     [B, s, nh, hd] chunk rows starting at per-slot offsets ``t``,
     ``pool`` the whole K/V pools read at ``layer``, over ``plan``
@@ -505,11 +551,12 @@ def _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=None,
     take, schedule = plan
     return ragged_prefill_attention(q, pool["k"], pool["v"], bt, t,
                                     take=take, sm_scale=scale, mesh=mesh,
-                                    layer=layer, schedule=schedule)
+                                    layer=layer, schedule=schedule,
+                                    window=window)
 
 
 def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
-                   select=None, plan=None):
+                   select=None, plan=None, window=None):
     """One layer's page write and attention over the CARRIED pools
     [L, P, pg, lanes]: the new rows k/v [B, s, kvh, hd] land in layer
     ``layer``'s pages through the block table, then q [B, s, nh, hd]
@@ -527,7 +574,12 @@ def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
     indexer ranks highest (``ops/key_selection.py``, one composition
     for decode rows and prefill chunks). Returns ``(att [B, s, nh,
     hd], pool, kept)``: ``kept`` [B, s] is the number of keys the
-    selection let each row attend (None without ``select``)."""
+    selection let each row attend (None without ``select``).
+
+    ``window``: a WINDOW layer, whose pool is its ring and ``bt`` the
+    ring's table: a row sees the keys of its window only, and both
+    kernels start their runs at the window's first page (``plan`` is
+    the window layers' own)."""
     rows = {"k": k, "v": v}
     if select is not None:
         rows["ki"] = select[2]
@@ -542,37 +594,53 @@ def _paged_kv_step(pool, layer, q, k, v, bt, t, scale, mesh=None,
                                            layer, bt, t, select[3], scale)
     elif q.shape[1] > 1:
         att = _paged_prefill_attend(q, pool, layer, bt, t, scale, mesh=mesh,
-                                    plan=plan)
+                                    plan=plan, window=window)
     else:
         att = _paged_attend(q, pool, layer, bt, t, scale, mesh=mesh,
-                            plan=plan)
+                            plan=plan, window=window)
     return att, pool, kept
 
 
 # which stack of a layer-spec model a bundle leaf belongs to: the norms
 # are stacked over every layer, the rest over the layers of their kind
 _LEAF_KIND = dict(
-    {n: "layer" for n in ("ln1", "ln2")},
-    **{n: "attn" for n in ("wq", "wk", "wv", "wo", "qn", "kn")},
+    {n: "layer" for n in ("ln1", "ln2", "pa", "pm")},
+    **{n: "attn" for n in ("wq", "wk", "wv", "wo", "qn", "kn", "ag")},
     **{n: "conv" for n in ("ci", "cw", "co")},
     **{n: "ssm" for n in ("si", "sw", "sb", "sa", "sd", "st", "sn", "so")},
     **{n: "dense" for n in ("dg", "du", "dd")},
-    **{n: "moe" for n in ("router", "rbias", "ld", "lu", "s1", "s2")})
+    **{n: "moe" for n in ("router", "rbias", "ld", "lu", "s1", "s2",
+                          "xg", "xu", "xd")})
+
+
+def attention_window(cfg):
+    """The window of a model that has WINDOW layers (``layer_types``
+    names ``"sliding_attention"``; ``sliding_window`` keys): a query at
+    position ``p`` sees key ``j`` iff ``p - j < window``. None for a
+    model whose every attention layer sees the whole context."""
+    types = getattr(cfg, "layer_types", None) or ()
+    if "sliding_attention" not in types:
+        return None
+    return int(cfg.sliding_window)
 
 
 def _layer_spec(cfg):
     """A model whose layers are NOT alike says so in its config
-    (``layer_types``: each layer's mixer, ``"full_attention"`` or
-    ``"conv"``; ``num_dense_layers``: how many leading layers keep a
-    dense FFN in an expert model; or ``sublayers``: a layer is ONE
-    sublayer behind one norm, a mixer OR an FFN, named by the stack it
-    reads: ``"ssm"``, ``"attn"``, ``"moe"``). Returns None for a model
-    of identical layers, else one dict a layer: the layer's index within
-    each stack it reads (``_LEAF_KIND``) — ``{"layer": l, "attn" |
-    "conv": i, "dense" | "moe": j}``, or ``{"layer": l, "ssm" | "attn" |
-    "moe": i}``. ``attn`` is also the layer's index in the page pool,
-    ``conv`` and ``ssm`` in the slot state, ``moe`` in the expert
-    stacks."""
+    (``layer_types``: each layer's mixer, ``"full_attention"``,
+    ``"sliding_attention"`` or ``"conv"``; ``num_dense_layers``: how
+    many leading layers keep a dense FFN in an expert model; or
+    ``sublayers``: a layer is ONE sublayer behind one norm, a mixer OR
+    an FFN, named by the stack it reads: ``"ssm"``, ``"attn"``,
+    ``"moe"``). Returns None for a model of identical layers, else one
+    dict a layer: the layer's index within each stack it reads
+    (``_LEAF_KIND``) — ``{"layer": l, "attn" | "conv": i, "dense" |
+    "moe": j}``, or ``{"layer": l, "ssm" | "attn" | "moe": i}``.
+    ``attn`` is also the layer's index in the page pool, ``conv`` and
+    ``ssm`` in the slot state, ``moe`` in the expert stacks. In a model
+    with WINDOW layers both kinds of attention read the ``attn``
+    stacks, and a layer's pool is its kind's: ``"kv"`` is a full
+    layer's index in the page pool, ``"ring"`` a window layer's in the
+    ring pool."""
     types = getattr(cfg, "layer_types", None)
     experts = bool(getattr(cfg, "num_experts", 0))
     n_dense = int(getattr(cfg, "num_dense_layers", 0) or 0) if experts else 0
@@ -581,10 +649,13 @@ def _layer_spec(cfg):
         return None
     types = tuple(types or ("full_attention",) * cfg.num_layers)
     spec, count = [], {}
+    windowed = "sliding_attention" in types
     for l, mixer in enumerate(types):
         kinds = (solo[l],) if solo is not None else (
             "conv" if mixer == "conv" else "attn",
             "moe" if experts and l >= n_dense else "dense")
+        if windowed and mixer != "conv":
+            kinds += ("ring" if mixer == "sliding_attention" else "kv",)
         at = {"layer": l}
         for kind in kinds:
             at[kind] = count[kind] = count.get(kind, -1) + 1
@@ -621,8 +692,8 @@ def _run_layers(layer_fn, x, blk_tree, caches, paged, spec=None):
     kind). ``aux`` comes back stacked, a key at a time, over the layers
     that returned that key."""
     if spec is not None:
-        lc = ({n: caches[n] for n in ("pool", "state") if n in caches}
-              if paged else caches)
+        lc = ({n: caches[n] for n in ("pool", "state", "ring")
+               if n in caches} if paged else caches)
         auxes = []
         for at in spec:
             # (tree_map: an int8 weight is an (int8, scale) pair)
@@ -773,7 +844,7 @@ def _ssm_mixer(blk, xx, state, layer, t, take, live, dims, eps, slots=None):
 
 def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
                    mesh=None, layer=None, qk_norm=False, indexer=None,
-                   plan=None):
+                   plan=None, window=None):
     """Shared llama-family attention sublayer for the decode loop:
     pre-RMSNorm, rope at absolute positions, GQA cache write + masked
     cached attention, output projection + residual. ``lc`` is this
@@ -786,10 +857,16 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
     sin))``): learned key selection — indexer queries ``blk["iq"]``,
     one shared key ``blk["ik"]`` (cached beside k and v) and head
     weights ``blk["iw"]`` pick the ``topk`` keys attention runs over.
-    ``tables`` None: no positional term (``nemotron_h``). Returns (xx,
-    lc, h2, kept) with h2 = the post-attention norm for the FFN (None
-    where the block has none) and kept [B, s] the keys the selection let
-    each row attend (None without an indexer)."""
+    ``tables`` None: no positional term (``nemotron_h``, ``afmoe``'s
+    full layers). ``window``: a WINDOW layer, whose keys are those of
+    its window (paged: ``lc`` and ``bt`` are the ring pool and its
+    table; dense: a mask). Two leaves a block may have (``afmoe``'s):
+    ``blk["ag"]``, a sigmoid gate on the attention output, ``(a *
+    sigmoid(h W_g)) W_o``; ``blk["pa"]``, an RMSNorm of the sublayer's
+    output before the residual. Returns (xx, lc, h2, kept) with h2 =
+    the pre-FFN norm for the FFN (None where the block has none) and
+    kept [B, s] the keys the selection let each row attend (None
+    without an indexer)."""
     b, s, nh, kvh, hd, scale = dims
     from ..ops.pallas import rope as rope_mod
     h = _rms(xx, blk["ln1"], eps)
@@ -815,7 +892,8 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
         select = (qi, _mm(h, blk["iw"]), ki, topk)
     if bt is not None:
         att, lc, kept = _paged_kv_step(lc, layer, q, k, v, bt, t, scale,
-                                       mesh=mesh, select=select, plan=plan)
+                                       mesh=mesh, select=select, plan=plan,
+                                       window=window)
     else:
         lc = _kv_write(lc, "k", k, t)
         lc = _kv_write(lc, "v", v, t)
@@ -831,8 +909,12 @@ def _rope_gqa_attn(blk, xx, lc, t, pos, dims, tables, eps, bt=None,
             rep = nh // kvh
             kk = jnp.repeat(kc, rep, axis=2) if rep > 1 else kc
             vv = jnp.repeat(vc, rep, axis=2) if rep > 1 else vc
-            att = _cached_attend(q, kk, vv, t, s, scale)
-    xx = xx + _mm(att.reshape(b, s, nh * hd), blk["wo"])
+            att = _cached_attend(q, kk, vv, t, s, scale, window)
+    att = att.reshape(b, s, nh * hd)
+    if "ag" in blk:
+        att = att * jax.nn.sigmoid(_mm(h, blk["ag"]))
+    out = _mm(att, blk["wo"])
+    xx = xx + (_rms(out, blk["pa"], eps) if "pa" in blk else out)
     # (a layer that is this sublayer alone has no second norm)
     h2 = _rms(xx, blk["ln2"], eps) if "ln2" in blk else None
     return xx, lc, h2, kept
@@ -879,7 +961,10 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
         P = tokens.shape[0]
         x = embed_tokens(tokens, t0)
         at = jnp.minimum(slots, caches["bt"].shape[0] - 1)
-        view = dict(caches, bt=caches["bt"][at])
+        # the per-slot tables: the block table, and a window model's
+        # ring table (``rbt``)
+        tables = [n for n in ("bt", "rbt") if n in caches]
+        view = dict(caches, **{n: caches[n][at] for n in tables})
         # a state TREE is viewed by its mixers, a layer at a time
         # (``_ssm_mixer``): the step is handed the whole of it and the
         # rows' slots
@@ -887,7 +972,7 @@ def _make_ragged_prefill_fn(step_fn, head_fn, embed_tokens):
         if "state" in caches and not own:
             view["state"] = caches["state"][:, at]
         out, new = step_fn(x, view, t0, take, *((slots,) if own else ()))
-        new = dict(new, bt=caches["bt"])
+        new = dict(new, **{n: caches[n] for n in tables})
         if "state" in caches and not own:
             new["state"] = caches["state"].at[:, slots].set(
                 new["state"], mode="drop")
@@ -933,7 +1018,7 @@ def _llama_family_weights(model, moe):
 
 def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                 cache_dtype=None, cache_backend="dense", page_size=None,
-                num_pages=None):
+                num_pages=None, window_rows=None):
     """(init_caches, embed_fn, step_fn, head_fn[, ragged]) for the
     llama family: pre-RMSNorm blocks, rope at absolute positions,
     GQA (kv heads cached unrepeated), SwiGLU. What a block has BESIDES
@@ -960,6 +1045,19 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
       positional term) or a latent expert layer (``latent_moe`` below:
       ``experts_held``, the share of the router's experts the stacks
       hold; ungated ``relu2`` experts; ``router_eps``).
+    - ``"sliding_attention"`` in ``layer_types`` (``afmoe``): WINDOW
+      layers (``sliding_window``), which see the keys of their window
+      only; paged, they hold a RING of pages a slot (``ring_pages`` of
+      ``window_rows``, the most rows one row of a launch writes; the
+      whole cache where the caller does not say) in a pool of their own
+      beside the full layers' page pool, and each kind has its own
+      kernel plan; the rotary embedding is the window layers' alone, so
+      the full layers carry no positional term. Block leaves ``ag`` (a sigmoid gate on the
+      attention output), ``pa`` / ``pm`` (norms of the attention's and
+      the FFN's output before each residual), ``xg`` / ``xu`` / ``xd``
+      (a shared SwiGLU expert beside the routed ones) are used where
+      the weights have them; ``embed_scale`` multiplies the embedding
+      (muP).
 
     ``cache_backend="paged"`` swaps the dense per-slot cache for a
     global page pool + per-slot block tables."""
@@ -994,6 +1092,9 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
     ssm_dims = getattr(cfg, "ssm_dims", None)
     # the share of the router's experts the stacks hold: (first, count)
     held = getattr(cfg, "experts_held", None)
+    # window layers beside full ones: each kind its own pool and plan
+    window = attention_window(cfg)
+    embed_scale = getattr(cfg, "embed_scale", None)
 
     ffn_dims = ({"wg": 1, "wu": 1, "wd": 1} if moe     # expert-parallel
                 else {"wg": 2, "wu": 2, "wd": 1})
@@ -1010,9 +1111,15 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                    rope_mod.precompute_freqs(int(dim), max_cache_len,
                                              cfg.rope_theta))
     dtype = p["table"].dtype
-    # the caches have a layer an ATTENTION layer, the slot state a layer
-    # a conv layer, the route read-back a layer an expert layer
+    # the caches have a layer an ATTENTION layer (paged, a FULL one: a
+    # window layer's is in its ring), the slot state a layer a conv
+    # layer, the route read-back a layer an expert layer
     L = cfg.num_layers if spec is None else n_of("attn")
+    ring = None
+    if window is not None and cache_backend == "paged":
+        L = n_of("kv")
+        ring = (n_of("ring"), ring_pages(
+            window, window_rows or max_cache_len, page_size))
     state = None
     if spec is not None and n_of("conv"):
         state = jax.ShapeDtypeStruct(
@@ -1038,7 +1145,7 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                 extra={"ki": (1, indexer[1])} if indexer else None,
                 route_k=top_k, kept=indexer is not None,
                 route_layers=None if spec is None else n_of("moe"),
-                state=state)
+                state=state, ring=ring)
         tree = _init_kv((L, batch, max_cache_len, kvh, hd), dtype,
                         cache_dtype,
                         index_dim=indexer[1] if indexer else None)
@@ -1061,6 +1168,8 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
 
     def _forward(x, caches, t, bt, take=None, slots=None):
         x = unwrap(x)
+        if embed_scale is not None:     # muP: the embedding, scaled
+            x = (x.astype(jnp.float32) * embed_scale).astype(x.dtype)
         b, s = x.shape[0], x.shape[1]
         # an idle slot's offset is parked past the table: its rows are
         # garbage nobody reads, rotated at the last real position
@@ -1073,30 +1182,50 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
         # the attention kernel's grid, once for every layer: a decode
         # step's live pages, a prefill launch's live query tiles and
         # theirs (key selection attends without either kernel)
-        plan = None
+        plan = wplan = None
         if paged and indexer is None:
             plan = (_paged_decode_plan(bt, t, page_size) if s == 1
                     else _paged_prefill_plan(bt, t, take, s, page_size))
+        if ring is not None:
+            # the window layers' own grid, through their ring's table
+            rbt = caches["rbt"]
+            wplan = (_paged_decode_plan(rbt, t, page_size, window)
+                     if s == 1 else _paged_prefill_plan(
+                         rbt, t, take, s, page_size, window))
         if state is not None:
             t_b = jnp.broadcast_to(t, (b,))
             rows_b = (jnp.full((b,), s, jnp.int32) if take is None
                       else take)
 
-        def attend(blk, xx, lc, l):
+        def attend(blk, xx, lc, at):
             """The attention sublayer over the carried caches ``lc``:
             the page pool (paged), a layer's dense caches (scanned), or
-            under a layer spec the whole dense tree, read at ``l``."""
+            under a layer spec the whole dense tree, read at the
+            layer's index ``at`` (the scan's, or from the spec). A
+            window layer reads its ring (paged) or masks its window
+            (dense); a full layer of a model with window layers carries
+            no positional term."""
+            if spec is None:
+                l, win = at, None
+            else:
+                l, win = at["attn"], window if "ring" in at else None
+            # a full layer beside window layers has no positional term
+            tab = None if window is not None and win is None else tables
             if paged:
+                leaf, table, i, p = (
+                    ("ring", rbt, at["ring"], wplan) if win is not None
+                    else ("pool", bt, l if spec is None else at.get("kv", l),
+                          plan))
                 xx, pool, h2, kept = _rope_gqa_attn(
-                    blk, xx, lc["pool"], t, pos, (b, s, nh, kvh, hd, scale),
-                    tables, eps, bt=bt, mesh=mesh, layer=l,
-                    qk_norm=qk_norm, indexer=indexer, plan=plan)
-                return xx, dict(lc, pool=pool), h2, kept
+                    blk, xx, lc[leaf], t, pos, (b, s, nh, kvh, hd, scale),
+                    tab, eps, bt=table, mesh=mesh, layer=i,
+                    qk_norm=qk_norm, indexer=indexer, plan=p, window=win)
+                return xx, dict(lc, **{leaf: pool}), h2, kept
             own = lc if spec is None else {n: lc[n][l] for n in lc
                                            if n != "state"}
             xx, own, h2, kept = _rope_gqa_attn(
                 blk, xx, own, t, pos, (b, s, nh, kvh, hd, scale),
-                tables, eps, qk_norm=qk_norm, indexer=indexer)
+                tab, eps, qk_norm=qk_norm, indexer=indexer, window=win)
             if spec is not None:
                 own = dict(lc, **{n: lc[n].at[l].set(own[n]) for n in own})
             return xx, own, h2, kept
@@ -1116,9 +1245,11 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                     eps)
                 lc = dict(lc, state=held_state)
                 h2 = _rms(xx, blk["ln2"], eps)
+            elif "wq" in blk and spec is not None and "ring" in at:
+                with jax.named_scope("window_attn"):
+                    xx, lc, h2, kept = attend(blk, xx, lc, at)
             elif "wq" in blk:
-                xx, lc, h2, kept = attend(blk, xx, lc,
-                                          at if spec is None else at["attn"])
+                xx, lc, h2, kept = attend(blk, xx, lc, at)
             else:       # a layer that is an FFN alone, behind its one norm
                 h2 = _rms(xx, blk["ln1"], eps)
             # what each slot's LAST row did, for the decode tick's
@@ -1126,12 +1257,16 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
             aux = {} if kept is None else {"kept": kept[:, -1]}
             if h2 is None:      # a layer that is a mixer alone
                 return xx, lc, aux
+            # the FFN's output, normed before the residual where the
+            # block has a post-FFN norm
+            post = ((lambda f: _rms(f, blk["pm"], eps)) if "pm" in blk
+                    else (lambda f: f))
             if "dg" in blk or not moe:
                 wg, wu, wd = (blk[n] for n in (
                     ("dg", "du", "dd") if "dg" in blk
                     else ("wg", "wu", "wd")))
-                return xx + _mm(jax.nn.silu(_mm(h2, wg)) * _mm(h2, wu),
-                                wd), lc, aux
+                return xx + post(_mm(jax.nn.silu(_mm(h2, wg))
+                                     * _mm(h2, wu), wd)), lc, aux
             kw = (dict(route_kw, bias=blk["rbias"])
                   if "rbias" in blk and use_bias else route_kw)
             if "ld" in blk:
@@ -1161,8 +1296,12 @@ def _make_llama_decode_fns(model, max_cache_len, weight_dtype=None, mesh=None,
                                    p["wd"],
                                    layer=at if spec is None else at["moe"],
                                    live=live)
+                if "xg" in blk:     # a shared expert beside the routed
+                    with jax.named_scope("shared_ffn"):
+                        y = y + _mm(jax.nn.silu(_mm(rows, blk["xg"]))
+                                    * _mm(rows, blk["xu"]), blk["xd"])
             aux["route"] = idx.reshape(b, s, top_k)[:, -1]
-            return xx + y.reshape(xx.shape), lc, aux
+            return xx + post(y.reshape(xx.shape)), lc, aux
 
         x, caches, aux = _run_layers(layer, x, blk_tree, caches, paged,
                                      spec)
@@ -1314,10 +1453,13 @@ class GenerationMixin:
 
     def _decode_bundle(self, max_cache_len, weight_dtype=None, mesh=None,
                        cache_dtype=None, cache_backend="dense",
-                       page_size=None, num_pages=None):
+                       page_size=None, num_pages=None, window_rows=None):
+        """The model's decode bundle (see below). ``window_rows``: for a
+        model with window layers, the most rows one row of a paged
+        launch writes, which sizes their ring (``ring_pages``)."""
         key = ("_pt_decode_bundle", max_cache_len, weight_dtype,
                None if mesh is None else id(mesh), cache_dtype,
-               cache_backend, page_size, num_pages)
+               cache_backend, page_size, num_pages, window_rows)
         cached = getattr(self, "_pt_decode_cache", None)
         if cached is None:
             cached = self._pt_decode_cache = {}
@@ -1326,6 +1468,8 @@ class GenerationMixin:
             return cached[key]
         kw = dict(cache_backend=cache_backend, page_size=page_size,
                   num_pages=num_pages)
+        if window_rows is not None:
+            kw["window_rows"] = window_rows
         # a model names its block family (``decode_family``); what its
         # blocks have beyond the family's plain form the builder reads
         # from the config

@@ -53,7 +53,7 @@ from . import on_tpu
 NEG_INF = -1e30
 
 __all__ = ["paged_attention", "decode_grid", "decode_schedule",
-           "prefill_grid", "prefill_schedule", "available"]
+           "prefill_grid", "prefill_schedule", "window_start", "available"]
 
 
 def available() -> bool:
@@ -63,20 +63,37 @@ def available() -> bool:
 # ----------------------------------------------------------------- kernel
 
 
-def decode_grid(lengths, page_size):
+def window_start(lengths, window):
+    """The first key position a query sees in a WINDOW layer, for a
+    query whose row holds ``lengths`` valid tokens (its position is
+    ``lengths - 1``): key ``j`` is visible to query ``p`` iff ``p - j <
+    window``, so the first is ``max(0, lengths - window)``. THE owner of
+    the window's edge: both kernels' grids, index maps, first pages and
+    masks, and the host's count of the keys a window layer attends, read
+    it here. Plain operators, for NumPy values, traced arrays and a
+    kernel's scalars alike."""
+    over = lengths - window
+    return over * (over > 0)
+
+
+def decode_grid(lengths, page_size, window=None):
     """``(pages, steps)`` of one decode call: the pages each slot's
     ``lengths`` valid tokens span ([slots]; 0 for a slot of length 0)
     and the steps the kernel's grid takes — their sum, the live pages,
     and one step where nothing is live (it finds length 0 and computes
-    nothing). Plain operators, so it counts NumPy lengths on the host
-    (the server's ``decode_grid_steps``) as it sizes the grid from
-    traced ones on the device: one owner of the count."""
+    nothing). With a ``window`` the span starts at the page of the
+    first key the window shows (``window_start``). Plain operators, so
+    it counts NumPy lengths on the host (the server's
+    ``decode_grid_steps``) as it sizes the grid from traced ones on the
+    device: one owner of the count."""
     pages = (lengths + (page_size - 1)) // page_size
+    if window is not None:
+        pages = pages - window_start(lengths, window) // page_size
     live = pages.sum()
     return pages, live + (live == 0)
 
 
-def decode_schedule(lengths, page_size, pages_per_slot):
+def decode_schedule(lengths, page_size, pages_per_slot, window=None):
     """``(entries, steps)``: what each step of the decode grid reads.
     ``entries[g]`` is the FLAT block-table index ``slot * pages_per_slot
     + page`` of step ``g < steps``, slot-major with a slot's pages in
@@ -85,15 +102,21 @@ def decode_schedule(lengths, page_size, pages_per_slot):
     block is visited once). ``entries`` has the static length ``slots *
     pages_per_slot``; past ``steps`` it holds valid indices nobody
     visits. A function of the lengths alone: every layer of a tick
-    attends the same lengths, so the tick computes it once."""
+    attends the same lengths, so the tick computes it once (once a
+    layer KIND: a ``window`` layer's run starts at its window's first
+    page, ``decode_grid``)."""
     slots = lengths.shape[0]
-    pages, steps = decode_grid(lengths.astype(jnp.int32), page_size)
+    lengths = lengths.astype(jnp.int32)
+    pages, steps = decode_grid(lengths, page_size, window)
     ends = jnp.cumsum(pages)
     g = jnp.arange(slots * pages_per_slot, dtype=jnp.int32)
     # the slot whose run [end - pages, end) holds g
     slot = jnp.minimum(jnp.sum(g[:, None] >= ends[None, :], axis=1),
                        slots - 1)
-    page = jnp.clip(g - (ends - pages)[slot], 0, pages_per_slot - 1)
+    page = g - (ends - pages)[slot]
+    if window is not None:
+        page = page + (window_start(lengths, window) // page_size)[slot]
+    page = jnp.clip(page, 0, pages_per_slot - 1)
     return ((slot * pages_per_slot + page).astype(jnp.int32),
             steps.astype(jnp.int32))
 
@@ -104,7 +127,8 @@ def decode_schedule(lengths, page_size, pages_per_slot):
 _INDEX_ENTRIES = 32768
 
 
-def prefill_grid(t0, take, width, tile, page_size, pages_per_slot):
+def prefill_grid(t0, take, width, tile, page_size, pages_per_slot,
+                 window=None):
     """``(pages, steps)`` of one prefill launch: the pages each (row,
     query tile) pair attends ([rows, tiles]; 0 for a pair that is not
     live) and the steps the kernel's grid takes — their sum, and one
@@ -114,10 +138,11 @@ def prefill_grid(t0, take, width, tile, page_size, pages_per_slot):
     its REAL rows of the ``width`` the launch carries. Tile ``i`` is
     live while ``i * tile < take`` and attends through its last row's
     page: the padding rows INSIDE a row's last live tile are computed
-    with it, a tile wholly past ``take`` is not. Plain operators, so it
-    counts NumPy values on the host (the server's
-    ``prefill_grid_steps``) as it sizes the grid from traced ones on
-    the device: one owner of the count."""
+    with it, a tile wholly past ``take`` is not. With a ``window`` a
+    pair attends from the page of the first key its FIRST row sees
+    (``window_start``). Plain operators, so it counts NumPy values on
+    the host (the server's ``prefill_grid_steps``) as it sizes the grid
+    from traced ones on the device: one owner of the count."""
     first = np.arange(0, width, tile, dtype=np.int32)          # [tiles]
     ends = np.minimum(first + tile, width)
     live = (t0 < page_size * pages_per_slot)[:, None] \
@@ -125,6 +150,10 @@ def prefill_grid(t0, take, width, tile, page_size, pages_per_slot):
     reach = (t0[:, None] + (ends[None, :] - 1)) // page_size + 1
     over = reach - pages_per_slot       # padding rows past the table
     pages = live * (reach - over * (over > 0))
+    if window is not None:
+        # the tile's first row holds t0 + first + 1 tokens
+        lo = window_start(t0[:, None] + first[None, :] + 1, window)
+        pages = pages - live * (lo // page_size)
     total = pages.sum()
     return pages, total + (total == 0)
 
@@ -135,7 +164,8 @@ def prefill_index_block(pairs, pages_per_slot):
     return -(-pairs * pages_per_slot // _INDEX_ENTRIES)
 
 
-def prefill_schedule(t0, take, width, tile, page_size, pages_per_slot):
+def prefill_schedule(t0, take, width, tile, page_size, pages_per_slot,
+                     window=None):
     """``(pair, bounds, index, steps)``: what each step of the prefill
     grid reads. The LIVE pairs of ``prefill_grid`` in row-major,
     tile-major order: ``pair[n]`` is the n-th's ``row * tiles + tile``
@@ -148,10 +178,12 @@ def prefill_schedule(t0, take, width, tile, page_size, pages_per_slot):
     tiles``, that plus one, and the launch's most steps over ``block``;
     past the live pairs they hold valid values nobody visits. A
     function of the launch's ``t0`` and ``take`` alone: every layer
-    attends the same chunks, so the launch computes it once."""
+    attends the same chunks, so the launch computes it once (once a
+    layer KIND: a ``window`` layer's pairs start at their window's
+    first page, which the kernel finds from ``t0`` as the grid does)."""
     pages, steps = prefill_grid(t0.astype(jnp.int32),
                                 take.astype(jnp.int32), width, tile,
-                                page_size, pages_per_slot)
+                                page_size, pages_per_slot, window)
     pages = pages.reshape(-1).astype(jnp.int32)
     pairs = pages.shape[0]
     n = jnp.arange(pairs, dtype=jnp.int32)
@@ -186,9 +218,11 @@ def prefill_step_pair(g, bounds_ref, index_ref, pairs, block):
 
 def _paged_attn_kernel(sched_ref, bt_ref, len_ref, layer_ref, q_ref, k_ref,
                        v_ref, o_ref, m_scr, l_scr, acc_scr, *, page_size,
-                       pages_per_slot, kv_heads, rep, sm_scale):
+                       pages_per_slot, kv_heads, rep, sm_scale, window=None):
     """Grid (steps,); step g attends page p of slot s, ``sched_ref[g] ==
-    s * pages_per_slot + p`` (``decode_schedule``).
+    s * pages_per_slot + p`` (``decode_schedule``). With a ``window``
+    a slot's run starts at the page of its window's first key, and keys
+    before it are masked (``window_start``).
 
     q_ref  [1, nh, hd]       slot s's query token
     k_ref  [1, 1, page_size, kvh*hd]  the page block_tables[s, p] points
@@ -203,8 +237,11 @@ def _paged_attn_kernel(sched_ref, bt_ref, len_ref, layer_ref, q_ref, k_ref,
     entry = sched_ref[pl.program_id(0)]
     s = entry // pages_per_slot
     p = entry % pages_per_slot
+    # the page a slot's run starts at
+    first = 0 if window is None else jax.lax.div(
+        window_start(len_ref[s], np.int32(window)), np.int32(page_size))
 
-    @pl.when(p == 0)
+    @pl.when(p == first)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -227,6 +264,8 @@ def _paged_attn_kernel(sched_ref, bt_ref, len_ref, layer_ref, q_ref, k_ref,
         col = p * page_size + jax.lax.broadcasted_iota(
             jnp.int32, (nh, page_size), 1)
         valid = col < length
+        if window is not None:      # the query at length - 1 sees
+            valid = valid & (col >= window_start(length, np.int32(window)))
 
         # per-kv-head-group contractions keep the MXU ops unbatched
         logits = []
@@ -280,13 +319,14 @@ def as_layered(k_pages, v_pages, layer):
 
 def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
                             sm_scale, interpret=False, layer=None,
-                            schedule=None):
+                            schedule=None, window=None):
     """q [S, nh, hd]; pages [L, P, pg, kvh*hd] read at ``layer``, or one
     layer's [P, pg, kvh, hd] (``as_layered``); block_tables [S, maxp]
     int32 (unused tail entries must hold any VALID page id, e.g. 0);
     lengths [S] int32; ``schedule`` what ``decode_schedule`` makes of
-    them (made here when the caller has none to share between layers).
-    Returns [S, nh, hd]."""
+    them (made here when the caller has none to share between layers);
+    ``window`` (static) the window of a window layer, None for a full
+    one. Returns [S, nh, hd]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -302,12 +342,13 @@ def _paged_attention_pallas(q, k_pages, v_pages, block_tables, lengths,
 
     lengths = lengths.astype(jnp.int32)
     if schedule is None:
-        schedule = decode_schedule(lengths, pg, maxp)
+        schedule = decode_schedule(lengths, pg, maxp, window)
     entries, steps = schedule
     flat_bt = block_tables.reshape(-1).astype(jnp.int32)
     kernel = functools.partial(
         _paged_attn_kernel, page_size=pg, pages_per_slot=maxp,
-        kv_heads=kvh, rep=rep, sm_scale=sm_scale)
+        kv_heads=kvh, rep=rep, sm_scale=sm_scale,
+        window=window)
 
     def row(g, sched, bt, ln, l):
         return (sched[g] // maxp, 0, 0)
@@ -364,7 +405,7 @@ def kv_head_shards(mesh, num_kv_heads, num_heads=None, axis="mp"):
 
 def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths,
                              layer, schedule, sm_scale, mesh, axis,
-                             interpret):
+                             interpret, window=None):
     """Per-shard Pallas launches over the mesh's ``axis``: the page
     pools arrive sharded on their merged kv-head axis (contiguous
     blocks of whole heads), q splits into the matching query-head
@@ -385,7 +426,7 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths,
     def fn(q, k_pages, v_pages, block_tables, lengths, layer, schedule):
         return _paged_attention_pallas(q, k_pages, v_pages, block_tables,
                                        lengths, sm_scale, interpret, layer,
-                                       schedule)
+                                       schedule, window)
 
     pool = P(None, None, None, axis)
     return jax.shard_map(
@@ -400,13 +441,14 @@ def _paged_attention_sharded(q, k_pages, v_pages, block_tables, lengths,
 
 
 def _ref_paged_attention(q, k_pages, v_pages, block_tables, lengths,
-                         sm_scale, layer=None):
+                         sm_scale, layer=None, window=None):
     """Gather-through-block-table reference. Mirrors the dense decode
     attention (`generation._cached_attend` at s=1) op-for-op so the paged
     server emits bit-identical tokens to the dense backend on every
     platform: valid positions carry the exact cached values, positions at
-    or beyond ``lengths`` are masked to -1e30 before the same f32 softmax
-    (contributing exactly 0.0), and the einsum specs match."""
+    or beyond ``lengths`` (and, with a ``window``, before its first key)
+    are masked to -1e30 before the same f32 softmax (contributing
+    exactly 0.0), and the einsum specs match."""
     k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
     S, nh, hd = q.shape
     _, P, pg, width = k_pages.shape
@@ -423,6 +465,9 @@ def _ref_paged_attention(q, k_pages, v_pages, block_tables, lengths,
     logits = jnp.einsum("bsnd,btnd->bnst", qb, k) * sm_scale
     pos = jnp.arange(T)
     ok = pos[None, None] < lengths[:, None, None]          # [S, 1, T]
+    if window is not None:
+        ok = ok & (pos[None, None]
+                   >= window_start(lengths, window)[:, None, None])
     logits = jnp.where(ok[:, None], logits.astype(jnp.float32), -1e30)
     p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bnst,btnd->bsnd", p, v)[:, 0]
@@ -433,7 +478,7 @@ def _ref_paged_attention(q, k_pages, v_pages, block_tables, lengths,
 
 def paged_attention(q, k_pages, v_pages, block_tables, lengths,
                     sm_scale=None, interpret=False, mesh=None, layer=None,
-                    schedule=None):
+                    schedule=None, window=None):
     """Ragged paged-attention decode step.
 
     q            [slots, num_heads, head_dim]   one query token per slot
@@ -449,9 +494,15 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
                  order; entries past a slot's allocation must hold a
                  valid id (the manager fills them with 0)
     lengths      [slots] int32  valid KV tokens per slot (ragged)
-    schedule     ``decode_schedule(lengths, page_size, pages_per_slot)``
-                 where the caller already has it — the serving tick
-                 makes it once for all its layers; made here otherwise
+    schedule     ``decode_schedule(lengths, page_size, pages_per_slot,
+                 window)`` where the caller already has it — the serving
+                 tick makes it once for all its layers of a kind; made
+                 here otherwise
+    window       None (a full layer), or a WINDOW layer's window: the
+                 query at position ``lengths - 1`` sees key ``j`` iff
+                 ``lengths - 1 - j < window``, and the grid starts at
+                 the page of the first such key (``window_start``); the
+                 table may then be a ring's (entries repeat)
     mesh         optional ``jax.sharding.Mesh`` whose ``mp`` axis the
                  page pools are sharded over on their kv-head axis
                  (sharded paged serving): the Pallas path then runs one
@@ -472,16 +523,17 @@ def paged_attention(q, k_pages, v_pages, block_tables, lengths,
     if available() or interpret:
         if schedule is None:
             schedule = decode_schedule(lengths, k_pages.shape[2],
-                                       block_tables.shape[1])
+                                       block_tables.shape[1], window)
         if mesh is not None:
             out = _paged_attention_sharded(
                 q, k_pages, v_pages, block_tables, lengths, layer,
-                schedule, sm_scale, mesh, "mp", interpret)
+                schedule, sm_scale, mesh, "mp", interpret, window)
             if out is not None:
                 return out
         return _paged_attention_pallas(q, k_pages, v_pages, block_tables,
                                        lengths, sm_scale,
                                        interpret=interpret, layer=layer,
-                                       schedule=schedule)
+                                       schedule=schedule, window=window)
     return _ref_paged_attention(q, k_pages, v_pages, block_tables,
-                                lengths, sm_scale, layer=layer)
+                                lengths, sm_scale, layer=layer,
+                                window=window)

@@ -60,7 +60,8 @@ import numpy as np
 from . import on_tpu
 from .paged_attention import (NEG_INF, as_layered, kv_head_shards,
                               prefill_grid, prefill_index_block,
-                              prefill_schedule, prefill_step_pair)
+                              prefill_schedule, prefill_step_pair,
+                              window_start)
 
 __all__ = ["ragged_prefill_attention", "QUERY_TILE", "available"]
 
@@ -79,9 +80,12 @@ def available() -> bool:
 def _ragged_prefill_kernel(pair_ref, bounds_ref, index_ref, bt_ref, t0_ref,
                            layer_ref, q_ref, k_ref, v_ref, o_ref, m_scr,
                            l_scr, acc_scr, *, page_size, tile, tiles, pairs,
-                           block, kv_heads, rep, sm_scale):
+                           block, kv_heads, rep, sm_scale, window=None):
     """Grid (steps,); step g attends one page of one live (row, query
-    tile) pair, found through the schedule (``prefill_schedule``).
+    tile) pair, found through the schedule (``prefill_schedule``). With
+    a ``window`` a pair's run starts at the page of the first key its
+    first row sees, and keys outside a row's window are masked
+    (``window_start``).
 
     q_ref  [1, tile, nh, hd]        the pair's query rows
     k_ref  [1, 1, page_size, kvh*hd]  the page the row's block table
@@ -110,6 +114,9 @@ def _ragged_prefill_kernel(pair_ref, bounds_ref, index_ref, bt_ref, t0_ref,
     at = pair_ref[n]
     t0 = (t0_ref[jax.lax.div(at, np.int32(tiles))]
           + jax.lax.rem(at, np.int32(tiles)) * tile)
+    if window is not None:      # the page the pair's run starts at
+        p = p + jax.lax.div(window_start(t0 + 1, np.int32(window)),
+                            np.int32(page_size))
 
     # a scheduled step is a page some row of its tile attends; only the
     # lone step of a launch with nothing live (an empty run) computes
@@ -142,6 +149,9 @@ def _ragged_prefill_kernel(pair_ref, bounds_ref, index_ref, bt_ref, t0_ref,
         row = jax.lax.broadcasted_iota(
             jnp.int32, (tile * nh, page_size), 0) // nh
         valid = col <= t0 + row
+        if window is not None:      # row c holds t0 + c + 1 tokens
+            valid = valid & (col >= window_start(t0 + row + 1,
+                                                 np.int32(window)))
         s_log = jnp.where(valid, s_log, NEG_INF)
 
         m_cur = jnp.max(s_log, axis=-1, keepdims=True)   # [tile*nh, 1]
@@ -175,13 +185,14 @@ def _ragged_prefill_kernel(pair_ref, bounds_ref, index_ref, bt_ref, t0_ref,
 
 def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, take,
                            sm_scale, interpret=False, layer=None,
-                           schedule=None):
+                           schedule=None, window=None):
     """q [S, C, nh, hd]; pages [L, P, pg, kvh*hd] read at ``layer``, or
     one layer's [P, pg, kvh, hd] (``as_layered``); block_tables
     [S, maxp] int32 (unused tail entries must hold any VALID page id,
     e.g. 0); t0/take [S] int32; ``schedule`` what ``prefill_schedule``
     makes of them (made here when the caller has none to share between
-    layers). Returns [S, C, nh, hd]."""
+    layers); ``window`` (static) a window layer's window, None for a
+    full one. Returns [S, C, nh, hd]."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -198,7 +209,7 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, take,
     tile = QUERY_TILE
     t0, take = t0.astype(jnp.int32), take.astype(jnp.int32)
     if schedule is None:
-        schedule = prefill_schedule(t0, take, C, tile, pg, maxp)
+        schedule = prefill_schedule(t0, take, C, tile, pg, maxp, window)
     pair, bounds, index, steps = schedule
     tiles = -(-C // tile)
     pairs = S * tiles
@@ -209,7 +220,8 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, take,
     flat_bt = block_tables.reshape(-1).astype(jnp.int32)
     kernel = functools.partial(
         _ragged_prefill_kernel, page_size=pg, tile=tile, tiles=tiles,
-        pairs=pairs, block=block, kv_heads=kvh, rep=rep, sm_scale=sm_scale)
+        pairs=pairs, block=block, kv_heads=kvh, rep=rep, sm_scale=sm_scale,
+        window=window)
 
     # truncating lax.div / lax.rem (the operands are non-negative): ``//``
     # and ``%`` are a dozen scalar operations each, in every index map
@@ -221,7 +233,14 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, take,
 
     def page(g, pr, bd, ix, bt, t0_, l):
         n = prefill_step_pair(g, bd, ix, pairs, block)
-        p = jax.lax.min(g - bd[n], np.int32(maxp - 1))
+        p = g - bd[n]
+        if window is not None:      # the run starts at the window's page
+            at = pr[n]
+            first = (t0_[jax.lax.div(at, ntiles)]
+                     + jax.lax.rem(at, ntiles) * np.int32(tile))
+            p = p + jax.lax.div(window_start(first + 1, np.int32(window)),
+                                np.int32(pg))
+        p = jax.lax.min(p, np.int32(maxp - 1))
         return (l[0], bt[jax.lax.div(pr[n], ntiles) * maxp + p], 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -254,7 +273,8 @@ def _ragged_prefill_pallas(q, k_pages, v_pages, block_tables, t0, take,
 def _visited(out, t0, take, page_size, pages_per_slot):
     """``out`` [S, C, nh, hd] with the rows of every (row, tile) pair
     that has no grid step (``prefill_grid``) read as 0: nothing wrote
-    the kernel's output block there."""
+    the kernel's output block there. A window leaves every live pair at
+    least its last row's page, so it changes no pair's liveness."""
     C = out.shape[1]
     pages, _ = prefill_grid(t0, take, C, QUERY_TILE, page_size,
                             pages_per_slot)
@@ -267,7 +287,7 @@ def _visited(out, t0, take, page_size, pages_per_slot):
 
 def _ragged_prefill_sharded(q, k_pages, v_pages, block_tables, t0, take,
                             layer, schedule, sm_scale, mesh, axis,
-                            interpret):
+                            interpret, window=None):
     """Per-shard Pallas launches over the mesh's ``axis`` (sharded
     paged serving): pools sharded on their merged kv-head axis, q split
     into the matching query-head groups (head axis 2 of
@@ -285,7 +305,7 @@ def _ragged_prefill_sharded(q, k_pages, v_pages, block_tables, t0, take,
     def fn(q, k_pages, v_pages, block_tables, t0, take, layer, schedule):
         return _ragged_prefill_pallas(q, k_pages, v_pages, block_tables,
                                       t0, take, sm_scale, interpret, layer,
-                                      schedule)
+                                      schedule, window)
 
     pool = P(None, None, None, axis)
     return jax.shard_map(
@@ -301,14 +321,15 @@ def _ragged_prefill_sharded(q, k_pages, v_pages, block_tables, t0, take,
 
 
 def _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0, sm_scale,
-                        layer=None):
+                        layer=None, window=None):
     """Gather-through-block-table reference. Mirrors the dense prefill
     attention (``generation._cached_attend``) op-for-op so the ragged
     prefill path emits BIT-IDENTICAL cache rows and logits to the dense
     batch-1 prefill on every platform: valid positions carry the exact
-    cached values, positions beyond a row's causal frontier are masked
-    to -1e30 before the same f32 softmax (contributing exactly 0.0),
-    and the einsum specs match."""
+    cached values, positions beyond a row's causal frontier (and, with
+    a ``window``, before its window) are masked to -1e30 before the same
+    f32 softmax (contributing exactly 0.0), and the einsum specs
+    match."""
     k_pages, v_pages, layer = as_layered(k_pages, v_pages, layer)
     S, C, nh, hd = q.shape
     _, P, pg, width = k_pages.shape
@@ -325,6 +346,8 @@ def _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0, sm_scale,
     pos = jnp.arange(T)
     row = t0[:, None] + jnp.arange(C, dtype=jnp.int32)[None]   # [S, C]
     ok = pos[None, None] <= row[:, :, None]                    # [S, C, T]
+    if window is not None:
+        ok = ok & (pos[None, None] >= window_start(row + 1, window)[..., None])
     logits = jnp.where(ok[:, None], logits.astype(jnp.float32), -1e30)
     p = jax.nn.softmax(logits, axis=-1).astype(q.dtype)
     return jnp.einsum("bnst,btnd->bsnd", p, v)
@@ -335,7 +358,8 @@ def _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0, sm_scale,
 
 def ragged_prefill_attention(q, k_pages, v_pages, block_tables, t0,
                              take=None, sm_scale=None, interpret=False,
-                             mesh=None, layer=None, schedule=None):
+                             mesh=None, layer=None, schedule=None,
+                             window=None):
     """Ragged packed-prefill attention over paged KV.
 
     q            [slots, chunk, num_heads, head_dim]  packed prompt
@@ -364,6 +388,11 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_tables, t0,
                  pages_per_slot)`` where the caller already has it — a
                  launch makes it once for all its layers; made here
                  otherwise
+    window       None (a full layer), or a WINDOW layer's window: row c
+                 of slot s sees key ``j`` iff ``t0[s] + c - j < window``
+                 (``window_start``), and a (row, tile) pair's grid run
+                 starts at the page of its first row's first key; the
+                 table may then be a ring's (entries repeat)
 
     Row c of slot s attends to key positions <= t0[s] + c; the rows of
     a query tile that holds no real row (``prefill_grid``) are 0.
@@ -384,19 +413,20 @@ def ragged_prefill_attention(q, k_pages, v_pages, block_tables, t0,
     if available() or interpret:
         if schedule is None:
             schedule = prefill_schedule(t0, take, q.shape[1], QUERY_TILE,
-                                        pg, maxp)
+                                        pg, maxp, window)
         out = None
         if mesh is not None:
             out = _ragged_prefill_sharded(
                 q, k_pages, v_pages, block_tables, t0, take, layer,
-                schedule, sm_scale, mesh, "mp", interpret)
+                schedule, sm_scale, mesh, "mp", interpret, window)
         if out is None:
             out = _ragged_prefill_pallas(
                 q, k_pages, v_pages, block_tables, t0, take, sm_scale,
-                interpret=interpret, layer=layer, schedule=schedule)
+                interpret=interpret, layer=layer, schedule=schedule,
+                window=window)
     else:
         out = _ref_ragged_prefill(q, k_pages, v_pages, block_tables, t0,
-                                  sm_scale, layer=layer)
+                                  sm_scale, layer=layer, window=window)
     # platform-consistent skip semantics: the kernel never writes the
     # rows of a pair it has no step for; zero the same rows of the
     # fallback so the two match there too

@@ -551,6 +551,10 @@ class ServerTelemetry:
             "the learned selection", labelnames=("kind",))
         self._c_keys_context = keys.labels(kind="context")
         self._c_keys_selected = keys.labels(kind="selected")
+        # a model's WINDOW layers: keys in the contexts of live decode
+        # rows there, and those their windows attend
+        self._c_keys_window = keys.labels(kind="window_context")
+        self._c_keys_window_seen = keys.labels(kind="window_attended")
         # reliability signals (paddle_tpu.reliability): admission
         # control, supervised-loop retries, breaker, health
         shed = r.counter("server_shed_total",
@@ -853,6 +857,13 @@ class ServerTelemetry:
         if self.enabled:
             self._c_keys_context.inc(context)
             self._c_keys_selected.inc(selected)
+
+    def on_window_keys(self, context, attended):
+        """A decode tick's live rows in the window layers: keys in
+        context, keys the windows attend."""
+        if self.enabled:
+            self._c_keys_window.inc(context)
+            self._c_keys_window_seen.inc(attended)
 
     def set_queue_depth(self, n):
         if self.enabled:
